@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import os
 
 import jax
 import jax.numpy as jnp
@@ -53,11 +54,30 @@ def _wrap(fn, kind):
     return wrapped
 
 
+def _wrap_bernoulli(fn):
+    """`bernoulli` has no dtype argument: it draws uniforms in the dtype
+    of `p`, and under x64 a Python-float `p` is a float64 — every
+    Dropout then draws 64-bit random bits and compares in f64, which a
+    TPU emulates.  A Python-float `p` takes the creation default (32-bit)
+    instead; an explicit array `p` (any width) passes through."""
+    @functools.wraps(fn)
+    def wrapped(key, p=0.5, *args, **kwargs):
+        if isinstance(p, float):
+            from .numpy_extension import default_float_dtype
+
+            p = jnp.asarray(p, default_float_dtype())
+        return fn(key, p, *args, **kwargs)
+
+    wrapped.__wrapped_32bit_default__ = True
+    return wrapped
+
+
 def install():
     global _applied
     if _applied:
         return
     _applied = True
+    jax.random.bernoulli = _wrap_bernoulli(jax.random.bernoulli)
     for name in _FLOAT_SAMPLERS:
         fn = getattr(jax.random, name, None)
         if fn is not None and not getattr(fn, "__wrapped_32bit_default__",
@@ -68,3 +88,25 @@ def install():
         if fn is not None and not getattr(fn, "__wrapped_32bit_default__",
                                           False):
             setattr(jax.random, name, _wrap(fn, "int"))
+
+
+def place_compile_cache():
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    Every chip call starts on a fresh machine and a whole-step program
+    takes minutes to compile, so the cache must survive the process —
+    and its path is part of the cache key, so it must not move (no
+    tempfile, pid or timestamp).  Placed from outside with
+    ``JAX_COMPILATION_CACHE_DIR`` (JAX reads the variable itself and
+    nothing is set here); otherwise ``<checkout>/.jax_cache``, next to
+    the package.  Returns the directory in effect."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+        # JAX decides once, at its first compile, whether the cache is in
+        # use; if something compiled before this import, start it over
+        compilation_cache.reset_cache()
+    return jax.config.jax_compilation_cache_dir

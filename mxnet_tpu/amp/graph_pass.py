@@ -90,9 +90,9 @@ def amp_rewrite(closed_jaxpr, target_dtype=jnp.bfloat16, stats=None):
         elif name in FP32_PRIMS:
             plan.append("fp32")
             stats.fp32_pinned_ops += 1
-        elif name in ("pjit", "closed_call", "custom_jvp_call",
-                      "custom_vjp_call", "custom_vjp_call_jaxpr",
-                      "remat2", "checkpoint", "convert_element_type"):
+        elif name in ("jit", "closed_call", "custom_jvp_call",
+                      "custom_vjp_call", "remat2", "checkpoint",
+                      "convert_element_type"):
             plan.append("exact")  # opaque bodies / explicit user casts
         else:
             plan.append("widest")

@@ -18,6 +18,8 @@ import warnings
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Device", "Context", "cpu", "gpu", "tpu", "cpu_pinned", "num_gpus",
            "num_tpus", "current_device", "default_device"]
 
@@ -26,9 +28,8 @@ _DEVTYPE_ALIASES = {
     "cpu_shared": "cpu",
 }
 
-# Accelerator device types: resolve to the default-backend accelerator. 'gpu' is
-# accepted for reference-API compatibility and resolves to the accelerator
-# backend actually present (tpu here).
+# Accelerator device types: all resolve to the TPU. 'gpu'/'cuda' are accepted
+# for reference-API compatibility (models written `ctx=mx.gpu(0)`).
 _ACCEL_TYPES = ("tpu", "gpu", "cuda")
 
 
@@ -72,36 +73,35 @@ class Device:
     def jax_device(self):
         """Resolve to a concrete jax (PJRT) device.
 
-        If the requested platform is absent (e.g. `tpu(0)` in a CPU-mesh test
-        run), fall back to the default backend's devices so code written for
-        TPU runs anywhere; warn once per platform.
+        An accelerator type resolves to a TPU or raises: a training run
+        that asked for the chip must not continue on the host.  The one
+        exception is a process explicitly pinned to the CPU
+        (``JAX_PLATFORMS=cpu``, as the test suite is), where code written
+        for `mx.tpu(i)` runs on the CPU devices, with one warning.
         """
         # NB: local_devices, not jax.devices() — under jax.distributed the
         # global list spans all processes and devices of other ranks are
         # non-addressable; mx.cpu(0)/mx.tpu(0) always mean THIS process's
         # devices (the reference's per-worker ctx semantics).
         dt = self.device_type
-        if dt in _ACCEL_TYPES:
+        if dt not in _ACCEL_TYPES:
+            devs = jax.local_devices(backend=dt)
+        elif jax.config.jax_platforms == "cpu":
+            if dt not in Device._warned_fallback:
+                Device._warned_fallback.add(dt)
+                warnings.warn(
+                    f"process is pinned to the CPU (JAX_PLATFORMS=cpu); "
+                    f"device type '{dt}' resolves to CPU devices",
+                    stacklevel=2,
+                )
+            devs = jax.local_devices()
+        else:
             try:
                 devs = jax.local_devices(backend="tpu")
-            except RuntimeError:
-                devs = None
-            if not devs:
-                try:
-                    devs = jax.local_devices(backend="gpu")
-                except RuntimeError:
-                    devs = None
-            if not devs:
-                if dt not in Device._warned_fallback:
-                    Device._warned_fallback.add(dt)
-                    warnings.warn(
-                        f"device type '{dt}' not available; falling back to "
-                        f"default backend '{jax.default_backend()}'",
-                        stacklevel=2,
-                    )
-                devs = jax.local_devices()
-        else:
-            devs = jax.local_devices(backend=dt)
+            except RuntimeError as e:
+                raise MXNetError(
+                    f"{self!r} asked for a TPU and none is available: {e}"
+                ) from e
         return devs[self.device_id % len(devs)]
 
     # -- default-device stack --------------------------------------------
@@ -145,10 +145,6 @@ def _accel_count():
     try:
         return len(jax.devices("tpu"))
     except RuntimeError:
-        pass
-    try:
-        return len(jax.devices("gpu"))
-    except RuntimeError:
         return 0
 
 
@@ -178,13 +174,12 @@ def default_device():
     global _default
     if _default is None:
         backend = jax.default_backend()
-        _default = Device("tpu" if backend in ("tpu", "gpu") else "cpu", 0)
+        _default = Device("tpu" if backend == "tpu" else "cpu", 0)
     return _default
 
 
 def from_jax_device(d):
     """Map a concrete jax device back to a Device descriptor."""
-    plat = d.platform
-    if plat in ("tpu", "gpu"):
+    if d.platform == "tpu":
         return Device("tpu", d.id)
     return Device("cpu", d.id)

@@ -49,14 +49,6 @@ def capture_enabled():
         return os.environ.get("MXTPU_DIAG_COMPILE", "1") != "0"
 
 
-def _first_dict(analysis):
-    """cost_analysis() is a dict on some jax versions, a 1-elem list of
-    dicts on others (0.4.x AOT path); normalize to a dict."""
-    if isinstance(analysis, (list, tuple)):
-        return dict(analysis[0]) if analysis else {}
-    return dict(analysis) if analysis else {}
-
-
 def capture_compile(block, variant, jitted, args, kwargs=None,
                     compile_seconds=None):
     """AOT-compile ``jitted`` for ``args`` and record its cost/memory
@@ -77,7 +69,8 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
     try:
         lowered = jitted.lower(*args, **(kwargs or {}))
         compiled = lowered.compile()
-        cost = _first_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
+        text = compiled.as_text()
         entry = {
             "block": str(block), "variant": str(variant),
             "flops": float(cost.get("flops", 0.0) or 0.0),
@@ -85,6 +78,14 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
             "transcendentals": float(
                 cost.get("transcendentals", 0.0) or 0.0),
             "compile_seconds": compile_seconds,
+            # Mosaic (Pallas) kernels in the optimized program: 0 on a
+            # program that was meant to carry a kernel means it took the
+            # XLA twin — the registry is where that shows
+            "tpu_custom_calls": text.count(
+                'custom_call_target="tpu_custom_call"'),
+            # cross-device sums the partitioner / shard_map put in
+            "all_reduces": (text.count(" all-reduce(")
+                            + text.count(" all-reduce-start(")),
         }
         try:
             mem = compiled.memory_analysis()

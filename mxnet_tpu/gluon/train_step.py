@@ -418,10 +418,16 @@ class TrainStep:
             else:
                 from jax.sharding import PartitionSpec as P
 
-                from ..parallel.collectives import (psum_tree_flat_traced,
-                                                    shard_map)
+                from ..parallel.collectives import psum_tree_flat_traced
 
                 def sharded(tws_, frozen_, key_, *ins):
+                    # params enter replicated; differentiate a VARYING
+                    # view of them so the vjp returns per-shard sums —
+                    # the cotangent of an unvarying primal is psum'd by
+                    # the transpose itself, and the explicit bucketed
+                    # psum below would then count each shard n_shards
+                    # times
+                    tws_ = jax.lax.pcast(tws_, axis, to="varying")
                     loss_d, gd_, aux_ = fwd_bwd(tws_, frozen_, key_, ins)
                     if loss_d.ndim == 0:
                         raise ValueError(
@@ -441,7 +447,7 @@ class TrainStep:
                         lambda v: jax.lax.psum(v, axis) / n_shards, aux_)
                     return loss_d, gd_, aux_
 
-                sm = shard_map(
+                sm = jax.shard_map(
                     sharded, mesh=mesh,
                     in_specs=(P(), P(), P(),
                               *([P(axis)] * len(inputs))),

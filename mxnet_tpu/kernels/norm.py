@@ -20,7 +20,8 @@ blocked reduction order differs from XLA's.
 
 `bn_train` is the drop-in custom_vjp twin of `_bn_train`: same
 signature, same residuals, same (dx, dgamma, dbeta, 0·shift) cotangent
-contract.  Unsupported shape/dtype (C % 128, rows % 8, non-float) falls back to
+contract.  Unsupported shape/dtype (C not 64 or a multiple of 128, rows % 8,
+non-float) falls back to
 the exact XLA implementation inside the same wrapper, recording the
 outcome via kernels.dispatch; a channel-axis-not-last site that would
 otherwise qualify records "channels_first" — the LayoutPass
@@ -54,9 +55,16 @@ def _block_rows(m, c):
     return 8
 
 
+def _lanes_ok(c):
+    """Channel counts the (bm, C) blocks tile on: whole 128-lane vregs,
+    or ResNet's 64 (a half-filled lane dim the compiler accepts because
+    the block spans the array's full last dim)."""
+    return c > 0 and (c % 128 == 0 or c == 64)
+
+
 def _supported(x, axis):
     """None when the kernel pair can run on this site, else the fallback
-    outcome name (the docs/kernels.md taxonomy).
+    outcome name (the docs/kernels.md fallback table).
 
     "channels_first" singles out the sites where ONLY the layout — not
     the size or dtype — blocks the kernel: the same tensor with its
@@ -69,13 +77,13 @@ def _supported(x, axis):
     if axis != x.ndim - 1:
         c = x.shape[axis] if 0 <= axis < x.ndim else 0
         m = x.size // c if c else 0
-        if (c and c % 128 == 0 and c <= 8192 and m >= 8 and m % 8 == 0
+        if (_lanes_ok(c) and c <= 8192 and m >= 8 and m % 8 == 0
                 and x.dtype in (jnp.float32, jnp.bfloat16)):
             return "channels_first"
         return "unsupported_shape"
     c = x.shape[-1]
     m = x.size // c if c else 0
-    if c == 0 or c % 128 or c > 8192 or m < 8 or m % 8:
+    if not _lanes_ok(c) or c > 8192 or m < 8 or m % 8:
         return "unsupported_shape"
     if x.dtype not in (jnp.float32, jnp.bfloat16):
         return "unsupported_dtype"
@@ -167,7 +175,7 @@ def _fwd_pallas(x, gamma, beta, shift, eps):
     ew = _nn()._bn_ew_dtype(x)
     bm = _block_rows(m, c)
     row = pl.BlockSpec((1, c), lambda p, i: (0, 0))
-    out, mean, var, inv = pl.pallas_call(
+    out, mean, var, inv = _dispatch.pallas_call(
         functools.partial(_fwd_kernel, ew=ew, n=m, eps=eps),
         grid=(2, m // bm),
         in_specs=[
@@ -260,7 +268,7 @@ def _bwd_pallas(x, gamma, shift, mean, inv, dy, dmean_ct, dvar_ct):
     bm = _block_rows(m, c)
     row = pl.BlockSpec((1, c), lambda p, i: (0, 0))
     big = pl.BlockSpec((bm, c), lambda p, i: (i, 0))
-    dx, dgamma, dbeta = pl.pallas_call(
+    dx, dgamma, dbeta = _dispatch.pallas_call(
         functools.partial(_bwd_kernel, ew=ew, n=m),
         grid=(2, m // bm),
         in_specs=[big, big, row, row, row, row, row, row],

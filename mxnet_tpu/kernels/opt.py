@@ -96,12 +96,14 @@ def _ladder_kernel(scal_ref, w_ref, g_ref, *refs, rule, clip, gn, mp,
                    n_state, hyper_keys, treedef, out_w_dtype):
     state_refs = refs[:n_state]
     outs = refs[n_state:]
-    lr = scal_ref[0]
-    wd = scal_ref[1]
-    t = scal_ref[2]
-    rescale = scal_ref[3]
-    gscale = scal_ref[4]
-    h = {k: scal_ref[5 + j] for j, k in enumerate(hyper_keys)}
+    # hyperparameters enter the rule as (1, 1) vectors, not SMEM scalars:
+    # a rule may do more than multiply with them (Adam's `beta ** t`), and
+    # Mosaic legalizes such math on vectors only
+    def vec(i):
+        return jnp.full((1, 1), scal_ref[i], jnp.float32)
+
+    lr, wd, t, rescale, gscale = (vec(i) for i in range(5))
+    h = {k: vec(5 + j) for j, k in enumerate(hyper_keys)}
     h["t"] = t
     h["rescale_grad"] = rescale
     g = g_ref[...]
@@ -173,7 +175,7 @@ def _ladder_pallas(cls, clip, gn, mp, w, st, g, lr, wd, t, scale, hyper):
         _ladder_kernel,
         rule=cls._rule, clip=clip, gn=gn, mp=mp, n_state=n_state,
         hyper_keys=hyper_keys, treedef=treedef, out_w_dtype=w.dtype)
-    outs = pl.pallas_call(
+    outs = _dispatch.pallas_call(
         kernel,
         grid=(m // bm,),
         in_specs=[smem, big, big] + [big] * n_state,

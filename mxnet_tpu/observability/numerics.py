@@ -23,7 +23,7 @@ must live IN the compiled program. :class:`NumericsPass` is a graph pass
 
 On a tripped ``step`` check the owner re-runs the recorded program
 through :func:`bisect` — an eager, eqn-by-eqn walk reusing
-``subgraph._eval_eqn`` that descends into pjit/remat/custom-call bodies
+``subgraph._eval_eqn`` that descends into jit/remat/custom-call bodies
 and stops at the FIRST equation producing a non-finite value, reporting
 op name, output shapes/dtypes, which operand was already non-finite,
 and per-operand stats. The report lands in the postmortem bundle
@@ -324,9 +324,8 @@ def _instrument_per_eqn(closed, label):
 # the bisect interpreter (postmortem attribution for step mode)
 # ---------------------------------------------------------------------------
 
-_CALL_PRIMS = ("pjit", "closed_call", "remat2", "checkpoint")
-_CUSTOM_PRIMS = ("custom_jvp_call", "custom_vjp_call",
-                 "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr")
+_CALL_PRIMS = ("jit", "closed_call", "remat2", "checkpoint")
+_CUSTOM_PRIMS = ("custom_jvp_call", "custom_vjp_call")
 
 
 def _operand_stats(x):
@@ -357,7 +356,7 @@ def _inner_closed(eqn):
 
     p = eqn.params
     name = eqn.primitive.name
-    if name in ("pjit", "closed_call"):
+    if name in ("jit", "closed_call"):
         return p.get("jaxpr")
     if name in ("remat2", "checkpoint"):
         inner = p.get("jaxpr")

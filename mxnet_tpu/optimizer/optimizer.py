@@ -238,6 +238,8 @@ class Optimizer:
             clip = self.clip_gradient
 
             def step(w, g, state, lr, wd, hyper):
+                lr, wd = _weak32(lr), _weak32(wd)
+                hyper = {k: _weak32(v) for k, v in hyper.items()}
                 g = g * hyper["rescale_grad"]
                 if clip is not None:
                     g = jnp.clip(g, -clip, clip)
@@ -265,8 +267,9 @@ class Optimizer:
         global-norm scale → per-element clip → `cls._rule` (→ master
         cast).  The XLA reference body — kernels/opt.py's Pallas ladder
         is its drop-in twin and falls back to it verbatim."""
-        h = dict(hyper)
+        h = {k: _weak32(v) for k, v in hyper.items()}
         h["t"] = t
+        lr, wd, scale = _weak32(lr), _weak32(wd), _weak32(scale)
         if mp:
             # legacy update_multi_precision order: cast the
             # low-precision grad to f32 FIRST, then rescale/
@@ -610,6 +613,44 @@ def _write_state(state, new_state):
         _write_state(s, ns)
 
 
+def _weak32(x):
+    """A traced Python float (weak float64 under the package's x64
+    contract) as a weak float32: scalar-only hyperparameter math in a rule
+    (``1 - beta``, ``lr * a / b``) then stays 32-bit instead of compiling
+    to emulated f64 on a TPU, and the scalar still takes the dtype of
+    whatever tensor it scales.  Anything else passes through."""
+    if (isinstance(x, jax.Array) and x.dtype == jnp.float64
+            and getattr(x, "weak_type", False)):
+        from jax._src.lax.lax import _convert_element_type
+
+        return _convert_element_type(x, _np.dtype("float32"),
+                                     weak_type=True)
+    return x
+
+
+def _one_minus_pow(beta, t):
+    """``1 - beta ** t``, the Adam-family bias correction, as a WEAK
+    float32 scalar.
+
+    `beta` and `t` reach a rule as traced Python scalars, which the
+    package's x64 contract makes float64 / int64; ``beta ** t`` on them is
+    64-bit transcendental math, which a TPU has to emulate — per
+    parameter, it made BERT-base's whole step take over 20 minutes to
+    compile.  Float32 ``-expm1(x)``, ``x = t * log(beta)``, is the same
+    number to ~1e-5 relative — float32's rounding of ``beta`` itself, at
+    beta = 0.999; the naive float32 ``1 - beta ** t`` adds its own
+    cancellation (~1e-4 at small t) on top; it is spelled ``-tanh(x / 2) * (exp(x) + 1)``
+    because the rule also traces into the Pallas ladder (kernels/opt.py)
+    and Mosaic lowers tanh and exp but not expm1.  Weak typing lets the
+    result scale a bf16, f32 or f64 tensor without promoting it, exactly
+    like the Python scalar it replaces."""
+    from jax._src.lax.lax import _convert_element_type
+
+    x = jnp.asarray(t, jnp.float32) * jnp.log(jnp.asarray(beta, jnp.float32))
+    out = -jnp.tanh(0.5 * x) * (jnp.exp(x) + 1.0)
+    return _convert_element_type(out, _np.dtype("float32"), weak_type=True)
+
+
 def _zeros_like(weight, dtype=None):
     return _wrap_out(jnp.zeros_like(weight._data, dtype=dtype))
 
@@ -805,7 +846,7 @@ class Adam(Optimizer):
         g = g + wd * w
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g
-        lr_t = lr * jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
+        lr_t = lr * jnp.sqrt(_one_minus_pow(b2, t)) / _one_minus_pow(b1, t)
         return w - lr_t * m / (jnp.sqrt(v) + hyper["eps"]), (m, v)
 
 
@@ -819,8 +860,8 @@ class AdamW(Adam):
         b1, b2, t = hyper["beta1"], hyper["beta2"], hyper["t"]
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g
-        mhat = m / (1 - b1 ** t)
-        vhat = v / (1 - b2 ** t)
+        mhat = m / _one_minus_pow(b1, t)
+        vhat = v / _one_minus_pow(b2, t)
         return w - lr * (mhat / (jnp.sqrt(vhat) + hyper["eps"]) + wd * w), (m, v)
 
 
@@ -836,8 +877,8 @@ class Nadam(Adam):
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g
         mhat = m / (1 - b1 ** (t + 1))
-        vhat = v / (1 - b2 ** t)
-        m_bar = b1 * mhat + (1 - b1) * g / (1 - b1 ** t)
+        vhat = v / _one_minus_pow(b2, t)
+        m_bar = b1 * mhat + (1 - b1) * g / _one_minus_pow(b1, t)
         return w - lr * m_bar / (jnp.sqrt(vhat) + hyper["eps"]), (m, v)
 
 
@@ -852,7 +893,7 @@ class AdaBelief(Adam):
         g = g + wd * w
         m = b1 * m + (1 - b1) * g
         s = b2 * s + (1 - b2) * jnp.square(g - m) + hyper["eps"]
-        lr_t = lr * jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
+        lr_t = lr * jnp.sqrt(_one_minus_pow(b2, t)) / _one_minus_pow(b1, t)
         return w - lr_t * m / (jnp.sqrt(s) + hyper["eps"]), (m, s)
 
 
@@ -867,7 +908,7 @@ class Adamax(Adam):
         g = g + wd * w
         m = b1 * m + (1 - b1) * g
         u = jnp.maximum(b2 * u, jnp.abs(g))
-        return w - (lr / (1 - b1 ** t)) * m / (u + hyper["eps"]), (m, u)
+        return w - (lr / _one_minus_pow(b1, t)) * m / (u + hyper["eps"]), (m, u)
 
 
 @register
@@ -893,8 +934,8 @@ class FTML(Optimizer):
         b1, b2, t = hyper["beta1"], hyper["beta2"], hyper["t"]
         g = g + wd * w
         v = b2 * v + (1 - b2) * g * g
-        d = (1 - b1 ** t) / lr * (
-            jnp.sqrt(v / (1 - b2 ** t)) + hyper["eps"])
+        d = _one_minus_pow(b1, t) / lr * (
+            jnp.sqrt(v / _one_minus_pow(b2, t)) + hyper["eps"])
         sigma = d - b1 * d_prev
         z = b1 * z + (1 - b1) * g - sigma * w
         return -z / d, (d, v, z)
@@ -1040,8 +1081,8 @@ class LAMB(Optimizer):
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g
         bc = hyper["bias_corr"]
-        mhat = jnp.where(bc > 0, m / (1 - b1 ** t), m)
-        vhat = jnp.where(bc > 0, v / (1 - b2 ** t), v)
+        mhat = jnp.where(bc > 0, m / _one_minus_pow(b1, t), m)
+        vhat = jnp.where(bc > 0, v / _one_minus_pow(b2, t), v)
         r = mhat / (jnp.sqrt(vhat) + hyper["eps"]) + wd * w
         w_norm = jnp.linalg.norm(w)
         r_norm = jnp.linalg.norm(r)
@@ -1064,8 +1105,8 @@ class LANS(LAMB):
         g = g / jnp.maximum(jnp.linalg.norm(g), 1e-12)  # normalized grad
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g
-        mhat = m / (1 - b1 ** t)
-        vhat = v / (1 - b2 ** t)
+        mhat = m / _one_minus_pow(b1, t)
+        vhat = v / _one_minus_pow(b2, t)
         denom = jnp.sqrt(vhat) + hyper["eps"]
         r1 = mhat / denom + wd * w            # momentum part
         r2 = g / denom + wd * w               # gradient (Nesterov) part

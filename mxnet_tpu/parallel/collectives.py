@@ -19,15 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental spelling, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma,
-                              **kw)
+from jax import shard_map
 
 from ..diagnostics import spans as _spans
 from ..diagnostics import watchdog as _watchdog
@@ -35,16 +27,7 @@ from ..telemetry import instruments as _telemetry
 
 __all__ = ["psum_tree", "psum_tree_flat", "psum_tree_flat_traced",
            "allreduce_mean", "all_gather", "reduce_scatter",
-           "ring_permute", "axis_size"]
-
-
-def axis_size(axis_name):
-    """Static size of a named mesh axis inside shard_map (version-compat:
-    jax.lax.axis_size where available, else the psum(1, axis) identity)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+           "ring_permute"]
 
 
 def _tree_bytes(tree):

@@ -57,13 +57,12 @@ def make_data_parallel_step(loss_fn, update_fn, mesh, axis="dp",
 
 def make_shard_map_step(loss_fn, update_fn, mesh, axis="dp"):
     """Explicit-collective variant: per-device bodies + lax.psum on grads."""
-    from .collectives import shard_map  # version-compat wrapper
 
     # check_vma=False: jax's replication checker rewrites grads of
     # replicated (P()) inputs with an extra psum, inflating them by the
     # axis size; with it off we own the collectives (explicit pmean).
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(axis), P()),
         out_specs=(P(), P(), P()),

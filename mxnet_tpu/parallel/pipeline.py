@@ -126,7 +126,6 @@ def pipeline_apply_sharded(stage_fn, stacked_params, microbatches, mesh,
     see `interleave_stages`). microbatches: (M, ...) replicated across
     stages; with num_virtual > 1, M must be a multiple of S.
     """
-    from .collectives import shard_map  # version-compat wrapper
 
     n_stages = mesh.shape[axis]
     for leaf in jax.tree_util.tree_leaves(stacked_params):
@@ -139,7 +138,7 @@ def pipeline_apply_sharded(stage_fn, stacked_params, microbatches, mesh,
 
     param_specs = jax.tree_util.tree_map(
         lambda p: P(axis, *([None] * (p.ndim - 1))), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda params, mb: pipeline_apply(stage_fn, params, mb, axis,
                                           num_virtual=num_virtual),
         mesh=mesh,
@@ -274,7 +273,6 @@ def pipeline_step_1f1b_sharded(stage_fn, loss_fn, stacked_params,
                                microbatches, labels, mesh, axis="pp"):
     """Jit pipeline_step_1f1b over `axis`; returns (loss, stacked_grads)
     with grads sharded like the params."""
-    from .collectives import shard_map  # version-compat wrapper
 
     n_stages = mesh.shape[axis]
     for leaf in jax.tree_util.tree_leaves(stacked_params):
@@ -291,7 +289,7 @@ def pipeline_step_1f1b_sharded(stage_fn, loss_fn, stacked_params,
         g = jax.tree_util.tree_map(lambda a: a[None], g)
         return loss, g
 
-    fn = shard_map(run, mesh=mesh,
+    fn = jax.shard_map(run, mesh=mesh,
                    in_specs=(param_specs, P(), P()),
                    out_specs=(P(), grad_specs),
                    check_vma=False)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import collectives as _collectives
 
 __all__ = ["ulysses_attention", "ulysses_attention_sharded"]
 
@@ -34,7 +33,7 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=None):
     (batch, heads, seq_local, head_dim) shards on the sequence axis;
     heads must divide the axis size evenly.
     """
-    n = _collectives.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, h, s_local, d = q.shape
     if h % n:
         raise ValueError(f"heads {h} not divisible by axis size {n}")
@@ -82,14 +81,13 @@ def ulysses_attention_sharded(q, k, v, mesh, axis="sp", causal=False,
     """Convenience wrapper mirroring ring_attention_sharded: (b, h, S, d)
     arrays sharded on the sequence dim over `axis`; one jitted shard_map
     program cached per (mesh, axis, causal, scale)."""
-    from .collectives import shard_map  # version-compat wrapper
 
     key = (mesh, axis, causal, scale)
     run = _jit_cache.get(key)
     if run is None:
         spec = P(None, None, axis, None)
 
-        @partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                  out_specs=spec, check_vma=False)
         def body(ql, kl, vl):
             return ulysses_attention(ql, kl, vl, axis, causal=causal,

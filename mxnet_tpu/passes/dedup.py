@@ -107,23 +107,17 @@ def _canon(obj):
 # function, which IS shared across traces of the same library op.  A
 # rule that can't be tokenized makes the program unhashable, so it
 # falls back to a private executable — correctness first.
-_RULE_JAXPR_THUNKS = frozenset((
-    "jvp_jaxpr_thunk", "jvp_jaxpr_fun", "fwd_jaxpr_thunk",
-))
+_RULE_JAXPR_THUNKS = frozenset(("jvp_jaxpr_fun", "fwd_jaxpr_thunk"))
 _RULE_FUN_PARAMS = frozenset(("fwd", "bwd", "jvp"))
 _RULE_DERIVED_PARAMS = frozenset(("out_trees",))  # fixed by the fwd jaxpr
-_CUSTOM_CALL_PRIMS = frozenset((
-    "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
-))
+_CUSTOM_CALL_PRIMS = frozenset(("custom_jvp_call", "custom_vjp_call"))
 
 
 def _rule_fun_token(obj):
     """Stable token for a wrapped rule callable: the underlying user
     function (``WrappedFun.f``), equal-by-identity across traces of the
     same op."""
-    target = getattr(obj, "__self__", obj)  # bound call_wrapped → WrappedFun
-    f = getattr(target, "f", None) or (obj if callable(obj) else None)
+    f = getattr(obj, "f", None) or (obj if callable(obj) else None)
     if f is None:
         raise _Unhashable
     return ("rulefn", f)
@@ -145,7 +139,7 @@ def _rule_jaxpr_token(eqn, thunk):
     n = len(eqn.invars) - int(eqn.params.get("num_consts") or 0)
     _RULE_DEPTH.d = 1
     try:
-        forced = thunk(*([False] * n))
+        forced = thunk.call_wrapped(*([False] * n))
         return ("rulejaxpr", _canon(forced))
     except _Unhashable:
         raise

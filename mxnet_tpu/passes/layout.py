@@ -48,11 +48,14 @@ docs/layout.md is the user-facing tour.
 """
 from __future__ import annotations
 
+import sys
+
 import numpy as _np
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.extend import core as jcore
 
 from .. import env as _env
 from ..telemetry import instruments as _telemetry
@@ -309,12 +312,12 @@ def _bn_target(eqn):
     the exact function to RE-EMIT (never inline: the custom VJP is the
     closed-form backward) — or None.  Identity checks only; anything
     unrecognized stays a barrier."""
-    if eqn.primitive.name != "custom_vjp_call_jaxpr":
+    if eqn.primitive.name != "custom_vjp_call":
         return None
     if eqn.params.get("num_consts") or len(eqn.invars) != 4 \
             or len(eqn.outvars) != 3:
         return None
-    wf = getattr(eqn.params.get("bwd"), "__self__", None)
+    wf = eqn.params.get("bwd")
     f = getattr(wf, "f", None)
     if f is None:
         return None
@@ -324,23 +327,18 @@ def _bn_target(eqn):
     if f is _nn._bn_train_bwd:
         target = _nn._bn_train
     else:
-        try:
-            from ..kernels import norm as _knorm
-            if f is _knorm._bn_train_bwd:
-                target = _knorm.bn_train
-        except ImportError:
-            pass
+        # only a loaded kernels.norm can have emitted the equation
+        _knorm = sys.modules.get("mxnet_tpu.kernels.norm")
+        if _knorm is not None and f is _knorm._bn_train_bwd:
+            target = _knorm.bn_train
     if target is None:
         return None
-    # nondiff args ride the WrappedFun's _add_args_ transform as
-    # Unhashable wrappers: ((eps, axis) order matches nondiff_argnums)
-    for t in getattr(wf, "transforms", ()):
-        if getattr(t[0], "__name__", "") != "_add_args_":
+    # nondiff args ride the WrappedFun's _prepend_static_args transform
+    # as Unhashable wrappers: ((eps, axis) order matches nondiff_argnums)
+    for t in wf.transforms:
+        if getattr(t[0], "__name__", "") != "_prepend_static_args":
             continue
-        try:
-            vals = tuple(getattr(a, "val", a) for a in t[1][0])
-        except Exception:
-            return None
+        vals = tuple(getattr(a, "val", a) for a in t[1][0])
         if len(vals) == 2:
             return target, float(vals[0]), int(vals[1])
     return None
@@ -355,12 +353,12 @@ def _is_relu(eqn):
     if eqn.params.get("num_consts") or len(eqn.invars) != 1 \
             or len(eqn.outvars) != 1:
         return False
-    target_jvp = getattr(jax.nn.relu, "jvp", None)
-    if target_jvp is None:
-        return False
-    thunk = eqn.params.get("jvp_jaxpr_thunk")
-    return any(getattr(o, "f", None) is target_jvp
-               for o in _closure_objects(thunk) or ())
+    # the eqn's jvp_jaxpr_fun closes over the custom_jvp's own wrapped
+    # jvp rule; its core function is jax.nn.relu.jvp exactly when the
+    # equation came from jax.nn.relu
+    fun = eqn.params["jvp_jaxpr_fun"]
+    return any(getattr(o, "f", None) is jax.nn.relu.jvp
+               for o in _closure_objects(fun.f))
 
 
 class _Interpreter:
@@ -389,7 +387,7 @@ class _Interpreter:
     def stored_perm(self, atom):
         """A non-identity permutation already held for `atom` (the
         channels-last propagation signal), else None."""
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jcore.Literal):
             return None
         d = self.vals.get(atom)
         if not d:
@@ -401,7 +399,7 @@ class _Interpreter:
         return None
 
     def read(self, atom, perm=None):
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jcore.Literal):
             v = atom.val
             if perm is None or _np.ndim(v) == 0 \
                     or tuple(perm) == _ident(_np.ndim(v)):
@@ -513,7 +511,7 @@ class _Interpreter:
 
     def transpose(self, eqn):
         xvar = eqn.invars[0]
-        if isinstance(xvar, jax.core.Literal):
+        if isinstance(xvar, jcore.Literal):
             return self.barrier(eqn)
         q = tuple(eqn.params["permutation"])
         d = self.vals.get(xvar)
@@ -539,7 +537,7 @@ class _Interpreter:
     def reshape(self, eqn):
         xvar = eqn.invars[0]
         if eqn.params.get("dimensions") is not None \
-                or isinstance(xvar, jax.core.Literal):
+                or isinstance(xvar, jcore.Literal):
             return self.barrier(eqn)
         new_sizes = tuple(eqn.params["new_sizes"])
         out_rank = len(new_sizes)
@@ -576,7 +574,7 @@ class _Interpreter:
         def maker(s):
             target = tuple(shape[s[i]] for i in range(out_rank))
             inv_s = {dim: i for i, dim in enumerate(s)}
-            if isinstance(xvar, jax.core.Literal):
+            if isinstance(xvar, jcore.Literal):
                 cands = [(_ident(_np.ndim(xvar.val)), xvar.val)]
             else:
                 ident = _ident(len(xvar.aval.shape))
@@ -641,7 +639,7 @@ class _Interpreter:
         p = None
         rank = 0
         for a in eqn.invars:
-            sh = _np.shape(a.val) if isinstance(a, jax.core.Literal) \
+            sh = _np.shape(a.val) if isinstance(a, jcore.Literal) \
                 else a.aval.shape
             if len(sh) == 0:
                 continue
@@ -654,7 +652,7 @@ class _Interpreter:
             return self.barrier(eqn)
         vals = []
         for a in eqn.invars:
-            sh = _np.shape(a.val) if isinstance(a, jax.core.Literal) \
+            sh = _np.shape(a.val) if isinstance(a, jcore.Literal) \
                 else a.aval.shape
             vals.append(self.read(a, p if len(sh) else None))
         subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
@@ -685,7 +683,7 @@ class _Interpreter:
                 self.reduce_window(eqn)
             elif name == "optimization_barrier":
                 self.opt_barrier(eqn)
-            elif name == "custom_vjp_call_jaxpr":
+            elif name == "custom_vjp_call":
                 bn = _bn_target(eqn)
                 if bn is not None:
                     self.bn(eqn, *bn)
@@ -731,8 +729,9 @@ class LayoutPass(GraphPass):
     """Whole-graph channels-last rewrite (module docstring has the full
     story).  Priority 20: after AmpPass(10) fixed dtypes (the byte-model
     scoring must see them) and before KernelPass(40) audits the program
-    XLA will actually compile.  Never fails a build — any internal error
-    returns the program unchanged with the error in ctx.notes."""
+    XLA will actually compile.  An internal error fails the build: a
+    rewrite that silently did nothing is indistinguishable from one that
+    had nothing to do."""
 
     name = "layout"
     priority = 20
@@ -744,13 +743,6 @@ class LayoutPass(GraphPass):
         self._forced = mode
 
     def run(self, closed, ctx):
-        try:
-            return self._run(closed, ctx)
-        except Exception as exc:
-            ctx.notes["layout"] = {"error": repr(exc)}
-            return closed
-
-    def _run(self, closed, ctx):
         m = self._forced if self._forced is not None else mode()
         note = {"mode": m, "kind": ctx.kind}
         ctx.notes["layout"] = note

@@ -3,7 +3,7 @@
 The reference stack's NNVM memory planner assigns storage by walking
 the graph in topological order and freeing buffers at their last use;
 the peak of that walk is the plan's residency requirement.  This module
-runs the same walk over a jaxpr (recursing into pjit/remat2/custom-call
+runs the same walk over a jaxpr (recursing into jit/remat2/custom-call
 sub-jaxprs) and reports the peak live bytes — a backend-independent
 estimate the remat `auto` policy and the diagnostics compile registry
 use.  XLA's own `memory_analysis().temp_size_in_bytes` is not usable
@@ -37,9 +37,8 @@ __all__ = [
 # safe to inline into the walk.  Loop/branch primitives (scan, while,
 # cond) slice or select their operands, so they stay opaque: their
 # outputs are counted, their bodies are not expanded.
-_INLINE_PRIMS = ("pjit", "remat2", "closed_call", "core_call",
-                 "custom_jvp_call", "custom_vjp_call",
-                 "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr")
+_INLINE_PRIMS = ("jit", "remat2", "closed_call", "core_call",
+                 "custom_jvp_call", "custom_vjp_call")
 
 
 def _aval_bytes(aval):

@@ -134,7 +134,7 @@ def _make_sub_jaxpr(eqns, out_needed):
 
 
 def _eval_eqn(eqn, invals):
-    """Evaluate one jaxpr equation. Plain call primitives (pjit, remat)
+    """Evaluate one jaxpr equation. Plain call primitives (jit, remat)
     inline their inner jaxpr. custom_jvp/vjp calls must NOT be inlined:
     inlining the primal body discards the custom derivative rule, so
     differentiating the re-evaluated program would silently use
@@ -145,7 +145,7 @@ def _eval_eqn(eqn, invals):
     import jax.core as _core
 
     name = eqn.primitive.name
-    if name == "pjit" or name == "closed_call":
+    if name == "jit" or name == "closed_call":
         inner = eqn.params["jaxpr"]
         return _core.eval_jaxpr(inner.jaxpr, inner.consts, *invals)
     if name in ("remat2", "checkpoint"):

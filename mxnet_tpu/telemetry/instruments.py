@@ -69,11 +69,37 @@ __all__ = [
     "record_elastic_restart", "record_reshard", "set_world_generation",
     "cost_measure_total", "cost_model_drift_ratio",
     "record_cost_measure", "set_cost_drift",
+    "DEVICE_PEAKS", "device_peaks",
 ]
 
-# v5e-class bf16 peak, the default MFU denominator (tools/perf_lab.py's
-# PEAK_BF16); override with set_flop_budget(..., peak_flops=...).
-DEFAULT_PEAK_FLOPS = 197e12
+# Published per-chip peaks, keyed by jax's `device_kind`.  The ONE table
+# every MFU / roofline denominator in the repo reads (set_flop_budget,
+# tools/perf_lab.py, tools/bench_estimate.py, tools/fusion_audit.py).  A
+# device that is not listed has no peak: device_peaks() raises for it.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e' (197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip)",
+    },
+}
+
+
+def device_peaks(device_kind=None):
+    """The published peaks of `device_kind` (default: this process's
+    first device).  KeyError for a device the table does not list — an
+    unknown chip has no assumed peak."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
 
 _COMPILE_BUCKETS = (.01, .05, .1, .25, .5, 1.0, 2.5, 5.0, 10.0, 30.0,
                     60.0, 120.0, 300.0)
@@ -783,9 +809,16 @@ def set_flop_budget(flops, peak=None):
     """Declare the per-step FLOP budget (and optionally the accelerator
     peak) so observe_step can keep the MFU gauge live. `flops` is the
     cost of ONE optimizer step (fwd+bwd+update), e.g. from XLA
-    cost_analysis as tools/perf_lab.py measures it."""
+    cost_analysis as tools/perf_lab.py measures it.  Without `peak` the
+    denominator is this device's DEVICE_PEAKS entry; on a device the
+    table does not list the peak stays unset and the MFU gauge silent."""
     flops_per_step.set(flops)
-    peak_flops.set(peak if peak is not None else DEFAULT_PEAK_FLOPS)
+    if peak is None:
+        try:
+            peak = device_peaks()["bf16_flops"]
+        except KeyError:
+            peak = 0.0
+    peak_flops.set(peak)
 
 
 def record_update_dispatch(path, donated_bytes=0):

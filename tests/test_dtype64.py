@@ -231,3 +231,70 @@ def test_tri_positional_dtype():
     # np.tri(3, 3, 0, 'int32') is legal numpy spelling
     assert str(mx.np.tri(3, 3, 0, "int32").dtype) == "int32"
     assert str(mx.np.tri(3).dtype) == "float32"
+
+
+# -- the contract must not leak 64-bit MATH into compiled training programs --
+# (a TPU emulates f64: found on the chip by chip_smoke.py, PR 22)
+
+def _eqn_dtypes(jaxpr, prims):
+    """(primitive, out dtype) of every equation named in `prims`, nested
+    call bodies included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in prims:
+            out.append((eqn.primitive.name, str(eqn.outvars[0].aval.dtype)))
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                out += _eqn_dtypes(getattr(inner, "jaxpr", inner), prims)
+    return out
+
+
+def test_dropout_mask_draws_32bit_uniforms():
+    """`bernoulli(key, 0.9)`: a Python-float p is a float64 under x64 and
+    used to draw 64-bit random bits for every Dropout; an explicit
+    float64 p still does."""
+    import jax
+    import jax.numpy as jnp
+
+    key = jax.random.PRNGKey(0)
+    jp = jax.make_jaxpr(lambda k: jax.random.bernoulli(k, 0.9, (8, 16)))(key)
+    bits = _eqn_dtypes(jp.jaxpr, ("random_bits",))
+    assert bits and all(dt == "uint32" for _, dt in bits), bits
+    jp = jax.make_jaxpr(lambda k: jax.random.bernoulli(
+        k, jnp.float64(0.9), (8, 16)))(key)
+    assert ("random_bits", "uint64") in _eqn_dtypes(jp.jaxpr,
+                                                    ("random_bits",))
+
+
+@pytest.mark.parametrize("name", ["Adam", "AdamW", "Nadam", "Adamax",
+                                  "LAMB"])
+def test_adam_family_rules_trace_no_64bit_transcendentals(name):
+    """lr / wd / t / betas reach a fused rule as traced Python scalars
+    (weak float64 / int64 under x64); the bias correction must not become
+    f64 pow / log / exp / sqrt / div — per parameter that made BERT-base's
+    whole step take over 20 minutes to compile for the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import optimizer as opt_mod
+    from mxnet_tpu.optimizer.optimizer import Optimizer
+
+    cls = getattr(opt_mod, name)
+    opt = cls()
+    w = jnp.ones((8, 128), jnp.float32)
+    st = jax.tree_util.tree_map(
+        lambda s: s._data if hasattr(s, "_data") else s,
+        opt.create_state(0, mx.nd.array(onp.ones((8, 128), "float32"))),
+        is_leaf=lambda s: hasattr(s, "_data"))
+    hyper = dict(opt._hyper(), rescale_grad=1.0)
+
+    def step(w, st, g, lr, wd, t, hyper):
+        return Optimizer._fused_param_step(cls, None, False, False, w, st,
+                                           g, lr, wd, t, 1.0, hyper)
+
+    jp = jax.make_jaxpr(step)(w, st, w, 1e-3, 0.01, 3, hyper)
+    heavy = _eqn_dtypes(jp.jaxpr, ("pow", "log", "exp", "sqrt", "div",
+                                   "tanh", "rsqrt", "integer_pow"))
+    assert heavy, "rule traced no math at all?"
+    assert all(dt != "float64" for _, dt in heavy), heavy

@@ -3,7 +3,7 @@ interpret-mode forward+grad parity for all three kernels, the
 MXTPU_KERNELS=0 kill switch (bitwise program identity, zero extra
 traces), byte-model acceptance (>=30% external-HBM reduction on the
 audited regions, asserted against recorded jaxprs), auto-mode declines,
-fallback taxonomy + flight-recorder events, and composition with
+fallback outcomes + flight-recorder events, and composition with
 whole-step donation, cross-CachedOp dedup, and remat."""
 import sys
 

@@ -248,10 +248,7 @@ def _mlp():
 
 
 def _flops(compiled):
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax returns [dict]
-        ca = ca[0]
-    return float(ca["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 @pytest.mark.parametrize("recipe", ["dp8", "dp4_tp2", "dp2_fsdp2_tp2",

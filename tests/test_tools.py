@@ -414,19 +414,26 @@ def test_fusion_audit_report_smoke(tmp_path):
     assert sizes == sorted(sizes, reverse=True)
 
 
-def test_bench_platform_stamp_and_cross_platform_gate(monkeypatch):
-    """Every bench snapshot is stamped with its platform, and the >3%
-    regression gate refuses to compare snapshots from different
-    platforms instead of emitting nonsense regressions."""
+def test_bench_main_refuses_to_run_without_a_tpu():
+    """bench.py measures on the chip or not at all: on the CPU backend
+    main() raises before it builds a model, prints no result line, and
+    no `_CPU_FALLBACK` row can be filed as evidence again."""
     sys.path.insert(0, REPO)
     import bench
 
-    # Platform inference: explicit stamp > _CPU_FALLBACK marker > tpu.
+    with pytest.raises(RuntimeError, match="measures on a TPU"):
+        bench.main()
+    assert not hasattr(bench, "_probe_accelerator")
+
+
+def test_bench_cross_platform_gate(monkeypatch):
+    """The >3% regression gate refuses to compare snapshots stamped with
+    different platforms instead of emitting nonsense regressions."""
+    sys.path.insert(0, REPO)
+    import bench
+
     assert bench._snapshot_platform({"platform": "tpu"}) == "tpu"
-    assert bench._snapshot_platform({"platform": "cpu"}) == "cpu"
-    assert bench._snapshot_platform(
-        {"rows": [{"metric": "x_CPU_FALLBACK"}]}) == "cpu"
-    assert bench._snapshot_platform({"rows": [{"metric": "foo_ms"}]}) == "tpu"
+    assert bench._snapshot_platform({"rows": []}) == "unstamped"
 
     prior = {"platform": "tpu",
              "rows": [{"metric": "train_step_ms", "value": 100.0}]}

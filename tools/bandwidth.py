@@ -26,7 +26,6 @@ def measure(sizes_mb, iters=10, warmup=2):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as Pspec
 
-    from mxnet_tpu.parallel.collectives import shard_map  # version compat
 
     devs = jax.devices()
     n = len(devs)
@@ -40,7 +39,7 @@ def measure(sizes_mb, iters=10, warmup=2):
 
         @jax.jit
         def allreduce(v):
-            return shard_map(
+            return jax.shard_map(
                 lambda s: jax.lax.psum(s, "dp"), mesh=mesh,
                 in_specs=Pspec("dp", None), out_specs=Pspec(None, None),
             )(v)

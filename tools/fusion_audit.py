@@ -50,8 +50,12 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-PEAK_FLOPS = 197e12
-HBM_BPS = 819e9
+from mxnet_tpu.telemetry.instruments import device_peaks  # noqa: E402
+
+# the chip this analytic model targets (the one peaks table of the repo)
+_V5E = device_peaks("TPU v5 lite")
+PEAK_FLOPS = _V5E["bf16_flops"]
+HBM_BPS = _V5E["hbm_bytes_per_s"]
 
 _ELEM_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "f64": 8, "i64": 8,
                "i32": 4, "ui32": 4, "i8": 1, "ui8": 1, "i1": 0.125,
@@ -146,22 +150,21 @@ def fusion_regions(ops):
 def audit(layout="NHWC", batch=256):
     import bench
 
-    platform = bench._probe_accelerator() or "cpu"
     import jax
 
-    if platform != "tpu":
-        jax.config.update("jax_platforms", "cpu")
+    # a StableHLO audit is platform-neutral: it runs wherever this
+    # process was started and names that platform in its report
+    platform = jax.devices()[0].platform
 
     net, step, params, momenta, x, y = bench.build_resnet_train(
         layout, batch, donate=True)
     key = jax.random.PRNGKey(0)
     lowered = step.lower(params, momenta, x, y, key)
     shlo = lowered.as_text()
-    flops = float((lowered.compile().cost_analysis() or [{}])[0].get(
+    flops = float((lowered.compile().cost_analysis() or {}).get(
         "flops", 0)) if platform == "tpu" else None
     if flops is None:
-        ca = lowered.compile().cost_analysis()
-        d = ca[0] if isinstance(ca, list) else ca
+        d = lowered.compile().cost_analysis()
         flops = float(d.get("flops", 0))
 
     ops = parse_stablehlo(shlo)

@@ -20,15 +20,13 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import bench  # noqa: E402
 from mxnet_tpu import telemetry  # noqa: E402
-from mxnet_tpu.diagnostics import introspect  # noqa: E402
 
-PEAK_BF16 = 197e12  # v5e-class peak
+from mxnet_tpu.telemetry.instruments import device_peaks  # noqa: E402
 
 
 def _analyze(compiled):
-    """(flops, peak_hbm_bytes) of an AOT-compiled executable; version-safe
-    (cost_analysis is a dict or a 1-list of dicts depending on jax)."""
-    cost = introspect._first_dict(compiled.cost_analysis())
+    """(flops, peak_hbm_bytes) of an AOT-compiled executable."""
+    cost = compiled.cost_analysis() or {}
     fl = float(cost.get("flops", 0.0) or 0.0)
     peak = 0
     try:
@@ -49,9 +47,11 @@ def main():
     batch = int(sys.argv[2]) if len(sys.argv) > 2 else 256
     mode = sys.argv[3] if len(sys.argv) > 3 else "step"
     iters, warmup = 20, 3
-    # stamp the platform so a silent CPU fallback can never be mistaken
-    # for an on-chip measurement (bench.py's _CPU_FALLBACK analog)
-    platform = jax.devices()[0].platform
+    # every line names the device it ran on; a device without a
+    # published peak (the CPU included) has no MFU and fails here
+    dev = jax.devices()[0]
+    platform = dev.platform
+    peak_bf16 = device_peaks(dev.device_kind)["bf16_flops"]
 
     net, step, params, momenta, x, y = bench.build_resnet_train(
         layout, batch, donate=(mode == "step"))
@@ -121,18 +121,18 @@ def main():
     # measured step time observed, so every consumer (this JSON line,
     # prometheus_text scrapes, bench snapshots) reads the same number
     # (docs/telemetry.md).
-    telemetry.set_flop_budget(fl, peak=PEAK_BF16)
+    telemetry.set_flop_budget(fl, peak=peak_bf16)
     telemetry.observe_step(dt / iters, examples=batch)
     mfu = (telemetry.instruments.mfu_ratio.value if telemetry.enabled()
-           else fl / (dt / iters) / PEAK_BF16)  # MXTPU_TELEMETRY=0 runs
+           else fl / (dt / iters) / peak_bf16)  # MXTPU_TELEMETRY=0 runs
     print(json.dumps({
         "mode": mode, "layout": layout, "batch": batch,
-        "platform": platform,
+        "platform": platform, "device_kind": dev.device_kind,
         "step_ms": round(step_ms, 2),
         "img_s": round(batch * iters / dt, 1),
         "xla_gflops_per_step": round(fl / 1e9, 2),
         "peak_hbm_mb": round(peak_hbm / 1e6, 2),
-        "mfu_vs_197T": round(mfu, 4),
+        "mfu": round(mfu, 4), "peak_bf16_flops": peak_bf16,
     }))
 
 
