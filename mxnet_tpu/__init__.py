@@ -38,6 +38,10 @@ from . import _jax_defaults as _jax_defaults_mod
 _jax_defaults_mod.install()  # 32-bit defaults on dtype-less jax.random
 _jax_defaults_mod.place_compile_cache()  # $JAX_COMPILATION_CACHE_DIR or .jax_cache
 
+from .telemetry import instruments as _instruments
+
+_instruments.install_compile_listener()  # what JAX compiles, counted
+
 from . import autograd, base, device, engine
 from . import env  # typed env-var registry (env_var.md analog)
 from . import _random

@@ -30,7 +30,6 @@ from .spans import (
     all_stacks,
     current_stack,
     current_step,
-    emit_chrome_spans,
     format_step_table,
     mark_step,
     records,
@@ -41,8 +40,7 @@ from .watchdog import guard
 
 __all__ = [
     "span", "records", "step_table", "format_step_table",
-    "emit_chrome_spans", "mark_step", "current_step", "current_stack",
-    "all_stacks",
+    "mark_step", "current_step", "current_stack", "all_stacks",
     "capture_compile", "compile_registry", "format_compile_table",
     "device_memory", "update_device_memory_gauge",
     "guard", "report", "reset",

@@ -28,10 +28,11 @@ a real HBM gauge on TPU/GPU, ``None`` per device on CPU (surfaced as
 from __future__ import annotations
 
 import os
+import re
 import threading
 
 __all__ = [
-    "capture_compile", "compile_registry", "reset",
+    "capture_compile", "compile_registry", "reset", "op_scopes",
     "device_memory", "update_device_memory_gauge",
     "format_compile_table", "capture_enabled",
 ]
@@ -47,6 +48,36 @@ def capture_enabled():
         return bool(_env.get("MXTPU_DIAG_COMPILE"))
     except Exception:
         return os.environ.get("MXTPU_DIAG_COMPILE", "1") != "0"
+
+
+_FUSION_BODY = re.compile(r" fusion\(.*?calls=%?([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(
+    r'^\s+(?:ROOT )?%?([\w.\-]+) = .*metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def op_scopes(hlo_text):
+    """{instruction name: op_name} of an optimized HLO module's text.
+
+    The op_name is the JAX name stack the instruction was traced under
+    (``jit(whole_step)/jvp(forward)/BottleneckV1_3/BatchNorm_bn2/mul``),
+    which is how a device trace's ``fusion.20`` gets back to the block
+    that emitted it.  A fusion carries the op_name of its root; the
+    instructions INSIDE fusion bodies never show in a trace and are left
+    out."""
+    bodies = set(_FUSION_BODY.findall(hlo_text))
+    out, skip = {}, False
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION.match(line)
+            if m:
+                skip = m.group(1) in bodies
+            continue
+        if not skip:
+            m = _INSTRUCTION.match(line)
+            if m:
+                out[m.group(1)] = m.group(2)
+    return out
 
 
 def capture_compile(block, variant, jitted, args, kwargs=None,
@@ -86,6 +117,10 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
             # cross-device sums the partitioner / shard_map put in
             "all_reduces": (text.count(" all-reduce(")
                             + text.count(" all-reduce-start(")),
+            # the map from a device trace's instruction names to the
+            # scopes of the program (forward / backward / optimizer /
+            # block), for whoever reduces a trace of this program
+            "op_scopes": op_scopes(text),
         }
         try:
             mem = compiled.memory_analysis()

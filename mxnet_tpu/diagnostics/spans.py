@@ -13,11 +13,17 @@ wraps its hot region in ``diagnostics.span(name, cat=phase)``:
 
 Records land in a bounded ring (``MXTPU_DIAG_RING_CAPACITY``, default
 4096 — old spans fall off, memory stays bounded on infinite loops), each
-tagged with the training-step index live at the time, so
-:func:`step_table` can pivot the ring into a per-step phase breakdown and
-:func:`emit_chrome_spans` can replay it as chrome-trace "X" events on the
-profiler.py timeline (same clock origin — spans and profiler scopes
-align in chrome://tracing / Perfetto).
+tagged with the training-step index live at the time and the name of the
+span that encloses it (``parent``), so :func:`step_table` can pivot the
+ring into a per-step phase breakdown.
+
+A span also enters ``jax.profiler.TraceAnnotation("mxtpu:" + name)``
+(``StepTraceAnnotation`` when it carries a ``step_num``): while a
+``jax.profiler`` trace is being taken the span lands in the
+``.xplane.pb`` host plane, ON THE CLOCK THE DEVICE OPERATIONS ARE ON, so
+XProf / Perfetto show ``mxtpu:train_step`` above the fusions it
+enqueued; outside a trace the annotation costs one "is a session
+active" check.  This module is the only user of either annotation class.
 
 ``MXTPU_DIAGNOSTICS=0`` disables collection at import; every helper
 early-outs on one bool check, so instrumented hot paths cost one branch
@@ -26,10 +32,11 @@ when off.
 from __future__ import annotations
 
 import collections
-import contextlib
 import os
 import threading
 import time
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = [
     "span", "enabled", "enable", "disable", "reset",
@@ -37,9 +44,15 @@ __all__ = [
     "current_stack", "all_stacks",
     "mark_step", "current_step",
     "set_trace_context", "trace_context",
-    "step_table", "format_step_table", "emit_chrome_spans",
-    "PHASES",
+    "step_table", "format_step_table",
+    "PHASES", "ANNOTATION_PREFIX", "STEP_CAT",
 ]
+
+# every span's profiler annotation is named ANNOTATION_PREFIX + span name
+ANNOTATION_PREFIX = "mxtpu:"
+# category of the span around one whole training step (TrainStep): its
+# children carry the phases, so step_table leaves it out of the sums
+STEP_CAT = "step"
 
 # the phase vocabulary step_table pivots on (free-form cats still record;
 # they land in the 'other' column). "serve" is the serving engine's
@@ -159,35 +172,65 @@ def _stack():
     return st
 
 
-@contextlib.contextmanager
-def span(name, cat="host"):
-    """Record a nested host span. Thread-safe; zero-ish cost when
-    disabled. The record keeps wall times from ``time.perf_counter()``
-    (the profiler clock), the nesting depth, and the current step index."""
-    if not _enabled:
-        yield
-        return
-    st = _stack()
-    t0 = time.perf_counter()
-    st.append((name, cat, t0))
-    try:
-        yield
-    finally:
+class span:
+    """Record a nested host span: ``with span(name, cat): ...``.
+
+    Thread-safe; one branch when disabled.  The ring record keeps wall
+    times from ``time.perf_counter()``, the nesting depth, the enclosing
+    span's name (``parent``) and the current step index; the profiler
+    annotation (module docstring) carries ``kv`` as event stats.
+    ``step_num`` marks the outermost span of a training step: the
+    profiler then groups the device's work under that step."""
+
+    __slots__ = ("name", "cat", "step_num", "kv", "_t0", "_ann", "_st",
+                 "_step")
+
+    def __init__(self, name, cat="host", step_num=None, **kv):
+        self.name, self.cat = name, cat
+        self.step_num, self.kv = step_num, kv
+        self._st = None
+
+    def __enter__(self):
+        if not _enabled:
+            return self
+        st = self._st = _stack()
+        if self.step_num is None:
+            ann = TraceAnnotation(ANNOTATION_PREFIX + self.name, **self.kv)
+        else:
+            ann = StepTraceAnnotation(ANNOTATION_PREFIX + self.name,
+                                      step_num=self.step_num, **self.kv)
+        ann.__enter__()
+        self._ann = ann
+        # a span belongs to the step it began in (TrainStep's own
+        # bookkeeping span advances the index before it closes)
+        self._step = _step[0]
+        self._t0 = time.perf_counter()
+        st.append((self.name, self.cat, self._t0))
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
         # record even when the body raises — the failing region is
         # exactly the one worth seeing (profiler.scope does the same)
+        st = self._st
+        if st is None:
+            return False
         t1 = time.perf_counter()
+        self._st = None
         st.pop()
+        self._ann.__exit__(exc_type, exc, tb)
         rec = {
-            "name": name, "cat": cat,
-            "t0": t0, "dur": t1 - t0,
+            "name": self.name, "cat": self.cat,
+            "t0": self._t0, "dur": t1 - self._t0,
             "tid": threading.get_ident(),
             "depth": len(st),
-            "step": _step[0],
+            "parent": st[-1][0] if st else None,
+            "step": self._step,
         }
         if _trace_ctx:
             rec.update(_trace_ctx)
         with _ring_lock:
             _ring.append(rec)
+        return False
 
 
 def records():
@@ -237,7 +280,8 @@ def step_table(recs=None):
 
     Only depth-0 spans of each category are summed (a ``fwd`` span nested
     under another ``fwd`` span would double-count its parent's time).
-    Categories outside PHASES accumulate under ``other``.
+    Categories outside PHASES accumulate under ``other``; the span
+    around a whole step (``STEP_CAT``) is left out, its children are in.
     """
     recs = records() if recs is None else recs
     # innermost-per-category: keep a span unless an enclosing span of the
@@ -251,7 +295,8 @@ def step_table(recs=None):
             min_depth[key] = r["depth"]
     table = {}
     for r in recs:
-        if r["depth"] != min_depth[(r["step"], r["cat"], r["tid"])]:
+        if r["cat"] == STEP_CAT or \
+                r["depth"] != min_depth[(r["step"], r["cat"], r["tid"])]:
             continue
         phase = r["cat"] if r["cat"] in PHASES else "other"
         row = table.setdefault(r["step"], {})
@@ -275,22 +320,3 @@ def format_step_table(recs=None):
     if len(lines) == 1:
         lines.append("  (no spans recorded)")
     return "\n".join(lines)
-
-
-def emit_chrome_spans():
-    """Replay the ring into profiler.py's host buffer as chrome-trace "X"
-    events (cat = the span's phase), so ``profiler.dump()`` shows the
-    diagnostics timeline alongside profiler scopes/tasks. Gated like every
-    host event: returns 0 when the profiler is not recording."""
-    from .. import profiler
-
-    emitted = 0
-    for r in records():
-        emitted += profiler.record_host_event(
-            f"span::{r['name']}",
-            profiler.perf_counter_to_trace_us(r["t0"]),
-            r["dur"] * 1e6,
-            cat=f"diag.{r['cat']}",
-            args={"step": r["step"], "depth": r["depth"]},
-        )
-    return emitted

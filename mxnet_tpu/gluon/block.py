@@ -123,6 +123,14 @@ class HookHandle:
         return False
 
 
+def _any_traced(args):
+    """True when a block is being called inside a jit/vjp trace."""
+    for a in args:
+        if isinstance(getattr(a, "_data", None), jax.core.Tracer):
+            return True
+    return False
+
+
 class Block:
     """Base container (reference: gluon/block.py:202)."""
 
@@ -135,6 +143,8 @@ class Block:
         if isinstance(value, Block):
             stale = self._children.get(name) is not value
             self._children[name] = value
+            object.__setattr__(value, "_scope_name",
+                               f"{type(value).__name__}_{name}")
             if stale:
                 # structure changed: any compiled variant is stale
                 # (reference: test_gluon.py test_hybrid_stale_cache)
@@ -153,6 +163,8 @@ class Block:
         name = name or str(len(self._children))
         self._children[name] = block
         object.__setattr__(self, name, block)
+        object.__setattr__(block, "_scope_name",
+                           f"{type(block).__name__}_{name}")
         self._clear_cached()  # adding a child invalidates compiled variants
         return block
 
@@ -250,9 +262,21 @@ class Block:
 
     def __call__(self, *args, **kwargs):
         self._fire_fwd_pre_hooks(args)
+        if _any_traced(args):
+            return self._forward_scoped(args, kwargs)
         out = self.forward(*args, **kwargs)
         self._fire_fwd_hooks(args, out)
         return out
+
+    def _forward_scoped(self, args, kwargs):
+        """``forward`` while a program is being traced, under this block's
+        name: ``<class>_<name its parent registered it under>``, e.g.
+        ``BottleneckV1_3/BatchNorm_bn2`` in every HLO instruction's
+        ``op_name``.  Stable across processes; metadata only, the traced
+        program is the same.  Hooks never fire on tracers."""
+        scope = getattr(self, "_scope_name", None) or type(self).__name__
+        with jax.named_scope(scope):
+            return self.forward(*args, **kwargs)
 
     def _fire_fwd_pre_hooks(self, args):
         pre = getattr(self, "_fwd_pre_hooks", ())
@@ -261,10 +285,8 @@ class Block:
         # same tracer guard as _fire_fwd_hooks: hooks observe executed
         # values only — firing during a jit trace would crash value-
         # reading hooks and fire once per compile instead of per call
-        for v in args:
-            data = getattr(v, "_data", None)
-            if data is not None and isinstance(data, jax.core.Tracer):
-                return
+        if _any_traced(args):
+            return
         for hook in pre:
             hook(self, args)
 
@@ -645,6 +667,8 @@ class HybridBlock(Block):
                         f"Original error: {type(e).__name__}: {e}",
                         stacklevel=2)
                     object.__setattr__(self, "_dynamic_graph", True)
+        elif _any_traced(args):
+            return self._forward_scoped(args, kwargs)
         out = self.forward(*args, **kwargs)
         self._fire_fwd_hooks(args, out)
         return out

@@ -356,19 +356,26 @@ class TrainStep:
             kind="whole_step_fwd", training=True))
 
         def fwd_bwd(tws, frozen, key, inputs):
+            # names on the device: jax.vjp turns these scopes into
+            # "jvp(forward)" on the forward's operations and
+            # "transpose(jvp(forward))" on the backward's, in the op_name
+            # of every HLO instruction (metadata only: the optimized
+            # program is the same program)
             def block_of(t):
-                return block_fwd(t, frozen, key, *inputs[:n_data])
+                with jax.named_scope("forward"):
+                    return block_fwd(t, frozen, key, *inputs[:n_data])
 
             def loss_of(out_datas):
-                out = _wrap_tree(out_datas)
-                labels = [NDArray(x) for x in inputs[n_data:]]
-                loss = loss_fn(out, *labels) if loss_fn is not None \
-                    else out
-                if not isinstance(loss, NDArray):
-                    raise TypeError(
-                        "loss_fn must return a single NDArray, got "
-                        f"{type(loss).__name__}")
-                return loss._data
+                with jax.named_scope("loss"):
+                    out = _wrap_tree(out_datas)
+                    labels = [NDArray(x) for x in inputs[n_data:]]
+                    loss = loss_fn(out, *labels) if loss_fn is not None \
+                        else out
+                    if not isinstance(loss, NDArray):
+                        raise TypeError(
+                            "loss_fn must return a single NDArray, got "
+                            f"{type(loss).__name__}")
+                    return loss._data
 
             # the tape differentiates the COMPILED block as one vjp node
             # and the loss ops outside it; splitting the vjp here mirrors
@@ -395,7 +402,13 @@ class TrainStep:
                 {n: g.astype(wdtype[n]) for n, g in gd.items()})
             return loss_data, gd, aux
 
-        def step(tws, frozen, states, key, lrs, wds, ts, hyper, *inputs):
+        # the function's name is the compiled module's (`jit_whole_step`)
+        # and so part of the persistent compile cache's key, which leaves
+        # every op_name out: a cached executable keeps the scopes it was
+        # compiled with, however the program names its work later.
+        # Rename it when the scopes below change their meaning.
+        def whole_step(tws, frozen, states, key, lrs, wds, ts, hyper,
+                       *inputs):
             # host side effect: runs once per jit trace (one XLA
             # compile), never on cache hits — except AOT introspection
             # re-lowers, which must not count as a user-visible retrace
@@ -437,10 +450,11 @@ class TrainStep:
                     # grads: per-shard sums over local samples — one
                     # flat-bucketed psum completes the global batch sum
                     # inside the SAME program
-                    if reduce_tree is not None:
-                        gd_ = reduce_tree(gd_, axis)
-                    else:
-                        gd_ = psum_tree_flat_traced(gd_, axis)
+                    with jax.named_scope("grad_reduce"):
+                        if reduce_tree is not None:
+                            gd_ = reduce_tree(gd_, axis)
+                        else:
+                            gd_ = psum_tree_flat_traced(gd_, axis)
                     # aux (BN running stats): cross-replica mean, the
                     # sync-BN convention for data-parallel stats
                     aux_ = jax.tree_util.tree_map(
@@ -456,19 +470,20 @@ class TrainStep:
             # fused optimizer update, unrolled per bucket — the exact
             # _fused_jitted math (shared body), fused into this program
             new_ws, new_states = {}, {}
-            for (_dtype_s, use_mp), names in bucket_specs:
-                nws, nsts = Optimizer._fused_step_body(
-                    cls, clip, False, use_mp,
-                    [tws[n] for n in names],
-                    [states[n] for n in names],
-                    [gd[n] for n in names],
-                    [lrs[n] for n in names],
-                    [wds[n] for n in names],
-                    [ts[n] for n in names],
-                    1.0, hyper)
-                for n, nw, ns in zip(names, nws, nsts):
-                    new_ws[n] = nw
-                    new_states[n] = ns
+            with jax.named_scope("optimizer"):
+                for (_dtype_s, use_mp), names in bucket_specs:
+                    nws, nsts = Optimizer._fused_step_body(
+                        cls, clip, False, use_mp,
+                        [tws[n] for n in names],
+                        [states[n] for n in names],
+                        [gd[n] for n in names],
+                        [lrs[n] for n in names],
+                        [wds[n] for n in names],
+                        [ts[n] for n in names],
+                        1.0, hyper)
+                    for n, nw, ns in zip(names, nws, nsts):
+                        new_ws[n] = nw
+                        new_states[n] = ns
             if tensor:
                 # pin outputs to their operand shardings: the updated
                 # params allgather back to the plan's layout (closing
@@ -482,7 +497,7 @@ class TrainStep:
                               for n, st in new_states.items()}
             return loss_data, new_ws, new_states, aux
 
-        return step
+        return whole_step
 
     def _bump_trace(self):
         self._traces += 1
@@ -553,6 +568,13 @@ class TrainStep:
 
     # -- execution ---------------------------------------------------------
     def __call__(self, *batch, batch_size=None):
+        # the outermost per-step span: in a profiled run the device's
+        # work groups under it as step `step_num` (diagnostics/spans.py)
+        with _spans.span("train_step", cat=_spans.STEP_CAT,
+                         step_num=_spans.current_step()):
+            return self._dispatch(batch, batch_size)
+
+    def _dispatch(self, batch, batch_size):
         for a in batch:
             if not isinstance(a, NDArray):
                 raise TypeError(
@@ -627,21 +649,92 @@ class TrainStep:
         return loss
 
     def _whole(self, batch, batch_size):
+        # children of `train_step`, contiguous, one per part of the call
+        # (docs/diagnostics.md has the table; chipbench reads them)
         self._last_path = "whole_step"
         tr = self._trainer
         opt = tr._optimizer
-        # the legacy Trainer.step prologue: grads scale by scale/batch
-        opt.rescale_grad = tr._scale / batch_size
-        # resolve counts/lr/wd in trainer order — the exact sequence
-        # update_fused drives, so schedules and Adam's t match bitwise
-        lrs, wds, ts = {}, {}, {}
-        for i, n, _p in self._train_items:
-            opt._update_count(i)
-            lrs[n] = opt._get_lr(i)
-            wds[n] = opt._get_wd(i)
-            ts[n] = opt._index_update_count[i]
-        hyper = dict(opt._hyper())
-        hyper["rescale_grad"] = opt.rescale_grad
+        with _spans.span("train_step.prologue"):
+            # the legacy Trainer.step prologue: grads scale by scale/batch
+            opt.rescale_grad = tr._scale / batch_size
+            # resolve counts/lr/wd in trainer order — the exact sequence
+            # update_fused drives, so schedules and Adam's t match bitwise
+            lrs, wds, ts = {}, {}, {}
+            for i, n, _p in self._train_items:
+                opt._update_count(i)
+                lrs[n] = opt._get_lr(i)
+                wds[n] = opt._get_wd(i)
+                ts[n] = opt._index_update_count[i]
+            hyper = dict(opt._hyper())
+            hyper["rescale_grad"] = opt.rescale_grad
+        with _spans.span("train_step.operands"):
+            donate, nmode, tws, frozen, states, key, inputs = \
+                self._operands(batch)
+            fn = self._jitted(donate)
+            before = _cache_size(fn)
+        t0 = time.perf_counter()
+        with _spans.span("whole_step", cat="fwd"), \
+                _watchdog.guard("whole_step"):
+            loss_data, new_ws, new_states, aux = fn(
+                tws, frozen, states, key, lrs, wds, ts, hyper, *inputs)
+        after = _cache_size(fn)
+        if after is not None and after != before:
+            with _spans.span("train_step.compile_capture", cat="compile"):
+                compile_seconds = time.perf_counter() - t0
+                _telemetry.record_compile("whole_step", self._variant,
+                                          compile_seconds)
+                # static for a built program: 3 per trained parameter +
+                # the rule's hyper-parameters, every one a host-to-device
+                # transfer inside each call
+                _telemetry.record_step_scalar_operands(
+                    (tws, frozen, states, key, lrs, wds, ts, hyper, inputs))
+                # AOT cost/memory analysis of the one-dispatch program for
+                # the compile registry (tools/diagnose.py whole-step
+                # report); lower against specs — the live buffers were
+                # just donated
+                self._introspecting = True
+                try:
+                    _introspect.capture_compile(
+                        "whole_step", self._variant, fn,
+                        (_specs(tws), _specs(frozen), _specs(states),
+                         _specs(key), lrs, wds, ts, hyper,
+                         *[_specs(x) for x in inputs]),
+                        compile_seconds=compile_seconds)
+                finally:
+                    self._introspecting = False
+        with _spans.span("train_step.writeback"):
+            if nmode != "off":
+                self._numerics_boundary(
+                    loss_data,
+                    (tws, frozen, states, key, lrs, wds, ts, hyper,
+                     *inputs))
+            # write results back into the live containers (the donated
+            # buffers are dead; these are the fresh in-place outputs)
+            for i, n, p in self._train_items:
+                w = p.data()
+                w._data = new_ws[n]
+                w._version += 1
+                _write_state(tr._states[i], new_states[n])
+                # grads were consumed in-program: mark the (untouched)
+                # grad buffers stale exactly like the legacy update
+                # bookkeeping
+                tr._grad_versions[i] = p.grad()._version
+            for p, v in zip(self._sink_params, aux):
+                target = p.data() if isinstance(p, Parameter) else p
+                target._data = v
+                target._version += 1
+        with _spans.span("train_step.bookkeeping"):
+            # what the program's own instrumentation costs per step
+            _telemetry.record_step_dispatch(
+                "whole_step", _donated_bytes(tws, states) if donate else 0)
+            tr._record_step_complete(batch_size)
+        return NDArray(loss_data)
+
+    def _operands(self, batch):
+        """Gather the whole-step call's array operands from the live
+        containers, place them on the mesh, and decide donation.  Returns
+        (donate, numerics mode, tws, frozen, states, key, inputs)."""
+        tr = self._trainer
         tws, states = {}, {}
         for i, n, p in self._train_items:
             tws[n] = p.data()._data
@@ -708,50 +801,4 @@ class TrainStep:
             # bisects by re-running the recorded program on THESE
             # operands — they must survive the dispatch
             donate = False
-        fn = self._jitted(donate)
-        before = _cache_size(fn)
-        t0 = time.perf_counter()
-        with _spans.span("whole_step", cat="fwd"), \
-                _watchdog.guard("whole_step"):
-            loss_data, new_ws, new_states, aux = fn(
-                tws, frozen, states, key, lrs, wds, ts, hyper, *inputs)
-        _telemetry.record_step_dispatch(
-            "whole_step", _donated_bytes(tws, states) if donate else 0)
-        after = _cache_size(fn)
-        if after is not None and after != before:
-            compile_seconds = time.perf_counter() - t0
-            _telemetry.record_compile("whole_step", self._variant,
-                                      compile_seconds)
-            # AOT cost/memory analysis of the one-dispatch program for
-            # the compile registry (tools/diagnose.py whole-step report);
-            # lower against specs — the live buffers were just donated
-            self._introspecting = True
-            try:
-                _introspect.capture_compile(
-                    "whole_step", self._variant, fn,
-                    (_specs(tws), _specs(frozen), _specs(states),
-                     _specs(key), lrs, wds, ts, hyper,
-                     *[_specs(x) for x in inputs]),
-                    compile_seconds=compile_seconds)
-            finally:
-                self._introspecting = False
-        if nmode != "off":
-            self._numerics_boundary(
-                loss_data,
-                (tws, frozen, states, key, lrs, wds, ts, hyper, *inputs))
-        # write results back into the live containers (the donated
-        # buffers are dead; these are the fresh in-place outputs)
-        for i, n, p in self._train_items:
-            w = p.data()
-            w._data = new_ws[n]
-            w._version += 1
-            _write_state(tr._states[i], new_states[n])
-            # grads were consumed in-program: mark the (untouched) grad
-            # buffers stale exactly like the legacy update bookkeeping
-            tr._grad_versions[i] = p.grad()._version
-        for p, v in zip(self._sink_params, aux):
-            target = p.data() if isinstance(p, Parameter) else p
-            target._data = v
-            target._version += 1
-        tr._record_step_complete(batch_size)
-        return NDArray(loss_data)
+        return donate, nmode, tws, frozen, states, key, inputs

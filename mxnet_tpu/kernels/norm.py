@@ -177,6 +177,7 @@ def _fwd_pallas(x, gamma, beta, shift, eps):
     row = pl.BlockSpec((1, c), lambda p, i: (0, 0))
     out, mean, var, inv = _dispatch.pallas_call(
         functools.partial(_fwd_kernel, ew=ew, n=m, eps=eps),
+        name="batchnorm_train_fwd",
         grid=(2, m // bm),
         in_specs=[
             pl.BlockSpec((bm, c), lambda p, i: (i, 0)),
@@ -270,6 +271,7 @@ def _bwd_pallas(x, gamma, shift, mean, inv, dy, dmean_ct, dvar_ct):
     big = pl.BlockSpec((bm, c), lambda p, i: (i, 0))
     dx, dgamma, dbeta = _dispatch.pallas_call(
         functools.partial(_bwd_kernel, ew=ew, n=m),
+        name="batchnorm_train_bwd",
         grid=(2, m // bm),
         in_specs=[big, big, row, row, row, row, row, row],
         out_specs=[big, row, row],

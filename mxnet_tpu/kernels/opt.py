@@ -177,6 +177,7 @@ def _ladder_pallas(cls, clip, gn, mp, w, st, g, lr, wd, t, scale, hyper):
         hyper_keys=hyper_keys, treedef=treedef, out_w_dtype=w.dtype)
     outs = _dispatch.pallas_call(
         kernel,
+        name="optimizer_update_ladder",
         grid=(m // bm,),
         in_specs=[smem, big, big] + [big] * n_state,
         out_specs=[big] * n_out,

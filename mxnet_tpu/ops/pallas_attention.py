@@ -196,6 +196,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         block_k=block_k, valid_len=valid_len, dropout_p=dropout_p)
     out, lse = _pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             _smem_spec(),
@@ -390,6 +391,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           valid_len=valid_len, dropout_p=dropout_p),
+        name="flash_attention_bwd_dq",
         grid=(bh, s_len // block_q, s_len // block_k),
         in_specs=[
             _smem_spec(),
@@ -410,6 +412,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           valid_len=valid_len, dropout_p=dropout_p),
+        name="flash_attention_bwd_dkv",
         grid=(bh, s_len // block_k, s_len // block_q),
         in_specs=[
             _smem_spec(),

@@ -668,35 +668,47 @@ class _Interpreter:
         for var, val in zip(jaxpr.invars, args):
             self.write(var, val)
         for eqn in jaxpr.eqns:
-            name = eqn.primitive.name
-            if name == "conv_general_dilated":
-                self.conv(eqn)
-            elif name == "transpose":
-                self.transpose(eqn)
-            elif name == "reshape":
-                self.reshape(eqn)
-            elif name == "broadcast_in_dim":
-                self.broadcast(eqn)
-            elif name in _REDUCE_PRIMS:
-                self.reduce(eqn)
-            elif name in _RW_PRIMS:
-                self.reduce_window(eqn)
-            elif name == "optimization_barrier":
-                self.opt_barrier(eqn)
-            elif name == "custom_vjp_call":
-                bn = _bn_target(eqn)
-                if bn is not None:
-                    self.bn(eqn, *bn)
-                else:
-                    self.barrier(eqn)
-            elif name == "custom_jvp_call" and _is_relu(eqn):
-                self.relu(eqn)
-            elif name in _ELEMENTWISE and len(eqn.outvars) == 1:
-                self.elementwise(eqn)
+            # bind under the equation's own name stack, so the scopes of
+            # the blocks (BottleneckV1_3/BatchNorm_bn2) survive the
+            # rewrite into the HLO's op_names
+            scope = str(eqn.source_info.name_stack)
+            if scope:
+                with jax.named_scope(scope):
+                    self.rewrite(eqn)
             else:
-                self.barrier(eqn)
+                self.rewrite(eqn)
         # outvars read at identity: surviving transposes sink to the edges
         return [self.read(v) for v in jaxpr.outvars]
+
+    def rewrite(self, eqn):
+        """One equation through the rule for its primitive."""
+        name = eqn.primitive.name
+        if name == "conv_general_dilated":
+            self.conv(eqn)
+        elif name == "transpose":
+            self.transpose(eqn)
+        elif name == "reshape":
+            self.reshape(eqn)
+        elif name == "broadcast_in_dim":
+            self.broadcast(eqn)
+        elif name in _REDUCE_PRIMS:
+            self.reduce(eqn)
+        elif name in _RW_PRIMS:
+            self.reduce_window(eqn)
+        elif name == "optimization_barrier":
+            self.opt_barrier(eqn)
+        elif name == "custom_vjp_call":
+            bn = _bn_target(eqn)
+            if bn is not None:
+                self.bn(eqn, *bn)
+            else:
+                self.barrier(eqn)
+        elif name == "custom_jvp_call" and _is_relu(eqn):
+            self.relu(eqn)
+        elif name in _ELEMENTWISE and len(eqn.outvars) == 1:
+            self.elementwise(eqn)
+        else:
+            self.barrier(eqn)
 
 
 # ---------------------------------------------------------------------------

@@ -57,29 +57,6 @@ def _record(name, t0_us, dur_us, cat="host"):
         })
 
 
-def perf_counter_to_trace_us(t):
-    """Convert a raw ``time.perf_counter()`` reading to this trace's
-    microsecond timeline (diagnostics spans store perf_counter stamps and
-    replay them here, so both layers share one clock origin)."""
-    return (t - _t_origin) * 1e6
-
-
-def record_host_event(name, ts_us, dur_us, cat="host", args=None):
-    """Append a complete chrome "X" event to the host buffer — the
-    diagnostics span bridge's entry point, gated like every host event.
-    Returns 1 if recorded, 0 if not recording."""
-    if not _host_recording():
-        return 0
-    ev = {"name": name, "cat": cat, "ph": "X", "ts": ts_us,
-          "dur": dur_us, "pid": os.getpid(),
-          "tid": threading.get_ident() % 100000}
-    if args:
-        ev["args"] = dict(args)
-    with _events_lock:
-        _events.append(ev)
-    return 1
-
-
 def record_counter_event(name, value, cat="telemetry"):
     """Append a chrome counter event (`"ph": "C"`) to the host buffer —
     the telemetry bridge's entry point (telemetry/chrome.py), gated like

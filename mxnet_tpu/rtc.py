@@ -56,6 +56,8 @@ class PallasKernel:
         if fn is None:
             if grid is not None:
                 pallas_kwargs = dict(pallas_kwargs, grid=grid)
+            # the kernel's own name in the HLO and in a device trace
+            pallas_kwargs.setdefault("name", self.name)
             if "interpret" not in pallas_kwargs:
                 # Mosaic lowering needs a TPU; elsewhere run the kernel in
                 # interpret mode (numerics-identical, like

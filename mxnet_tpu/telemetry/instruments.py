@@ -15,11 +15,15 @@ base units), unprefixed — one process, one framework.
 """
 from __future__ import annotations
 
+import threading
+
 from .registry import REGISTRY, counter, gauge, histogram
 
 __all__ = [
     "jit_compile_total", "jit_compile_seconds", "jit_trace_total",
     "hybridize_fallback_total",
+    "xla_compile_seconds_total", "xla_programs_total",
+    "install_compile_listener",
     "transfer_total", "transfer_bytes_total",
     "sync_total", "sync_blocked_seconds_total",
     "collective_total", "collective_bytes_total",
@@ -29,6 +33,7 @@ __all__ = [
     "update_dispatch_total", "fused_bucket_size", "update_donated_bytes",
     "record_update_dispatch", "record_fused_bucket",
     "step_dispatch_total", "step_donated_bytes",
+    "step_scalar_operands", "record_step_scalar_operands",
     "pass_applied_total", "pass_rewrite_ms", "graph_dedup_hits_total",
     "remat_policy", "record_pass", "record_dedup_hit",
     "record_remat_policy",
@@ -132,6 +137,19 @@ jit_trace_total = counter(
     "is one XLA compile, including shape-cache misses AFTER the variant "
     "was first built (gluon/block.py cached_fn; the serving warmup "
     "zero-miss proof reads the per-block counterpart)", ["block", "variant"])
+# what JAX itself compiles, under every jit of the package and of the
+# user: fed by ONE jax.monitoring listener (install_compile_listener)
+xla_compile_seconds_total = counter(
+    "xla_compile_seconds_total",
+    "Seconds JAX spent per compile stage, over every program of the "
+    "process: trace (jaxpr), lower (to MLIR), backend (XLA compile OR "
+    "the persistent-cache lookup and load — JAX times both under one "
+    "event), cache_load (the load alone; backend - cache_load is what "
+    "XLA spent building)", ["stage"])
+xla_programs_total = counter(
+    "xla_programs_total",
+    "Programs JAX obtained an executable for: how=loaded from the "
+    "persistent compile cache, how=built by XLA", ["how"])
 hybridize_fallback_total = counter(
     "hybridize_fallback_total",
     "Hybridized blocks that fell back to imperative execution on a "
@@ -227,6 +245,13 @@ step_donated_bytes = counter(
     "Bytes of parameter + optimizer-state buffers donated into "
     "whole-step dispatches so the weights update in place (HBM reuse "
     "instead of a second copy of the model)")
+
+step_scalar_operands = gauge(
+    "step_scalar_operands",
+    "Python-scalar leaves (lr / wd / update count per trained parameter "
+    "+ the rule's hyper-parameters) among the operands of the built "
+    "whole-step program: each is a separate host-to-device transfer "
+    "inside every call (gluon/train_step.py)")
 
 # -- graph-pass pipeline (mxnet_tpu/passes/; docs/passes.md) ----------------
 pass_applied_total = counter(
@@ -645,6 +670,49 @@ def nbytes_of(x):
     return 0
 
 
+_XLA_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_xla_tls = threading.local()
+
+
+def _on_xla_duration(event, duration, **_kw):
+    stage = _XLA_STAGE_OF_EVENT.get(event)
+    if stage is None or not REGISTRY.enabled:
+        return
+    xla_compile_seconds_total.labels(stage).inc(duration)
+    # JAX (0.9) reports a cache hit's retrieval time from INSIDE the
+    # region it times as backend_compile_duration, on the same thread:
+    # the backend event that follows a load is that load, not a build
+    if stage == "cache_load":
+        _xla_tls.load_seconds = duration
+    elif stage == "backend":
+        load_seconds = getattr(_xla_tls, "load_seconds", None)
+        _xla_tls.load_seconds = None
+        how = "built" if load_seconds is None else "loaded"
+        xla_programs_total.labels(how).inc()
+        # the black box keeps WHEN each program arrived (perf_counter
+        # `pc`, the spans' clock): a reader can tell set-up's compiles
+        # from those of a later phase of the process
+        _flight_record("xla_compile", how=how, seconds=duration,
+                       load_seconds=load_seconds or 0.0)
+
+
+def install_compile_listener():
+    """Register the one jax.monitoring listener behind
+    xla_compile_seconds_total / xla_programs_total (idempotent; called
+    at import of mxnet_tpu, before anything compiles)."""
+    if getattr(_on_xla_duration, "installed", False):
+        return
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_xla_duration)
+    _on_xla_duration.installed = True
+
+
 def record_compile(block, variant, seconds):
     _flight_record("compile", block=str(block), variant=str(variant),
                    seconds=seconds)
@@ -841,6 +909,18 @@ def record_step_dispatch(path, donated_bytes=0):
     step_dispatch_total.labels(path).inc()
     if donated_bytes:
         step_donated_bytes.inc(donated_bytes)
+
+
+def record_step_scalar_operands(operands):
+    """The whole-step program was built for `operands`: count the
+    Python scalars among their leaves."""
+    if not REGISTRY.enabled:
+        return
+    import jax
+
+    step_scalar_operands.set(sum(
+        isinstance(x, (bool, int, float))
+        for x in jax.tree_util.tree_leaves(operands)))
 
 
 def record_pass(name, ms):

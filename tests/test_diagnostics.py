@@ -136,30 +136,6 @@ def test_step_table_no_double_count_nested_same_cat(fresh):
     assert table[0]["fwd"] == pytest.approx(outer["dur"])
 
 
-def test_emit_chrome_spans_into_profiler(fresh, tmp_path):
-    import json
-
-    from mxnet_tpu import profiler
-
-    with spans.span("traced", cat="sync"):
-        pass
-    # not recording -> nothing lands
-    assert spans.emit_chrome_spans() == 0
-    profiler.set_config(profile_all=True,
-                        filename=str(tmp_path / "trace.json"))
-    try:
-        assert spans.emit_chrome_spans() == 1
-        path = profiler.dump()
-        with open(path) as f:
-            events = json.load(f)["traceEvents"]
-    finally:
-        profiler.set_config(profile_all=False)
-    ev = [e for e in events if e["name"] == "span::traced"]
-    assert len(ev) == 1
-    assert ev[0]["ph"] == "X" and ev[0]["cat"] == "diag.sync"
-    assert ev[0]["args"]["step"] == 0
-
-
 # -- compile registry -------------------------------------------------------
 
 def test_compile_registry_on_hybrid_block(fresh):
@@ -390,3 +366,185 @@ def test_print_summary_still_errors_on_undeducible():
     out = a + b
     with pytest.raises(ValueError, match="shape for input"):
         mx.visualization.print_summary(out, shape={})
+
+
+# -- one span API on the profiler's clock (ISSUE 25) ------------------------
+
+STEP_CHILDREN = ["train_step.prologue", "train_step.operands", "whole_step",
+                 "train_step.writeback", "train_step.bookkeeping"]
+
+
+def _toy_train_step():
+    from mxnet_tpu.gluon import TrainStep
+
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, activation="relu"), nn.Dense(4))
+    net.initialize()
+    net.hybridize()
+    trainer = Trainer(net.collect_params(), "sgd", {"learning_rate": 0.1})
+    step = TrainStep(net, lambda out, y: ((out - y) ** 2).mean(), trainer)
+    return step, mx.np.ones((4, 6)), mx.np.zeros((4, 4))
+
+
+def _host_annotations(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of the mxtpu: annotations."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(spans.ANNOTATION_PREFIX):
+                    out.append((ev.name[len(spans.ANNOTATION_PREFIX):],
+                                ev.start_ns, ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+@pytest.fixture
+def profiled_steps(fresh, tmp_path):
+    """Three whole steps of a toy TrainStep under jax.profiler."""
+    import jax
+
+    step, x, y = _toy_train_step()
+    step(x, y)          # compile outside the trace
+    diagnostics.reset()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(3):
+            step(x, y)
+    finally:
+        jax.profiler.stop_trace()
+    return _host_annotations(tmp_path)
+
+
+def test_profiled_steps_carry_step_num(profiled_steps):
+    steps = [e for e in profiled_steps if e[0] == "train_step"]
+    assert [e[3]["step_num"] for e in steps] == [0, 1, 2]
+
+
+def test_profiled_children_inside_parent_in_order(profiled_steps):
+    steps = [e for e in profiled_steps if e[0] == "train_step"]
+    assert len(steps) == 3
+    for _n, t0, t1, _s in steps:
+        inside = [e for e in profiled_steps
+                  if e[0] != "train_step" and t0 <= e[1] and e[2] <= t1]
+        assert [e[0] for e in inside] == STEP_CHILDREN
+        for a, b in zip(inside, inside[1:]):
+            assert a[2] <= b[1]          # contiguous parts never overlap
+    # every child annotation lies in some step: nothing is left outside
+    assert len(profiled_steps) == 3 * (1 + len(STEP_CHILDREN))
+
+
+def test_ring_records_carry_parent(fresh):
+    with spans.span("outer", cat="fwd"):
+        with spans.span("inner"):
+            pass
+    by_name = {r["name"]: r for r in spans.records()}
+    assert by_name["inner"]["parent"] == "outer"
+    assert by_name["outer"]["parent"] is None
+
+
+def test_train_step_children_cover_the_outer_span(fresh):
+    step, x, y = _toy_train_step()
+    step(x, y)
+    diagnostics.reset()
+    for _ in range(2):
+        step(x, y)
+    recs = [r for r in spans.records() if r["name"] != "train_step"]
+    outer = [r for r in spans.records() if r["name"] == "train_step"]
+    assert [r["name"] for r in recs] == STEP_CHILDREN * 2
+    assert all(r["parent"] == "train_step" and r["depth"] == 1
+               for r in recs)
+    assert [r["step"] for r in outer] == [0, 1] and \
+        [r["step"] for r in recs] == [0] * 5 + [1] * 5
+    # structure, not wall time: the children start inside the outer span
+    # and each starts where the one before it ended or later
+    for o, kids in zip(outer, (recs[:5], recs[5:])):
+        assert o["t0"] <= kids[0]["t0"]
+        assert kids[-1]["t0"] + kids[-1]["dur"] <= o["t0"] + o["dur"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["t0"] + a["dur"] <= b["t0"]
+
+
+def test_first_call_has_compile_capture_child(fresh):
+    step, x, y = _toy_train_step()
+    step(x, y)
+    names = [r["name"] for r in spans.records()
+             if r.get("parent") == "train_step"]
+    assert names.count("train_step.compile_capture") == 1
+    assert names.index("whole_step") < names.index(
+        "train_step.compile_capture") < names.index("train_step.writeback")
+
+
+def test_step_table_leaves_out_the_step_span(fresh):
+    with spans.span("train_step", cat=spans.STEP_CAT, step_num=0):
+        with spans.span("whole_step", cat="fwd"):
+            pass
+    row = spans.step_table()[0]
+    assert set(row) == {"fwd"}
+
+
+def test_disabled_records_nothing_and_builds_no_annotation(
+        fresh, monkeypatch):
+    step, x, y = _toy_train_step()
+    step(x, y)
+    made = []
+
+    class Counting:
+        def __init__(self, *a, **k):
+            made.append(a)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Counting)
+    monkeypatch.setattr(spans, "StepTraceAnnotation", Counting)
+    diagnostics.reset()
+    traces = step.jit_trace_count()
+    spans.disable()
+    try:
+        step(x, y)
+        with spans.span("off"):
+            pass
+    finally:
+        spans.enable()
+    assert spans.records() == [] and made == []
+    assert step.jit_trace_count() == traces
+    step(x, y)      # and on again: one annotation per span
+    assert len(made) == 1 + len(STEP_CHILDREN)
+
+
+def test_raising_body_closes_annotation_and_records(fresh, monkeypatch):
+    closed = []
+
+    class Recording:
+        def __init__(self, name, **kv):
+            self.name = name
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, exc_type, exc, tb):
+            closed.append((self.name, exc_type))
+            return False
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Recording)
+    with pytest.raises(KeyError):
+        with spans.span("outer"):
+            with spans.span("boom", cat="sync"):
+                raise KeyError("x")
+    assert closed == [("mxtpu:boom", KeyError), ("mxtpu:outer", KeyError)]
+    assert [r["name"] for r in spans.records()] == ["boom", "outer"]
+    assert spans.current_stack() == []

@@ -517,3 +517,95 @@ def test_byte_model_predicts_30pct_optimizer_mp(monkeypatch):
     xla_f32, k_f32 = pmem.optimizer_region_bytes(size, jnp.float32, 1,
                                                  False)
     assert xla_f32 == k_f32
+
+
+# -- a stable name on every Pallas call (ISSUE 25) ----------------------------
+
+def _pallas_names(closed):
+    """Names of the pallas_call equations of a jaxpr, nested ones too."""
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(eqn.params["name"])
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None and hasattr(inner, "eqns"):
+                    walk(inner)
+                elif hasattr(v, "eqns"):
+                    walk(v)
+    walk(closed.jaxpr)
+    return out
+
+
+def _flash_names(grad):
+    from mxnet_tpu.ops.pallas_attention import flash_attention
+
+    q = jnp.ones((1, 2, 128, 64), jnp.float32)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, interpret=True).sum()
+
+    fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
+    return _pallas_names(jax.make_jaxpr(fn)(q, q, q))
+
+
+def _bn_names(grad, monkeypatch):
+    _force(monkeypatch)
+    x, gamma, beta, shift = _bn_operands(m=64, c=128)
+
+    def f(x, gamma, beta):
+        return knorm.bn_train(x, gamma, beta, shift, 1e-5, 1)[0].sum()
+
+    fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
+    return _pallas_names(jax.make_jaxpr(fn)(x, gamma, beta))
+
+
+def _opt_names(monkeypatch):
+    _force(monkeypatch)
+    w, st, g = _opt_operands(mp=True, n_state=1)
+    return _pallas_names(jax.make_jaxpr(
+        lambda w, st, g: kopt.param_step(
+            SGD, 0.5, False, True, w, st, g, 0.125, 1e-4, 3, 1.0,
+            {"rescale_grad": 1.0 / 8, "momentum": 0.9}))(w, st, g))
+
+
+@pytest.mark.parametrize("which,expect", [
+    ("flash_fwd", ["flash_attention_fwd"]),
+    ("flash_bwd", ["flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"]),
+    ("bn_fwd", ["batchnorm_train_fwd"]),
+    ("bn_bwd", ["batchnorm_train_fwd", "batchnorm_train_bwd"]),
+    ("opt", ["optimizer_update_ladder"]),
+])
+def test_pallas_calls_carry_stable_names(monkeypatch, which, expect):
+    names = {"flash_fwd": lambda: _flash_names(False),
+             "flash_bwd": lambda: _flash_names(True),
+             "bn_fwd": lambda: _bn_names(False, monkeypatch),
+             "bn_bwd": lambda: _bn_names(True, monkeypatch),
+             "opt": lambda: _opt_names(monkeypatch)}[which]()
+    assert sorted(set(names)) == sorted(expect)
+
+
+def test_rtc_kernel_is_named_after_itself(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    from mxnet_tpu import rtc
+
+    seen = []
+    real = pl.pallas_call
+
+    def spy(body, **kw):
+        seen.append(kw.get("name"))
+        return real(body, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+
+    def double(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    kern = rtc.PallasModule(double).get_kernel("double")
+    out = kern.launch([mnp.ones((8, 128))], (8, 128), "float32")
+    assert seen == ["double"]
+    onp.testing.assert_allclose(out.asnumpy(), 2.0)

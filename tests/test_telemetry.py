@@ -489,3 +489,70 @@ def test_promparse_content_type_constant():
 
     assert promparse.CONTENT_TYPE == \
         "text/plain; version=0.0.4; charset=utf-8"
+
+
+# -- what JAX compiles, counted inside the program (ISSUE 25) ----------------
+
+def _xla_counts():
+    from mxnet_tpu.telemetry import instruments as ti
+
+    programs = {k[0]: c.value for k, c in ti.xla_programs_total.series()}
+    seconds = {k[0]: c.value
+               for k, c in ti.xla_compile_seconds_total.series()}
+    return programs, seconds
+
+
+def test_compile_listener_counts_a_fresh_jit_once(fresh):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.observability import flight
+
+    x = jnp.arange(7.0)
+    x.block_until_ready()           # its own programs compile here
+    f = jax.jit(lambda v: jnp.tanh(v * 3.25 + 0.125).sum())
+    p0, s0 = _xla_counts()
+    n_events = sum(e["kind"] == "xla_compile" for e in flight.events())
+    f(x)
+    p1, s1 = _xla_counts()
+    got = {k: p1.get(k, 0) - p0.get(k, 0) for k in ("built", "loaded")}
+    # one program: built, or loaded where a cache already held it
+    assert sorted(got.values()) == [0, 1]
+    for stage in ("trace", "lower", "backend"):
+        assert s1[stage] > s0.get(stage, 0.0)
+    if got["loaded"]:
+        assert s1["cache_load"] > s0.get("cache_load", 0.0)
+    else:
+        assert s1.get("cache_load", 0.0) == s0.get("cache_load", 0.0)
+    events = [e for e in flight.events() if e["kind"] == "xla_compile"]
+    assert len(events) == n_events + 1
+    assert events[-1]["how"] == ("loaded" if got["loaded"] else "built")
+    assert events[-1]["seconds"] > 0
+    f(x)                            # the second call compiles nothing
+    assert _xla_counts() == (p1, s1)
+
+
+def test_compile_listener_is_installed_once():
+    from jax._src import monitoring
+
+    from mxnet_tpu.telemetry import instruments as ti
+
+    ti.install_compile_listener()
+    ti.install_compile_listener()
+    assert monitoring._event_duration_secs_listeners.count(
+        ti._on_xla_duration) == 1
+
+
+def test_compile_listener_silent_when_disabled(fresh):
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.arange(5.0)
+    x.block_until_ready()
+    before = _xla_counts()
+    telemetry.disable()
+    try:
+        jax.jit(lambda v: (v * 1.75 - 2.0).sum())(x)
+    finally:
+        telemetry.enable()
+    assert _xla_counts() == before

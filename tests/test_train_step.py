@@ -517,3 +517,155 @@ def test_wholestep_with_prefetched_loader_trains():
         loss = step(x, y)
         assert onp.isfinite(loss.asnumpy().astype("float32")).all()
     assert step.last_path == "whole_step", step.ineligible_reason()
+
+
+# -- names inside the program (ISSUE 25) -------------------------------------
+
+def _conv_bn_dense_step():
+    mx.seed(0)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Conv2D(4, 3, padding=1), gluon.nn.BatchNorm(),
+            gluon.nn.Activation("relu"), gluon.nn.GlobalAvgPool2D(),
+            gluon.nn.Dense(OUT))
+    net.initialize()
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9})
+    step = gluon.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                           trainer)
+    r = onp.random.RandomState(5)
+    x = mnp.array(r.standard_normal((4, 3, 8, 8)).astype("float32"))
+    y = mnp.array(r.randint(0, OUT, (4,)))
+    return step, trainer, x, y
+
+
+@pytest.fixture
+def scoped_step(monkeypatch):
+    from mxnet_tpu import diagnostics
+    from mxnet_tpu.diagnostics import introspect
+
+    was = telemetry.enabled()
+    diagnostics.reset()
+    telemetry.enable()
+    telemetry.reset()
+    texts, parse = [], introspect.op_scopes
+    monkeypatch.setattr(introspect, "op_scopes",
+                        lambda text: (texts.append(text), parse(text))[1])
+    step, trainer, x, y = _conv_bn_dense_step()
+    for _ in range(5):
+        step(x, y)
+    entry = diagnostics.compile_registry()[("whole_step", step._variant)]
+    yield step, trainer, entry, texts[-1]
+    diagnostics.reset()
+    telemetry.reset()
+    if not was:
+        telemetry.disable()
+
+
+@pytest.mark.parametrize("scope", [
+    "/jvp(forward)/", "/transpose(jvp(forward))/", "/jvp(loss)/",
+    "/optimizer/", "/jvp(forward)/BatchNorm_1/",
+    "/transpose(jvp(forward))/Conv2D_0/", "/jvp(forward)/Dense_4/"])
+def test_whole_step_hlo_names_its_work(scoped_step, scope):
+    entry = scoped_step[2]
+    names = entry["op_scopes"].values()
+    assert all(v.startswith("jit(whole_step)/") for v in names
+               if v.startswith("jit("))
+    assert any(scope in v for v in names), sorted(set(names))[:40]
+
+
+def test_op_scopes_resolve_the_entry_instructions(scoped_step):
+    """Every entry instruction that carries an op_name is in the map.
+    (The CPU compiler's own copies, constants, layout transposes and its
+    rewritten weight-gradient convolution carry none; what share of the
+    DEVICE's time resolves is the chip's number,
+    device_scope_coverage_pct.train.)"""
+    import re
+
+    entry, text = scoped_step[2], scoped_step[3]
+    entry_block = text[text.index("\nENTRY "):]
+    lines = entry_block[:entry_block.index("\n}")].splitlines()[1:]
+    named = [(m.group(1), "op_name=" in ln) for ln in lines
+             for m in [re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", ln)]
+             if m and " parameter(" not in ln]
+    assert len(named) > 20
+    with_meta = [n for n, has in named if has]
+    resolved = [n for n, _ in named if n in entry["op_scopes"]]
+    assert sorted(resolved) == sorted(with_meta)
+    assert len(resolved) >= 0.6 * len(named)
+    # and none of a fusion's inner instructions leaked into the map
+    bodies = set(re.findall(r" fusion\(.*?calls=%?([\w.\-]+)", text))
+    assert bodies and not bodies & set(entry["op_scopes"])
+    inner = re.search(
+        r"\n%?fused_computation[\w.\-]* .*\{\n\s+%?([\w.\-]+) = ", text)
+    assert inner and inner.group(1) not in entry["op_scopes"]
+
+
+def test_scopes_add_no_retrace(scoped_step):
+    step = scoped_step[0]
+    assert step.jit_trace_count() == 1
+    assert _whole_trace_count() == 1
+
+
+def test_step_scalar_operands_gauge(scoped_step):
+    step, trainer = scoped_step[:2]
+    trained = len(step._train_items)
+    hyper = dict(trainer._optimizer._hyper())
+    hyper["rescale_grad"] = 1.0
+    assert trained == 6
+    assert ti.step_scalar_operands.value == 3 * trained + len(hyper)
+
+
+def test_named_scope_leaves_program_identity_alone():
+    """Scopes are metadata: dedup's structural key and the measurement
+    plane's fingerprint of a body do not see them."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.observability import measure
+    from mxnet_tpu.passes import dedup
+
+    def body(x, w):
+        return jnp.tanh(x @ w).sum()
+
+    def scoped(x, w):
+        with jax.named_scope("BottleneckV1_3"):
+            with jax.named_scope("BatchNorm_bn2"):
+                return body(x, w)
+
+    args = (jnp.ones((4, 8)), jnp.ones((8, 2)))
+    plain_j, scoped_j = jax.make_jaxpr(body)(*args), \
+        jax.make_jaxpr(scoped)(*args)
+    assert "BatchNorm_bn2" in str(
+        scoped_j.jaxpr.eqns[0].source_info.name_stack)
+    assert dedup.structural_key(plain_j) is not None
+    assert dedup.structural_key(plain_j) == dedup.structural_key(scoped_j)
+    assert measure.fingerprint_of(plain_j) == measure.fingerprint_of(
+        scoped_j)
+
+
+def test_layout_pass_keeps_block_scopes():
+    """The channels-last rewrite re-emits every equation by hand: each
+    is bound under its own name stack, so a block's scope survives."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from mxnet_tpu.passes import layout
+
+    def fwd(x, w):
+        with jax.named_scope("Conv2D_0"):
+            y = lax.conv_general_dilated(
+                x, w, (1, 1), "SAME",
+                dimension_numbers=("NCHW", "OIHW", "NCHW"))
+        with jax.named_scope("Activation_1"):
+            return jnp.maximum(y, 0.0)
+
+    args = (jnp.ones((2, 3, 8, 8)), jnp.ones((4, 3, 3, 3)))
+    closed = jax.make_jaxpr(fwd)(*args)
+    rw = layout._Interpreter(layout._Stats())
+    out = jax.make_jaxpr(lambda *a: rw.run(closed, a))(*args)
+    stacks = {e.primitive.name: str(e.source_info.name_stack)
+              for e in out.jaxpr.eqns}
+    assert stacks["conv_general_dilated"] == "Conv2D_0"
+    assert stacks["max"] == "Activation_1"
