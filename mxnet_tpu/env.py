@@ -246,7 +246,7 @@ register(
     "gluon.TrainStep compiled whole-step path: forward + backward + "
     "gradient allreduce + fused optimizer update captured in ONE donated "
     "jit dispatch per training step (params/optimizer state donated, "
-    "per-param lr/wd/t as weak scalars — LR schedules never retrace). "
+    "per-param lr/wd/t as packed vectors — LR schedules never retrace). "
     "0 forces the legacy three-phase record/backward/Trainer.step "
     "sequence; sparse grads, overriding optimizers, clip_global_norm and "
     "multi-copy params fall back automatically (docs/performance.md).")

@@ -8,9 +8,13 @@ and the PR-4 fused optimizer update — into a single `jax.jit` program:
 
   * parameter weights and optimizer state are DONATED, so XLA updates
     them in place (no second copy of the model in HBM);
-  * per-param lr/wd/update-count enter as weak-typed python scalars —
-    the same trick as `Optimizer.update_fused` — so LR schedules change
-    values, never signatures: zero retraces after the first step;
+  * per-param lr/wd/update-count and the rule's hyper-parameters enter
+    as four host arrays (float32[n], float32[n], int32[n] in trained-
+    parameter order, and `Optimizer._packed_hyper`) — the same operands
+    as `Optimizer.update_fused`, read out by `_weak_elems` as weak
+    scalars — so LR schedules change values, never signatures (zero
+    retraces after the first step) and a call makes four small
+    transfers however many parameters train;
   * the forward runs through the exact `_traced_forward` body the
     CachedOp jit uses, the backward is `jax.vjp` seeded with ones (the
     `loss.backward()` contract), and the update unrolls
@@ -46,6 +50,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 
 from .. import _random
 from .. import autograd as ag
@@ -55,7 +60,8 @@ from ..diagnostics import watchdog as _watchdog
 from ..ndarray.ndarray import NDArray
 from ..optimizer.optimizer import (Optimizer, _cache_size, _donate_enabled,
                                    _donated_bytes, _donation_safe, _specs,
-                                   _unwrap, _write_state)
+                                   _unpack_hyper, _unwrap, _weak_elems,
+                                   _write_state)
 from ..telemetry import instruments as _telemetry
 from .block import HybridBlock, _traced_forward
 from .parameter import Parameter
@@ -263,11 +269,10 @@ class TrainStep:
             tr._ensure_states(i, p.data())
             items.append((i, name_of[id(p)], p))
         self._train_items = items
+        self._train_index = [i for i, _n, _p in items]
         # bucket by (weight dtype, multi-precision) in trainer order —
         # the exact bucketing update_fused(multi_precision=True) builds,
         # so the unrolled update is the same program member-for-member
-        import numpy as _np
-
         buckets = {}
         for i, n, p in items:
             s = tr._states[i]
@@ -284,6 +289,9 @@ class TrainStep:
         self._variant = (f"{type(opt).__name__.lower()}"
                          f"-p{len(items)}-b{len(self._buckets)}"
                          f"-{mode_tag}")
+        # layout of the packed hyper-parameter operand, fixed for the
+        # life of the compiled program
+        self._hyper_keys = tuple(sorted(opt._hyper()))
         self._step_fn = self._make_step_fn()
         self._built = True
 
@@ -298,6 +306,9 @@ class TrainStep:
         cls = type(opt)
         clip = opt.clip_gradient
         wdtype = {n: p.data().dtype for _i, n, p in self._train_items}
+        # position of each trained parameter in the lrs / wds / ts operands
+        pos = {n: k for k, (_i, n, _p) in enumerate(self._train_items)}
+        hyper_keys = self._hyper_keys
         bucket_specs = self._buckets
         mesh, axis = self._mesh, self._axis
         kv = tr._kvstore
@@ -471,16 +482,18 @@ class TrainStep:
             # _fused_jitted math (shared body), fused into this program
             new_ws, new_states = {}, {}
             with jax.named_scope("optimizer"):
+                lr_of, wd_of, t_of = (_weak_elems(v) for v in (lrs, wds, ts))
+                h, scale = _unpack_hyper(hyper_keys, hyper)
                 for (_dtype_s, use_mp), names in bucket_specs:
                     nws, nsts = Optimizer._fused_step_body(
                         cls, clip, False, use_mp,
                         [tws[n] for n in names],
                         [states[n] for n in names],
                         [gd[n] for n in names],
-                        [lrs[n] for n in names],
-                        [wds[n] for n in names],
-                        [ts[n] for n in names],
-                        1.0, hyper)
+                        [lr_of[pos[n]] for n in names],
+                        [wd_of[pos[n]] for n in names],
+                        [t_of[pos[n]] for n in names],
+                        scale, h)
                     for n, nw, ns in zip(names, nws, nsts):
                         new_ws[n] = nw
                         new_states[n] = ns
@@ -659,14 +672,9 @@ class TrainStep:
             opt.rescale_grad = tr._scale / batch_size
             # resolve counts/lr/wd in trainer order — the exact sequence
             # update_fused drives, so schedules and Adam's t match bitwise
-            lrs, wds, ts = {}, {}, {}
-            for i, n, _p in self._train_items:
-                opt._update_count(i)
-                lrs[n] = opt._get_lr(i)
-                wds[n] = opt._get_wd(i)
-                ts[n] = opt._index_update_count[i]
-            hyper = dict(opt._hyper())
-            hyper["rescale_grad"] = opt.rescale_grad
+            # — into one host array per family
+            lrs, wds, ts = opt._packed_schedule(self._train_index)
+            hyper = opt._packed_hyper(self._hyper_keys)
         with _spans.span("train_step.operands"):
             donate, nmode, tws, frozen, states, key, inputs = \
                 self._operands(batch)
@@ -683,9 +691,10 @@ class TrainStep:
                 compile_seconds = time.perf_counter() - t0
                 _telemetry.record_compile("whole_step", self._variant,
                                           compile_seconds)
-                # static for a built program: 3 per trained parameter +
-                # the rule's hyper-parameters, every one a host-to-device
-                # transfer inside each call
+                # static for a built program, and 4 when it is sound (the
+                # four host arrays above): more means Python scalars
+                # leaked back into the call, each one a host-to-device
+                # transfer per step
                 _telemetry.record_step_scalar_operands(
                     (tws, frozen, states, key, lrs, wds, ts, hyper, inputs))
                 # AOT cost/memory analysis of the one-dispatch program for
@@ -696,9 +705,8 @@ class TrainStep:
                 try:
                     _introspect.capture_compile(
                         "whole_step", self._variant, fn,
-                        (_specs(tws), _specs(frozen), _specs(states),
-                         _specs(key), lrs, wds, ts, hyper,
-                         *[_specs(x) for x in inputs]),
+                        (*_specs((tws, frozen, states, key)), lrs, wds,
+                         ts, hyper, *_specs(inputs)),
                         compile_seconds=compile_seconds)
                 finally:
                     self._introspecting = False

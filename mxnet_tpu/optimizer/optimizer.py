@@ -14,8 +14,9 @@ are bucketed by (weight dtype, multi-precision) and each bucket runs ONE
 donated jit dispatch doing rescale → global-norm clip → per-element clip →
 `_rule` for every member — O(buckets) dispatches instead of O(params), with
 weight/state buffers donated so XLA updates them in place. Per-param lr/wd/
-update-counts enter as weak-typed scalars, so schedule changes never
-retrace. MXTPU_FUSED_UPDATE=0 restores the per-param loop.
+update-counts enter as one host array per family (`_weak_elems` reads them
+out inside the program), so schedule changes never retrace.
+MXTPU_FUSED_UPDATE=0 restores the per-param loop.
 """
 from __future__ import annotations
 
@@ -219,6 +220,33 @@ class Optimizer:
         """Dynamic (non-recompiling) hyperparameters as a dict of scalars."""
         return {}
 
+    def _packed_schedule(self, indices):
+        """Advance the update counts of `indices` and resolve their lr /
+        wd, one parameter after the other (so num_update-driven schedules
+        and Adam's t see exactly the per-parameter loop's sequence), into
+        the three per-parameter operands of a fused program: float32[n],
+        float32[n], int32[n]. A Python scalar among a jitted call's
+        operands is a host-to-device transfer of its own on every call;
+        a vector is one, whatever n is."""
+        n = len(indices)
+        lrs = _np.empty(n, _np.float32)
+        wds = _np.empty(n, _np.float32)
+        ts = _np.empty(n, _np.int32)
+        for k, i in enumerate(indices):
+            self._update_count(i)
+            lrs[k] = self._get_lr(i)
+            wds[k] = self._get_wd(i)
+            ts[k] = self._index_update_count[i]
+        return lrs, wds, ts
+
+    def _packed_hyper(self, keys, scale=1.0):
+        """The one hyper-parameter operand of a fused program: float32
+        [`_hyper()` in the order of `keys`, rescale_grad, global-norm
+        scale] — `_unpack_hyper` is its inverse inside the program."""
+        hyper = self._hyper()
+        return _np.asarray([*(hyper[k] for k in keys), self.rescale_grad,
+                            scale], _np.float32)
+
     # -- the pure rule; subclasses override -------------------------------
     @staticmethod
     def _rule(w, g, state, lr, wd, hyper):
@@ -315,26 +343,31 @@ class Optimizer:
         """One jit for a whole bucket of n same-dtype params: the python
         loop unrolls at trace time into a single XLA program (the
         multi-tensor-apply analog), weights+states donated so outputs
-        reuse their HBM. lr/wd/t arrive as tuples of python scalars —
-        weak-typed leaves whose VALUES never retrace (only a length or
-        dtype change does), which also preserves the legacy dtype
-        promotion (bf16 math stays bf16)."""
+        reuse their HBM. lr/wd/t arrive as three host vectors of length n
+        (float32, float32, int32) and the hyper-parameters as one
+        (`_packed_hyper`): four transfers a call whatever n is, and
+        VALUES never retrace. `_weak_elems` hands the rule weak scalars,
+        which preserves the legacy dtype promotion (bf16 math stays
+        bf16)."""
         cls = type(self)
+        hkeys = tuple(sorted(self._hyper()))
         gn = self.clip_global_norm is not None
         try:
             from ..kernels import dispatch as _kdispatch
             kmode = _kdispatch.mode()
         except ImportError:
             kmode = "off"
-        key = (cls, self.clip_gradient, "fused", n, mp, gn, donate, kmode)
+        key = (cls, self.clip_gradient, "fused", n, mp, gn, donate, kmode,
+               hkeys)
         fn = Optimizer._jit_cache.get(key)
         if fn is None:
             clip = self.clip_gradient
 
-            def step(ws, states, gs, lrs, wds, ts, scale, hyper):
+            def step(ws, states, gs, lrs, wds, ts, hvec):
+                hyper, scale = _unpack_hyper(hkeys, hvec)
                 return Optimizer._fused_step_body(
-                    cls, clip, gn, mp, ws, states, gs, lrs, wds, ts,
-                    scale, hyper)
+                    cls, clip, gn, mp, ws, states, gs, _weak_elems(lrs),
+                    _weak_elems(wds), _weak_elems(ts), scale, hyper)
 
             fn = jax.jit(step, donate_argnums=(0, 1) if donate else ())
             Optimizer._jit_cache[key] = fn
@@ -489,8 +522,10 @@ class Optimizer:
         """Fused multi-tensor update: ONE donated jit dispatch per
         (weight dtype, multi-precision) bucket covering the whole list —
         rescale → global-norm clip → per-element clip → `_rule` — with
-        per-param lr/wd/t as weak scalars so an LR schedule never
-        retraces. Sparse grads peel off to the legacy per-param path;
+        per-param lr/wd/t packed into one host vector each (and the
+        hyper-parameters into a fourth) so an LR schedule never retraces
+        and a bucket costs four transfers, not 3 per parameter. Sparse
+        grads peel off to the legacy per-param path;
         numerics match the per-param loop bitwise (same op order, same
         dtype promotion)."""
         from ..ndarray.sparse import RowSparseNDArray
@@ -506,22 +541,18 @@ class Optimizer:
             dense.append((i, w, g, s))
         # resolve hyperparams in list order so num_update-driven
         # schedules see exactly the legacy per-param sequence
+        lrs, wds, ts = self._packed_schedule([it[0] for it in dense])
         buckets = {}
-        for i, w, g, s in dense:
-            self._update_count(i)
-            lr, wd = self._get_lr(i), self._get_wd(i)
-            t = self._index_update_count[i]
+        for k, (i, w, g, s) in enumerate(dense):
             use_mp = (multi_precision
                       and isinstance(s, tuple) and len(s) == 2
                       and isinstance(s[0], NDArray)
                       and s[0].dtype == _np.float32
                       and w.dtype != _np.float32)
             buckets.setdefault((str(w.dtype), use_mp), []).append(
-                (i, w, g, s, lr, wd, t))
+                (i, w, g, s, k))
         if not buckets:
             return
-        hyper = dict(self._hyper())
-        hyper["rescale_grad"] = self.rescale_grad
         scale = 1.0
         if self.clip_global_norm is not None:
             sq = 0.0
@@ -534,22 +565,21 @@ class Optimizer:
             if gnorm > self.clip_global_norm:
                 scale = self.clip_global_norm / gnorm
         donate_env = _donate_enabled()
+        hvec = self._packed_hyper(sorted(self._hyper()), scale)
         for (dtype_s, use_mp), items in buckets.items():
             ws = [it[1]._data for it in items]
             gs = [it[2]._data for it in items]
             sts = [jax.tree_util.tree_map(
                 _unwrap, it[3], is_leaf=lambda x: isinstance(x, NDArray))
                 for it in items]
-            lrs = tuple(it[4] for it in items)
-            wds = tuple(it[5] for it in items)
-            ts = tuple(it[6] for it in items)
+            sel = [it[4] for it in items]
             donate = donate_env and _donation_safe((ws, sts), (gs,))
             fn = self._fused_jitted(len(items), use_mp, donate)
             before = _cache_size(fn)
             with _spans.span("fused_update", cat="optimizer"), \
                     _watchdog.guard("fused_update"):
-                new_ws, new_sts = fn(ws, sts, gs, lrs, wds, ts, scale,
-                                     hyper)
+                new_ws, new_sts = fn(ws, sts, gs, lrs[sel], wds[sel],
+                                     ts[sel], hvec)
             _telemetry.record_update_dispatch(
                 "fused", _donated_bytes(ws, sts) if donate else 0)
             _telemetry.record_fused_bucket("update", len(items))
@@ -562,8 +592,8 @@ class Optimizer:
 
                 _introspect.capture_compile(
                     "fused_update", variant, fn,
-                    (_specs(ws), _specs(sts), _specs(gs), lrs, wds, ts,
-                     scale, hyper))
+                    (*_specs((ws, sts, gs)), lrs[sel], wds[sel], ts[sel],
+                     hvec))
             for it, nw, ns in zip(items, new_ws, new_sts):
                 w, s = it[1], it[3]
                 w._data = nw
@@ -626,6 +656,33 @@ def _weak32(x):
         return _convert_element_type(x, _np.dtype("float32"),
                                      weak_type=True)
     return x
+
+
+def _weak_elems(vec):
+    """Every element of a packed operand vector (lr / wd float32[n],
+    update counts int32[n], `_packed_hyper`) as a WEAK scalar of the
+    vector's dtype, read by static index inside the program.
+
+    An element of a float32 vector is strongly typed: ``lr * g`` on a bf16
+    gradient would promote to float32, where the Python scalar it replaces
+    took the gradient's dtype.  Weak typing keeps that promotion, and the
+    host's float64 -> float32 rounding (NumPy, to nearest even) is the one
+    `_weak32` did on the device, so the update is bitwise what the scalar
+    operands gave."""
+    from jax._src.lax.lax import _convert_element_type
+
+    return [_convert_element_type(
+        jax.lax.index_in_dim(vec, i, keepdims=False), vec.dtype,
+        weak_type=True) for i in range(vec.shape[0])]
+
+
+def _unpack_hyper(keys, hvec):
+    """Inverse of `Optimizer._packed_hyper` inside a program: the rule's
+    hyper dict (with rescale_grad) and the global-norm scale."""
+    *vals, rescale, scale = _weak_elems(hvec)
+    hyper = dict(zip(keys, vals))
+    hyper["rescale_grad"] = rescale
+    return hyper, scale
 
 
 def _one_minus_pow(beta, t):

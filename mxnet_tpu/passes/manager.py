@@ -87,7 +87,7 @@ class PassContext:
         # jax.jit by apply()/apply_pipeline().  None means "let jax
         # infer from operands" — the default everywhere today; the
         # whole-step path places operands with device_put instead
-        # (python scalars in its arg list make pytree-prefix shardings
+        # (host arrays in its arg list make pytree-prefix shardings
         # fragile), so these are for block/export seams and tests.
         self.in_shardings = in_shardings
         self.out_shardings = out_shardings

@@ -28,7 +28,7 @@ numerics interposer.  For each seam kind it:
     retrace behavior).
 
 The whole-step seam deliberately keeps ``in_shardings`` unset: its
-argument list mixes python scalars (lrs/wds/ts) with pytrees, where
+argument list mixes host arrays (lrs/wds/ts) with pytrees, where
 pjit's prefix-matching of shardings is version-fragile, and TrainStep
 already places every operand explicitly in ``_whole``.  The stamp
 there is the note + telemetry only, which is also what keeps

@@ -248,10 +248,12 @@ step_donated_bytes = counter(
 
 step_scalar_operands = gauge(
     "step_scalar_operands",
-    "Python-scalar leaves (lr / wd / update count per trained parameter "
-    "+ the rule's hyper-parameters) among the operands of the built "
-    "whole-step program: each is a separate host-to-device transfer "
-    "inside every call (gluon/train_step.py)")
+    "Host-resident leaves (Python scalars, NumPy arrays) among the "
+    "operands of the built whole-step program: each is a separate "
+    "host-to-device transfer inside every call. 4 when sound — lr / wd / "
+    "update counts / hyper-parameters travel as one host array per "
+    "family; 3 more per trained parameter means Python scalars leaked "
+    "back into the call (gluon/train_step.py)")
 
 # -- graph-pass pipeline (mxnet_tpu/passes/; docs/passes.md) ----------------
 pass_applied_total = counter(
@@ -912,14 +914,15 @@ def record_step_dispatch(path, donated_bytes=0):
 
 
 def record_step_scalar_operands(operands):
-    """The whole-step program was built for `operands`: count the
-    Python scalars among their leaves."""
+    """The whole-step program was built for `operands`: count the leaves
+    that live on the host (Python scalars, NumPy arrays and scalars)."""
     if not REGISTRY.enabled:
         return
     import jax
+    import numpy as np
 
     step_scalar_operands.set(sum(
-        isinstance(x, (bool, int, float))
+        isinstance(x, (bool, int, float, np.ndarray, np.generic))
         for x in jax.tree_util.tree_leaves(operands)))
 
 

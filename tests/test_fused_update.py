@@ -327,6 +327,65 @@ def test_pushpull_fused_respects_bucket_cap(monkeypatch):
             assert float(o.asnumpy()[0]) == 3.0
 
 
+@pytest.mark.parametrize("name,kwargs,mp", [
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 0.01,
+             "multi_precision": True}, True),
+    ("adam", {"learning_rate": 0.01, "wd": 0.02}, False),
+    ("sgd", {"learning_rate": 0.05, "clip_global_norm": 0.5}, False),
+], ids=["sgd_masters", "adam", "clip_global_norm"])
+def test_fused_operands_are_packed(name, kwargs, mp, monkeypatch):
+    """A bucket's call carries no Python scalar and four host arrays
+    (lrs, wds, update counts, hyper-parameters + scale) whatever the
+    bucket's size; their values change from step to step on one trace."""
+    import jax
+
+    monkeypatch.setenv("MXTPU_FUSED_UPDATE", "1")
+    calls, fns = [], []
+    fused_jitted = optimizer.Optimizer._fused_jitted
+
+    def recording(self, n, use_mp, donate):
+        fn = fused_jitted(self, n, use_mp, donate)
+        fns.append(fn)
+
+        def call(*args):
+            calls.append(args)
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(optimizer.Optimizer, "_fused_jitted", recording)
+    n = 9
+    opt = optimizer.create(name, **kwargs)
+    ws, gs = _param_set(13, n=n, dtype="bfloat16" if mp else "float32")
+    for k, w in enumerate(ws):
+        opt.lr_mult[k], opt.wd_mult[k] = 1.0 + k, 0.5 * k
+    states = [opt.create_state_multi_precision(i, w)
+              for i, w in enumerate(ws)]
+    for k in range(3):
+        opt.set_learning_rate(0.05 / (k + 1))
+        if mp:
+            opt.update_multi_precision(list(range(n)), ws, gs, states)
+        else:
+            opt.update(list(range(n)), ws, gs, states)
+    assert len(calls) == 3 and len({id(f) for f in fns}) == 1
+    assert fns[0]._cache_size() == 1
+    for k, args in enumerate(calls):
+        leaves = jax.tree_util.tree_leaves(args)
+        assert not [x for x in leaves if isinstance(x, (bool, int, float))]
+        assert sum(isinstance(x, onp.ndarray) for x in leaves) == 4
+        lrs, wds, ts, hvec = args[3:]
+        assert [(v.dtype.name, v.shape) for v in (lrs, wds, ts)] == [
+            ("float32", (n,)), ("float32", (n,)), ("int32", (n,))]
+        assert (ts == k + 1).all()
+        assert onp.array_equal(
+            lrs, onp.float32([0.05 / (k + 1) * (1.0 + j)
+                              for j in range(n)]))
+        assert len(set(wds)) == (n if opt.wd else 1)
+        assert hvec.dtype == onp.float32 \
+            and hvec.shape == (len(opt._hyper()) + 2,)
+    if opt.clip_global_norm is not None:
+        assert 0.0 < calls[0][-1][-1] < 1.0  # the global-norm scale
+
+
 def test_fused_compile_registry_records_bucket(monkeypatch):
     """diagnose.py reads fused-bucket composition from the compile
     registry — a fresh fused trace must land there under block

@@ -608,12 +608,110 @@ def test_scopes_add_no_retrace(scoped_step):
 
 
 def test_step_scalar_operands_gauge(scoped_step):
-    step, trainer = scoped_step[:2]
-    trained = len(step._train_items)
-    hyper = dict(trainer._optimizer._hyper())
-    hyper["rescale_grad"] = 1.0
-    assert trained == 6
-    assert ti.step_scalar_operands.value == 3 * trained + len(hyper)
+    """4 is sound whatever the number of trained parameters (it read
+    3 * 6 + 2 here): the host-resident operands are the four arrays lr /
+    wd / update counts / hyper-parameters travel in, each one transfer a
+    step (test_wholestep_operands_are_packed: none is a Python scalar)."""
+    step = scoped_step[0]
+    assert len(step._train_items) == 6
+    assert ti.step_scalar_operands.value == 4
+
+
+# -- packed optimizer operands ------------------------------------------------
+
+def _record_whole_step_calls(monkeypatch):
+    """Every operand tuple the jitted whole-step program is called with."""
+    calls = []
+    jitted = gluon.TrainStep._jitted
+
+    def recording(self, donate):
+        fn = jitted(self, donate)
+
+        def call(*args):
+            calls.append(args)
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(gluon.TrainStep, "_jitted", recording)
+    return calls
+
+
+def _mults(net):
+    for k, p in enumerate(net.collect_params().values()):
+        p.lr_mult, p.wd_mult = 1.0 + k, 0.5 * k
+
+
+@pytest.mark.parametrize("opt,kw,dtype,prepare", [
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9,
+             "multi_precision": True}, "bfloat16", None),
+    ("adam", {"learning_rate": 0.01, "wd": 0.02}, None, None),
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9,
+             "lr_scheduler": mx.lr_scheduler.FactorScheduler(1, 0.5)},
+     None, None),
+    ("sgd", {"learning_rate": 0.05, "wd": 0.01}, None, _mults),
+], ids=["sgd_masters", "adam", "lr_scheduler", "lr_wd_mult"])
+def test_wholestep_operands_are_packed(opt, kw, dtype, prepare, monkeypatch):
+    """The jitted call's operands hold no Python scalar and at most four
+    host arrays (lrs, wds, update counts, hyper-parameters), whose VALUES
+    follow the optimizer's bookkeeping from step to step on one trace."""
+    import jax
+
+    calls = _record_whole_step_calls(monkeypatch)
+    mx.seed(0)
+    net = _net_plain(dtype)
+    if prepare is not None:
+        prepare(net)
+    trainer = gluon.Trainer(net.collect_params(), opt, dict(kw))
+    step = gluon.TrainStep(net, gluon.loss.L2Loss(), trainer)
+    xs, ys = _data(3, dtype=dtype or "float32")
+    for x, y in zip(xs, ys):
+        step(x, y)
+    assert step.last_path == "whole_step", step.ineligible_reason()
+    assert step.jit_trace_count() == 1 and len(calls) == 3
+    o = trainer._optimizer
+    n = len(step._train_items)
+    for k, args in enumerate(calls):
+        leaves = jax.tree_util.tree_leaves(args)
+        assert not [x for x in leaves if isinstance(x, (bool, int, float))]
+        host = [x for x in leaves if isinstance(x, onp.ndarray)]
+        assert len(host) <= 4
+        assert all(isinstance(x, jax.Array) for x in leaves
+                   if not isinstance(x, onp.ndarray))
+        lrs, wds, ts, hyper = args[4:8]
+        assert [(v.dtype.name, v.shape) for v in (lrs, wds, ts)] == [
+            ("float32", (n,)), ("float32", (n,)), ("int32", (n,))]
+        assert (ts == k + 1).all()
+        assert hyper.dtype == onp.float32 \
+            and hyper.shape == (len(o._hyper()) + 2,)
+    # the last call carried what the optimizer resolves now
+    lrs, wds, _ts, hyper = calls[-1][4:8]
+    idx = step._train_index
+    assert onp.array_equal(lrs, onp.float32([o._get_lr(i) for i in idx]))
+    assert onp.array_equal(wds, onp.float32([o._get_wd(i) for i in idx]))
+    assert onp.array_equal(hyper, o._packed_hyper(sorted(o._hyper())))
+    if "lr_scheduler" in kw:
+        assert calls[0][4][0] > calls[1][4][0] > calls[2][4][0]
+    if prepare is not None:
+        assert len(set(lrs)) == n and len(set(wds)) == n
+
+
+@pytest.mark.parametrize("opt,kw", [
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 0.01}),
+    ("adam", {"learning_rate": 0.01, "wd": 0.02}),
+])
+def test_wholestep_bf16_without_masters_stays_bf16(opt, kw, monkeypatch):
+    """The weak-type guard: an element of a float32 operand vector must
+    reach the rule as a WEAK scalar, or `w - lr * g` on a bf16 weight
+    without a master promotes to float32. Weights and state stay bf16 and
+    match the per-parameter Optimizer.update loop bitwise."""
+    whole = _run_path(True, _net_plain, opt, kw, dtype="bfloat16")
+    monkeypatch.setenv("MXTPU_FUSED_UPDATE", "0")
+    loop = _run_path(False, _net_plain, opt, kw, dtype="bfloat16")
+    _assert_same(whole, loop)
+    assert all(str(v.dtype) == "bfloat16" for v in whole["params"].values())
+    assert all(str(x.dtype) == "bfloat16"
+               for st in whole["states"] for x in st)
+    assert any(st for st in whole["states"])
 
 
 def test_named_scope_leaves_program_identity_alone():
