@@ -90,16 +90,39 @@ def install():
             setattr(jax.random, name, _wrap(fn, "int"))
 
 
+# what JAX stores: (config name, the environment variable that sets it,
+# what we set where the user's environment does not)
+_CACHE_POLICY = (
+    ("jax_persistent_cache_min_compile_time_secs",
+     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 0.0),
+    ("jax_persistent_cache_min_entry_size_bytes",
+     "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", -1),
+)
+
+
 def place_compile_cache():
-    """Point JAX's persistent compilation cache at a fixed directory.
+    """Point JAX's persistent compilation cache at a fixed directory, and
+    have it keep every program.
 
     Every chip call starts on a fresh machine and a whole-step program
     takes minutes to compile, so the cache must survive the process —
     and its path is part of the cache key, so it must not move (no
     tempfile, pid or timestamp).  Placed from outside with
-    ``JAX_COMPILATION_CACHE_DIR`` (JAX reads the variable itself and
-    nothing is set here); otherwise ``<checkout>/.jax_cache``, next to
-    the package.  Returns the directory in effect."""
+    ``JAX_COMPILATION_CACHE_DIR`` (JAX reads the variable itself and no
+    directory is set here); otherwise ``<checkout>/.jax_cache``, next to
+    the package.
+
+    JAX stores only what took a second to compile.  A process builds
+    dozens of programs that take a tenth of one (a copy, a cast, an
+    initializer's draw of each distinct shape) and, never stored, builds
+    them anew at every start: seconds of set-up, every time.  So the
+    threshold goes to 0 and the size limit to none, on every platform and
+    wherever the directory came from — unless JAX's own variable for
+    either is in the environment, which then stands
+    (docs/compile_cache.md).  Returns the directory in effect."""
+    for name, var, ours in _CACHE_POLICY:
+        if var not in os.environ:
+            jax.config.update(name, ours)
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         from jax.experimental.compilation_cache import compilation_cache
 

@@ -165,13 +165,10 @@ def unscale(trainer):
                 g._version += 1
 
 
-def _cast_param(p, dtype, keep_fp32=False):
-    name = p.name.lower()
+def _keeps_fp32(p):
     # norms' scale/shift and running stats stay fp32 (cast-list analog)
-    if keep_fp32 or any(k in name for k in ("gamma", "beta", "running",
-                                            "moving")):
-        return
-    p.cast(dtype)
+    name = p.name.lower()
+    return any(k in name for k in ("gamma", "beta", "running", "moving"))
 
 
 def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,
@@ -206,9 +203,11 @@ def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,
             net.pass_pipeline().register(_passes.AmpPass(dtype))
             net._jit_variants.clear()
         return net
-    for p in net.collect_params().values():
-        if p._data_map is not None or p.shape is not None:
-            _cast_param(p, dtype)
+    from ..gluon.parameter import cast_params
+
+    cast_params([p for p in net.collect_params().values()
+                 if (p._data_map is not None or p.shape is not None)
+                 and not _keeps_fp32(p)], dtype)
     net._clear_cached()
     # wrap forward so inputs are cast on entry
     orig_forward = net.forward

@@ -58,7 +58,7 @@ LOSS_OUTPUT_FUNCTIONS = [
 ]
 
 # ops whose *params* stay fp32 while activations run bf16 (norm scale/
-# shift and running stats — amp._cast_param applies this rule)
+# shift and running stats — amp._keeps_fp32 applies this rule)
 BF16_USE_FP32_PARAMS = {
     "BatchNorm": ["gamma", "beta", "moving_mean", "moving_var"],
     "LayerNorm": ["gamma", "beta"],

@@ -2,8 +2,9 @@
 
 Every jit compile on the CachedOp path (gluon/block.py) calls
 :func:`capture_compile` with the jitted callable and its concrete example
-arguments. We AOT-lower the same signature (``fn.lower(*args).compile()``)
-and harvest what XLA knows about the program:
+arguments. We ask for the same signature's executable
+(``fn.lower(*args).compile()``) and harvest what XLA knows about the
+program:
 
   * ``compiled.cost_analysis()``   -> flops, bytes accessed, transcendentals
   * ``compiled.memory_analysis()`` -> argument/output/temp/generated-code
@@ -16,10 +17,15 @@ numbers also land on the telemetry registry as ``mxtpu_compile_flops`` /
 ``mxtpu_compile_peak_hbm_bytes`` gauges, so they flow through every
 existing exporter (Prometheus / JSON / chrome counters).
 
-Cost: one extra XLA compile per cache miss (the AOT-lowered executable is
-not the one jit executes — jax keeps those caches separate). Compiles
-happen once per (block, variant), so this doubles a one-time cost, never
-steady-state step time; set ``MXTPU_DIAG_COMPILE=0`` to skip it.
+Cost: reading the text and the analyses of the executable jit just
+built.  ``lower`` and ``compile`` answer from jit's own caches (the traced
+jaxpr, the lowered module, its executable) when they are asked for exactly
+what jit was called with: the same shapes and types AND, for an operand
+that is committed to its devices, the same sharding.  A caller whose
+buffers were donated passes ``ShapeDtypeStruct``s that keep those
+shardings (``optimizer._specs``); a spec that leaves one out lowers and
+compiles the whole program a second time.  ``MXTPU_DIAG_COMPILE=0`` skips
+the capture.
 
 ``device_memory()`` reads ``jax.local_devices()[*].memory_stats()`` live —
 a real HBM gauge on TPU/GPU, ``None`` per device on CPU (surfaced as
@@ -82,8 +88,8 @@ def op_scopes(hlo_text):
 
 def capture_compile(block, variant, jitted, args, kwargs=None,
                     compile_seconds=None):
-    """AOT-compile ``jitted`` for ``args`` and record its cost/memory
-    analysis under ``(block, variant)``. Never raises: introspection must
+    """Record the cost/memory analysis of the executable ``jitted`` has
+    for ``args`` under ``(block, variant)``. Never raises: introspection must
     not be able to fail a training step. Returns the entry dict or None
     (disabled / analysis unavailable on this backend)."""
     # the measurement plane hooks the same seam: every compiled program

@@ -38,10 +38,10 @@ from ..diagnostics import introspect as _introspect
 from ..diagnostics import spans as _spans
 from ..passes import _state as _pass_state
 from ..telemetry import instruments as _telemetry
-from ..base import DeferredInitializationError, normalize_dtype
+from ..base import DeferredInitializationError
 from ..device import Device, current_device
 from ..ndarray.ndarray import NDArray
-from .parameter import Constant, Parameter
+from .parameter import Constant, Parameter, cast_params
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "current_state_sink"]
 
@@ -317,9 +317,7 @@ class Block:
         return self
 
     def cast(self, dtype):
-        dtype = normalize_dtype(dtype)
-        for p in self.collect_params().values():
-            p.cast(dtype)
+        cast_params(self.collect_params().values(), dtype)
         self._clear_cached()
         return self
 
@@ -424,10 +422,8 @@ class Block:
                 else:  # 'saved': retype data AND grad buffers together
                     p.cast(arr.dtype)
             if p._data_map is None and p._deferred is None:
-                p.shape = arr.shape
-                p.initialize(device=device or current_device())
-            elif p._deferred is not None:
-                p._finish_deferred_init(arr.shape)
+                # never initialized: the saved value is its initialization
+                p._defer_to_data(device or current_device())
             p.set_data(NDArray(jnp.asarray(arr, p.dtype)))
         if not ignore_extra:
             extra = set(loaded) - set(params)
@@ -766,8 +762,9 @@ class HybridBlock(Block):
         serving.InferenceEngine.warmup() calls this once per batch
         bucket, so the registry proves which shapes are pre-compiled
         (and what each costs) — the per-bucket analog of the cache-miss
-        capture in _call_cached. Costs one extra XLA compile per call;
-        gated by MXTPU_DIAG_COMPILE like every introspection. Returns
+        capture in _call_cached. Reads the executable jit already holds
+        for these arguments (diagnostics/introspect.py); gated by
+        MXTPU_DIAG_COMPILE like every introspection. Returns
         the registry entry dict or None."""
         with ag.pause():
             if self._jit_variants.get(False) is None:
@@ -907,8 +904,8 @@ class HybridBlock(Block):
                 type(self).__name__, variant, compile_seconds)
             # AOT-introspect what XLA built for this signature: flops,
             # bytes accessed, arg/out/temp sizes → the compile registry
-            # (diagnostics.report / tools/diagnose.py). Costs one extra
-            # compile per variant; MXTPU_DIAG_COMPILE=0 skips.
+            # (diagnostics.report / tools/diagnose.py), read off the
+            # executable jit just built; MXTPU_DIAG_COMPILE=0 skips.
             _introspect.capture_compile(
                 type(self).__name__, variant, jitted,
                 (pd, key, *arr_datas), compile_seconds=compile_seconds)

@@ -9,25 +9,76 @@ parameter temporarily exposes a jax tracer instead of its concrete buffer).
 """
 from __future__ import annotations
 
+import functools
 import uuid
 
 import threading as _threading
 
+import jax
 import jax.numpy as jnp
 import numpy as _np
 
 from .. import initializer as init_mod
 from ..base import DeferredInitializationError, normalize_dtype
 from ..device import Device, current_device
-from ..ndarray.ndarray import NDArray, _wrap_out
+from ..ndarray.ndarray import NDArray, _wrap_out, device_groups
 
-__all__ = ["Parameter", "Constant"]
+__all__ = ["Parameter", "Constant", "cast_params"]
 
 
 def _shape_known(shape):
     return shape is not None and all(
         d is not None and int(d) > 0 for d in shape
     )
+
+
+def _device_list(device):
+    devices = device if isinstance(device, (list, tuple)) else [device]
+    return [d if isinstance(d, Device) else Device(d) for d in devices]
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _as_dtype(arrays, dtype):
+    return [a.astype(dtype) for a in arrays]
+
+
+# what one cast program may read: the old buffers of a call are freed
+# when it returns, so old and new tree overlap by this much and no more
+_CAST_BYTES = 1 << 30
+
+
+def _runs(arrays, budget):
+    """`arrays` cut into runs of at most `budget` bytes (or one array)."""
+    run, size = [], 0
+    for a in arrays:
+        if run and size + a._data.nbytes > budget:
+            yield run
+            run, size = [], 0
+        run.append(a)
+        size += a._data.nbytes
+    if run:
+        yield run
+
+
+def cast_params(params, dtype):
+    """Cast the data and the gradient buffers of every parameter of
+    `params` to `dtype`, in one program for the whole tree (a tree of
+    one parameter is `Parameter.cast`; a tree of gigabytes takes one
+    program per `_CAST_BYTES`, so that it never holds two whole copies)."""
+    dtype = normalize_dtype(dtype)
+    data, grads = [], []
+    for p in params:
+        p.dtype = dtype
+        if p._data_map is not None:
+            data.extend(p._data_map.values())
+            grads.extend((p._grad_map or {}).values())
+    todo = [a for a in data + grads if a._data.dtype != dtype]
+    for ks in device_groups([a._data for a in todo]):
+        for run in _runs([todo[k] for k in ks], _CAST_BYTES):
+            for a, v in zip(run, _as_dtype([a._data for a in run], dtype)):
+                a._data = v
+    for a in data:
+        a._version += 1
 
 
 class Parameter:
@@ -126,8 +177,7 @@ class Parameter:
             device = ctx
         if device is None:
             device = current_device()
-        devices = device if isinstance(device, (list, tuple)) else [device]
-        devices = [d if isinstance(d, Device) else Device(d) for d in devices]
+        devices = _device_list(device)
         if self._data_map is not None and not force_reinit:
             return
         default_init = default_init or init_mod.Uniform()
@@ -158,8 +208,13 @@ class Parameter:
             {"__init__": declared} if declared is not None else {})
         master = global_init.init_array(desc, self._shape, self.dtype,
                                         explicit=declared is None)
+        self._install(devices, master.copyto)
+
+    def _install(self, devices, copy_on):
+        """Become initialized: the value is `copy_on(device)` on each of
+        `devices`, with fresh gradient buffers beside it."""
         self._ctx_list = list(devices)
-        self._data_map = {d: master.copyto(d) for d in devices}
+        self._data_map = {d: copy_on(d) for d in devices}
         self._grad_map = {}
         if self.grad_req != "null":
             self._init_grad_buffers()
@@ -174,8 +229,11 @@ class Parameter:
         self._grad_map = {}
         shape = self._shape if self._layout_perm is None \
             else tuple(self._shape[i] for i in self._layout_perm)
+        # host zeros, placed: `jnp.zeros` is an XLA program per distinct
+        # (shape, type), a transfer is none
+        zeros = _np.zeros(shape, self.dtype)
         for d, arr in self._data_map.items():
-            g = _wrap_out(jnp.zeros(shape, self.dtype)).copyto(d)
+            g = NDArray(jax.device_put(zeros, d.jax_device), d)
             self._grad_map[d] = g
             arr._grad = g
             arr._grad_req = self._grad_req
@@ -217,6 +275,13 @@ class Parameter:
             self._grad_map = {}
             return
         self._init_grad_buffers()
+
+    def _defer_to_data(self, device):
+        """Never initialized, and about to be handed its value on `device`
+        (a checkpoint's): wait for `set_data` as a parameter of unknown
+        shape does, so that no initializer draws a value to be
+        overwritten."""
+        self._deferred = (None, _device_list(device), None)
 
     def _finish_deferred_init(self, shape=None):
         """Complete deferred init once the full shape is known."""
@@ -319,18 +384,20 @@ class Parameter:
 
     def set_data(self, data):
         """Set value on all devices (reference: Parameter.set_data)."""
-        if self._data_map is None:
-            if self._deferred is not None:
-                # deferred-init param: the incoming value fixes the shape
-                self.shape = data.shape
-                self._finish_deferred_init()
-                self.set_data(data)
-                return
+        if self._data_map is None and self._deferred is None:
             raise RuntimeError(
                 f"Parameter {self._name} has not been initialized; call "
                 ".initialize() before set_data (reference parity)")
         if not isinstance(data, NDArray):
             data = NDArray(jnp.asarray(data, self.dtype))
+        deferred = self._data_map is None
+        if deferred:
+            # the incoming value fixes the shape
+            self.shape = data.shape
+            if not _shape_known(self._shape):
+                raise DeferredInitializationError(
+                    f"Parameter {self._name}: shape still unknown "
+                    f"{self._shape}")
         src = data._data
         # set_data speaks the LOGICAL layout (checkpoints, user code);
         # convert to the persistent physical layout once, here, so NCHW
@@ -341,6 +408,12 @@ class Parameter:
                 pass  # already physical (internal caller)
             else:
                 src = jnp.transpose(src, self._layout_perm)
+        if deferred:
+            # ... and takes the place of the initializer's draw, which
+            # nobody would read (a draw is an XLA program per shape)
+            value = jnp.asarray(src, self.dtype)
+            self._install(self._deferred[1], lambda d: NDArray(value, d))
+            return
         for d in self._ctx_list:
             arr = self._data_map[d]
             # honor the declared dtype, not the old buffer's — load with
@@ -392,8 +465,7 @@ class Parameter:
 
     def reset_ctx(self, ctx=None, device=None):
         device = device if device is not None else ctx
-        devices = device if isinstance(device, (list, tuple)) else [device]
-        devices = [d if isinstance(d, Device) else Device(d) for d in devices]
+        devices = _device_list(device)
         self._check_initialized()
         master = self._data_map[self._ctx_list[0]]
         self._ctx_list = devices
@@ -404,14 +476,7 @@ class Parameter:
     reset_device = reset_ctx
 
     def cast(self, dtype):
-        dtype = normalize_dtype(dtype)
-        self.dtype = dtype
-        if self._data_map is not None:
-            for d, arr in self._data_map.items():
-                arr._data = arr._data.astype(dtype)
-                arr._version += 1
-            for g in (self._grad_map or {}).values():
-                g._data = g._data.astype(dtype)
+        cast_params([self], dtype)
 
     # misc
     def var(self):
@@ -442,7 +507,4 @@ class Constant(Parameter):
         self._value = value
 
     def _finish_init(self, init, devices, default_init):  # noqa: ARG002
-        self._ctx_list = list(devices)
-        self._data_map = {d: self._value.copyto(d) for d in devices}
-        self._grad_map = {}
-        self._deferred = None
+        self._install(devices, self._value.copyto)

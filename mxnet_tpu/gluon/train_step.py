@@ -266,8 +266,8 @@ class TrainStep:
             if p.grad_req == "null":
                 continue
             p._check_initialized()
-            tr._ensure_states(i, p.data())
             items.append((i, name_of[id(p)], p))
+        tr._ensure_states([(i, p.data()) for i, _n, p in items])
         self._train_items = items
         self._train_index = [i for i, _n, _p in items]
         # bucket by (weight dtype, multi-precision) in trainer order —

@@ -158,10 +158,16 @@ class Trainer:
                    label="trainer")
         self._plan_applied = True
 
-    def _ensure_states(self, i, weight):
-        if not self._states_created[i]:
-            self._states[i] = self._optimizer.create_state_multi_precision(
-                i, weight)
+    def _ensure_states(self, items):
+        """Create the optimizer state of every (index, weight) of `items`
+        that has none yet: all of them in one program."""
+        items = [(i, w) for i, w in items if not self._states_created[i]]
+        if not items:
+            return
+        states = self._optimizer.create_states_multi_precision(
+            *zip(*items))
+        for (i, weight), state in zip(items, states):
+            self._states[i] = state
             self._states_created[i] = True
             if self._plan_applied:
                 # optimizer state (momentum, fp32 master copies, fused
@@ -171,7 +177,7 @@ class Trainer:
                 # ZeRO plan (fsdp axis + MXTPU_ZERO) it lands on the
                 # sharded-bucket layout instead, 1/N per rank
                 opt_mod.place_state_like(
-                    self._states[i], weight, plan=self._sharding_plan,
+                    state, weight, plan=self._sharding_plan,
                     name=self._param_names[i])
 
     def allreduce_grads(self, ignore_stale_grad=False):
@@ -275,37 +281,37 @@ class Trainer:
         # replicated across devices stay on the legacy per-param loop.
         fuse = _env.get("MXTPU_FUSED_UPDATE") and \
             self._optimizer._supports_fused()
-        f_idx, f_w, f_g, f_s = [], [], [], []
+        todo = []       # (index, parameter, primary weight, its gradient)
         for i, p in enumerate(self._params):
             if p.grad_req == "null":
                 continue
             p._check_initialized()
-            devs = p.list_ctx()
-            for dev in devs:
-                w = p.data(dev)
-                g = p.grad(dev)
-                # stale = grad buffer untouched since the last update
-                # (reference: Parameter._fresh_grad per-step flag)
-                fresh = self._grad_versions.get(i) != g._version
-                if not ignore_stale_grad or fresh:
-                    self._ensure_states(i, w)
-                    if getattr(p, "grad_stype", "default") == "row_sparse":
-                        # hand the optimizer only the touched rows
-                        # (lazy_update semantics; Parameter docs)
-                        self._optimizer.update_multi_precision(
-                            i, w, p._as_row_sparse_grad(g),
-                            self._states[i])
-                    elif fuse and len(devs) == 1:
-                        f_idx.append(i)
-                        f_w.append(w)
-                        f_g.append(g)
-                        f_s.append(self._states[i])
-                    else:
-                        self._optimizer.update_multi_precision(
-                            i, w, g, self._states[i])
-                    self._grad_versions[i] = g._version
-                break  # update primary; replicate below
-            if len(p.list_ctx()) > 1:
+            dev = p.list_ctx()[0]   # update primary; replicate below
+            g = p.grad(dev)
+            # stale = grad buffer untouched since the last update
+            # (reference: Parameter._fresh_grad per-step flag)
+            fresh = self._grad_versions.get(i) != g._version
+            if not ignore_stale_grad or fresh:
+                todo.append((i, p, p.data(dev), g))
+        self._ensure_states([(i, w) for i, _p, w, _g in todo])
+        f_idx, f_w, f_g, f_s = [], [], [], []
+        for i, p, w, g in todo:
+            if getattr(p, "grad_stype", "default") == "row_sparse":
+                # hand the optimizer only the touched rows
+                # (lazy_update semantics; Parameter docs)
+                self._optimizer.update_multi_precision(
+                    i, w, p._as_row_sparse_grad(g), self._states[i])
+            elif fuse and len(p.list_ctx()) == 1:
+                f_idx.append(i)
+                f_w.append(w)
+                f_g.append(g)
+                f_s.append(self._states[i])
+            else:
+                self._optimizer.update_multi_precision(
+                    i, w, g, self._states[i])
+            self._grad_versions[i] = g._version
+        for p in self._params:
+            if p.grad_req != "null" and len(p.list_ctx()) > 1:
                 primary = p.data(p.list_ctx()[0])
                 for dev in p.list_ctx()[1:]:
                     primary.copyto(p.data(dev))

@@ -11,6 +11,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 
 from . import _random
 from .base import registry
@@ -79,10 +80,10 @@ class Initializer:
             data = self._init_weight(name, shape, dtype)
         elif lname.endswith("bias") or lname.endswith("beta") or \
                 lname.endswith("running_mean") or lname.endswith("moving_mean"):
-            data = jnp.zeros(shape, dtype)
+            data = _np.zeros(shape, dtype)
         elif lname.endswith("gamma") or lname.endswith("running_var") or \
                 lname.endswith("moving_var"):
-            data = jnp.ones(shape, dtype)
+            data = _np.ones(shape, dtype)
         else:
             data = self._init_weight(name, shape, dtype)
         if isinstance(arr, NDArray):
@@ -91,7 +92,10 @@ class Initializer:
         return arr
 
     def init_array(self, name, shape, dtype, explicit=False):
-        out = NDArray(jnp.zeros(shape, dtype))
+        # the holder only says what shape and type to fill.  Constants
+        # are made on the host throughout this file: `jnp.zeros` is an
+        # XLA program per distinct (shape, type), a transfer is none
+        out = NDArray(_np.zeros(shape, dtype))
         self(name, out, explicit=explicit)
         return out
 
@@ -105,7 +109,7 @@ class Initializer:
 @register
 class Zero(Initializer):
     def _init_weight(self, name, shape, dtype):
-        return jnp.zeros(shape, dtype)
+        return _np.zeros(shape, dtype)
 
 
 _REG.register(Zero, "zeros")
@@ -114,7 +118,7 @@ _REG.register(Zero, "zeros")
 @register
 class One(Initializer):
     def _init_weight(self, name, shape, dtype):
-        return jnp.ones(shape, dtype)
+        return _np.ones(shape, dtype)
 
 
 _REG.register(One, "ones")
@@ -127,7 +131,10 @@ class Constant(Initializer):
         self.value = value
 
     def _init_weight(self, name, shape, dtype):
-        return jnp.full(shape, self.value, dtype)
+        value = self.value
+        if isinstance(value, NDArray):      # NumPy will not fill from one
+            value = value.asnumpy()
+        return _np.full(shape, value, dtype)
 
 
 @register

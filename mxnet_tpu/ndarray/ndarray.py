@@ -759,6 +759,27 @@ def _wrap_out(data, device=None):
     return NDArray(data, device)
 
 
+def device_groups(arrays):
+    """Positions of `arrays`, grouped by where each lives: its set of
+    devices, and whether it is committed to them.  A jitted call takes
+    operands of one set of devices only, and commits every result if any
+    operand was committed; a group's results live exactly where the
+    eager operation would have left each of them.
+
+    What a phase does to every parameter (a cast, a fresh optimizer
+    state) is one jitted call per group.  Eager, every distinct
+    (operation, shape, type) is an XLA program of its own -- ~0.1 s each
+    on a TPU, in every process; traced together they are one, however
+    many parameters there are."""
+    groups = {}
+    for k, a in enumerate(arrays):
+        # a host array goes wherever jit puts it: a group of its own kind
+        where = (tuple(sorted(d.id for d in a.devices())), a.committed) \
+            if isinstance(a, jax.Array) else None
+        groups.setdefault(where, []).append(k)
+    return list(groups.values())
+
+
 def _is_sparse(a):
     return getattr(a, "stype", None) in ("csr", "row_sparse")
 

@@ -27,7 +27,7 @@ import numpy as _np
 from ..base import registry
 from ..diagnostics import spans as _spans
 from ..diagnostics import watchdog as _watchdog
-from ..ndarray.ndarray import NDArray, _wrap_out
+from ..ndarray.ndarray import NDArray, _wrap_out, device_groups
 from ..telemetry import instruments as _telemetry
 
 _REG = registry("optimizer")
@@ -48,6 +48,11 @@ def create(name, **kwargs):
 
 def _unwrap(x):
     return x._data if isinstance(x, NDArray) else x
+
+
+def _spread(x):
+    """Does this array live on more than one device?"""
+    return isinstance(x, jax.Array) and len(x.sharding.device_set) > 1
 
 
 def _cache_size(fn):
@@ -89,10 +94,17 @@ def _donation_safe(donated, protected=()):
 
 def _specs(tree):
     """Shape/dtype skeleton of an argument tree — what capture_compile
-    lowers against AFTER the live buffers were donated into the step."""
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-        if hasattr(x, "shape") else x, tree)
+    lowers against AFTER the live buffers were donated into the step.  A
+    committed array's spec keeps its sharding: where an operand lives is
+    part of what jit lowered for, and a spec that leaves it out lowers
+    and compiles the whole program a second time."""
+    def spec(x):
+        if not hasattr(x, "shape"):
+            return x
+        where = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)
+
+    return jax.tree_util.tree_map(spec, tree)
 
 
 def _donated_bytes(*trees):
@@ -209,11 +221,57 @@ class Optimizer:
         return None
 
     def create_state_multi_precision(self, index, weight):
-        low_precision = weight.dtype.name in ("float16", "bfloat16")
-        if self.multi_precision and low_precision:
-            master = _wrap_out(weight._data.astype(jnp.float32))
-            return (master, self.create_state(index, master))
-        return self.create_state(index, weight)
+        return self.create_states_multi_precision([index], [weight])[0]
+
+    def create_states_multi_precision(self, indices, weights):
+        """The state of every (index, weight): `create_state`, under a
+        float32 master where `multi_precision` asks for one, traced over
+        the whole list into ONE program (one per set of devices).  The
+        masters' casts and the states' zeros of a model are then one
+        compile, not one per distinct shape and type.  `create_state` is
+        traced: one that needs the weight's values on the host (a user's
+        own optimizer calling `asnumpy`) runs eagerly instead."""
+        def one(index, data, where):
+            weight = NDArray(data)
+            if self.multi_precision and \
+                    weight.dtype.name in ("float16", "bfloat16"):
+                master = NDArray(data.astype(jnp.float32))
+                state = (master, self.create_state(index, master))
+            else:
+                state = self.create_state(index, weight)
+
+            def leaf(x):
+                x = _unwrap(x)
+                # zeros read nothing of a weight that is spread over
+                # devices, and the compiler would make them whole on
+                # every one: a leaf of the weight's shape is laid out as
+                # the weight is, which is what the eager `zeros_like` did
+                if where is not None and getattr(x, "shape", None) == data.shape:
+                    x = jax.lax.with_sharding_constraint(x, where)
+                return x
+
+            return jax.tree_util.tree_map(
+                leaf, state, is_leaf=lambda x: isinstance(x, NDArray))
+
+        datas = [w._data for w in weights]
+        wheres = [d.sharding if _spread(d) else None for d in datas]
+        states = [None] * len(datas)
+        for ks in device_groups(datas):
+            def create_states(group, ks=ks):
+                return [one(indices[k], d, wheres[k])
+                        for k, d in zip(ks, group)]
+
+            group = [datas[k] for k in ks]
+            try:
+                # keep_unused: zeros read nothing of the weight, and jit
+                # would drop it from the call, and with it where the
+                # state lives
+                made = jax.jit(create_states, keep_unused=True)(group)
+            except jax.errors.JAXTypeError:     # host code under the tracer
+                made = create_states(group)
+            for k, st in zip(ks, made):
+                states[k] = jax.tree_util.tree_map(NDArray, st)
+        return states
 
     # -- hyper vector passed into the jitted rule -------------------------
     def _hyper(self):
@@ -1281,11 +1339,14 @@ class Updater:
     def __call__(self, index, grad, weight):
         if not isinstance(index, (list, tuple)):
             index, grad, weight = [index], [grad], [weight]
+        index = [i.decode() if isinstance(i, bytes) else i for i in index]
+        # the states this call is the first to need, in one program
+        new = {i: w for i, w in zip(index, weight) if i not in self.states}
+        fresh = dict(zip(new, self.optimizer.create_states_multi_precision(
+            list(new), list(new.values())))) if new else {}
         for i, g, w in zip(index, grad, weight):
-            if isinstance(i, bytes):
-                i = i.decode()
             if i not in self.states:
-                st = self.optimizer.create_state_multi_precision(i, w)
+                st = fresh[i]
                 flat = self.pending_loaded.pop(i, None)
                 if flat is None:
                     flat = self.pending_loaded.pop(str(i), None)

@@ -681,7 +681,7 @@ _XLA_STAGE_OF_EVENT = {
 _xla_tls = threading.local()
 
 
-def _on_xla_duration(event, duration, **_kw):
+def _on_xla_duration(event, duration, fun_name=None, **_kw):
     stage = _XLA_STAGE_OF_EVENT.get(event)
     if stage is None or not REGISTRY.enabled:
         return
@@ -696,11 +696,12 @@ def _on_xla_duration(event, duration, **_kw):
         _xla_tls.load_seconds = None
         how = "built" if load_seconds is None else "loaded"
         xla_programs_total.labels(how).inc()
-        # the black box keeps WHEN each program arrived (perf_counter
-        # `pc`, the spans' clock): a reader can tell set-up's compiles
-        # from those of a later phase of the process
-        _flight_record("xla_compile", how=how, seconds=duration,
-                       load_seconds=load_seconds or 0.0)
+        # the black box keeps WHICH program arrived (JAX's `fun_name`,
+        # `jit(whole_step)`) and WHEN (perf_counter `pc`, the spans'
+        # clock): a reader can tell set-up's compiles from those of a
+        # later phase of the process, and name the ones built anew
+        _flight_record("xla_compile", name=fun_name, how=how,
+                       seconds=duration, load_seconds=load_seconds or 0.0)
 
 
 def install_compile_listener():

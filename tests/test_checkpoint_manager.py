@@ -398,12 +398,13 @@ def test_sigkill_mid_write_then_bitwise_resume(tmp_path, baseline_run):
     outdir.mkdir()
     proc = subprocess.Popen(
         [sys.executable, WORKER, "kill", str(outdir), str(ckdir)],
-        env=ENV, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+        env=ENV, stdout=subprocess.DEVNULL,
+        stderr=open(outdir / "stderr", "wb"))
     marker = outdir / "write_started"
     deadline = time.time() + 120
     while not marker.exists():
         assert proc.poll() is None, \
-            (b"" if proc.stderr is None else proc.stderr.read())[-2000:]
+            (outdir / "stderr").read_bytes()[-2000:]
         assert time.time() < deadline, "worker never started the write"
         time.sleep(0.02)
     proc.kill()                     # SIGKILL mid-write
@@ -438,18 +439,19 @@ def test_sigterm_preemption_snapshot_and_clean_exit(tmp_path):
     outdir.mkdir()
     proc = subprocess.Popen(
         [sys.executable, WORKER, "preempt", str(outdir), str(ckdir)],
-        env=ENV, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+        env=ENV, stdout=subprocess.DEVNULL,
+        stderr=open(outdir / "stderr", "wb"))
     ready = outdir / "ready"
     deadline = time.time() + 120
     while not ready.exists():
         assert proc.poll() is None, \
-            (b"" if proc.stderr is None else proc.stderr.read())[-2000:]
+            (outdir / "stderr").read_bytes()[-2000:]
         assert time.time() < deadline, "worker never armed the handler"
         time.sleep(0.02)
     proc.send_signal(signal.SIGTERM)
     proc.wait(timeout=120)
     assert proc.returncode == 0, \
-        (b"" if proc.stderr is None else proc.stderr.read())[-2000:]
+        (outdir / "stderr").read_bytes()[-2000:]
 
     rep = verify_checkpoint(str(ckdir))
     assert rep["ok"], rep
@@ -474,16 +476,18 @@ def test_preemption_failed_snapshot_exits_nonzero(tmp_path):
     outdir.mkdir()
     proc = subprocess.Popen(
         [sys.executable, WORKER, "preempt_fail", str(outdir), str(ckdir)],
-        env=ENV, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+        env=ENV, stdout=subprocess.DEVNULL,
+        stderr=open(outdir / "stderr", "wb"))
     ready = outdir / "ready"
     deadline = time.time() + 120
     while not ready.exists():
         assert proc.poll() is None, \
-            (b"" if proc.stderr is None else proc.stderr.read())[-2000:]
+            (outdir / "stderr").read_bytes()[-2000:]
         assert time.time() < deadline, "worker never armed the handler"
         time.sleep(0.02)
     proc.send_signal(signal.SIGTERM)
-    _, err = proc.communicate(timeout=120)
+    proc.wait(timeout=120)
+    err = (outdir / "stderr").read_bytes()
     assert proc.returncode == 1, (proc.returncode, err[-2000:])
     assert b"FAILED" in err, err[-2000:]
 

@@ -72,6 +72,8 @@ def test_flight_disabled_records_nothing(monkeypatch):
 
 
 def test_flight_identity_and_trace_id(monkeypatch):
+    # whatever an earlier test of this worker stamped (a plan's mesh)
+    monkeypatch.setattr(flight, "_identity", {})
     monkeypatch.setenv("MXTPU_JOB_ID", "jobX")
     ident = flight.identity()
     assert ident["job"] == "jobX"

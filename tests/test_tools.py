@@ -311,7 +311,10 @@ def test_blackbox_merges_sigkilled_ranks(tmp_path):
                          MXTPU_JOB_ID="blackbox-test",
                          MXTPU_FLIGHTREC_FLUSH_STEPS="1",
                          MXTPU_FLIGHTREC_DIR=str(tmp_path)),
-                stdout=subprocess.DEVNULL, stderr=subprocess.PIPE))
+                stdout=subprocess.DEVNULL,
+                # a file, not a pipe: nobody reads while the worker runs,
+                # and a full pipe would block it
+                stderr=open(tmp_path / f"stderr{r}", "wb")))
         paths = [str(tmp_path / f"mxtpu_blackbox.rank{r}.json")
                  for r in range(2)]
 
@@ -325,10 +328,10 @@ def test_blackbox_merges_sigkilled_ranks(tmp_path):
 
         deadline = time.monotonic() + 240
         while not all(_complete(p) for p in paths):
-            for pr in procs:
+            for r, pr in enumerate(procs):
                 if pr.poll() is not None:
-                    raise AssertionError(
-                        f"worker died: {pr.stderr.read().decode()[-2000:]}")
+                    err = (tmp_path / f"stderr{r}").read_text()
+                    raise AssertionError(f"worker died: {err[-2000:]}")
             assert time.monotonic() < deadline, "bundles never appeared"
             time.sleep(0.25)
     finally:
@@ -506,7 +509,8 @@ def fleet(tmp_path_factory):
                        MXTPU_FLIGHTREC_DIR=str(tmp))
             procs.append(subprocess.Popen(
                 [sys.executable, str(script), str(steps), portfile],
-                env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE))
+                env=env, stdout=subprocess.DEVNULL,
+                stderr=open(tmp / f"stderr{rank}", "wb")))
         deadline = time.time() + 180
         for rank in (0, 1):
             portfile = str(tmp / f"port{rank}")
@@ -514,11 +518,11 @@ def fleet(tmp_path_factory):
                 if time.time() > deadline:
                     raise RuntimeError(
                         f"rank {rank} never published its port: "
-                        + procs[rank].stderr.read().decode()[-2000:])
+                        + (tmp / f"stderr{rank}").read_text()[-2000:])
                 if procs[rank].poll() is not None:
                     raise RuntimeError(
                         f"rank {rank} died: "
-                        + procs[rank].stderr.read().decode()[-2000:])
+                        + (tmp / f"stderr{rank}").read_text()[-2000:])
                 time.sleep(0.05)
             ports[rank] = int(open(portfile).read())
         yield {"tmp": tmp, "ports": ports}
