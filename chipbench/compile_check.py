@@ -76,9 +76,9 @@ def main():
     specs = ref.param_specs(cfg)
     weights = {n: jnp.zeros(s, jnp.float32) for n, s, *_ in specs}
 
-    if wl["driver"] != "train_step":
+    if wl["driver"] not in ("train_step", "train_loader"):
         raise KeyError(f"no compile check for driver {wl['driver']!r}")
-    b = tp["batch"]
+    b = tp.get("batch") or tp["loader"]["batch_size"]
     batch = tuple(jnp.zeros(s, jnp.int32 if k != "uniform"
                             else jnp.float32)
                   for s, k, *_ in ref.input_specs(cfg, b))

@@ -32,9 +32,10 @@ def control_numbers(workload, cfg, seed, precision=None):
     precision = precision or cfg["control_precision"]
     tp = workload["traffic_params"]
     weights = wmod.make_weights(ref.param_specs(cfg), seed, cfg["dtype"])
-    if workload["driver"] != "train_step":
-        raise KeyError(f"no control for driver {workload['driver']!r}")
-    batches = wmod.make_batches(ref.input_specs(cfg, tp["batch"]), seed, 3)
+    # the batches the cell's first steps see, as its driver makes them
+    batches = importlib.import_module(
+        "drivers." + workload["driver"]).reference_batches(
+            cfg, tp, seed, 3, ref)
     a = train_ref.train_steps(ref, cfg, weights, batches, 3)
     b = train_ref.train_steps(ref, cfg, weights, batches, 3, precision)
     weight_leaves = [n for n, w in weights.items()

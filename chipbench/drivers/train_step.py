@@ -5,8 +5,14 @@ three steps from the seed (on three different batches, through the very
 call the window uses) and hands that same object to the window.  The
 plain reference follows those three steps after the window has closed
 and the program's state has been freed.
+
+``run`` feeds the step from a pool of resident batches.  The parts
+(``Program``, ``first_steps``, ``window``, ``compare``) are what any
+driver of a TrainStep is made of: ``train_loader`` feeds the same step
+from gluon.data.DataLoader.
 """
 import gc
+import itertools
 import time
 
 import numpy as onp
@@ -45,37 +51,55 @@ def program_state(net, trainer, opt, jax, NDArray):
     return out
 
 
-def run(h):
-    jax, mx = h.jax, h.mx
-    import jax.numpy as jnp
+def reference_batches(cfg, traffic, seed, n, ref):
+    """The ``n`` batches the first steps of this driver's cell see."""
+    return wmod.make_batches(ref.input_specs(cfg, traffic["batch"]), seed, n)
 
-    from mxnet_tpu import gluon
-    from mxnet_tpu.ndarray.ndarray import NDArray
-    from mxnet_tpu.telemetry import instruments as ti
 
-    cfg, traffic, ref = h.cfg, h.traffic, h.reference
-    opt = cfg["optimizer"]
-    limits = cfg["limits"]["train_step"]
-    b, pool = traffic["batch"], traffic["pool"]
-    if pool < REF_STEPS:
-        raise ValueError(f"pool {pool} < {REF_STEPS}: the first steps need "
-                         "batches that all differ")
-    ctx = mx.tpu(0)
+class Program:
+    """The system under test: net, trainer and the ONE TrainStep, built
+    through the normal entry points on the seeded ``weights``."""
 
-    with h.span("make_weights"):
-        weights = wmod.make_weights(ref.param_specs(cfg), h.seed,
-                                    cfg["dtype"])
-        batches = wmod.make_batches(ref.input_specs(cfg, b), h.seed, pool)
-    with h.span("build"):
-        net = h.model.build(mx, cfg, weights, ctx)
-        loss_fn, n_data = h.model.loss(mx, cfg)
-        trainer = gluon.Trainer(
-            net.collect_params(), opt["name"],
+    def __init__(self, h, weights):
+        from mxnet_tpu import gluon
+        from mxnet_tpu.ndarray.ndarray import NDArray
+        from mxnet_tpu.telemetry import instruments as ti
+
+        self.h, self.NDArray, self._ti = h, NDArray, ti
+        self.opt = opt = h.cfg["optimizer"]
+        self.net = h.model.build(h.mx, h.cfg, weights, h.mx.tpu(0))
+        loss_fn, n_data = h.model.loss(h.mx, h.cfg)
+        self.trainer = gluon.Trainer(
+            self.net.collect_params(), opt["name"],
             {k: v for k, v in opt.items() if k != "name"},
             kvstore="tpu_dist")
-        step = gluon.TrainStep(net, loss_fn, trainer, n_data=n_data)
-    feed = [tuple(NDArray(a) for a in bt) for bt in batches]
-    w0 = {n: w for n, w in weights.items() if ref.trainable(n)}
+        self.step = gluon.TrainStep(self.net, loss_fn, self.trainer,
+                                    n_data=n_data)
+
+    def state(self):
+        return program_state(self.net, self.trainer, self.opt, self.h.jax,
+                             self.NDArray)
+
+    def traces(self):
+        """Programs traced so far, by the program's own counters."""
+        return (sum(c.value for _, c in self._ti.jit_trace_total.series())
+                + self.step.jit_trace_count())
+
+    def params(self):
+        return [p.data()._data for p in self.net.collect_params().values()]
+
+    def free(self):
+        self.net = self.trainer = self.step = None
+
+
+def first_steps(h, prog, w0, feed):
+    """The first ``REF_STEPS`` steps, through the window's own call, on
+    ``feed[k]``: (mean loss of each, {leaf: norm of the first gradient as
+    the optimizer got it}, {leaf: norm of the parameters' change})."""
+    jax = h.jax
+    import jax.numpy as jnp
+
+    opt = prog.opt
 
     @jax.jit
     def grad_norms(w0, inner):
@@ -87,37 +111,33 @@ def run(h):
         return train_ref.leaf_norms(
             {n: now[n].astype(jnp.float32) - w0[n] for n in now})
 
-    def traces():
-        return sum(c.value for _, c in ti.jit_trace_total.series())
-
-    # -- the first steps: through the window's own call and feed ---------
-    prog_losses, prog_gn = [], None
+    losses, gn = [], None
     for k in range(REF_STEPS):
         with h.span("first_call" if k == 0 else "first_steps",
                     compile=(k == 0)):
-            loss = step(*feed[k])
-            prog_losses.append(float(onp.mean(
+            loss = prog.step(*feed[k])
+            losses.append(float(onp.mean(
                 onp.asarray(loss._data).astype(onp.float32))))
         if k == 0:
             with h.span("grad_norms", compile=True):
-                st = program_state(net, trainer, opt, jax, NDArray)
-                prog_gn = grad_norms(w0, {n: s[1] for n, s in st.items()})
-                prog_gn = {n: float(v) for n, v in prog_gn.items()}
+                gn = grad_norms(w0, {n: s[1] for n, s in prog.state().items()})
+                gn = {n: float(v) for n, v in gn.items()}
     with h.span("change_norms", compile=True):
-        st = program_state(net, trainer, opt, jax, NDArray)
-        prog_dw = change_norms(w0, {n: s[0] for n, s in st.items()})
-        prog_dw = {n: float(v) for n, v in prog_dw.items()}
-    if step.last_path != "whole_step":
-        raise RuntimeError(f"TrainStep ran {step.last_path}: "
-                           f"{step.ineligible_reason()}")
-    # every batch of the pool once more, with the fetch the window makes
-    with h.span("warm_pool"):
-        for k in range(pool):
-            loss = step(*feed[k])
-        onp.asarray(loss._data)
-    traces0, step_traces0 = traces(), step.jit_trace_count()
+        dw = change_norms(w0, {n: s[0] for n, s in prog.state().items()})
+        dw = {n: float(v) for n, v in dw.items()}
+    if prog.step.last_path != "whole_step":
+        raise RuntimeError(f"TrainStep ran {prog.step.last_path}: "
+                           f"{prog.step.ineligible_reason()}")
+    return losses, gn, dw
 
-    # -- the window ------------------------------------------------------
+
+def window(h, prog, next_batch, loss):
+    """The measured window: ``step(*next_batch())`` back to back for
+    ``h.seconds``, the loss fetched every ``FETCH_EVERY``-th step, closed
+    by ``block_until_ready`` on loss and parameters.  ``loss`` is the
+    warm-up's last.  Returns (``setup_s``, the run's record for the
+    per-layer readers, peak device memory)."""
+    jax, step = h.jax, prog.step
     pauses = []         # the collector's pauses in the window: [generation, s]
 
     def on_gc(phase, info):
@@ -126,6 +146,7 @@ def run(h):
         else:
             pauses[-1][1] = time.perf_counter() - pauses[-1][1]
 
+    traces0 = prog.traces()
     gc.callbacks.append(on_gc)
     setup_s = h.window_opens()
     dispatch, n, tracing, want_trace, traced_steps = [], 0, False, h.trace, 0
@@ -136,9 +157,10 @@ def run(h):
             jax.block_until_ready(loss._data)
             h.trace_start()
             tracing, want_trace = n, False
+        batch = next_batch()
         ts = time.perf_counter()
         with h.annotate("enqueue_step"):
-            loss = step(*feed[n % pool])
+            loss = step(*batch)
         dispatch.append(time.perf_counter() - ts)
         n += 1
         if n % FETCH_EVERY == 0:
@@ -153,9 +175,7 @@ def run(h):
             tracing, traced_steps = False, TRACE_STEPS
         if time.perf_counter() >= deadline:
             break
-    jax.block_until_ready(
-        [loss._data] + [p.data()._data
-                        for p in net.collect_params().values()])
+    jax.block_until_ready([loss._data] + prog.params())
     window_s = time.perf_counter() - t0
     gc.callbacks.remove(on_gc)
     h.note(gc_pauses_ms=[[g, d * 1e3] for g, d in pauses if d >= 5e-3],
@@ -165,36 +185,74 @@ def run(h):
     if tracing is not False:       # the window closed inside the slice
         h.trace_stop()
         traced_steps = n - tracing
-    retraces = (traces() - traces0) + (step.jit_trace_count()
-                                       - step_traces0)
-    peak = h.memory_peak()
+    record = {"dispatch_s": dispatch, "steps": n,
+              "traced_steps": traced_steps, "window_s": window_s,
+              "retraces": prog.traces() - traces0}
+    return setup_s, record, h.memory_peak()
 
-    # -- free the program, then the reference ----------------------------
-    del step, trainer, net, feed, st, loss
-    gc.collect()
-    checks = h.checks
+
+def compare(h, weights, batches, prog_numbers, retraces):
+    """The plain reference through the same first steps on ``batches``,
+    and every number of the program beside it under the configuration's
+    limits."""
+    ref = h.reference
+    limits = h.cfg["limits"]["train_step"]
     t_ref = time.perf_counter()
-    ref_losses, ref_gn, ref_dw = train_ref.train_steps(
-        ref, cfg, weights, batches, REF_STEPS)
-    weight_leaves = [n for n, w in w0.items() if w.ndim >= 2]
-    for name, value, note in train_numbers(
-            (prog_losses, prog_gn, prog_dw), (ref_losses, ref_gn, ref_dw),
-            weight_leaves):
+    ref_numbers = train_ref.train_steps(ref, h.cfg, weights, batches,
+                                        REF_STEPS)
+    weight_leaves = [n for n, w in weights.items()
+                     if ref.trainable(n) and w.ndim >= 2]
+    for name, value, note in train_numbers(prog_numbers, ref_numbers,
+                                           weight_leaves):
         base, _, k = name.partition(".step")
         limit = limits[base][int(k) - 1] if k else limits[name]
-        checks.add(name, value, limit, note)
-    checks.add("retraces_in_window", retraces, 0)
-    h.dump("leaves", {"losses": [prog_losses, ref_losses],
-                      "grad_norm": [prog_gn, ref_gn],
-                      "dw_norm": [prog_dw, ref_dw]})
+        h.checks.add(name, value, limit, note)
+    h.checks.add("retraces_in_window", retraces, 0)
+    h.dump("leaves", {k: [p, r] for k, p, r in zip(
+        ("losses", "grad_norm", "dw_norm"), prog_numbers, ref_numbers)})
     h.note(reference_s=time.perf_counter() - t_ref)
 
-    rate = n * b / window_s
+
+def result(batch, setup_s, run, peak):
+    """What ``run.py`` takes from a driver; the rate is samples completed
+    over the seconds of the whole window."""
     return {
         "setup_s": setup_s,
-        "attempted": n, "failed": 0,
-        "end_to_end": {"train_samples_s": rate},
+        "attempted": run["steps"], "failed": 0,
+        "end_to_end": {"train_samples_s":
+                       run["steps"] * batch / run["window_s"]},
         "memory_peak_bytes": peak,
-        "run": {"dispatch_s": dispatch, "steps": n, "batch": b,
-                "traced_steps": traced_steps, "window_s": window_s},
+        "run": dict(run, batch=batch),
     }
+
+
+def run(h):
+    cfg, traffic, ref = h.cfg, h.traffic, h.reference
+    pool = traffic["pool"]
+    if pool < REF_STEPS:
+        raise ValueError(f"pool {pool} < {REF_STEPS}: the first steps need "
+                         "batches that all differ")
+    with h.span("make_weights"):
+        weights = wmod.make_weights(ref.param_specs(cfg), h.seed,
+                                    cfg["dtype"])
+        batches = reference_batches(cfg, traffic, h.seed, pool, ref)
+    with h.span("build"):
+        prog = Program(h, weights)
+    feed = [tuple(prog.NDArray(a) for a in bt) for bt in batches]
+    w0 = {n: w for n, w in weights.items() if ref.trainable(n)}
+    prog_numbers = first_steps(h, prog, w0, feed)
+    # every batch of the pool once more, with the fetch the window makes
+    with h.span("warm_pool"):
+        for k in range(pool):
+            loss = prog.step(*feed[k])
+        onp.asarray(loss._data)
+
+    setup_s, run_, peak = window(h, prog, itertools.cycle(feed).__next__,
+                                 loss)
+
+    # -- free the program, then the reference ----------------------------
+    prog.free()
+    del feed, loss
+    gc.collect()
+    compare(h, weights, batches, prog_numbers, run_["retraces"])
+    return result(traffic["batch"], setup_s, run_, peak)

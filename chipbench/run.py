@@ -245,6 +245,14 @@ def main(argv=None):
                                            "unit": units.get(name, "")}
     print(json.dumps({"notes": h.notes, "spans": h.spans,
                       "cache_events": h.cache_events}), flush=True)
+    # every number compared beside its limit: the last lines of standard
+    # error, and the last key of the result
+    result["checks"] = {r["name"]: [r["value"], r["limit"]]
+                        for r in h.checks.rows}
+    for r in h.checks.rows:
+        print(f"check {r['name']} {r['value']!r} limit {r['limit']!r}"
+              + ("" if r["ok"] else " FAILED"), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

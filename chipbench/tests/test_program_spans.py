@@ -59,13 +59,15 @@ def test_idle_under_hand_checked(small):
     "device_backward_ms.train", "device_optimizer_ms.train",
     "device_batchnorm_ms.train", "device_scope_coverage_pct.train",
     "host_prologue_ms.train", "host_call_ms.train",
-    "host_writeback_ms.train"])
+    "host_writeback_ms.train", "data_wait_ms.train",
+    "data_wait_p95_ms.train"])
 def test_reader_on_the_small_trace(small, name):
     # per traced step (2): forward fusion.1 + convolution.2 = 14 us / 2;
     # backward fusion.3 = 6 / 2; optimizer fusion.4 = 2 / 2; BatchNorm
     # fusion.1 + fusion.3 = 12 / 2; copy.5 (1 us of 23) has no scope.
     # Host, median of two steps: prologue + operands (1.5, 1.6 us),
-    # the call (5.5, 5.4), write-back + bookkeeping (1.0, 2.0)
+    # the call (5.5, 5.4), write-back + bookkeeping (1.0, 2.0); the waits
+    # for a batch (3.0, 4.0): median 3.5, 95th percentile 3.95
     trace, _devices, _host, expect = small
     value = harness._load_reader(name).read(trace, RUN)
     assert value == pytest.approx(expect[name])
@@ -148,7 +150,8 @@ def test_readers_find_nothing_on_a_program_without_spans(monkeypatch):
         "idle_in_call_ms.train", "device_forward_ms.train",
         "device_backward_ms.train", "device_optimizer_ms.train",
         "device_batchnorm_ms.train", "device_scope_coverage_pct.train",
-        "xla_backend_compile_s", "xla_programs_compiled")}
+        "xla_backend_compile_s", "xla_programs_compiled",
+        "data_wait_ms.train", "data_wait_p95_ms.train")}
     assert got.pop("host_call_ms.train") == pytest.approx(60.0)
     assert set(got.values()) == {None}
 
