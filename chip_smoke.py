@@ -12,8 +12,6 @@ BERT-base, with random weights made from ``--seed``:
                    reference on the chip
   serve_resnet50   serving.InferenceEngine: warm the bucket ladder, then
                    answer mixed-size requests with zero retraces
-  kernels          MXTPU_KERNELS=force BN / optimizer kernels against
-                   their XLA twins on the chip
 
 ``--chips 4`` runs ONLY the data-parallel whole step across four chips
 and the one-device reference it is compared with.
@@ -40,9 +38,6 @@ CFG = {
              "model": {}},        # model={} -> bert_12_768_12 as published
     "serve": {"max_batch": 8, "rows": (1, 3, 8, 2, 5, 1, 4, 8)},
     "flash": {"shape": (8, 12, 384, 64)},
-    "bn_shapes": ((128 * 56 * 56, 64), (128 * 56 * 56, 256),
-                  (128 * 7 * 7, 2048)),
-    "opt_shapes": ((768, 3072), (3, 3, 512, 512)),
     "dp": {"batch": 128, "image": 224, "classes": 1000},
 }
 
@@ -327,114 +322,6 @@ def serve_resnet50(mx, seed, platform):
 
 
 # ---------------------------------------------------------------------------
-# phase: kernels
-# ---------------------------------------------------------------------------
-
-def kernels(mx, seed, platform):
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.kernels import norm as knorm
-    from mxnet_tpu.kernels import opt as kopt
-    from mxnet_tpu.ops import nn as ops_nn
-    from mxnet_tpu.optimizer import SGD, Adam
-    from mxnet_tpu.optimizer.optimizer import Optimizer
-    from mxnet_tpu.telemetry import instruments as ti
-
-    def outcomes():
-        return {labels: child.value
-                for labels, child in ti.kernel_dispatch_total.series()}
-
-    before = outcomes()
-    os.environ["MXTPU_KERNELS"] = "force"
-    try:
-        key = jax.random.PRNGKey(seed)
-        bn_err = {}
-        for m, ch in CFG["bn_shapes"]:
-            kx, kd, kg, kb, key = jax.random.split(key, 5)
-            x = (jax.random.normal(kx, (m, ch)) * 2 + 0.5).astype(jnp.bfloat16)
-            dy = jax.random.normal(kd, (m, ch)).astype(jnp.bfloat16)
-            gamma = 1 + 0.1 * jax.random.normal(kg, (ch,), jnp.float32)
-            beta = 0.1 * jax.random.normal(kb, (ch,), jnp.float32)
-            shift = jnp.zeros((ch,), jnp.float32)
-
-            def fwd_bwd(impl):
-                def run(x, gamma, beta, dy):
-                    (out, mean, var), vjp = jax.vjp(
-                        lambda a, g, b: impl(a, g, b, shift, 1e-5, 1),
-                        x, gamma, beta)
-                    dx, dg, db = vjp((dy, jnp.zeros_like(mean),
-                                      jnp.zeros_like(var)))
-                    return out, mean, var, dx, dg, db
-                return jax.jit(run)
-
-            kern = fwd_bwd(knorm.bn_train)
-            text = kern.lower(x, gamma, beta, dy).compile().as_text()
-            _check(text.count("tpu_custom_call") >= 2,
-                   f"bn_train at {(m, ch)} compiled without its kernels")
-            got = kern(x, gamma, beta, dy)
-            ref = fwd_bwd(ops_nn._bn_train)(x, gamma, beta, dy)
-            tols = {"out": (2e-2, 2e-2), "mean": (2e-3, 2e-3),
-                    "var": (2e-3, 2e-3), "dx": (2e-2, 2e-2),
-                    "dgamma": (2e-2, 1e-1), "dbeta": (2e-2, 1e-1)}
-            bn_err[f"{m}x{ch}"] = {
-                n: _close(f"bn_train {n} at {(m, ch)}", g, r, *tols[n])
-                for n, g, r in zip(tols, got, ref)}
-        say("kernels.bn_train", dtype="bfloat16", max_abs_err=bn_err)
-
-        opt_err = {}
-        rules = ((SGD, 1, {"rescale_grad": 1.0 / 128, "momentum": 0.9}),
-                 (Adam, 2, {"rescale_grad": 1.0 / 128, "beta1": 0.9,
-                            "beta2": 0.999, "eps": 1e-8}))
-        for shape in CFG["opt_shapes"]:
-            for cls, n_state, hyper in rules:
-                for mp in (True, False):
-                    kw, kg, ks, key = jax.random.split(key, 4)
-                    wdt = jnp.bfloat16 if mp else jnp.float32
-                    master = jax.random.normal(kw, shape, jnp.float32)
-                    w = master.astype(wdt)
-                    g = jax.random.normal(kg, shape, jnp.float32).astype(wdt)
-                    inner = tuple(
-                        jnp.abs(jax.random.normal(k_, shape, jnp.float32))
-                        * 0.01 for k_ in jax.random.split(ks, n_state))
-                    inner = inner[0] if n_state == 1 else inner
-                    st = (master, inner) if mp else inner
-                    args = (0.1, 1e-4, 3, 1.0, hyper)
-
-                    def run(impl):
-                        return jax.jit(lambda w, st, g: impl(
-                            cls, None, False, mp, w, st, g, *args))
-
-                    kern = run(kopt.param_step)
-                    _check("tpu_custom_call" in
-                           kern.lower(w, st, g).compile().as_text(),
-                           f"param_step {cls.__name__} mp={mp} at {shape} "
-                           "compiled without its kernel")
-                    got = jax.tree_util.tree_leaves(kern(w, st, g))
-                    ref = jax.tree_util.tree_leaves(
-                        run(Optimizer._fused_param_step)(w, st, g))
-                    _check(len(got) == len(ref), "state trees differ")
-                    opt_err[f"{cls.__name__},mp={int(mp)},"
-                            f"{'x'.join(map(str, shape))}"] = max(
-                        _close(f"param_step {cls.__name__} mp={mp} leaf {i}",
-                               a, r,
-                               *((1e-2, 1e-2) if r.dtype == jnp.bfloat16
-                                 else (1e-4, 1e-5)))
-                        for i, (a, r) in enumerate(zip(got, ref)))
-        say("kernels.param_step", max_abs_err=opt_err)
-    finally:
-        os.environ.pop("MXTPU_KERNELS", None)
-
-    moved = {k: v - before.get(k, 0) for k, v in outcomes().items()
-             if v - before.get(k, 0)}
-    _check(moved, "no kernel dispatch was recorded")
-    bad = {k: v for k, v in moved.items() if k[1] != "kernel"}
-    _check(not bad, f"kernel dispatch fell back: {bad}")
-    say("kernels.dispatch", kernel_dispatch_total={
-        f"{k[0]}:{k[1]}": v for k, v in sorted(moved.items())})
-
-
-# ---------------------------------------------------------------------------
 # --chips 4: the data-parallel whole step and its one-device reference
 # ---------------------------------------------------------------------------
 
@@ -599,7 +486,7 @@ def main(argv=None):
         phases = {"train_dp4": functools.partial(train_dp4, chips=4)}
     else:
         phases = {f.__name__: f for f in (train_resnet50, train_bert_base,
-                                          serve_resnet50, kernels)}
+                                          serve_resnet50)}
     for name, phase in phases.items():
         t0 = time.perf_counter()
         hits, misses = _CACHE_EVENTS["hits"], _CACHE_EVENTS["misses"]

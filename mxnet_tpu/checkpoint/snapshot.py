@@ -34,31 +34,25 @@ __all__ = ["capture", "apply"]
 SNAPSHOT_VERSION = 1
 
 
-def _state_spec(state, prefix, out, transform=None):
+def _state_spec(state, prefix, out):
     """Flatten one optimizer-state tree: leaves (NDArray) land in `out`
     under generated keys; returns a JSON-able spec mirroring the
-    structure — None | "key-string" | [child specs]. `transform`, when
-    given, maps each leaf's host array before it is stored (the layout
-    path de-permutes physically re-laid-out momentum back to the
-    logical shape so checkpoints stay layout-agnostic)."""
+    structure — None | "key-string" | [child specs]."""
     from ..ndarray.ndarray import NDArray
 
     if state is None:
         return None
     if isinstance(state, NDArray):
-        arr = state.asnumpy()
-        if transform is not None:
-            arr = transform(arr)
-        out[prefix] = arr
+        out[prefix] = state.asnumpy()
         return prefix
     if isinstance(state, (tuple, list)):
-        return [_state_spec(s, f"{prefix}.{j}", out, transform)
+        return [_state_spec(s, f"{prefix}.{j}", out)
                 for j, s in enumerate(state)]
     raise CheckpointError(
         f"unserializable optimizer state at {prefix}: {type(state)}")
 
 
-def _state_from_spec(spec, arrays, transform=None):
+def _state_from_spec(spec, arrays):
     import jax.numpy as jnp
 
     from ..ndarray.ndarray import NDArray
@@ -68,47 +62,8 @@ def _state_from_spec(spec, arrays, transform=None):
     if isinstance(spec, str):
         if spec not in arrays:
             raise CheckpointError(f"missing optimizer state array {spec!r}")
-        arr = arrays[spec]
-        if transform is not None:
-            arr = transform(arr)
-        return NDArray(jnp.asarray(arr))
-    return tuple(_state_from_spec(s, arrays, transform) for s in spec)
-
-
-def _save_transform(p):
-    """Physical→logical de-permutation for one param's state leaves, or
-    None when the param was never re-laid-out (passes/layout.py)."""
-    perm = getattr(p, "_layout_perm", None)
-    if perm is None:
-        return None
-    logical = tuple(p._shape)
-    phys = tuple(logical[i] for i in perm)
-    if phys == logical:
-        return None
-    inv = tuple(int(i) for i in np.argsort(perm))
-
-    def t(arr):
-        return np.transpose(arr, inv) if tuple(arr.shape) == phys else arr
-
-    return t
-
-
-def _load_transform(p):
-    """Logical→physical permutation applied on restore, matching the
-    trainer's CURRENT layout (which may differ from save time — the
-    checkpoint itself is always logical)."""
-    perm = getattr(p, "_layout_perm", None)
-    if perm is None:
-        return None
-    logical = tuple(p._shape)
-    if tuple(logical[i] for i in perm) == logical:
-        return None
-
-    def t(arr):
-        return (np.transpose(arr, perm)
-                if tuple(arr.shape) == logical else arr)
-
-    return t
+        return NDArray(jnp.asarray(arrays[spec]))
+    return tuple(_state_from_spec(s, arrays) for s in spec)
 
 
 def _stale_indices(trainer):
@@ -135,19 +90,13 @@ def capture(trainer, user_state=None):
     """
     arrays = {}
     param_names, param_dtypes, param_shapes = [], [], []
-    layout_perms = []
     for i, p in enumerate(trainer._params):
         p._check_initialized()
-        # logical (declared) shape regardless of any persistent NHWC
-        # re-layout, so checkpoints are portable across MXTPU_LAYOUT
-        arrays[f"param/{i}"] = p.logical_data().asnumpy()
+        arrays[f"param/{i}"] = p.data().asnumpy()
         param_names.append(p.name)
         param_dtypes.append(str(np.dtype(p.dtype)) if p.dtype else None)
         param_shapes.append(list(arrays[f"param/{i}"].shape))
-        perm = getattr(p, "_layout_perm", None)
-        layout_perms.append(list(perm) if perm is not None else None)
-    state_specs = [_state_spec(s, f"opt/{i}", arrays,
-                               _save_transform(trainer._params[i]))
+    state_specs = [_state_spec(s, f"opt/{i}", arrays)
                    for i, s in enumerate(trainer._states)]
     meta = {
         "snapshot_version": SNAPSHOT_VERSION,
@@ -159,10 +108,6 @@ def capture(trainer, user_state=None):
         "states_created": list(trainer._states_created),
         "optimizer": trainer._optimizer.bookkeeping_state(),
         "stale_indices": _stale_indices(trainer),
-        # observability only: which params were physically re-laid-out
-        # at save time (arrays are ALWAYS logical — apply re-permutes
-        # to whatever the restoring trainer's layout is)
-        "layout_perms": layout_perms,
         # the ShardingPlan this run trained under (docs/sharding.md):
         # arrays above are host numpy — asnumpy() gathers every shard —
         # so the payload itself is placement-free; the record is for
@@ -216,9 +161,7 @@ def apply(trainer, arrays, meta):
     for i, p in enumerate(trainer._params):
         p.set_data(arrays[f"param/{i}"])  # fans out to every device copy
     specs = meta.get("state_specs") or [None] * len(trainer._params)
-    trainer._states = [
-        _state_from_spec(s, arrays, _load_transform(trainer._params[i]))
-        for i, s in enumerate(specs)]
+    trainer._states = [_state_from_spec(s, arrays) for s in specs]
     trainer._states_created = list(
         meta.get("states_created") or [s is not None for s in specs])
     opt_meta = meta.get("optimizer")

@@ -261,7 +261,7 @@ def verify_parity(trainer, arrays, atol=0.0):
 
     compared = 0
     for i, p in enumerate(trainer._params):
-        _cmp(f"param/{i}", p.logical_data().asnumpy())
+        _cmp(f"param/{i}", p.data().asnumpy())
         compared += 1
     for i, st in enumerate(trainer._states):
         if st is None:

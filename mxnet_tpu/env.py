@@ -94,21 +94,6 @@ register(
     "Accumulate bf16 reductions in fp32 (the framework always does this "
     "on TPU; exposed for reference parity).")
 register(
-    "MXTPU_BENCH_LAYOUT", str, "NHWC",
-    "bench.py conv layout experiment knob: NHWC (channels-last, MXU lane "
-    "dim) or NCHW.")
-register(
-    "MXTPU_BENCH_BATCH", int, 256,
-    "bench.py per-chip batch size.")
-register(
-    "MXTPU_BENCH_HEADLINE_ONLY", bool, False,
-    "bench.py: skip the secondary rows (LeNet/BERT/INT8), emit only the "
-    "ResNet training+inference numbers.")
-register(
-    "SCALING_DEVICES", int, 8,
-    "benchmark/scaling.py virtual device count for the weak-scaling "
-    "partition-efficiency measurement.")
-register(
     "MXNET_KVSTORE_BIGARRAY_BOUND", int, 1 << 20,
     "Parity knob: arrays above this element count prefer sharded "
     "(reduce-scatter) allreduce in tpu_dist.")
@@ -125,7 +110,8 @@ register(
     "MXTPU_IO_WORKER_NTHREADS", int, 2,
     "Native-runtime IO worker threads (checkpoint writes, RecordIO "
     "prefetch; reference: the IO-priority pool of "
-    "threaded_engine_perdevice.cc).")
+    "threaded_engine_perdevice.cc). Read by native/mxtpu_runtime.cc "
+    "when the engine starts.")
 register(
     "MXTPU_SERVE_MAX_BATCH", int, 32,
     "serving.InferenceEngine default max micro-batch size (top of the "
@@ -292,11 +278,6 @@ register(
     "clean shutdown so supervisors treat the job as resumable, not "
     "crashed).")
 register(
-    "MXTPU_CKPT_DIR", str, "",
-    "Default checkpoint directory for tools and the estimator "
-    "CheckpointHandler when none is passed explicitly; empty = require "
-    "an explicit directory.")
-register(
     "MXTPU_ELASTIC_MAX_RESTARTS", int, 3,
     "Supervisor restart budget (tools/supervisor.py via "
     "elastic.RestartPolicy; docs/elasticity.md): lifetime cap on "
@@ -367,11 +348,6 @@ register(
     "structurally identical blocks (multi-head models, serving "
     "replicas). Reuses count in graph_dedup_hits_total.")
 register(
-    "MXTPU_BENCH_BUDGET_S", int, 1200,
-    "bench.py wall-clock budget (seconds); secondary rows are skipped "
-    "with an error row once exceeded so the driver always gets the "
-    "headline JSON quickly.")
-register(
     "MXTPU_NUMERICS", str, "off",
     "In-graph numerics checking (observability.numerics; "
     "docs/observability.md): 'step' fuses ONE is-finite AND-reduce over "
@@ -414,40 +390,6 @@ register(
     "Job identity stamped into flight-recorder events and span records; "
     "(job_id, step) is the cross-rank trace ID tools/blackbox.py aligns "
     "per-rank postmortem bundles on. Empty = 'local'.")
-register(
-    "MXTPU_KERNELS", str, "off",
-    "Hand-fused Pallas bandwidth kernels for the HBM-bound regions the "
-    "r5 fusion audit ranked worst (mxnet_tpu/kernels; docs/kernels.md): "
-    "'off' (default) never touches a call site — bitwise-identical to "
-    "the XLA paths with zero extra traces; 'auto' uses a kernel at a "
-    "call site only when the passes/memory.py external-bytes model "
-    "predicts it saves HBM traffic over the fused-XLA estimate; 'force' "
-    "uses a kernel whenever shape/dtype/rule support allows. Unsupported "
-    "sites always fall back to the existing XLA path (fallbacks count "
-    "in kernel_dispatch_total and land in the flight recorder).")
-register(
-    "MXTPU_KERNELS_INTERPRET", bool, False,
-    "Run the mxnet_tpu/kernels Pallas kernels in interpret mode so they "
-    "execute off-TPU (CPU parity tests). Without it, non-TPU platforms "
-    "take the XLA fallback even under MXTPU_KERNELS=force.")
-register(
-    "MXTPU_LAYOUT", str, "off",
-    "Whole-graph channels-last layout pass (passes/layout.py; "
-    "docs/layout.md): 'off' (default) never consults the pass — "
-    "captured programs and weight buffers are bitwise-identical to main "
-    "with zero extra traces; 'auto' rewrites conv-bearing graphs to "
-    "NHWC/HWIO only when the passes/memory.py external-bytes model "
-    "predicts the saved per-conv relayouts outweigh the boundary "
-    "transposes it must insert; 'nhwc' rewrites whenever a "
-    "channels-first conv is present. Conv weights are re-laid-out "
-    "persistently (one-time OIHW→HWIO device transpose); checkpoints "
-    "round-trip the logical NCHW layout either way.")
-register(
-    "MXTPU_LAYOUT_MIN_BYTES", int, 1 << 20,
-    "MXTPU_LAYOUT=auto declines graphs whose channels-first conv "
-    "activations (inputs + outputs) total fewer external bytes than "
-    "this — relayout bookkeeping swamps any bandwidth win on tiny "
-    "graphs (passes/layout.py).")
 register(
     "MXTPU_MESH", str, "",
     "Device-mesh axis spec for the sharding subsystem "
@@ -504,15 +446,6 @@ register(
     "'Authorization: Bearer <token>' or get 401. GET endpoints stay "
     "open — they serve the same read-only snapshots a postmortem "
     "bundle contains.")
-register(
-    "MXTPU_BN_COMPUTE", str, "f32",
-    "Element-wise dtype of the O(N·H·W·C) BatchNorm tensors (ops/nn.py "
-    "_bn_ew_dtype; the r5 audit's top falsifiable prediction): 'f32' "
-    "(default, today's measured-correct config) or 'bf16' — keep the "
-    "big elementwise chains in the activation dtype and promote only "
-    "the reduction accumulators to f32. Applies to the XLA custom-VJP "
-    "path and the Pallas norm kernels alike; A/B on chip before "
-    "changing the default.")
 register(
     "MXTPU_DIAGNOSTICS", bool, True,
     "Diagnostics span recording (diagnostics/spans.py): per-phase "

@@ -359,9 +359,7 @@ class Block:
         for name, p in self._collect_params_with_prefix().items():
             if p._data_map is None:
                 continue
-            # logical layout: files stay portable whether or not this
-            # process re-laid the weight out (passes/layout.py)
-            arrays[name] = _np.asarray(p.logical_data().asnumpy())
+            arrays[name] = _np.asarray(p.data().asnumpy())
         # the serialize+write runs on a native-engine IO thread so training
         # continues while the checkpoint lands; loads (and waitall) barrier
         # on the path's engine var (_checkpoint_io; reference: engine-pushed
@@ -842,23 +840,12 @@ class HybridBlock(Block):
                 jitted = self._jit_variants.get(training)
                 if jitted is None:
                     self._ensure_initialized(args)
-                    # persistent NHWC weight re-layout BEFORE the first
-                    # trace: the captured program sees HWIO invars, so
-                    # layout costs no extra compile (passes/layout.py;
-                    # MXTPU_LAYOUT=off returns immediately)
-                    from ..passes import layout as _layout_pass
-
-                    _layout_pass.prepare_block(self)
                     compile_t0 = time.perf_counter()
                     with _spans.span(type(self).__name__, cat="compile"):
                         jitted = self._build_variant(training, args)
                     self._jit_variants[training] = jitted
         else:
             self._ensure_initialized(args)
-            if not getattr(self, "_layout_prepared", False):
-                from ..passes import layout as _layout_pass
-
-                _layout_pass.prepare_block(self)
         params = self._cached_param_list
         names = [n for n, _ in params]
         param_nds = [p.data() for _, p in params]
@@ -959,7 +946,7 @@ class HybridBlock(Block):
         This is the TPU-native export of the CachedOp: the function is
         jit/pjit/shard_map-able, differentiable, and shardable; aux-state
         updates (BN running stats) come back in new_params instead of
-        mutating. Used by bench.py, __graft_entry__ and the sharded
+        mutating. Used by __graft_entry__ and the sharded
         training paths.
         """
         params = sorted(self.collect_params().items())

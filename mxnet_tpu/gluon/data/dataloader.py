@@ -5,9 +5,10 @@ Worker modes (matching the reference's semantics):
   * num_workers>0 (default) — multiprocessing fork workers, like the
     reference's _MultiWorkerIter: each worker loads + batchifies to plain
     numpy in its own interpreter (PIL decode and augmenters hold the GIL,
-    so processes are the only way decode scales — measured in
-    benchmark/pipeline.py); the parent converts to device arrays so
-    children never touch jax: the chip belongs to the parent process.
+    so processes are the only way decode scales; what this path costs
+    on the chip's machine is PERF.md section 5); the parent converts to
+    device arrays so children never touch jax: the chip belongs to the
+    parent process.
   * num_workers>0, thread_pool=True — prefetching thread pool over the
     native C++ pipeline (iter_prefetcher.h analog): right when samples
     are already numpy (no GIL-bound decode) or datasets are unpicklable.

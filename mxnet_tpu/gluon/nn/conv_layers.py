@@ -78,20 +78,10 @@ class _Conv(HybridBlock):
                 dilate=self._dilation, output_padding=self._output_padding,
                 groups=self._groups, layout=self._layout)
         else:
-            kernel_layout = None
-            if getattr(self.weight, "_layout_perm", None) is not None:
-                # weight buffers live in a persistently re-laid-out
-                # physical shape (passes/layout.py); tell the op which
-                # spec the bytes actually are so dn stays consistent
-                sp = "DHW"[-self._ndim:]
-                spec = ("O" + sp + "I") if self._channels_last \
-                    else ("OI" + sp)
-                kernel_layout = "".join(
-                    spec[i] for i in self.weight._layout_perm)
             out = npx.convolution(
                 *args, stride=self._strides, pad=self._padding,
                 dilate=self._dilation, groups=self._groups,
-                layout=self._layout, kernel_layout=kernel_layout)
+                layout=self._layout)
         if self._activation:
             out = npx.activation(out, self._activation)
         return out

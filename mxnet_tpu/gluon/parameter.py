@@ -21,7 +21,7 @@ import numpy as _np
 from .. import initializer as init_mod
 from ..base import DeferredInitializationError, normalize_dtype
 from ..device import Device, current_device
-from ..ndarray.ndarray import NDArray, _wrap_out, device_groups
+from ..ndarray.ndarray import NDArray, device_groups
 
 __all__ = ["Parameter", "Constant", "cast_params"]
 
@@ -117,11 +117,6 @@ class Parameter:
         self._grad_map = None
         self._ctx_list = None
         self._deferred = None  # (init, device_list, default_init)
-        # persistent physical layout (passes/layout.py prepare_block):
-        # None = physical == logical; else data()/grad() buffers hold
-        # transpose(logical, _layout_perm) while self.shape, set_data,
-        # logical_data and every checkpoint stay in the LOGICAL layout
-        self._layout_perm = None
         # tracer visible during CachedOp tracing — THREAD-LOCAL so a trace
         # in one thread cannot leak tracers into concurrent inference
         # threads (reference: cached_op_threadsafe.cc isolation)
@@ -227,11 +222,9 @@ class Parameter:
         grad_req change: reused buffers would feed stale gradients into
         an 'add' accumulation."""
         self._grad_map = {}
-        shape = self._shape if self._layout_perm is None \
-            else tuple(self._shape[i] for i in self._layout_perm)
         # host zeros, placed: `jnp.zeros` is an XLA program per distinct
         # (shape, type), a transfer is none
-        zeros = _np.zeros(shape, self.dtype)
+        zeros = _np.zeros(self._shape, self.dtype)
         for d, arr in self._data_map.items():
             g = NDArray(jax.device_put(zeros, d.jax_device), d)
             self._grad_map[d] = g
@@ -345,17 +338,6 @@ class Parameter:
         dev = x.device
         return self._data_map.get(dev, self._data_map[self._ctx_list[0]])
 
-    def logical_data(self, ctx=None, device=None):
-        """The value in the parameter's LOGICAL layout (``self.shape``),
-        undoing any persistent physical re-layout — what checkpoints and
-        save_parameters serialize so files stay portable across
-        MXTPU_LAYOUT settings."""
-        arr = self.data(ctx=ctx, device=device)
-        if self._layout_perm is None or self._traced_data is not None:
-            return arr
-        inv = tuple(int(i) for i in _np.argsort(self._layout_perm))
-        return _wrap_out(jnp.transpose(arr._data, inv))
-
     def list_data(self):
         self._check_initialized()
         return [self._data_map[d] for d in self._ctx_list]
@@ -399,15 +381,6 @@ class Parameter:
                     f"Parameter {self._name}: shape still unknown "
                     f"{self._shape}")
         src = data._data
-        # set_data speaks the LOGICAL layout (checkpoints, user code);
-        # convert to the persistent physical layout once, here, so NCHW
-        # era files load bitwise onto re-laid-out parameters
-        if self._layout_perm is not None:
-            phys = tuple(self._shape[i] for i in self._layout_perm)
-            if tuple(src.shape) == phys and phys != tuple(self._shape):
-                pass  # already physical (internal caller)
-            else:
-                src = jnp.transpose(src, self._layout_perm)
         if deferred:
             # ... and takes the place of the initializer's draw, which
             # nobody would read (a draw is an XLA program per shape)

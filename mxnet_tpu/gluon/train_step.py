@@ -523,10 +523,9 @@ class TrainStep:
 
             # the whole-step program compiles through the pipeline seam
             # too; the forward body was already rewritten via
-            # wrap_forward, so the only shipped pass claiming
-            # kind=whole_step is the audit-only KernelPass (when
-            # MXTPU_KERNELS is on) — with kernels off this resolves to
-            # the plain donated jit
+            # wrap_forward, and the passes that claim kind=whole_step
+            # (numerics, sharding) join only when asked for — otherwise
+            # this resolves to the plain donated jit
             fn = _passes.apply(self._step_fn, _passes.PassContext(
                 label="whole_step", variant=self._variant,
                 kind="whole_step", training=True,
@@ -606,14 +605,6 @@ class TrainStep:
             # deferred-shape params just materialized: the trainer's
             # ShardingPlan (if any) can now place them (no-op otherwise)
             self._trainer._maybe_apply_plan()
-        if not getattr(self._net, "_layout_prepared", False):
-            # persistent NHWC weight re-layout BEFORE tws/frozen are
-            # built: the donated whole-step program then updates the
-            # physical (HWIO) buffers in place, never re-transposing
-            # (passes/layout.py; MXTPU_LAYOUT=off returns immediately)
-            from ..passes import layout as _layout_pass
-
-            _layout_pass.prepare_block(self._net, trainer=self._trainer)
         if not self._eligible():
             return self._phased(batch, batch_size)
         if not self._built:

@@ -2,11 +2,10 @@
 drift auditing.
 
 The other half of the measurement plane (observability/measure.py runs
-the microbenchmarks; this module keeps the results). Three subsystems
-make performance decisions from the analytic byte model in
-``passes/memory.py`` — kernel dispatch, the remat auto policy, the
-layout accept test — and nothing ever checked whether those predictions
-match reality. The CostDB closes the loop:
+the microbenchmarks; this module keeps the results). The remat auto
+policy decides from the analytic byte model in ``passes/memory.py``,
+and nothing else checks whether its predictions match reality. The
+CostDB records both sides:
 
   * every measured program lands here keyed by ``(fingerprint,
     platform)`` — the PR-7 dedup structural fingerprint, so two
@@ -24,12 +23,11 @@ match reality. The CostDB closes the loop:
     and each program's drift ratio is its own implied bandwidth over
     the median. A ratio far from 1.0 (beyond
     ``MXTPU_COSTDB_DRIFT_MAX``, either direction) means the byte model
-    is lying about THAT program — exactly the case where
-    ``MXTPU_KERNELS=auto`` or remat-auto chose wrong;
+    is lying about THAT program — exactly the case where remat-auto
+    chose wrong;
   * :func:`audit` publishes ``cost_model_drift_ratio{site,program}``
-    gauges (one per measured program, plus one per kernel-dispatch
-    site recorded inside it) and drops a ``cost_drift`` flight event
-    the first time a program trips.
+    gauges (one per measured program) and drops a ``cost_drift`` flight
+    event the first time a program trips.
 
 Surfaced by opsd ``GET /costdb``, ``tools/diagnose.py --passes``,
 ``tools/costdb.py`` (list/measure/verify/diff), postmortem bundles, and
@@ -268,8 +266,7 @@ def drift_report(entries=None, threshold=None):
         {"threshold": float,
          "calibration": {platform: bytes_per_ms},
          "programs": [{program, fingerprint, platform, drift_ratio,
-                       tripped, wall_ms_p50, predicted_bytes,
-                       sites}, ...],
+                       tripped, wall_ms_p50, predicted_bytes}, ...],
          "tripped": [the subset with tripped=True]}
     """
     if entries is None:
@@ -300,7 +297,6 @@ def drift_report(entries=None, threshold=None):
                                 or ratio < 1.0 / threshold),
                 "wall_ms_p50": e.get("wall_ms_p50"),
                 "predicted_bytes": e.get("predicted_bytes"),
-                "sites": e.get("sites") or [],
             })
     programs.sort(key=lambda r: -abs(_log_ratio(r["drift_ratio"])))
     return {
@@ -323,11 +319,9 @@ def _log_ratio(r):
 def audit(entries=None, threshold=None):
     """Run the drift join and publish it: one
     ``cost_model_drift_ratio{site="program", program}`` gauge per
-    measured program plus one per kernel-dispatch site recorded inside
-    it (the BN-kernel / fused-optimizer analytic scores), and a
-    ``cost_drift`` flight event the FIRST time a (fingerprint,
-    platform) trips — re-audits (opsd polls) don't spam the ring.
-    Never raises; returns the :func:`drift_report` dict."""
+    measured program, and a ``cost_drift`` flight event the FIRST time
+    a (fingerprint, platform) trips — re-audits (opsd polls) don't spam
+    the ring. Never raises; returns the :func:`drift_report` dict."""
     try:
         rep = drift_report(entries=entries, threshold=threshold)
     except Exception as e:
@@ -339,9 +333,6 @@ def audit(entries=None, threshold=None):
         for r in rep["programs"]:
             _instr.set_cost_drift("program", r["program"],
                                   r["drift_ratio"])
-            for s in r["sites"]:
-                _instr.set_cost_drift(str(s.get("site", "?")),
-                                      r["program"], r["drift_ratio"])
     except Exception:
         pass
     for r in rep["tripped"]:

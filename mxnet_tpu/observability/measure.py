@@ -8,12 +8,12 @@ seam: under ``MXTPU_MEASURE=on_compile`` each registration runs a
 warmed, synchronized wall-clock microbenchmark of the jitted callable
 on the live device and records ``{fingerprint, platform, wall_ms
 p50/p95, peak_bytes if available, arg shapes/dtypes, analytic
-predictions, kernel-dispatch site scores, telemetry snapshot}`` into
-the CostDB. ``MXTPU_MEASURE=cli`` instead stashes the callables for a
-deferred :func:`sweep` (what ``tools/costdb.py measure`` drives), and
-the default ``off`` returns before touching jax — default runs stay
+predictions, telemetry snapshot}`` into the CostDB.
+``MXTPU_MEASURE=cli`` instead stashes the callables for a deferred
+:func:`sweep` (what ``tools/costdb.py measure`` drives), and the
+default ``off`` returns before touching jax — default runs stay
 bitwise-identical with zero extra jit traces and zero extra device
-dispatches (same kill-switch contract as ``MXTPU_KERNELS=off``).
+dispatches.
 
 Mechanics worth knowing:
 
@@ -29,12 +29,7 @@ Mechanics worth knowing:
   * the analytic predictions come from ``passes/memory.py``
     (``estimate_region_bytes`` / ``estimate_peak_bytes``) over a
     re-trace wrapped in ``suppress_trace_bumps`` so measurement never
-    perturbs the zero-retrace telemetry proofs;
-  * kernel dispatch (``kernels/dispatch.record``) reports each site's
-    analytic XLA-vs-kernel byte scores to :func:`note_site`; the
-    snapshot current at registration rides into the entry so the drift
-    auditor can join program-level measurements against the BN-kernel
-    and fused-optimizer decisions made inside that program.
+    perturbs the zero-retrace telemetry proofs.
 """
 from __future__ import annotations
 
@@ -46,7 +41,7 @@ import time
 
 __all__ = [
     "mode", "enabled", "maybe_register", "pending", "sweep",
-    "measure_callable", "note_site", "site_scores", "fingerprint_of",
+    "measure_callable", "fingerprint_of",
     "reset", "SMALL_LEAF_BYTES",
 ]
 
@@ -65,8 +60,7 @@ _MODES = {
 
 _tls = threading.local()
 _lock = threading.Lock()
-_pending = {}      # (block, variant) -> {"fn", "args", "kwargs", "sites"}
-_SITE_SCORES = {}  # kernel -> latest {"site", outcome, bytes, ...}
+_pending = {}      # (block, variant) -> {"fn", "args", "kwargs"}
 
 
 def _env_get(name, default):
@@ -96,37 +90,6 @@ def mode():
 
 def enabled():
     return mode() != "off"
-
-
-# ---------------------------------------------------------------------------
-# kernel-dispatch site scores
-# ---------------------------------------------------------------------------
-
-
-def note_site(kernel, outcome, xla_bytes=None, kernel_bytes=None,
-              bytes_saved=0):
-    """Called by ``kernels/dispatch.record`` with the analytic scores
-    behind one dispatch decision. Always cheap (dict store); kept even
-    when measurement is off so turning measurement on later still has
-    the latest scores to join against."""
-    score = {
-        "site": str(kernel), "outcome": str(outcome),
-        "xla_bytes": None if xla_bytes is None else int(xla_bytes),
-        "kernel_bytes": None if kernel_bytes is None
-        else int(kernel_bytes),
-        "bytes_saved": int(bytes_saved or 0),
-    }
-    with _lock:
-        _SITE_SCORES[score["site"]] = score
-    sink = getattr(_tls, "site_sink", None)
-    if sink is not None:
-        sink.append(score)
-
-
-def site_scores():
-    """Latest analytic score per kernel-dispatch site."""
-    with _lock:
-        return {k: dict(v) for k, v in _SITE_SCORES.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -181,17 +144,15 @@ def maybe_register(block, variant, jitted, args, kwargs=None):
     try:
         spec_args = _to_spec(tuple(args))
         spec_kwargs = _to_spec(dict(kwargs or {}))
-        sites = list(site_scores().values())
         if mode() == "cli":
             with _lock:
                 _pending[(str(block), str(variant))] = {
                     "fn": jitted, "args": spec_args,
-                    "kwargs": spec_kwargs, "sites": sites,
+                    "kwargs": spec_kwargs,
                 }
             return None
         return measure_callable(jitted, spec_args, block=block,
-                                variant=variant, kwargs=spec_kwargs,
-                                sites=sites)
+                                variant=variant, kwargs=spec_kwargs)
     except Exception:
         return None
 
@@ -215,7 +176,7 @@ def sweep():
         try:
             entry = measure_callable(
                 rec["fn"], rec["args"], block=block, variant=variant,
-                kwargs=rec["kwargs"], sites=rec["sites"])
+                kwargs=rec["kwargs"])
         except Exception:
             entry = None
         if entry is not None:
@@ -262,7 +223,7 @@ def _leaf_summary(tree, cap=32):
 
 
 def _telemetry_snapshot():
-    keep = ("jit_trace_total", "kernel_dispatch_total")
+    keep = ("jit_trace_total",)
     try:
         from ..telemetry import exporters as _exp
 
@@ -297,8 +258,7 @@ def _percentile(sorted_ms, q):
     return sorted_ms[i]
 
 
-def measure_callable(fn, args, block="?", variant="?", kwargs=None,
-                     sites=None):
+def measure_callable(fn, args, block="?", variant="?", kwargs=None):
     """Run the warmed, synchronized microbenchmark of ``fn(*args,
     **kwargs)`` and record the CostDB entry. Returns the entry dict, or
     None when the program can't be materialized on this backend."""
@@ -317,56 +277,48 @@ def measure_callable(fn, args, block="?", variant="?", kwargs=None,
 
         # identity + analytic predictions from one suppressed re-trace
         # (trace caches make this cheap when the program is warm; the
-        # suppression keeps zero-retrace telemetry proofs honest). The
-        # site sink stays active through the warmup/timed runs too:
-        # whichever call first traces the program for real is where the
-        # dispatch decisions — note_site — actually fire.
+        # suppression keeps zero-retrace telemetry proofs honest).
         fingerprint = None
         predicted_bytes = predicted_peak = None
-        collected = []
-        _tls.site_sink = collected
         try:
-            try:
-                from ..passes import _state as _pstate
+            from ..passes import _state as _pstate
 
-                with _pstate.suppress_trace_bumps():
-                    closed = jax.make_jaxpr(
-                        lambda *a: fn(*a, **mat_kwargs))(*mat_args)
-                fingerprint = fingerprint_of(closed)
-                from ..passes import memory as _memory
+            with _pstate.suppress_trace_bumps():
+                closed = jax.make_jaxpr(
+                    lambda *a: fn(*a, **mat_kwargs))(*mat_args)
+            fingerprint = fingerprint_of(closed)
+            from ..passes import memory as _memory
 
-                regions = _memory.estimate_region_bytes(closed)
-                predicted_bytes = sum(
-                    int(r.get("external_bytes", 0) or 0) for r in regions)
-                predicted_peak = int(_memory.estimate_peak_bytes(closed))
-            except Exception:
-                pass
-            if fingerprint is None:
-                fingerprint = hashlib.sha1(
-                    f"{block}/{variant}".encode()).hexdigest()[:16]
-            if not predicted_bytes:
-                # degenerate programs: price the visible I/O so the
-                # drift join has a nonzero denominator
-                predicted_bytes = sum(
-                    int(getattr(x, "nbytes", 0) or 0)
-                    for x in jax.tree_util.tree_leaves((mat_args,
-                                                        mat_kwargs)))
+            regions = _memory.estimate_region_bytes(closed)
+            predicted_bytes = sum(
+                int(r.get("external_bytes", 0) or 0) for r in regions)
+            predicted_peak = int(_memory.estimate_peak_bytes(closed))
+        except Exception:
+            pass
+        if fingerprint is None:
+            fingerprint = hashlib.sha1(
+                f"{block}/{variant}".encode()).hexdigest()[:16]
+        if not predicted_bytes:
+            # degenerate programs: price the visible I/O so the
+            # drift join has a nonzero denominator
+            predicted_bytes = sum(
+                int(getattr(x, "nbytes", 0) or 0)
+                for x in jax.tree_util.tree_leaves((mat_args,
+                                                    mat_kwargs)))
 
-            for _ in range(warmup):
-                out = fn(*_materialize(args), **_materialize(kwargs))
-                jax.block_until_ready(out)
-            times_ms = []
-            for _ in range(runs):
-                a = _materialize(args)
-                k = _materialize(kwargs)
-                jax.block_until_ready((a, k))  # zeros before the clock
-                t0 = time.perf_counter()
-                out = fn(*a, **k)
-                jax.block_until_ready(out)
-                times_ms.append((time.perf_counter() - t0) * 1000.0)
-            times_ms.sort()
-        finally:
-            _tls.site_sink = None
+        for _ in range(warmup):
+            out = fn(*_materialize(args), **_materialize(kwargs))
+            jax.block_until_ready(out)
+        times_ms = []
+        for _ in range(runs):
+            a = _materialize(args)
+            k = _materialize(kwargs)
+            jax.block_until_ready((a, k))  # zeros before the clock
+            t0 = time.perf_counter()
+            out = fn(*a, **k)
+            jax.block_until_ready(out)
+            times_ms.append((time.perf_counter() - t0) * 1000.0)
+        times_ms.sort()
 
         platform = jax.default_backend()
         entry = {
@@ -382,13 +334,6 @@ def measure_callable(fn, args, block="?", variant="?", kwargs=None,
             "predicted_bytes": predicted_bytes,
             "predicted_peak_bytes": predicted_peak,
             "args": _leaf_summary((args, kwargs)),
-            # pjit caching makes the re-trace's sink see only the sites
-            # that actually re-ran; the registration snapshot fills in
-            # the rest, sink scores winning where both saw a site
-            "sites": list({
-                **{s["site"]: s for s in (sites or [])},
-                **{s["site"]: s for s in collected},
-            }.values()),
             "telemetry": _telemetry_snapshot(),
             "time": time.time(),
         }
@@ -409,9 +354,7 @@ def measure_callable(fn, args, block="?", variant="?", kwargs=None,
 
 
 def reset():
-    """Test hygiene: drop pending programs + site scores."""
+    """Test hygiene: drop pending programs."""
     with _lock:
         _pending.clear()
-        _SITE_SCORES.clear()
     _tls.busy = False
-    _tls.site_sink = None

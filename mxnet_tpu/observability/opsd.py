@@ -230,7 +230,6 @@ def costdb_payload(n=64):
         "drift": rep.get("programs"),
         "tripped": rep.get("tripped"),
         "pending": _measure.pending(),
-        "site_scores": _measure.site_scores(),
         "entries": entries[-n:] if n else [],
     }
 

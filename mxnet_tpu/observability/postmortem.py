@@ -136,7 +136,6 @@ def build_bundle(reason, extra=None):
             "entries": d.entries(),
             "drift": _costdb.drift_report(),
             "pending": _measure.pending(),
-            "site_scores": _measure.site_scores(),
         }
     except Exception as e:
         bundle["costdb"] = {"error": repr(e)}

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import env as _env
 from ..base import normalize_dtype
 from .registry import register_op
 
@@ -69,18 +68,13 @@ def _layout_spec(layout):
 
 @register_op("convolution")
 def conv(x, weight, bias=None, stride=None, pad=None, dilate=None, groups=1,
-         layout=None, kernel_layout=None):
+         layout=None):
     """N-d convolution; layout NCHW (default) or NHWC family.
 
     weight (O, I/g, *k) channels-first, (O, *k, I/g) channels-last — matching
     the reference's per-layout weight shapes. Reference:
     src/operator/nn/convolution.cc. Lowers to a single XLA
     conv_general_dilated → MXU; channels-last keeps C in lanes.
-
-    `kernel_layout` overrides the weight spec alone (e.g. "HWIO") — the
-    persistent-relayout path (passes/layout.py) feeds physically
-    transposed weights while the data layout stays whatever `layout`
-    says; output shape and numerics are unchanged.
     """
     nd = x.ndim - 2
     if layout is None:
@@ -90,8 +84,6 @@ def conv(x, weight, bias=None, stride=None, pad=None, dilate=None, groups=1,
         lhs_spec, rhs_spec, lnd = _layout_spec(layout)
         assert lnd == nd, f"layout {layout} does not match input ndim {x.ndim}"
         channels_last = layout[-1] == "C"
-    if kernel_layout is not None:
-        rhs_spec = kernel_layout
     stride = stride or (1,) * nd
     pad = pad or (0,) * nd
     dilate = dilate or (1,) * nd
@@ -282,24 +274,10 @@ def _bn_shapes(x, axis):
     return reduce_axes, tuple(bshape), n
 
 
-def _bn_ew_dtype(x):
-    """Element-wise dtype for the O(N·H·W·C) BN tensors. Default: f32
-    (today's measured-correct config). MXTPU_BN_COMPUTE=bf16 keeps the
-    big elementwise chains in the activation dtype and promotes only the
-    REDUCTION accumulators to f32 (jnp.sum dtype=) — the r4 HLO audit's
-    staged experiment: the program hands XLA ~2.9k f32 elementwise ops
-    whose only f32-ness is stat math; if any fail to fuse on TPU they
-    double HBM traffic. A/B on chip before changing the default.
-    The Pallas BN kernels (kernels/norm.py) read the same knob, so the
-    elementwise-dtype experiment stays a single switch either way."""
-    if _env.get("MXTPU_BN_COMPUTE") == "bf16":
-        return x.dtype
-    return jnp.float32
-
-
 def _bn_train_impl(x, gamma, beta, shift, eps, axis):
     """One reduction pass (sum + sum-of-squares multi-output-fused by XLA,
-    reading the activation once) + one fused elementwise normalize.
+    reading the activation once) + one fused elementwise normalize, all
+    in float32 whatever the activation's dtype.
 
     The sums are taken over (x - shift) with shift = the moving mean — a
     per-channel constant that costs nothing (it fuses into the same pass)
@@ -307,22 +285,19 @@ def _bn_train_impl(x, gamma, beta, shift, eps, axis):
     E[x²]−E[x]² form once the running mean tracks the data scale
     (var is shift-invariant mathematically)."""
     reduce_axes, bshape, n = _bn_shapes(x, axis)
-    ew = _bn_ew_dtype(x)
-    s = lax.stop_gradient(shift.astype(ew)).reshape(bshape)
-    xf = x.astype(ew) - s
-    # accumulate in f32 regardless of the elementwise dtype
-    xf32 = xf.astype(jnp.float32)
-    s1 = jnp.sum(xf, reduce_axes, dtype=jnp.float32)
-    s2 = jnp.sum(xf32 * xf32, reduce_axes, dtype=jnp.float32)
+    s = lax.stop_gradient(shift.astype(jnp.float32)).reshape(bshape)
+    xf = x.astype(jnp.float32) - s
+    s1 = jnp.sum(xf, reduce_axes)
+    s2 = jnp.sum(xf * xf, reduce_axes)
     mean_c = s1 / n
     var = jnp.maximum(s2 / n - mean_c * mean_c, 0.0)
-    mean = mean_c + s.astype(jnp.float32).reshape(s1.shape)
+    mean = mean_c + s.reshape(s1.shape)
     inv = lax.rsqrt(var + eps)
     scale = (gamma.astype(jnp.float32) * inv).reshape(bshape)
     # xf is already centered on s, so normalize against the centered mean
     offset = (beta.astype(jnp.float32)
               - mean_c * gamma.astype(jnp.float32) * inv).reshape(bshape)
-    out = (xf * scale.astype(ew) + offset.astype(ew)).astype(x.dtype)
+    out = (xf * scale + offset).astype(x.dtype)
     return out, mean, var, inv
 
 
@@ -344,30 +319,23 @@ def _bn_train_bwd(eps, axis, res, cts):
     dy, dmean_ct, dvar_ct = cts
     x, gamma, beta, shift, mean, inv = res
     reduce_axes, bshape, n = _bn_shapes(x, axis)
-    ew = _bn_ew_dtype(x)
-    dyf = dy.astype(ew)
-    # center on the saved shift BEFORE any low-precision subtraction,
-    # like the forward: in bf16 mode, mean.astype(bf16) has granularity
-    # ~mean/256, so (x - mean) directly would wreck xhat for
-    # large-mean activations; (x - shift) - (mean - shift) keeps both
-    # operands on the data's centered scale (mean - shift is computed
-    # in f32 and is small once the moving mean tracks the data)
+    dyf = dy.astype(jnp.float32)
+    # centered on the saved shift like the forward: (x - shift) and
+    # (mean - shift) are both on the data's centered scale
     s = lax.stop_gradient(shift.astype(jnp.float32)).reshape(bshape)
-    xf = x.astype(ew) - s.astype(ew)
-    mean_c = (mean.reshape(bshape) - s).astype(ew)
-    xhat = (xf - mean_c) * inv.astype(ew).reshape(bshape)
-    # reductions always accumulate f32 (dtype=), whatever the elementwise
-    dbeta = jnp.sum(dyf, reduce_axes, dtype=jnp.float32)
-    dgamma = jnp.sum(dyf * xhat, reduce_axes, dtype=jnp.float32)
+    xf = x.astype(jnp.float32) - s
+    mean_c = mean.reshape(bshape) - s
+    xhat = (xf - mean_c) * inv.reshape(bshape)
+    dbeta = jnp.sum(dyf, reduce_axes)
+    dgamma = jnp.sum(dyf * xhat, reduce_axes)
     g32 = gamma.astype(jnp.float32)
-    dx = (g32 * inv).astype(ew).reshape(bshape) * (
-        dyf - (dbeta.astype(ew).reshape(bshape)
-               + xhat * dgamma.astype(ew).reshape(bshape)) / n)
+    dx = (g32 * inv).reshape(bshape) * (
+        dyf - (dbeta.reshape(bshape)
+               + xhat * dgamma.reshape(bshape)) / n)
     # cotangents of the batch-stat outputs (aux moving-stat path; usually
     # zero) — cheap broadcast terms that fuse into the dx pass
-    dx = dx + (dmean_ct.astype(ew).reshape(bshape) / n
-               + dvar_ct.astype(ew).reshape(bshape) * 2.0
-               * (xf - mean_c) / n)
+    dx = dx + (dmean_ct.reshape(bshape) / n
+               + dvar_ct.reshape(bshape) * 2.0 * (xf - mean_c) / n)
     return (dx.astype(x.dtype), dgamma.astype(gamma.dtype),
             dbeta.astype(beta.dtype), jnp.zeros_like(shift))
 
@@ -387,16 +355,8 @@ def batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-5,
     """
     axis = axis % x.ndim  # normalize negative axis (-1 = channels-last)
     if training and not use_global_stats:
-        bn = _bn_train
-        try:
-            from ..kernels import dispatch as _kdispatch
-            if _kdispatch.mode() != "off":
-                from ..kernels import norm as _knorm
-                bn = _knorm.bn_train
-        except ImportError:
-            pass
-        out, mean, var = bn(x, gamma, beta, moving_mean,
-                            float(eps), axis)
+        out, mean, var = _bn_train(x, gamma, beta, moving_mean,
+                                   float(eps), axis)
         new_mean = moving_mean * momentum + mean.astype(moving_mean.dtype) * (1 - momentum)
         new_var = moving_var * momentum + var.astype(moving_var.dtype) * (1 - momentum)
         return out, new_mean, new_var

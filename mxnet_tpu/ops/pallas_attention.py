@@ -28,10 +28,26 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..kernels.dispatch import pallas_call as _pallas_call
 from .registry import register_op
 
 __all__ = ["flash_attention", "attention_reference"]
+
+
+def _pallas_call(kernel, *, name, **kwargs):
+    """`pl.pallas_call` under a stable `name` (what a device trace and
+    the HLO show instead of `jvp__.N`), whose kernel body and BlockSpec
+    index maps trace with x64 off.  The package turns `jax_enable_x64`
+    on for user arrays (the 64-bit dtype contract); under it a Python
+    `0` in an index map is an i64, which Mosaic refuses to legalize.
+    Kernel operands are bf16/f32/int32, so nothing 64-bit crosses this
+    boundary."""
+    import jax.experimental.pallas as pl
+
+    def call(*operands):
+        with jax.enable_x64(False):
+            return pl.pallas_call(kernel, name=name, **kwargs)(*operands)
+
+    return call
 
 
 _H1 = 0x9E3779B1

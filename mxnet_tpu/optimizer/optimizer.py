@@ -351,8 +351,7 @@ class Optimizer:
                           hyper):
         """One parameter's ladder inside a fused bucket: rescale →
         global-norm scale → per-element clip → `cls._rule` (→ master
-        cast).  The XLA reference body — kernels/opt.py's Pallas ladder
-        is its drop-in twin and falls back to it verbatim."""
+        cast)."""
         h = {k: _weak32(v) for k, v in hyper.items()}
         h["t"] = t
         lr, wd, scale = _weak32(lr), _weak32(wd), _weak32(scale)
@@ -378,21 +377,11 @@ class Optimizer:
         """Traced body of one fused bucket, unrolled over the bucket at
         trace time. Shared verbatim by `_fused_jitted` and the whole-step
         compiled path (gluon/train_step.py) so both produce bitwise-equal
-        numerics — same op order, same dtype promotion.  When
-        MXTPU_KERNELS is enabled each parameter's ladder goes through the
-        Pallas dispatch instead (which itself falls back per-param)."""
-        step_one = Optimizer._fused_param_step
-        try:
-            from ..kernels import dispatch as _kdispatch
-            if _kdispatch.mode() != "off":
-                from ..kernels import opt as _kopt
-                step_one = _kopt.param_step
-        except ImportError:
-            pass
+        numerics — same op order, same dtype promotion."""
         new_ws, new_states = [], []
         for w, st, g, lr, wd, t in zip(ws, states, gs, lrs, wds, ts):
-            nw, ns = step_one(cls, clip, gn, mp, w, st, g, lr, wd, t,
-                              scale, hyper)
+            nw, ns = Optimizer._fused_param_step(
+                cls, clip, gn, mp, w, st, g, lr, wd, t, scale, hyper)
             new_ws.append(nw)
             new_states.append(ns)
         return new_ws, new_states
@@ -410,13 +399,7 @@ class Optimizer:
         cls = type(self)
         hkeys = tuple(sorted(self._hyper()))
         gn = self.clip_global_norm is not None
-        try:
-            from ..kernels import dispatch as _kdispatch
-            kmode = _kdispatch.mode()
-        except ImportError:
-            kmode = "off"
-        key = (cls, self.clip_gradient, "fused", n, mp, gn, donate, kmode,
-               hkeys)
+        key = (cls, self.clip_gradient, "fused", n, mp, gn, donate, hkeys)
         fn = Optimizer._jit_cache.get(key)
         if fn is None:
             clip = self.clip_gradient
@@ -754,10 +737,10 @@ def _one_minus_pow(beta, t):
     compile.  Float32 ``-expm1(x)``, ``x = t * log(beta)``, is the same
     number to ~1e-5 relative — float32's rounding of ``beta`` itself, at
     beta = 0.999; the naive float32 ``1 - beta ** t`` adds its own
-    cancellation (~1e-4 at small t) on top; it is spelled ``-tanh(x / 2) * (exp(x) + 1)``
-    because the rule also traces into the Pallas ladder (kernels/opt.py)
-    and Mosaic lowers tanh and exp but not expm1.  Weak typing lets the
-    result scale a bf16, f32 or f64 tensor without promoting it, exactly
+    cancellation (~1e-4 at small t) on top; it is spelled
+    ``-tanh(x / 2) * (exp(x) + 1)``, the same function, and stays so:
+    another spelling rounds differently, and every stored step and
+    parity test holds these bits.  Weak typing lets the result scale a bf16, f32 or f64 tensor without promoting it, exactly
     like the Python scalar it replaces."""
     from jax._src.lax.lax import _convert_element_type
 

@@ -5,8 +5,7 @@ for a captured program (CachedOp variants, export, symbol lowering, the
 whole-step train program) flows through :func:`apply`, which runs the
 resolved passes jaxpr → jaxpr before XLA sees the graph.  Shipped
 passes: :class:`AmpPass` (auto mixed precision), :class:`RematPass`
-(segmented rematerialization with an `auto` cost-model policy),
-:class:`KernelPass` (the bandwidth-kernel audit; docs/kernels.md), and
+(segmented rematerialization with an `auto` cost-model policy) and
 cross-CachedOp structural dedup (MXTPU_GRAPH_DEDUP).  docs/passes.md
 covers the architecture and how to write a custom pass.
 """
@@ -37,17 +36,11 @@ from .dedup import (  # noqa: F401
     reset_executable_cache,
     structural_key,
 )
-from .kernel_pass import KernelPass  # noqa: F401
-from .layout import LayoutPass  # noqa: F401
 from . import _state  # noqa: F401
 from . import memory  # noqa: F401
 
 register_named_pass("amp", AmpPass)
 register_named_pass("remat", RematPass)
-register_named_pass("kernels", KernelPass)
-# force-named layout (MXTPU_PASSES=layout) rewrites unconditionally;
-# MXTPU_LAYOUT owns the auto/off policy via resolve_passes injection
-register_named_pass("layout", lambda: LayoutPass("nhwc"))
 
 
 def _numerics_factory():
@@ -74,8 +67,6 @@ __all__ = [
     "AmpPass",
     "DedupExecutable",
     "GraphPass",
-    "KernelPass",
-    "LayoutPass",
     "PassContext",
     "PassManager",
     "RematPass",

@@ -225,20 +225,6 @@ def resolve_passes(ctx):
     if _numerics.mode() != "off" \
             and not any(p.name == "numerics" for p in passes):
         passes.append(_numerics.NumericsPass())
-    # same one-normalization contract as numerics: kernels.dispatch.mode()
-    # both injects the audit pass here and gates the sites themselves
-    from ..kernels import dispatch as _kdispatch
-    if _kdispatch.mode() != "off" \
-            and not any(p.name == "kernels" for p in passes):
-        from .kernel_pass import KernelPass
-        passes.append(KernelPass())
-    # and once more for layout: layout.mode() injects the NHWC rewrite
-    # here and gates prepare_block at the CachedOp/TrainStep entries —
-    # MXTPU_LAYOUT=off touches neither (zero extra traces)
-    from . import layout as _layout
-    if _layout.mode() != "off" \
-            and not any(p.name == "layout" for p in passes):
-        passes.append(_layout.LayoutPass())
     # sharding joins only when the context CARRIES a plan (mesh=None →
     # ctx.plan None → never injected, the kill-switch acceptance
     # contract) and MXTPU_SHARDING isn't off — the same mode() Trainer

@@ -29,8 +29,6 @@ __all__ = [
     "estimate_peak_bytes",
     "estimate_training_peak_bytes",
     "estimate_region_bytes",
-    "norm_region_bytes",
-    "optimizer_region_bytes",
 ]
 
 # Call-like primitives whose sub-jaxpr binds the eqn's operands 1:1 —
@@ -192,21 +190,20 @@ def estimate_training_peak_bytes(closed):
 
 
 # ---------------------------------------------------------------------------
-# per-region external-bytes model (promoted from tools/fusion_audit.py)
+# per-region external-bytes model
 # ---------------------------------------------------------------------------
 #
-# tools/fusion_audit.py runs this segmentation over the lowered StableHLO
-# text of a whole train step (union-find of fusable ops; external bytes =
-# cross-region SSA edges).  The KernelPass `auto` decision needs the SAME
-# model at the jaxpr level — before lowering, per call site — so the
-# segmentation is promoted here, on top of the liveness walk's flattening
-# (_sub_jaxpr / _aval_bytes).
+# A union-find of fusable ops at the jaxpr level — before lowering — on
+# top of the liveness walk's flattening (_sub_jaxpr / _aval_bytes);
+# external bytes = values crossing a region's boundary.  Its one reader
+# is the measurement plane (observability/measure.py), which records the
+# prediction beside each program's measured time.
 #
-# Calibration: the r5 audit's empirical finding is that XLA on TPU treats
-# REDUCTIONS and large WIDENING CONVERTS as fusion roots — their producers
-# fuse in, their consumers start a new kernel, so the value at the boundary
-# round-trips through HBM.  That is exactly what made the BN-stats f32
-# population the worst region of the step.  The model below encodes it:
+# The model treats REDUCTIONS and large WIDENING CONVERTS as fusion
+# roots — their producers fuse in, their consumers start a new kernel, so
+# the value at the boundary round-trips through HBM (a pre-chip
+# assumption: on the compiled ResNet-50 step XLA fuses BatchNorm's sums
+# into the convolutions, PERF.md section 5):
 #
 #   * anchor prims (conv/dot/gather/...) are their own region;
 #   * reduce prims and >=`widen_threshold`-byte widening converts are
@@ -416,67 +413,3 @@ def estimate_region_bytes(closed, widen_threshold=1 << 20):
         })
     out.sort(key=lambda r: -r["external_bytes"])
     return out
-
-
-# -- analytic per-site models (what the `auto` dispatch decision reads) -----
-#
-# The jaxpr segmentation above is the honest accounting over a whole
-# captured program (the KernelPass report, the >=30% acceptance test);
-# at a single call site the region shapes are known in closed form, so
-# the dispatch decision uses these O(1) per-channel-ignoring formulas.
-# Both express the same model: reduce/widen roots break the XLA program
-# into passes that round-trip the population through HBM; the Pallas
-# kernel's floor is one (or two, for two-phase stats) reads of the
-# operands plus one write of each output.
-
-def _itemsize(dtype):
-    try:
-        return np.dtype(dtype).itemsize
-    except TypeError:
-        return 4
-
-
-def norm_region_bytes(shape, x_dtype, ew_dtype):
-    """(xla_bytes, kernel_bytes) for ONE BatchNorm training call site —
-    forward and backward regions combined (a site either uses the kernel
-    pair or neither: the residual layout must match).
-
-    XLA (per the root model): fwd reads x, round-trips the centered
-    population xf across the sum/sum² reduce boundary, writes out; bwd
-    reads x and dy, round-trips xhat and the cast dy across the
-    dbeta/dgamma reduce boundary, writes dx.  Kernel: fwd reads x twice
-    (two-phase stats) and writes out; bwd reads x and dy twice and
-    writes dx.  Per-channel vectors are noise and ignored."""
-    n = 1
-    for d in shape:
-        n *= int(d)
-    bx = _itemsize(x_dtype)
-    be = _itemsize(ew_dtype)
-    xla_fwd = n * bx + 2 * n * be + n * bx
-    xla_bwd = 2 * n * bx + 4 * n * be + n * bx
-    k_fwd = 2 * n * bx + n * bx
-    k_bwd = 2 * (2 * n * bx) + n * bx
-    return xla_fwd + xla_bwd, k_fwd + k_bwd
-
-
-def optimizer_region_bytes(w_size, w_dtype, n_state, mp):
-    """(xla_bytes, kernel_bytes) for ONE parameter's fused-update chain.
-
-    The floor both paths pay: read grad, read+write each state leaf,
-    read+write the master/weight, write the low-precision weight copy
-    (mp).  XLA additionally round-trips the widened f32 grad across the
-    mp cast boundary (the audit's optimizer-chain region); without mp
-    there is no widening root, the chain is one region, and the model
-    predicts zero savings — `auto` declines, which is correct: XLA
-    already fuses the pure-f32 chain perfectly."""
-    n = int(w_size)
-    bw = _itemsize(w_dtype)
-    if mp:
-        floor = (n * bw            # read low-precision grad
-                 + 2 * n * 4       # master read+write
-                 + n_state * 2 * n * 4  # state leaves read+write (f32)
-                 + n * bw)         # write low-precision weight copy
-        xla = floor + 2 * n * 4    # g32 round-trip at the cast root
-        return xla, floor
-    floor = (n * bw + 2 * n * bw + n_state * 2 * n * bw)
-    return floor, floor

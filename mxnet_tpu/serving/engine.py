@@ -32,9 +32,8 @@ buckets, the deadline-aware bounded drain in :meth:`stop`, and the
 replica front door (frontdoor.py).
 
 ``mode="sync"`` keeps the serialized PR-3 loop (collect → assemble →
-dispatch → block → settle on one thread) for A/B measurement —
-``tools/serve_bench.py --engine sync`` is the baseline the pipeline's
-speedup is quoted against.
+dispatch → block → settle on one thread) as the baseline
+tests/test_serving_pipeline.py compares the pipeline with.
 
 Everything else is unchanged contract: bucket-ladder padding so steady
 state never sees an online XLA compile, ``warmup()`` with the

@@ -28,8 +28,8 @@ daemon thread executing batches FIFO, each taking ``device_ms`` of
 
 The block sets ``_host_native = True`` so the engine skips the
 ``jnp.asarray`` device transfer and feeds padded host numpy straight in.
-Used by tests/test_serving_pipeline.py and ``tools/serve_bench.py
---block slow``.
+Used by tests/test_serving_pipeline.py: a test fake, not a source of
+numbers.
 """
 from __future__ import annotations
 

@@ -28,8 +28,8 @@ spec by sharding optimizer state (momentum/variance/fp32 masters) along
 the fsdp axis on the first unsharded divisible dim, so each rank owns
 1/N of optimizer memory (docs/sharding.md).
 
-:data:`RECIPES` promotes the ``MULTICHIP_r05.json`` dryrun
-configurations into user-facing plan recipes
+:data:`RECIPES` names the dryrun configurations of
+``__graft_entry__.dryrun_multichip`` as user-facing plan recipes
 (``plan_recipe("dp4_tp2")``); tests/test_sharding_layouts.py holds each
 to the dryrun bar of >= 99.5% partition efficiency on an 8-device mesh.
 """
@@ -263,12 +263,13 @@ def zero_state_spec(spec, shape, axis_sizes, fsdp_axis):
     return spec
 
 
-# -- promoted MULTICHIP_r05 plan recipes -------------------------------------
-# The r05 dryrun validated mesh dp=4 tp=2 (+ ring-attention over tp,
-# 8-expert MoE, 8-stage pipeline as parallel/-module companions) at
-# >= 99.5% partition efficiency on 8 chips. Each entry here is the
-# user-facing spelling of one validated topology: axes + the layout +
-# which companion subsystem (if any) completes it.
+# -- plan recipes -----------------------------------------------------------
+# The dryrun (__graft_entry__.dryrun_multichip, eight virtual CPU
+# devices) partitions mesh dp=4 tp=2 (+ ring-attention over tp, 8-expert
+# MoE, 8-stage pipeline as parallel/-module companions) at >= 99.5%
+# partition efficiency. Each entry here is the user-facing spelling of
+# one such topology: axes + the layout + which companion subsystem (if
+# any) completes it.
 RECIPES = {
     "dp8": {
         "axes": "dp=-1",
@@ -279,7 +280,7 @@ RECIPES = {
     "dp4_tp2": {
         "axes": "dp=4,tp=2",
         "layout": True,
-        "note": "the MULTICHIP_r05 dryrun mesh: batch over dp, matmul "
+        "note": "the dryrun mesh: batch over dp, matmul "
                 "weights column/row-split over tp by structural role",
     },
     "dp2_fsdp2_tp2": {

@@ -10,7 +10,7 @@ metrics registry instrumented through the hot layers —
 
 — with three sinks:
 
-  * ``telemetry.dump()``            JSON snapshot (bench.py embeds it)
+  * ``telemetry.dump()``            JSON snapshot
   * ``telemetry.prometheus_text()`` Prometheus text exposition format
   * ``telemetry.emit_chrome_counters()``  chrome-trace counter events into
     the profiler.py buffer (metrics on the profiler timeline)

@@ -3,7 +3,7 @@
 Two of the three exporters (the chrome-trace bridge lives in chrome.py):
 
   * :func:`dump` — a plain-dict snapshot suitable for `json.dumps`,
-    embedding in bench JSON lines (bench.py does), or asserting in tests;
+    embedding in a result line, or asserting in tests;
   * :func:`prometheus_text` — Prometheus text exposition format v0.0.4
     (`# HELP` / `# TYPE` comments, cumulative `_bucket{le=...}` series,
     `_sum`/`_count` for histograms) ready to serve from a /metrics

@@ -63,10 +63,6 @@ __all__ = [
     "set_slo_burn", "record_slo_violation", "nbytes_of",
     "numerics_trip_total", "flight_events_total", "postmortem_dump_total",
     "record_numerics_trip", "record_flight_event", "record_postmortem",
-    "kernel_dispatch_total", "kernel_bytes_saved",
-    "record_kernel_dispatch",
-    "layout_rewrite_total", "layout_transpose_total",
-    "record_layout_rewrite",
     "sharding_plan_applied_total", "sharding_mesh_axis_size",
     "sharding_pass_stamp_total",
     "record_sharding_apply", "record_sharding_stamp",
@@ -77,10 +73,9 @@ __all__ = [
     "DEVICE_PEAKS", "device_peaks",
 ]
 
-# Published per-chip peaks, keyed by jax's `device_kind`.  The ONE table
-# every MFU / roofline denominator in the repo reads (set_flop_budget,
-# tools/perf_lab.py, tools/bench_estimate.py, tools/fusion_audit.py).  A
-# device that is not listed has no peak: device_peaks() raises for it.
+# Published per-chip peaks, keyed by jax's `device_kind`: the MFU
+# gauge's denominator (set_flop_budget).  A device that is not listed
+# has no peak: device_peaks() raises for it.
 DEVICE_PEAKS = {
     "TPU v5 lite": {
         "bf16_flops": 197e12,
@@ -437,42 +432,6 @@ postmortem_dump_total = counter(
     "numerics / crash / exit / periodic / manual)", ["reason"])
 
 
-# -- Pallas bandwidth kernels (mxnet_tpu/kernels/; docs/kernels.md) ---------
-kernel_dispatch_total = counter(
-    "kernel_dispatch_total",
-    "Kernel-dispatch decisions by kernel and outcome, recorded once per "
-    "TRACE of a call site (never per step): outcome 'kernel' means the "
-    "Pallas kernel was emitted into the captured program; every other "
-    "outcome names why the site fell back to the XLA path (platform / "
-    "channels_first / unsupported_shape / unsupported_dtype / "
-    "unsupported_rule / no_savings / too_small; 'channels_first' means "
-    "the layout, not the size, blocked the kernel — the LayoutPass "
-    "fixes exactly these, so fusion_audit coverage stays honest)",
-    ["kernel", "outcome"])
-kernel_bytes_saved = counter(
-    "kernel_bytes_saved",
-    "External HBM bytes the passes/memory.py byte model predicts each "
-    "dispatched Pallas kernel saves over the fused-XLA estimate — a "
-    "per-compiled-program prediction accumulated at trace time, not a "
-    "per-step measurement (docs/kernels.md decision table)")
-
-
-# -- layout pass (passes/layout.py; docs/layout.md) -------------------------
-layout_rewrite_total = counter(
-    "layout_rewrite_total",
-    "conv_general_dilated equations the LayoutPass rewrote to "
-    "channels-last (NHWC/HWIO) dimension numbers — accumulated once per "
-    "pipeline build (a new variant / input signature), never per step")
-layout_transpose_total = counter(
-    "layout_transpose_total",
-    "Transpose equations the LayoutPass accounted for per build, by "
-    "origin: 'inserted' — materialized at an unavoidable layout "
-    "boundary (graph inputs/outputs, unrecognized ops); 'elided' — "
-    "avoided relative to the naive per-op channels-last rewrite "
-    "(cancelled transpose pairs + absorbed pre-existing transposes)",
-    ["origin"])
-
-
 # -- sharding (mxnet_tpu/sharding; docs/sharding.md) ------------------------
 sharding_plan_applied_total = counter(
     "sharding_plan_applied_total",
@@ -487,8 +446,8 @@ sharding_mesh_axis_size = gauge(
 sharding_pass_stamp_total = counter(
     "sharding_pass_stamp_total",
     "ShardingPass stamps: one per pipeline build whose context carried "
-    "a plan (per seam kind) — accumulated at trace time like "
-    "layout_rewrite_total, never per step", ["label", "kind"])
+    "a plan (per seam kind) — accumulated at trace time, "
+    "never per step", ["label", "kind"])
 
 
 def record_sharding_apply(label, axis_sizes, params=0):
@@ -585,22 +544,6 @@ def record_postmortem(reason):
     postmortem_dump_total.labels(reason).inc()
 
 
-def record_kernel_dispatch(kernel, outcome, bytes_saved=0):
-    """One trace-time kernel-dispatch decision at a call site: `outcome`
-    is 'kernel' (Pallas emitted) or a fallback reason; `bytes_saved` is
-    the byte model's predicted HBM saving for a dispatched kernel.
-    Fallbacks also land in the flight recorder so postmortems show
-    which path a program actually compiled with."""
-    if outcome != "kernel":
-        _flight_record("kernel_fallback", kernel=str(kernel),
-                       reason=str(outcome))
-    if not REGISTRY.enabled:
-        return
-    kernel_dispatch_total.labels(kernel, outcome).inc()
-    if bytes_saved:
-        kernel_bytes_saved.inc(int(bytes_saved))
-
-
 # -- measurement plane ------------------------------------------------------
 cost_measure_total = counter(
     "cost_measure_total",
@@ -610,9 +553,9 @@ cost_measure_total = counter(
 cost_model_drift_ratio = gauge(
     "cost_model_drift_ratio",
     "Predicted-vs-measured drift of the analytic byte model per "
-    "measured program (site='program') and per kernel-dispatch site "
-    "recorded inside it: the program's implied bandwidth over the "
-    "platform median, 1.0 = the model prices it like everything else "
+    "measured program (site='program'): the program's implied "
+    "bandwidth over the platform median, 1.0 = the model prices it "
+    "like everything else "
     "(observability/costdb.py drift auditor)", ["site", "program"])
 
 
@@ -632,19 +575,6 @@ def set_cost_drift(site, program, ratio):
         return
     cost_model_drift_ratio.labels(str(site), str(program)).set(
         float(ratio))
-
-
-def record_layout_rewrite(rewritten, inserted, elided):
-    """One LayoutPass build's accounting: convs rewritten to
-    channels-last plus the transposes it inserted vs elided."""
-    if not REGISTRY.enabled:
-        return
-    if rewritten:
-        layout_rewrite_total.inc(int(rewritten))
-    if inserted:
-        layout_transpose_total.labels("inserted").inc(int(inserted))
-    if elided:
-        layout_transpose_total.labels("elided").inc(int(elided))
 
 
 def _flight_record(kind, **fields):
@@ -879,8 +809,8 @@ def record_collective(op, nbytes, seconds):
 def set_flop_budget(flops, peak=None):
     """Declare the per-step FLOP budget (and optionally the accelerator
     peak) so observe_step can keep the MFU gauge live. `flops` is the
-    cost of ONE optimizer step (fwd+bwd+update), e.g. from XLA
-    cost_analysis as tools/perf_lab.py measures it.  Without `peak` the
+    cost of ONE optimizer step (fwd+bwd+update), e.g. from XLA's
+    cost_analysis.  Without `peak` the
     denominator is this device's DEVICE_PEAKS entry; on a device the
     table does not list the peak stays unset and the MFU gauge silent."""
     flops_per_step.set(flops)

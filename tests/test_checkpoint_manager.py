@@ -102,6 +102,44 @@ def test_save_restore_bitwise_roundtrip(tmp_path):
     assert all(trainer._states_created)
 
 
+def test_restore_ignores_meta_key_of_deleted_layout_pass(tmp_path):
+    """Every checkpoint written before the layout pass was deleted
+    carries ``meta["layout_perms"]`` — one None per parameter, since the
+    pass was off by default — beside arrays in the logical layout. The
+    key is not read: such a checkpoint restores parameters and
+    optimizer state bit for bit."""
+    import json
+
+    net, trainer = _build()
+    for s in range(1, 3):
+        _train_one(net, trainer, s)
+    mgr = CheckpointManager(tmp_path, trainer)
+    mgr.save(step=2)
+    mgr.flush()
+    mpath = os.path.join(str(tmp_path), mgr_mod._STEP_FMT.format(2),
+                         mgr_mod.MANIFEST_NAME)
+    with open(mpath) as f:
+        manifest = json.load(f)
+    assert "layout_perms" not in manifest["meta"]
+    manifest["meta"]["layout_perms"] = [None] * len(trainer._params)
+    with open(mpath, "w") as f:
+        json.dump(manifest, f)
+
+    want_params = _params_of(trainer)
+    want_states = [tuple(x.asnumpy().copy() for x in s)
+                   for s in trainer._states]
+    for p in trainer._params:
+        p.set_data(onp.zeros(p.shape, "float32"))
+    trainer._states = [None] * len(trainer._params)
+
+    assert mgr.restore().step == 2
+    for got, want in zip(_params_of(trainer), want_params):
+        onp.testing.assert_array_equal(got, want)
+    for got_s, want_s in zip(trainer._states, want_states):
+        for got, want in zip(got_s, want_s):
+            onp.testing.assert_array_equal(got.asnumpy(), want)
+
+
 def test_resume_matches_uninterrupted_in_process(tmp_path):
     """Save at step 4, keep training to 10; a restored trainer re-running
     5..10 must reproduce the SAME losses bitwise (CPU XLA is

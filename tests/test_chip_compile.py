@@ -1,10 +1,11 @@
-"""Compile the main path's Pallas kernels for a DESCRIBED TPU v5e.
+"""Compile the main path's Pallas kernels, and a whole training step,
+for a DESCRIBED TPU v5e.
 
 The TPU's compiler is installed in the sandbox and compiles for a chip
 that is described, not attached — so what Mosaic refuses (an i64 index
 map, a block that does not tile, too much VMEM) fails HERE, at real
-widths, at no chip time.  Interpret-mode parity (tests/test_kernels.py,
-tests/test_flash_attention.py) cannot see any of that.  A compile that
+widths, at no chip time.  Interpret-mode parity
+(tests/test_flash_attention.py) cannot see any of that.  A compile that
 passes is not a chip run: chip_smoke.py checks the numbers on the chip.
 
 This is the ONLY file that describes the chip, and it does so inside a
@@ -59,18 +60,6 @@ def spec(one_chip):
     return make
 
 
-@pytest.fixture
-def force_kernels(monkeypatch):
-    """The dispatch asks jax.devices() for the platform and, on the
-    CPU-pinned test process, would answer 'platform'; the compile target
-    here IS a TPU, so steer it in the test."""
-    from mxnet_tpu.kernels import dispatch
-
-    monkeypatch.setenv("MXTPU_KERNELS", "force")
-    monkeypatch.delenv("MXTPU_KERNELS_INTERPRET", raising=False)
-    monkeypatch.setattr(dispatch, "platform_ok", lambda: True)
-
-
 def _kernel_calls(fn, *specs):
     text = jax.jit(fn).lower(*specs).compile().as_text()
     return text.count('custom_call_target="tpu_custom_call"')
@@ -107,48 +96,59 @@ def test_flash_attention_compiles_for_v5e(spec, seq, case):
         assert calls == 3      # forward, dQ, dK/dV
 
 
-# -- batch-norm training pair at ResNet-50's b=128 activations --------------
+# -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
-@pytest.mark.parametrize("rows,channels", [
-    (128 * 56 * 56, 64), (128 * 56 * 56, 256), (128 * 7 * 7, 2048)])
-def test_bn_train_compiles_for_v5e(spec, force_kernels, rows, channels):
-    from mxnet_tpu.kernels import norm
-
-    x = spec((rows, channels), jnp.bfloat16)
-    c = spec((channels,), jnp.float32)
-
-    def fwd_bwd(x, gamma, beta, shift):
-        def loss(x, gamma, beta):
-            out, mean, var = norm.bn_train(x, gamma, beta, shift, 1e-5, 1)
-            return out.astype(jnp.float32).sum() + mean.sum() + var.sum()
-        return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, gamma, beta)
-
-    assert _kernel_calls(fwd_bwd, x, c, c, c) == 2     # forward, backward
+class _Lowered(Exception):
+    pass
 
 
-# -- fused optimizer ladder at BERT-base's FFN weight -----------------------
+@pytest.mark.parametrize("optimizer,params", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4,
+             "multi_precision": True}),
+    ("adam", {"learning_rate": 1e-3, "wd": 0.01, "multi_precision": True}),
+], ids=["sgd", "adam"])
+def test_whole_step_compiles_for_v5e_without_kernels_or_f64(
+        one_chip, optimizer, params):
+    """BatchNorm's statistics and the optimizer ladder reach the chip as
+    plain XLA (nothing for them is a Pallas call), and the package's
+    64-bit contract leaks no f64 tensor into the compiled step (D10)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, gluon
+    from mxnet_tpu.gluon import nn
 
-@pytest.mark.parametrize("rule", ["sgd_momentum", "adam"])
-def test_param_step_compiles_for_v5e(spec, force_kernels, rule):
-    from mxnet_tpu.kernels import opt
-    from mxnet_tpu.optimizer import SGD, Adam
+    net = nn.HybridSequential()
+    net.add(nn.Conv2D(64, 3, padding=1, layout="NHWC", use_bias=False),
+            nn.BatchNorm(axis=-1), nn.Activation("relu"),
+            nn.MaxPool2D(2, layout="NHWC"),
+            nn.Conv2D(128, 3, padding=1, layout="NHWC", use_bias=False),
+            nn.BatchNorm(axis=-1), nn.Activation("relu"),
+            nn.GlobalAvgPool2D(layout="NHWC"), nn.Flatten(), nn.Dense(10))
+    net.initialize()
+    x = mx.np.zeros((32, 32, 32, 3))
+    y = mx.np.zeros((32,), dtype="int32")
+    net(x)
+    amp.convert_hybrid_block(net, target_dtype="bfloat16")
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), optimizer, params,
+                            kvstore="tpu_dist")
+    step = gluon.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                           trainer)
+    jitted = step._jitted
 
-    w = spec((768, 3072), jnp.bfloat16)
-    f32 = spec((768, 3072), jnp.float32)
-    if rule == "sgd_momentum":
-        hyper = {"rescale_grad": 1.0 / 8, "momentum": 0.9}
+    def intercept(donate):
+        fn = jitted(donate)
 
-        def step(w, master, mom, g):
-            return opt.param_step(SGD, None, False, True, w, (master, mom),
-                                  g, 0.1, 1e-4, 3, 1.0, hyper)
-        calls = _kernel_calls(step, w, f32, f32, w)
-    else:
-        hyper = {"rescale_grad": 1.0 / 8, "beta1": 0.9, "beta2": 0.999,
-                 "eps": 1e-8}
+        def lower_only(*a):
+            described = jax.tree_util.tree_map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                               sharding=one_chip)
+                if hasattr(v, "shape") else v, a)
+            raise _Lowered(fn.lower(*described).compile().as_text())
+        return lower_only
 
-        def step(w, master, m, v, g):
-            return opt.param_step(Adam, None, False, True, w,
-                                  (master, (m, v)), g, 2e-5, 0.01, 3, 1.0,
-                                  hyper)
-        calls = _kernel_calls(step, w, f32, f32, f32, w)
-    assert calls == 1
+    step._jitted = intercept
+    with pytest.raises(_Lowered) as caught:
+        step(x, y)
+    text = caught.value.args[0]
+    assert "convolution" in text and "f64[" not in text
+    assert "tpu_custom_call" not in text

@@ -541,6 +541,29 @@ def test_rtc_pallas_module():
         mod.get_kernel("missing")
 
 
+def test_rtc_kernel_is_named_after_itself(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    from mxnet_tpu import rtc
+
+    seen = []
+    real = pl.pallas_call
+
+    def spy(body, **kw):
+        seen.append(kw.get("name"))
+        return real(body, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+
+    def double(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    kern = rtc.PallasModule(double).get_kernel("double")
+    out = kern.launch([mx.np.ones((8, 128))], (8, 128), "float32")
+    assert seen == ["double"]
+    onp.testing.assert_allclose(out.asnumpy(), 2.0)
+
+
 def test_dgl_non_uniform_sparse_probability():
     """Review regression: fewer positive-prob neighbors than num_neighbor
     must not crash rng.choice."""

@@ -284,3 +284,39 @@ class TestFlashDropout:
         q, k, v = self._qkv(S=32, D=8)
         with pytest.raises(ValueError):
             fa.flash_attention(q, k, v, dropout_p=0.1)
+
+
+# -- a stable name on every Pallas call (ISSUE 25) ----------------------------
+
+def _pallas_names(closed):
+    """Names of the pallas_call equations of a jaxpr, nested ones too."""
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(eqn.params["name"])
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None and hasattr(inner, "eqns"):
+                    walk(inner)
+                elif hasattr(v, "eqns"):
+                    walk(v)
+    walk(closed.jaxpr)
+    return out
+
+
+@pytest.mark.parametrize("grad,expect", [
+    (False, ["flash_attention_fwd"]),
+    (True, ["flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv"]),
+], ids=["flash_fwd", "flash_bwd"])
+def test_pallas_calls_carry_stable_names(grad, expect):
+    q = jnp.ones((1, 2, 128, 64), jnp.float32)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, interpret=True).sum()
+
+    fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
+    names = _pallas_names(jax.make_jaxpr(fn)(q, q, q))
+    assert sorted(set(names)) == sorted(expect)

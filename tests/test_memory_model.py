@@ -1,9 +1,7 @@
 """passes/memory.py analytic byte-model edge cases: empty programs, the
 1 MiB widening-convert fusion-root boundary in estimate_region_bytes,
-liveness freeing in estimate_peak_bytes, call-primitive inlining, and
-the closed-form per-site models' dtype-width behavior (bf16 vs f32) —
-the numbers the kernel `auto` dispatch and the CostDB drift auditor
-both trust.
+liveness freeing in estimate_peak_bytes and call-primitive inlining —
+the numbers the remat `auto` policy and the CostDB drift auditor read.
 """
 import jax
 import jax.numpy as jnp
@@ -118,49 +116,3 @@ def test_call_primitives_are_inlined():
     flat_peak = pmem.estimate_peak_bytes(jax.make_jaxpr(flat)(x))
     nested_peak = pmem.estimate_peak_bytes(jax.make_jaxpr(nested)(x))
     assert flat_peak == nested_peak
-
-
-# -- closed-form per-site models ---------------------------------------------
-
-def test_norm_region_bytes_formula_and_widths():
-    shape = (8, 128)
-    n = 8 * 128
-
-    def expect(bx, be):
-        xla = (n * bx + 2 * n * be + n * bx) \
-            + (2 * n * bx + 4 * n * be + n * bx)
-        kernel = (2 * n * bx + n * bx) + (2 * (2 * n * bx) + n * bx)
-        return xla, kernel
-
-    assert pmem.norm_region_bytes(shape, jnp.float32, jnp.float32) == \
-        expect(4, 4)
-    assert pmem.norm_region_bytes(shape, jnp.bfloat16, jnp.float32) == \
-        expect(2, 4)
-    # halving the activation dtype halves the kernel floor exactly
-    _, k32 = pmem.norm_region_bytes(shape, jnp.float32, jnp.float32)
-    _, k16 = pmem.norm_region_bytes(shape, jnp.bfloat16, jnp.float32)
-    assert k16 * 2 == k32
-    # bf16 elementwise dtype shrinks only the round-trip terms
-    xla_f32ew, _ = pmem.norm_region_bytes(shape, jnp.bfloat16, jnp.float32)
-    xla_bf16ew, _ = pmem.norm_region_bytes(shape, jnp.bfloat16,
-                                           jnp.bfloat16)
-    assert xla_bf16ew == xla_f32ew - 6 * n * 2
-
-
-def test_optimizer_region_bytes_mp_gates_the_savings():
-    n = 4096
-    # no multi-precision: one fused region, model predicts zero savings
-    xla, kernel = pmem.optimizer_region_bytes(n, jnp.float32, 1, False)
-    assert xla == kernel
-    # multi-precision: XLA pays exactly the widened-grad round-trip
-    xla, kernel = pmem.optimizer_region_bytes(n, jnp.bfloat16, 1, True)
-    assert xla - kernel == 2 * n * 4
-    floor = (n * 2          # bf16 grad read
-             + 2 * n * 4    # f32 master read+write
-             + 2 * n * 4    # one f32 state leaf read+write
-             + n * 2)       # bf16 weight-copy write
-    assert kernel == floor
-    # each extra state leaf adds one f32 read+write pair to both sides
-    xla1, k1 = pmem.optimizer_region_bytes(n, jnp.bfloat16, 1, True)
-    xla2, k2 = pmem.optimizer_region_bytes(n, jnp.bfloat16, 2, True)
-    assert (xla2 - xla1) == (k2 - k1) == 2 * n * 4

@@ -148,14 +148,14 @@ def test_env_var_registry():
     assert mx.env.get("MXNET_ENGINE_TYPE") in (
         "ThreadedEnginePerDevice", "NaiveEngine")
     assert isinstance(mx.env.get("MXTPU_DISABLE_NATIVE"), bool)
-    assert mx.env.get("MXTPU_BENCH_BATCH") == 256
+    assert mx.env.get("MXTPU_SERVE_MAX_BATCH") == 32
     d = mx.env.doc()
     assert "MXNET_ENGINE_TYPE" in d and "MXTPU_MP_START" in d
     assert len(mx.env.all_vars()) >= 12
     # typed override
     import os
-    os.environ["MXTPU_BENCH_BATCH"] = "128"
+    os.environ["MXTPU_SERVE_MAX_BATCH"] = "128"
     try:
-        assert mx.env.get("MXTPU_BENCH_BATCH") == 128
+        assert mx.env.get("MXTPU_SERVE_MAX_BATCH") == 128
     finally:
-        del os.environ["MXTPU_BENCH_BATCH"]
+        del os.environ["MXTPU_SERVE_MAX_BATCH"]

@@ -177,11 +177,14 @@ def test_named_pass_env_forces_amp(monkeypatch):
     np.testing.assert_array_equal(expected, net(x).asnumpy())
 
 
-def test_unknown_named_pass_raises(monkeypatch):
-    monkeypatch.setenv("MXTPU_PASSES", "nonsuch")
+@pytest.mark.parametrize("name", ["nonsuch", "kernels", "layout"])
+def test_unknown_named_pass_raises(monkeypatch, name):
+    # "kernels" and "layout" named passes that were deleted (PR 31)
+    monkeypatch.setenv("MXTPU_PASSES", name)
     net = _mlp(seed=1)
-    with pytest.raises(ValueError, match="nonsuch"):
+    with pytest.raises(ValueError, match=name) as err:
         net(_x())
+    assert "['amp', 'numerics', 'remat', 'sharding']" in str(err.value)
 
 
 def test_amp_pass_composes_with_whole_step():
@@ -230,8 +233,15 @@ def test_remat_preserves_custom_vjp_rules(monkeypatch, policy):
     l1, g1 = _loss_and_grads(_custom_grad_net(seed=77), x)
     np.testing.assert_array_equal(l0, l1)
     assert set(g0) == set(g1)
+    # Four of the six leaves are numerically zero here (BatchNorm's
+    # output sums to zero over the batch and make_loss's cotangent is a
+    # constant), so their bits are the rounding of sums whose terms are
+    # as large as the largest leaf (d2.bias, 48): a recomputed forward
+    # may round them differently.  A lost custom rule moves d2.bias and
+    # bn.beta by their own size, far over a few ulps of that scale.
+    scale = max(float(np.abs(g).max()) for g in g0.values())
     for n in g0:
-        np.testing.assert_array_equal(g0[n], g1[n])
+        np.testing.assert_allclose(g1[n], g0[n], rtol=0, atol=2e-6 * scale)
 
 
 def test_segmented_remat_keeps_custom_vjp_bwd():

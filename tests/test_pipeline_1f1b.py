@@ -111,8 +111,8 @@ def test_1f1b_grad_step_reduces_loss():
 
 
 def test_schedule_efficiency_bound():
-    """The analytic bound SCALING.json reports for the interleaved
-    schedule: M*v/(M*v + S - 1) >= 0.90 at M=32, S=8, v=4 (GPipe v=1 was
+    """The analytic bound of the interleaved schedule:
+    M*v/(M*v + S - 1) >= 0.90 at M=32, S=8, v=4 (GPipe v=1 was
     0.8205)."""
     M, S_, v = 32, 8, 4
     eff = (M * v) / (M * v + S_ - 1)

@@ -1,10 +1,11 @@
-"""SpecLayout rule library + promoted MULTICHIP_r05 recipes (ISSUE 19,
+"""SpecLayout rule library + the plan recipes (ISSUE 19,
 mxnet_tpu/sharding/layouts.py): role -> PartitionSpec resolution with
 mesh/shape pruning, structural block-role classification, name-token
 fallback, ZeRO state-spec extension, ShardingPlan.from_layout / env
 construction, and the dryrun bar — every promoted recipe partitions a
 train step at >= 99.5% efficiency on the 8-virtual-device CPU mesh
-(the benchmark/scaling.py flops-per-device methodology)."""
+(per-device FLOPs of the partitioned module over the ideal 1/N, by
+XLA's cost model)."""
 import numpy as onp
 import pytest
 
@@ -217,8 +218,8 @@ BATCH, HID, CLS = 1024, 512, 16
 
 
 def _mlp():
-    """Named-param MLP forward+backward (benchmark/scaling.py's
-    methodology, lifted onto plan-resolved shardings): the returned
+    """Named-param MLP forward+backward on plan-resolved shardings:
+    the returned
     grads land on the plan's STATE specs — the reduce-scatter layout
     the ZeRO-sharded optimizer consumes."""
     rng = onp.random.RandomState(0)
@@ -254,7 +255,7 @@ def _flops(compiled):
 @pytest.mark.parametrize("recipe", ["dp8", "dp4_tp2", "dp2_fsdp2_tp2",
                                     "fsdp4"])
 def test_recipe_partition_efficiency(recipe):
-    """Every promoted MULTICHIP_r05 recipe partitions the train step at
+    """Every plan recipe partitions the train step at
     >= 99.5% efficiency: per-device FLOPs of the GSPMD module vs the
     ideal 1/N of the single-device module (XLA cost model), with params
     on the layout's specs and gradients delivered on the ZeRO state

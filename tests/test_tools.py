@@ -1,6 +1,6 @@
-"""Tools: im2rec pack/read round-trip, launch.py local mode, bandwidth,
-opperf harness (reference: tools/im2rec, tools/launch.py,
-tools/bandwidth/measure.py, benchmark/opperf/).
+"""Tools: im2rec pack/read round-trip, launch.py local mode, bandwidth
+(reference: tools/im2rec, tools/launch.py, tools/bandwidth/measure.py),
+and the diagnose / ckpt / blackbox / fleetctl command lines.
 """
 import json
 import os
@@ -92,62 +92,6 @@ def test_bandwidth_harness():
     row = json.loads(rc.stdout.strip().split("\n")[-1])
     assert row["n_devices"] == 4
     assert row["algo_bw_gbps"] > 0
-
-
-def test_serve_bench_smoke():
-    rc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "serve_bench.py"),
-         "--clients", "4", "--requests", "5", "--max-batch", "8"],
-        env=ENV, capture_output=True, text=True, timeout=300)
-    assert rc.returncode == 0, rc.stderr
-    row = json.loads(rc.stdout.strip().split("\n")[-1])
-    assert row["metric"] == "inference_qps"
-    assert row["value"] > 0
-    assert row["completed"] == 4 * 5
-    assert row["shed"] == 0 and row["timeout"] == 0
-    assert row["recompiles_since_warmup"] == 0
-    assert row["warmup"]["buckets"] == [1, 2, 4, 8]
-    assert row["engine"]["requests"]["ok"] >= 20
-    assert row["p50_ms"] is not None and row["p99_ms"] >= row["p50_ms"]
-
-
-def test_serve_bench_open_loop_smoke(tmp_path):
-    """Tier-1-safe open-loop run (~2s): Poisson arrivals against the
-    pipelined engine on the simulated slow block, JSON artifact out."""
-    out = tmp_path / "open.json"
-    rc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "serve_bench.py"),
-         "--mode", "open", "--block", "slow", "--device-ms", "5",
-         "--qps", "80", "--duration-s", "1.5", "--max-batch", "8",
-         "--timeout-ms", "5000", "--json-out", str(out)],
-        env=ENV, capture_output=True, text=True, timeout=300)
-    assert rc.returncode == 0, rc.stderr
-    row = json.loads(rc.stdout.strip().split("\n")[-1])
-    assert row["metric"] == "open_loop_p99_ms"
-    assert row["mode"] == "open" and row["engine_mode"] == "pipelined"
-    assert row["completed"] > 0
-    assert row["p99_ms"] >= row["p50_ms"] > 0
-    assert set(row["classes"]) == {"interactive", "batch"}
-    inter = row["classes"]["interactive"]
-    assert inter["offered"] >= inter["completed"] > 0
-    assert row["recompiles_since_warmup"] == 0
-    # the artifact on disk is the same well-formed object
-    art = json.loads(out.read_text())
-    assert art["metric"] == "open_loop_p99_ms"
-    assert art["completed"] == row["completed"]
-
-
-def test_opperf_harness():
-    rc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "opperf.py"),
-         "--size", "64", "--iters", "2", "--ops", "add,dot,conv2d"],
-        env=ENV, capture_output=True, text=True, timeout=300)
-    assert rc.returncode == 0, rc.stderr
-    rows = [json.loads(x) for x in rc.stdout.strip().split("\n")]
-    ops = {r["op"] for r in rows}
-    assert ops == {"add", "dot", "conv2d"}
-    assert all(r["fwd_ms"] > 0 for r in rows)
-    assert all(r["fwd_bwd_ms"] > 0 for r in rows)
 
 
 def test_diagnose_passes_smoke():
@@ -385,80 +329,6 @@ raise RuntimeError("boom")
     assert b["reason"] == "crash:RuntimeError", b["reason"]
     kinds = [e["kind"] for e in b["events"]]
     assert "crash" in kinds and "tick" in kinds
-
-
-def test_fusion_audit_report_smoke(tmp_path):
-    """--report ranks regions by external HBM bytes, annotates kernel
-    coverage, and carries the byte-model predictions for the three
-    audited regions (bn fwd+bwd >= 30%, optimizer mp >= 30%, optimizer
-    non-mp 0% -- which is why auto declines it)."""
-    out = tmp_path / "report.json"
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "fusion_audit.py"),
-         "--report", "--model", "mlp", "--batch", "32",
-         "--json", str(out)],
-        env=ENV, capture_output=True, text=True, timeout=300)
-    assert r.returncode == 0, r.stderr
-    rep = json.load(open(out))
-    assert rep["model"] == "mlp"
-    assert rep["mode"] == "off"          # MXTPU_KERNELS unset in ENV
-    assert rep["n_regions"] >= 1
-    assert rep["external_bytes_total"] > 0
-    assert set(rep["coverage_bytes"]) == {"covered", "fallback", "uncovered"}
-    preds = rep["kernels"]
-    assert preds["bn_fwd_bwd"]["predicted_reduction"] >= 0.30
-    assert preds["optimizer_mp"]["predicted_reduction"] >= 0.30
-    assert preds["optimizer_f32"]["predicted_reduction"] == 0.0
-    for row in rep["regions"]:
-        assert row["coverage"] in ("covered", "fallback", "uncovered")
-        assert row["external_bytes"] >= 0 and row["rank"] >= 1
-    # Rows arrive ranked by external bytes, descending.
-    sizes = [row["external_bytes"] for row in rep["regions"]]
-    assert sizes == sorted(sizes, reverse=True)
-
-
-def test_bench_main_refuses_to_run_without_a_tpu():
-    """bench.py measures on the chip or not at all: on the CPU backend
-    main() raises before it builds a model, prints no result line, and
-    no `_CPU_FALLBACK` row can be filed as evidence again."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    with pytest.raises(RuntimeError, match="measures on a TPU"):
-        bench.main()
-    assert not hasattr(bench, "_probe_accelerator")
-
-
-def test_bench_cross_platform_gate(monkeypatch):
-    """The >3% regression gate refuses to compare snapshots stamped with
-    different platforms instead of emitting nonsense regressions."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    assert bench._snapshot_platform({"platform": "tpu"}) == "tpu"
-    assert bench._snapshot_platform({"rows": []}) == "unstamped"
-
-    prior = {"platform": "tpu",
-             "rows": [{"metric": "train_step_ms", "value": 100.0}]}
-    monkeypatch.setattr(bench, "_latest_bench_snapshot",
-                        lambda: ("BENCH_r99.json", prior))
-
-    # Cross-platform: refused, noted, zero regressions reported.
-    current = {"platform": "cpu",
-               "rows": [{"metric": "train_step_ms", "value": 500.0}]}
-    assert bench._check_regressions(current) == []
-    assert "platform" in current.get("comparison_note", "")
-
-    # Same platform: a lower-is-better _ms metric rising >3% is flagged.
-    current = {"platform": "tpu",
-               "rows": [{"metric": "train_step_ms", "value": 110.0}]}
-    regs = bench._check_regressions(current)
-    assert any("train_step_ms" in str(reg) for reg in regs)
-
-    # ... and an in-tolerance run passes the gate clean.
-    current = {"platform": "tpu",
-               "rows": [{"metric": "train_step_ms", "value": 101.0}]}
-    assert bench._check_regressions(current) == []
 
 
 # -- fleetctl + diagnose --live against live ops servers ---------------------

@@ -740,30 +740,3 @@ def test_named_scope_leaves_program_identity_alone():
     assert dedup.structural_key(plain_j) == dedup.structural_key(scoped_j)
     assert measure.fingerprint_of(plain_j) == measure.fingerprint_of(
         scoped_j)
-
-
-def test_layout_pass_keeps_block_scopes():
-    """The channels-last rewrite re-emits every equation by hand: each
-    is bound under its own name stack, so a block's scope survives."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from mxnet_tpu.passes import layout
-
-    def fwd(x, w):
-        with jax.named_scope("Conv2D_0"):
-            y = lax.conv_general_dilated(
-                x, w, (1, 1), "SAME",
-                dimension_numbers=("NCHW", "OIHW", "NCHW"))
-        with jax.named_scope("Activation_1"):
-            return jnp.maximum(y, 0.0)
-
-    args = (jnp.ones((2, 3, 8, 8)), jnp.ones((4, 3, 3, 3)))
-    closed = jax.make_jaxpr(fwd)(*args)
-    rw = layout._Interpreter(layout._Stats())
-    out = jax.make_jaxpr(lambda *a: rw.run(closed, a))(*args)
-    stacks = {e.primitive.name: str(e.source_info.name_stack)
-              for e in out.jaxpr.eqns}
-    assert stacks["conv_general_dilated"] == "Conv2D_0"
-    assert stacks["max"] == "Activation_1"

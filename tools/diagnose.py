@@ -231,13 +231,6 @@ def _passes_report():
         "remat_policy": {labels[0]: policy_names.get(int(g.value),
                                                      int(g.value))
                          for labels, g in ti.remat_policy.series()},
-        "layout": {
-            "config": {k: _env.get(k) for k in
-                       ("MXTPU_LAYOUT", "MXTPU_LAYOUT_MIN_BYTES")},
-            "rewrites": int(ti.layout_rewrite_total.value),
-            "transposes": {labels[0]: int(c.value) for labels, c in
-                           ti.layout_transpose_total.series()},
-        },
         "executable_cache": passes.executable_cache_info(),
         "sharding": _sharding_report(),
         "costdb": _costdb_report(),
@@ -301,12 +294,6 @@ def _passes_report_lines(pr):
         lines.append(f"  dedup {block}: {n} hit(s)")
     for block, policy in sorted(pr["remat_policy"].items()):
         lines.append(f"  remat {block}: policy={policy}")
-    lay = pr["layout"]
-    lay_cfg = " ".join(f"{k}={v!r}" for k, v in lay["config"].items())
-    tr = lay["transposes"]
-    lines.append(f"  layout: {lay_cfg} rewrites={lay['rewrites']} "
-                 f"transposes inserted={tr.get('inserted', 0)} "
-                 f"elided={tr.get('elided', 0)}")
     cache = pr["executable_cache"]
     lines.append(f"  executable cache: {cache['entries']} entries, "
                  f"{cache['hits']} hits, {cache['misses']} misses, "
