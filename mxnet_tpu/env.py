@@ -74,10 +74,6 @@ register(
     "Disable the native C++ runtime (engine/storage/RecordIO/pipeline) and "
     "fall back to pure-python equivalents.")
 register(
-    "MXTPU_MP_START", str, "",
-    "DataLoader multiprocessing start method override: fork | spawn | "
-    "forkserver. Default: fork from a single-threaded parent, else spawn.")
-register(
     "MXNET_CPU_WORKER_NTHREADS", int, 1,
     "Default host worker-thread count hint for the native pipeline "
     "(reference: threaded_engine_perdevice.cc:98).")
@@ -236,13 +232,6 @@ register(
     "0 forces the legacy three-phase record/backward/Trainer.step "
     "sequence; sparse grads, overriding optimizers, clip_global_norm and "
     "multi-copy params fall back automatically (docs/performance.md).")
-register(
-    "MXTPU_DEVICE_PREFETCH", int, 0,
-    "Default DataLoader device_prefetch depth: keep up to N batches "
-    "ahead of the consumer already jax.device_put to the accelerator, so "
-    "the next batch's host->device transfer overlaps the current step's "
-    "compute (double-buffered input pipeline). 0 disables; the "
-    "DataLoader(device_prefetch=...) argument overrides per loader.")
 register(
     "MXTPU_CKPT_ASYNC", bool, True,
     "CheckpointManager default: write+commit checkpoints on an engine IO "

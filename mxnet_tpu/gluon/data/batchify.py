@@ -1,4 +1,10 @@
-"""Batchify functions (reference: python/mxnet/gluon/data/batchify.py)."""
+"""Batchify functions (reference: python/mxnet/gluon/data/batchify.py).
+
+Each function of this module has a host half that does the whole of its
+work in NumPy and returns `Staged` arrays, and is that half followed by
+`to_nd`. A DataLoader worker process runs the host half alone (`host_half`):
+the chip belongs to the parent, which finishes the batch.
+"""
 from __future__ import annotations
 
 import numpy as _np
@@ -9,13 +15,60 @@ __all__ = ["Stack", "Pad", "Group", "Append", "AsList",
            "default_batchify_fn"]
 
 
+class Staged(_np.ndarray):
+    """A host array that is an NDArray in waiting: what a host half
+    returns where the batchify function returns an NDArray."""
+
+
+def map_leaves(fn, tree):
+    """`tree` with `fn` applied to whatever is not a tuple, list or dict."""
+    if isinstance(tree, tuple):
+        return tuple(map_leaves(fn, v) for v in tree)
+    if isinstance(tree, list):
+        return [map_leaves(fn, v) for v in tree]
+    if isinstance(tree, dict):
+        return {k: map_leaves(fn, v) for k, v in tree.items()}
+    return fn(tree)
+
+
+def to_nd(batch):
+    """Finish a host half's batch: each Staged array becomes an NDArray."""
+    from ... import numpy as mnp
+
+    return map_leaves(
+        lambda x: mnp.array(x.view(_np.ndarray)) if isinstance(x, Staged)
+        else x, batch)
+
+
+def host_half(fn):
+    """What a worker process runs for `fn`. A callable from outside this
+    module is its own host half: the DataLoader forks workers for it only
+    where it yields no device array."""
+    if fn is default_batchify_fn:
+        return _default_host
+    return getattr(fn, "host", fn)
+
+
+def _stack_host(arrs):
+    return _np.stack([_np.asarray(a) for a in arrs]).view(Staged)
+
+
 def _stack_arrs(arrs):
     from ... import numpy as mnp
 
     if isinstance(arrs[0], NDArray):
         return mnp.stack(arrs)
-    out = _np.stack([_np.asarray(a) for a in arrs])
-    return mnp.array(out)
+    return to_nd(_stack_host(arrs))
+
+
+def _per_field(data, stack):
+    if isinstance(data[0], (tuple, list)):
+        return tuple(_per_field([d[i] for d in data], stack)
+                     for i in range(len(data[0])))
+    if isinstance(data[0], dict):
+        return {k: _per_field([d[k] for d in data], stack)
+                for k in data[0]}
+    return stack(data)
 
 
 def default_batchify_fn(data):
@@ -23,16 +76,16 @@ def default_batchify_fn(data):
     dataloader.py default_batchify_fn). Dict samples batch per key — an
     extension beyond the reference (which errors on dicts), matching
     the dataset idioms modern pipelines use."""
-    if isinstance(data[0], (tuple, list)):
-        return tuple(default_batchify_fn([d[i] for d in data])
-                     for i in range(len(data[0])))
-    if isinstance(data[0], dict):
-        return {k: default_batchify_fn([d[k] for d in data])
-                for k in data[0]}
-    return _stack_arrs(data)
+    return _per_field(data, _stack_arrs)
+
+
+def _default_host(data):
+    return _per_field(data, _stack_host)
 
 
 class Stack:
+    host = staticmethod(_stack_host)
+
     def __call__(self, data):
         return _stack_arrs(data)
 
@@ -48,9 +101,7 @@ class Pad:
         self._val = val
         self._dtype = dtype
 
-    def __call__(self, data):
-        from ... import numpy as mnp
-
+    def host(self, data):
         arrs = [_np.asarray(d) for d in data]
         # pad EVERY dim to the batch max (reference Pad handle pads to
         # the max shape; test_gluon_data.py test_batchify_pad expects
@@ -65,7 +116,10 @@ class Pad:
         out = _np.stack(padded)
         if self._dtype:
             out = out.astype(self._dtype)
-        return mnp.array(out)
+        return out.view(Staged)
+
+    def __call__(self, data):
+        return to_nd(self.host(data))
 
 
 class Group:
@@ -75,6 +129,9 @@ class Group:
         if len(fns) == 1 and isinstance(fns[0], (list, tuple)):
             fns = fns[0]
         self._fns = fns
+
+    def host(self, data):
+        return Group(*map(host_half, self._fns))(data)
 
     def __call__(self, data):
         assert len(data[0]) == len(self._fns)
@@ -91,16 +148,17 @@ class Append:
         self._expand = expand
         self._batch_axis = batch_axis
 
-    def __call__(self, data):
-        from ... import numpy as mnp
-
+    def host(self, data):
         out = []
         for d in data:
             arr = _np.asarray(d)
             if self._expand:
                 arr = _np.expand_dims(arr, self._batch_axis)
-            out.append(mnp.array(arr))
+            out.append(arr.view(Staged))
         return out
+
+    def __call__(self, data):
+        return to_nd(self.host(data))
 
 
 class AsList:

@@ -133,8 +133,8 @@ def test_checkpoint_handler_best_not_rotated(tmp_path):
 
 def test_dataloader_process_workers():
     """Multiprocessing worker mode (reference default,
-    dataloader.py:123-305): fork workers batchify numpy; parent converts
-    to device arrays; order preserved."""
+    dataloader.py:123-305): fork workers batchify numpy into shared
+    memory; parent converts to device arrays; order preserved."""
     import numpy as onp
 
     from mxnet_tpu.gluon.data import DataLoader
@@ -157,13 +157,13 @@ def test_dataloader_process_workers():
 
 def test_dataloader_process_workers_ndarray_fallback():
     """Datasets yielding device arrays must NOT fork (jax is not
-    fork-safe) — the loader silently falls back to the threaded path."""
+    fork-safe): the loader's workers are threads."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.data import ArrayDataset, DataLoader
 
     ds = ArrayDataset(mx.np.ones((16, 4)), mx.np.zeros((16,)))
     loader = DataLoader(ds, batch_size=4, num_workers=2)
-    assert not loader._fork_safe()
+    assert loader._thread_bound()
     batches = list(loader)
     assert len(batches) == 4
 

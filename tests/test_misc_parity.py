@@ -150,7 +150,7 @@ def test_env_var_registry():
     assert isinstance(mx.env.get("MXTPU_DISABLE_NATIVE"), bool)
     assert mx.env.get("MXTPU_SERVE_MAX_BATCH") == 32
     d = mx.env.doc()
-    assert "MXNET_ENGINE_TYPE" in d and "MXTPU_MP_START" in d
+    assert "MXNET_ENGINE_TYPE" in d and "MXTPU_DISABLE_NATIVE" in d
     assert len(mx.env.all_vars()) >= 12
     # typed override
     import os

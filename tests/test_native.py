@@ -219,20 +219,6 @@ class TestRecordIO:
         assert hdr2.label == 3.0 and hdr2.id == 42
 
 
-class TestDataLoaderNative:
-    def test_workers_use_native_pipeline(self):
-        from mxnet_tpu.gluon.data import ArrayDataset, DataLoader
-
-        x = np.arange(64, dtype=np.float32).reshape(32, 2)
-        y = np.arange(32, dtype=np.int32)
-        ds = ArrayDataset(x, y)
-        dl = DataLoader(ds, batch_size=4, num_workers=3)
-        seen = list(dl)
-        assert len(seen) == 8
-        xs = np.concatenate([np.asarray(b[0]) for b in seen])
-        np.testing.assert_array_equal(np.sort(xs.ravel()), x.ravel())
-
-
 def test_checkpoint_io_through_engine(tmp_path):
     """save_parameters pushes the .npz write through the native engine
     (IO thread); load barriers on the path var (VERDICT r1 weak #10 —

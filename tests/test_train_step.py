@@ -489,16 +489,20 @@ def test_device_prefetch_overlaps_transfer_with_compute():
             _spans.disable()
 
 
-def test_device_prefetch_env_default(monkeypatch):
+@pytest.mark.parametrize("depth,prefetched", [(None, 0), (0, 0), (2, 6)])
+def test_device_prefetch_is_the_argument_alone(monkeypatch, depth,
+                                               prefetched):
+    """The argument is the one way to set the depth: the variable that
+    used to be its default is not read."""
     monkeypatch.setenv("MXTPU_DEVICE_PREFETCH", "2")
     ds = _toy_dataset()
-    loader = gluon.data.DataLoader(ds, batch_size=4)  # no explicit arg
+    loader = gluon.data.DataLoader(ds, batch_size=4, device_prefetch=depth)
     telemetry.enable()
     try:
         base = ti.data_prefetch_total.value
         batches = list(loader)
         assert len(batches) == 6
-        assert ti.data_prefetch_total.value - base == 6
+        assert ti.data_prefetch_total.value - base == prefetched
     finally:
         telemetry.disable()
 
