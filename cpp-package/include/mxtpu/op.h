@@ -7988,7 +7988,8 @@ inline std::vector<PackedTensor> flash_attention(
     long long block_k = 128,
     const char* interpret_json = nullptr,
     double dropout_p = 0.0,
-    const char* dropout_seed_json = nullptr) {
+    const char* dropout_seed_json = nullptr,
+    const char* block_diffusion_json = nullptr) {
   std::vector<PackedTensor> ins_;
   ins_.push_back(q);
   ins_.push_back(k);
@@ -8001,6 +8002,7 @@ inline std::vector<PackedTensor> flash_attention(
   if (interpret_json) a_.raw("interpret", interpret_json);
   a_.put_num("dropout_p", dropout_p);
   if (dropout_seed_json) a_.raw("dropout_seed", dropout_seed_json);
+  if (block_diffusion_json) a_.raw("block_diffusion", block_diffusion_json);
   return rt.invoke("flash_attention", ins_, a_.str());
 }
 
@@ -9662,6 +9664,19 @@ inline std::vector<PackedTensor> rmspropalex_update(
   a_.put_num("clip_gradient", clip_gradient);
   a_.put_num("clip_weights", clip_weights);
   return rt.invoke("rmspropalex_update", ins_, a_.str());
+}
+
+inline std::vector<PackedTensor> rotary_embedding(
+    PyRuntime& rt,
+    const PackedTensor& x,
+    const PackedTensor& positions,
+    double theta = 10000.0) {
+  std::vector<PackedTensor> ins_;
+  ins_.push_back(x);
+  ins_.push_back(positions);
+  detail::JsonBuilder a_;
+  a_.put_num("theta", theta);
+  return rt.invoke("rotary_embedding", ins_, a_.str());
 }
 
 inline std::vector<PackedTensor> round(

@@ -166,9 +166,12 @@ def unscale(trainer):
 
 
 def _keeps_fp32(p):
-    # norms' scale/shift and running stats stay fp32 (cast-list analog)
+    # norms' scale/shift and running stats stay fp32 (cast-list analog),
+    # and so does an expert layer's router: its top-k is decided on
+    # float32 probabilities
     name = p.name.lower()
-    return any(k in name for k in ("gamma", "beta", "running", "moving"))
+    return any(k in name for k in ("gamma", "beta", "running", "moving",
+                                   "router"))
 
 
 def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,
@@ -209,6 +212,10 @@ def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,
                  if (p._data_map is not None or p.shape is not None)
                  and not _keeps_fp32(p)], dtype)
     net._clear_cached()
+    if not getattr(net, "amp_casts_inputs", True):
+        # the block's floating inputs are not activations (noise levels,
+        # weights of a loss): they keep the precision they come in
+        return net
     # wrap forward so inputs are cast on entry
     orig_forward = net.forward
 

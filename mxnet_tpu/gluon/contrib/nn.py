@@ -4,14 +4,21 @@
 `parallel/moe.py`). The reference has no MoE; this layer plus
 `parallel.moe_ffn_sharded` gives expert parallelism as a first-class
 capability (shard the expert dimension over an 'ep' mesh axis).
+
+`DroplessMoE` — the expert layer that holds a share: top-k routing that
+drops nothing over gated three-matrix experts, told which experts live
+here (`ep_rank` of `ep_size`); `parallel.moe.dropless_moe` is its
+functional part (docs/moe.md).
 """
 from __future__ import annotations
 
+from ... import autograd as ag
 from ...ndarray.ndarray import apply_op
-from ..block import HybridBlock
+from ...telemetry import instruments as _telemetry
+from ..block import HybridBlock, current_state_sink
 from ..parameter import Parameter
 
-__all__ = ["MoEDense"]
+__all__ = ["MoEDense", "DroplessMoE"]
 
 
 class MoEDense(HybridBlock):
@@ -57,3 +64,85 @@ class MoEDense(HybridBlock):
     def __repr__(self):
         return (f"MoEDense(experts={self._E}, top_k={self._top_k}, "
                 f"capacity_factor={self._cf})")
+
+
+class DroplessMoE(HybridBlock):
+    """A chip's share of a dropless mixture of gated experts.
+
+    Input (..., in_units) -> output (..., in_units).  The router scores
+    all ``num_experts`` experts of the layer (softmax in float32, the
+    ``top_k`` largest, gates divided by their sum when
+    ``normalize_top_k``); this block holds experts ``ep_rank *
+    num_experts / ep_size`` onward, ``num_experts / ep_size`` of them,
+    stacked: ``gate_proj``/``up_proj`` (held, in_units, hidden_units) and
+    ``down_proj`` (held, hidden_units, in_units).  The output is the sum
+    over the held experts among each token's ``top_k``: with ``ep_size``
+    1 the whole layer, otherwise this chip's part of it, and the parts of
+    all ``ep_size`` shares add up to the whole (tests/test_sdar_moe.py).
+    No token is dropped; there is no capacity and no auxiliary loss.
+
+    ``running_load`` (not trained) holds what the last training step counted on
+    the device: [assignments routed to the held experts, busiest held
+    expert's rows over their mean].  `telemetry.flush_moe_load()` reads
+    it into the gauges ``moe_rows_routed_here`` and
+    ``moe_expert_load_max_over_mean``.
+    """
+
+    def __init__(self, in_units, hidden_units, num_experts, top_k, *,
+                 ep_size=1, ep_rank=0, normalize_top_k=True,
+                 dtype="float32", weight_initializer=None):
+        super().__init__()
+        if num_experts % ep_size or not 0 <= ep_rank < ep_size:
+            raise ValueError(
+                f"{num_experts} experts over ep_size={ep_size}, "
+                f"ep_rank={ep_rank}: not a share")
+        held = num_experts // ep_size
+        self._top_k = int(top_k)
+        self._first = int(ep_rank) * held
+        self._normalize = bool(normalize_top_k)
+        # the router stays float32 under amp.convert_hybrid_block
+        self.router = Parameter("router", shape=(num_experts, in_units),
+                                init=weight_initializer)
+        self.gate_proj = Parameter(
+            "gate_proj", shape=(held, in_units, hidden_units), dtype=dtype,
+            init=weight_initializer)
+        self.up_proj = Parameter(
+            "up_proj", shape=(held, in_units, hidden_units), dtype=dtype,
+            init=weight_initializer)
+        self.down_proj = Parameter(
+            "down_proj", shape=(held, hidden_units, in_units), dtype=dtype,
+            init=weight_initializer)
+        self.running_load = Parameter("running_load", shape=(2,), init="zeros",
+                              grad_req="null", differentiable=False)
+        self.running_load.is_moe_load = True    # TrainStep stages it
+
+    def forward(self, x):
+        from ...parallel import moe as _moe
+
+        def pure(xv, r, g, u, d):
+            out, load = _moe.dropless_moe(
+                xv.reshape(-1, xv.shape[-1]), r, g, u, d,
+                top_k=self._top_k, first_expert=self._first,
+                normalize=self._normalize)
+            return out.reshape(xv.shape), load
+
+        out, load = apply_op(
+            pure, x, self.router.data_for(x), self.gate_proj.data_for(x),
+            self.up_proj.data_for(x), self.down_proj.data_for(x),
+            name="dropless_moe")
+        if ag.is_training():
+            sink = current_state_sink()
+            if sink is not None:
+                sink.record(self.running_load, load._data)
+            else:
+                self.running_load.data_for(x)._assign_from(load.detach())
+                _telemetry.stage_moe_load(
+                    getattr(self, "_scope_name", None)
+                    or type(self).__name__, load._data)
+        return out
+
+    def __repr__(self):
+        held, d, f = self.gate_proj.shape
+        return (f"DroplessMoE({d} -> {f} -> {d}, experts "
+                f"{self._first}..{self._first + held - 1} of "
+                f"{self.router.shape[0]}, top_k={self._top_k})")

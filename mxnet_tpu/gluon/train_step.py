@@ -260,7 +260,7 @@ class TrainStep:
         net = self._net
         params = sorted(net.collect_params().items())
         self._block_params = params
-        name_of = {id(p): n for n, p in params}
+        self._name_of = name_of = {id(p): n for n, p in params}
         items = []  # (trainer index, block param name, Parameter)
         for i, p in enumerate(tr._params):
             if p.grad_req == "null":
@@ -722,6 +722,11 @@ class TrainStep:
                 target = p.data() if isinstance(p, Parameter) else p
                 target._data = v
                 target._version += 1
+                if getattr(p, "is_moe_load", False):
+                    # an expert layer's counters: the array stays on the
+                    # device until telemetry.flush_moe_load() is asked
+                    _telemetry.stage_moe_load(
+                        self._name_of[id(p)].rpartition(".")[0], v)
         with _spans.span("train_step.bookkeeping"):
             # what the program's own instrumentation costs per step
             _telemetry.record_step_dispatch(

@@ -23,6 +23,7 @@ __all__ = [
     "activation", "leaky_relu", "relu", "sigmoid", "softmax", "log_softmax",
     "softmin", "fully_connected", "convolution", "deconvolution", "pooling",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "rms_norm",
+    "rotary_embedding",
     "lrn", "dropout", "embedding", "one_hot", "pick", "topk", "sequence_mask",
     "sequence_last", "sequence_reverse", "l2_normalization", "upsampling",
     "moments", "gamma", "erf", "erfinv", "set_np", "reset_np", "is_np_array",
@@ -61,6 +62,7 @@ layer_norm = _op(_nn.layer_norm, 3)
 group_norm = _op(_nn.group_norm, 3)
 instance_norm = _op(_nn.instance_norm, 3)
 rms_norm = _op(_nn.rms_norm, 2)
+rotary_embedding = _op(_nn.rotary_embedding, 2)
 lrn = _op(_nn.lrn, 1)
 embedding = _op(_nn.embedding, 2)
 one_hot = _op(_nn.one_hot, 1)

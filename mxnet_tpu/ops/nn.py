@@ -478,6 +478,29 @@ def rms_norm(x, gamma, axis=-1, eps=1e-6):
     return out
 
 
+@register_op("rotary_embedding")
+def rotary_embedding(x, positions, theta=10000.0):
+    """Rotary position embedding, rotate-half form: with the head's
+    width d, frequencies theta ** (-2i / d) for i < d / 2 and the angle
+    a = position * frequency laid out twice along the width,
+
+        out = x * cos(a) + concat(-x[d/2:], x[:d/2]) * sin(a).
+
+    x: (..., S, d); ``positions``: the S explicit position ids (or any
+    shape that broadcasts against x's leading dimensions, e.g. (B, 1,
+    S)).  Angles and the rotation are float32; the result has x's
+    type."""
+    d = x.shape[-1]
+    half = d // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
 @register_op("lrn")
 def lrn(x, nsize=5, alpha=1e-4, beta=0.75, knorm=2.0):
     """Local response normalization (reference: nn/lrn.cc)."""

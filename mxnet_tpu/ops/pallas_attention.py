@@ -30,7 +30,11 @@ import jax.numpy as jnp
 
 from .registry import register_op
 
-__all__ = ["flash_attention", "attention_reference"]
+__all__ = ["flash_attention", "attention_reference", "SAVED_BY_NAME"]
+
+# names (jax.ad_checkpoint.checkpoint_name) of the forward kernel's output
+# and logsumexp among the residuals of `flash_attention`'s backward
+SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
 def _pallas_call(kernel, *, name, **kwargs):
@@ -76,18 +80,137 @@ def _dropout_keep(seed, bh, q_pos, k_pos, dropout_p):
     return h <= thresh
 
 
+_BIG = 2 ** 30
+
+
+def _mask_codes(causal, block_diffusion, s_len, valid_len=None):
+    """The mask as two static codes per position, or None when nothing
+    is masked: ``(qc, kc)``, int32 arrays of shape (s_len, 2) and
+    (2, s_len), with
+
+        keep[i, j] = (kc[0, j] == qc[i, 0]) | (kc[1, j] <= qc[i, 1])
+
+    One rule for every mask the kernels know, evaluated from a (block_q,
+    2) column and a (2, block_k) row per tile:
+
+    * padding (``valid_len``): a padded key's codes match no query;
+    * ``causal``: the threshold term alone, position against position;
+    * ``block_diffusion=(B, L)``, the vectorised training mask of block
+      diffusion (Arriola et al., arXiv:2503.09573) over the 2L positions
+      [noisy ; clean] with block b(i) = (i mod L) // B: noisy->noisy iff
+      same block (the equality term), noisy->clean iff b(j) < b(i),
+      clean->clean iff b(j) <= b(i) (the threshold term), clean->noisy
+      never.  Every query keeps its own block, so no row is empty.
+    """
+    import numpy as onp
+
+    pos = onp.arange(s_len)
+    valid = pos < (s_len if valid_len is None else valid_len)
+    never_q, never_k = onp.full(s_len, -1), onp.full(s_len, -2)
+    if block_diffusion is not None:
+        if causal:
+            raise ValueError("causal and block_diffusion exclude each other")
+        blen, half = (int(x) for x in block_diffusion)
+        if (s_len if valid_len is None else valid_len) != 2 * half:
+            raise ValueError(
+                f"block_diffusion=(block {blen}, half {half}) needs a "
+                f"sequence of {2 * half} positions [noisy ; clean]")
+        noisy = pos < half
+        blk = onp.where(noisy, pos, pos - half) // blen
+        q_eq = onp.where(noisy, blk, -1)
+        q_thr = onp.where(noisy, blk - 1, blk)
+        k_eq = onp.where(noisy & valid, blk, -2)
+        k_thr = onp.where(~noisy & valid, blk, _BIG)
+    elif causal:
+        q_eq, q_thr, k_eq = never_q, pos, never_k
+        k_thr = onp.where(valid, pos, _BIG)
+    elif valid_len is not None:
+        q_eq, q_thr, k_eq = never_q, onp.zeros(s_len, int), never_k
+        k_thr = onp.where(valid, 0, _BIG)
+    else:
+        return None
+    return (onp.stack([q_eq, q_thr], 1).astype(onp.int32),
+            onp.stack([k_eq, k_thr], 0).astype(onp.int32))
+
+
+def _keep(qc, kc, use_eq=True):
+    """The rule of ``_mask_codes`` on (n, 2) query and (2, m) key codes
+    (jnp or numpy): an (n, m) boolean."""
+    keep = kc[1:2, :] <= qc[:, 1:2]
+    if use_eq:
+        keep = keep | (kc[0:1, :] == qc[:, 0:1])
+    return keep
+
+
+def _tile_schedule(codes, nq, nk, block_q, block_k, by_key=False):
+    """The live (q tile, k tile) pairs of a mask as one int32 per grid
+    step, in q-major order (forward, dQ) or k-major (dK/dV): bits 17..
+    the q tile, bits 2..16 the k tile, bit 1 set on the first pair of
+    its row (column), bit 0 on the last.  A tile the mask empties is not
+    in the list, so the grid never visits it; every row and every column
+    keeps at least one pair, so every output block is written."""
+    import numpy as onp
+
+    if nq >= 1 << 14 or nk >= 1 << 15:
+        raise ValueError(f"too many tiles for the schedule: {nq} x {nk}")
+    if codes is None:
+        live = onp.ones((nq, nk), bool)
+    else:
+        qc = codes[0].astype(onp.int64).reshape(nq, block_q, 2)
+        kc = codes[1].astype(onp.int64).reshape(2, nk, block_k)
+        q_eq, k_eq = qc[:, :, 0], kc[0]
+        q_lo = onp.where(q_eq >= 0, q_eq, _BIG).min(1)
+        q_hi = onp.where(q_eq >= 0, q_eq, -_BIG).max(1)
+        k_lo = onp.where(k_eq >= 0, k_eq, _BIG).min(1)
+        k_hi = onp.where(k_eq >= 0, k_eq, -_BIG).max(1)
+        live = ((k_lo[None] <= q_hi[:, None]) & (k_hi[None] >= q_lo[:, None])
+                | (kc[1].min(1)[None] <= qc[:, :, 1].max(1)[:, None]))
+        live[:, 0] |= ~live.any(1)
+        live[0, :] |= ~live.any(0)
+    qi, kj = onp.nonzero(live.T)[::-1] if by_key else onp.nonzero(live)
+    major = kj if by_key else qi
+    edge = onp.concatenate([[True], major[1:] != major[:-1], [True]])
+    return ((qi << 17) | (kj << 2) | (edge[:-1] << 1) | edge[1:]).astype(
+        onp.int32)
+
+
+def _head_div(bh, group):
+    """Row of the key-value head that query-head row ``bh`` reads."""
+    return bh if group == 1 else jax.lax.div(bh, jnp.int32(group))
+
+
+def _tiles_of(e):
+    """(q tile, k tile) of one word of the schedule."""
+    return (jax.lax.shift_right_logical(e, 17),
+            jax.lax.shift_right_logical(e, 2) & 0x7FFF)
+
+
+def _step_of(sched_ref, t):
+    """(q tile, k tile, first of its row, last of its row) of grid step
+    ``t``: scalar arithmetic on one prefetched word."""
+    e = sched_ref[t]
+    return _tiles_of(e) + ((e & 2) != 0, (e & 1) != 0)
+
+
 def attention_reference(q, k, v, causal=False, scale=None,
-                        dropout_p=0.0, dropout_seed=None):
+                        dropout_p=0.0, dropout_seed=None,
+                        block_diffusion=None):
     """Plain jnp attention (the numeric oracle + off-TPU fallback).
-    q/k/v: (B, H, S, D). dropout uses the same counter-hash mask as the
-    Pallas kernel, applied to the normalized probabilities (numerator
-    only, inverted scaling) — bit-identical semantics to the kernel."""
+    q: (B, H, S, D); k/v: (B, Hkv, S, D) with H a multiple of Hkv (query
+    head h reads key-value head h // (H // Hkv)).  ``block_diffusion``
+    as in `flash_attention`.  dropout uses the same counter-hash mask as
+    the Pallas kernel, applied to the normalized probabilities
+    (numerator only, inverted scaling) — bit-identical semantics to the
+    kernel."""
     b, h, s, d = q.shape
+    group = h // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k.astype(q.dtype)) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask, scores, -jnp.inf)
+    codes = _mask_codes(causal, block_diffusion, s)
+    if codes is not None:
+        scores = jnp.where(_keep(*codes), scores, -jnp.inf)
     p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
@@ -100,46 +223,48 @@ def attention_reference(q, k, v, causal=False, scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
 
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
-                l_ref, acc_ref, *,
-                scale, causal, block_q, block_k, valid_len=None,
-                dropout_p=0.0):
+def _scores(q, k, qc_ref, kc_ref, scale, masked, use_eq):
+    """One tile of QK^T * scale with the mask's dead pairs at -inf."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
+    if masked:
+        s = jnp.where(_keep(qc_ref[...], kc_ref[...], use_eq), s, -jnp.inf)
+    return s
+
+
+def _tile_keep(seed_ref, bh, q_idx, kv_idx, block_q, block_k, shape,
+               dropout_p):
+    """The dropout keep mask of one tile, the same in all three
+    kernels."""
+    q_pos = q_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return _dropout_keep(seed_ref[0], bh, q_pos, k_pos, dropout_p)
+
+
+def _fwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref, kc_ref,
+                o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                scale, masked, use_eq, block_q, block_k, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
-    kv_idx = pl.program_id(2)
+    # hoisted: program_id inside pl.when bodies breaks interpret mode
+    bh_idx = pl.program_id(0)
+    q_idx, kv_idx, first, last = _step_of(sched_ref, pl.program_id(1))
 
-    @pl.when(kv_idx == 0)
+    @pl.when(first)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                     # (block_q, d)
-    k = k_ref[0]                                     # (block_k, d)
     v = v_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
-
-    if causal or valid_len is not None:
-        k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        keep = jnp.ones(s.shape, bool)
-        if causal:
-            q_idx = pl.program_id(1)
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            keep &= q_pos >= k_pos
-        if valid_len is not None:
-            # S was padded up to a tile multiple; padded keys are dead
-            keep &= k_pos < valid_len
-        s = jnp.where(keep, s, -jnp.inf)
+    s = _scores(q_ref[0], k_ref[0], qc_ref, kc_ref, scale, masked, use_eq)
 
     m_prev = m_ref[:]                                # (block_q, 1)
     l_prev = l_ref[:]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
-    # guard fully-masked rows (causal blocks above the diagonal)
+    # guard rows with nothing live so far (a tile the mask half empties)
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
     p = jnp.exp(s - m_safe)
     p = jnp.where(jnp.isfinite(m_new), p, 0.0)
@@ -149,13 +274,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     # un-dropped): out = Σ M·p·v / (l·(1−p)) — FlashAttention dropout
     p_v = p
     if dropout_p > 0.0:
-        q_idx = pl.program_id(1)
-        q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, p.shape, 0)
-        k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, p.shape, 1)
-        keep = _dropout_keep(seed_ref[0], pl.program_id(0), q_pos, k_pos,
-                             dropout_p)
+        keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx, block_q,
+                          block_k, p.shape, dropout_p)
         p_v = jnp.where(keep, p, 0.0)
     acc = acc_ref[:] * alpha + jax.lax.dot_general(
         p_v.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -164,7 +284,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     l_ref[:] = l_new
     acc_ref[:] = acc
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finish():
         denom = jnp.maximum(l_ref[:], 1e-30)
         # lse records the TRUE softmax normalizer (backward recomputes
@@ -187,191 +307,201 @@ def _seed_arr(dropout_seed):
     return jnp.asarray(dropout_seed, jnp.int32).reshape((1,))
 
 
-def _smem_spec():
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
-
-
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               valid_len=None, dropout_p=0.0, dropout_seed=None):
-    import jax.experimental.pallas as pl
-
-    b, h, s_len, d = q.shape
-    bh = b * h
-    qr = q.reshape(bh, s_len, d)
-    kr = k.reshape(bh, s_len, d)
-    vr = v.reshape(bh, s_len, d)
-    block_q = min(block_q, s_len)
-    block_k = min(block_k, s_len)
-    grid = (bh, s_len // block_q, s_len // block_k)
-
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, valid_len=valid_len, dropout_p=dropout_p)
-    out, lse = _pallas_call(
-        kernel,
-        name="flash_attention_fwd",
-        grid=grid,
-        in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            _scratch((block_q, 1)),   # running max m
-            _scratch((block_q, 1)),   # running sum l
-            _scratch((block_q, d)),   # output accumulator
-        ],
-        interpret=interpret,
-    )(_seed_arr(dropout_seed), qr, kr, vr)
-    return out.reshape(b, h, s_len, d), lse[..., 0]
-
-
 def _scratch(shape):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _recompute_p(q, k, lse_col, scale, causal, q_idx, kv_idx, block_q,
-                 block_k, valid_len=None):
+class _Plan:
+    """What the three kernels share for one call: shapes, tile sizes,
+    the mask's codes and the BlockSpecs that follow the tile schedule.
+    An index map gets the grid indices, then the two prefetched scalar
+    operands (dropout seed, schedule)."""
+
+    def __init__(self, q, k, causal, block_q, block_k, valid_len,
+                 block_diffusion):
+        import numpy as onp
+
+        b, h, s_len, d = q.shape
+        self.shape, self.kv_shape = q.shape, k.shape
+        self.group = h // k.shape[1]
+        if h != self.group * k.shape[1]:
+            raise ValueError(f"{h} query heads over {k.shape[1]} key-value "
+                             "heads: not a multiple")
+        self.bh, self.bkv, self.s_len, self.d = b * h, b * k.shape[1], s_len, d
+        self.block_q, self.block_k = min(block_q, s_len), min(block_k, s_len)
+        self.nq, self.nk = s_len // self.block_q, s_len // self.block_k
+        codes = _mask_codes(causal, block_diffusion, s_len, valid_len)
+        self.masked = codes is not None
+        self.use_eq = block_diffusion is not None
+        self._codes = codes
+        self.codes = codes if codes is not None else (
+            onp.zeros((s_len, 2), onp.int32), onp.zeros((2, s_len), onp.int32))
+
+    def schedule(self, by_key=False):
+        return jnp.asarray(_tile_schedule(
+            self._codes, self.nq, self.nk, self.block_q, self.block_k,
+            by_key))
+
+    def flat(self, x, kv=False):
+        return x.reshape(self.bkv if kv else self.bh, self.s_len, -1)
+
+    def specs(self, head_of):
+        """(q-sized block, k-sized block, q codes, k codes) BlockSpecs
+        for a grid whose axis 1 walks the schedule; ``head_of(*grid
+        indices)`` gives (query head row, key-value head row).  The first
+        two take the block's width."""
+        import jax.experimental.pallas as pl
+
+        def q_of(ids, sched):
+            return _tiles_of(sched[ids[1]])[0]
+
+        def k_of(ids, sched):
+            return _tiles_of(sched[ids[1]])[1]
+
+        def q_tile(width):
+            return pl.BlockSpec(
+                (1, self.block_q, width),
+                lambda *a: (head_of(*a[:-2])[0], q_of(a, a[-1]), 0))
+
+        def k_tile(width):
+            return pl.BlockSpec(
+                (1, self.block_k, width),
+                lambda *a: (head_of(*a[:-2])[1], k_of(a, a[-1]), 0))
+
+        return (q_tile, k_tile,
+                pl.BlockSpec((self.block_q, 2),
+                             lambda *a: (q_of(a, a[-1]), 0)),
+                pl.BlockSpec((2, self.block_k),
+                             lambda *a: (0, k_of(a, a[-1]))))
+
+    def call(self, kernel, name, grid, in_specs, out_specs, out_shape,
+             scratch, interpret, **static):
+        from jax.experimental.pallas import tpu as pltpu
+
+        return _pallas_call(
+            functools.partial(
+                kernel, masked=self.masked, use_eq=self.use_eq,
+                block_q=self.block_q, block_k=self.block_k, **static),
+            name=name,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+                out_specs=out_specs, scratch_shapes=scratch),
+            out_shape=out_shape, interpret=interpret)
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               valid_len=None, dropout_p=0.0, dropout_seed=None,
+               block_diffusion=None):
+    plan = _Plan(q, k, causal, block_q, block_k, valid_len, block_diffusion)
+    group, d = plan.group, plan.d
+    rows = plan.schedule()
+    q_tile, k_tile, q_code, k_code = plan.specs(
+        lambda b, t: (b, _head_div(b, group)))
+    out, lse = plan.call(
+        _fwd_kernel, "flash_attention_fwd",
+        grid=(plan.bh, len(rows)),
+        in_specs=[q_tile(d), k_tile(d), k_tile(d), q_code, k_code],
+        out_specs=[q_tile(d), q_tile(1)],
+        out_shape=[
+            jax.ShapeDtypeStruct((plan.bh, plan.s_len, d), q.dtype),
+            jax.ShapeDtypeStruct((plan.bh, plan.s_len, 1), jnp.float32),
+        ],
+        scratch=[
+            _scratch((plan.block_q, 1)),   # running max m
+            _scratch((plan.block_q, 1)),   # running sum l
+            _scratch((plan.block_q, d)),   # output accumulator
+        ],
+        interpret=interpret, scale=scale, dropout_p=dropout_p,
+    )(_seed_arr(dropout_seed), rows, plan.flat(q),
+      plan.flat(k, True), plan.flat(v, True), *plan.codes)
+    return out.reshape(q.shape), lse[..., 0]
+
+
+def _recompute_p(q, k, lse_col, qc_ref, kc_ref, scale, masked, use_eq):
     """exp(QK^T * scale - lse) for one (q block, k block) tile.
     lse_col: (block_q, 1) column (see _finish in _fwd_kernel)."""
-    import jax.experimental.pallas as pl  # noqa: F401
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if causal or valid_len is not None:
-        k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        keep = jnp.ones(s.shape, bool)
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            keep &= q_pos >= k_pos
-        if valid_len is not None:
-            keep &= k_pos < valid_len
-        s = jnp.where(keep, s, -jnp.inf)
+    s = _scores(q, k, qc_ref, kc_ref, scale, masked, use_eq)
     return jnp.where(jnp.isfinite(lse_col), jnp.exp(s - lse_col), 0.0)
 
 
-def _tile_keep(seed_ref, bh, q_idx, kv_idx, block_q, block_k, shape,
-               dropout_p):
-    """Regenerate the forward pass's keep mask for one tile."""
-    q_pos = q_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return _dropout_keep(seed_ref[0], bh, q_pos, k_pos, dropout_p)
-
-
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_acc, *, scale, causal, block_q,
-                   block_k, valid_len=None, dropout_p=0.0):
+def _bwd_dq_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, qc_ref, kc_ref, dq_ref, dq_acc, *,
+                   scale, masked, use_eq, block_q, block_k, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
-    kv_idx = pl.program_id(2)
+    bh_idx = pl.program_id(0)
+    q_idx, kv_idx, first, last = _step_of(sched_ref, pl.program_id(1))
 
-    @pl.when(kv_idx == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_idx = pl.program_id(1)
-    bh_idx = pl.program_id(0)  # hoisted: program_id inside pl.when
-    # bodies breaks interpret mode
-    # causal: tiles strictly above the diagonal are all-zero P — skip
-    if causal:
-        live = kv_idx * block_k <= q_idx * block_q + block_q - 1
-    else:
-        live = kv_idx >= 0  # always true (traced predicate)
-    if valid_len is not None:
-        # k tiles entirely inside the padding are all-zero P — skip
-        live &= kv_idx * block_k < valid_len
+    p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0], qc_ref, kc_ref,
+                     scale, masked, use_eq)
+    dp = jax.lax.dot_general(
+        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)    # (bq, bk)
+    if dropout_p > 0.0:
+        # dP̂ = M/(1−p)·(dO V^T); delta already equals
+        # rowsum(P̂∘dP̂) because delta = rowsum(dO∘O)
+        keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx,
+                          block_q, block_k, p.shape, dropout_p)
+        dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
+    ds = p * (dp - delta_ref[0]) * scale
+    dq_acc[:] += jax.lax.dot_general(
+        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(live)
-    def _accum():
-        p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0], scale, causal,
-                         q_idx, kv_idx, block_q, block_k, valid_len)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # (bq, bk)
-        if dropout_p > 0.0:
-            # dP̂ = M/(1−p)·(dO V^T); delta already equals
-            # rowsum(P̂∘dP̂) because delta = rowsum(dO∘O)
-            keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx,
-                              block_q, block_k, p.shape, dropout_p)
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
-        ds = p * (dp - delta_ref[0]) * scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finish():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    causal, block_q, block_k, valid_len=None,
-                    dropout_p=0.0):
+def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, qc_ref, kc_ref, dk_ref, dv_ref,
+                    dk_acc, dv_acc, *, scale, masked, use_eq, block_q,
+                    block_k, group, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
-    q_idx = pl.program_id(2)       # q blocks stream in the inner axis
+    # grid: (key-value head, live pair in k-major order, query head of
+    # the group): the pairs of one k tile and, inside each, the query
+    # heads that share this key-value head all add into one dK and dV
+    g_idx = pl.program_id(2)
+    bh_idx = pl.program_id(0) * group + g_idx
+    q_idx, kv_idx, first, last = _step_of(sched_ref, pl.program_id(1))
 
-    @pl.when(q_idx == 0)
+    @pl.when(first & (g_idx == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    kv_idx = pl.program_id(1)
-    bh_idx = pl.program_id(0)  # hoisted: program_id inside pl.when
-    # bodies breaks interpret mode
-    if causal:
-        # q tiles strictly above this k tile's diagonal see zero P
-        live = kv_idx * block_k <= q_idx * block_q + block_q - 1
+    p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0], qc_ref, kc_ref,
+                     scale, masked, use_eq)
+    if dropout_p > 0.0:
+        keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx,
+                          block_q, block_k, p.shape, dropout_p)
+        p_d = jnp.where(keep, p, 0.0) / (1.0 - dropout_p)
     else:
-        live = q_idx >= 0  # always true (traced predicate)
-    if valid_len is not None:
-        live &= kv_idx * block_k < valid_len
+        keep = None
+        p_d = p
+    # dV += P_d^T dO (P_d = dropped+rescaled probs, what fwd used)
+    dv_acc[:] += jax.lax.dot_general(
+        p_d.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(
+        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if dropout_p > 0.0:
+        dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
+    ds = p * (dp - delta_ref[0]) * scale
+    # dK += dS^T Q
+    dk_acc[:] += jax.lax.dot_general(
+        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(live)
-    def _accum():
-        p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0], scale, causal,
-                         q_idx, kv_idx, block_q, block_k, valid_len)
-        if dropout_p > 0.0:
-            keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx,
-                              block_q, block_k, p.shape, dropout_p)
-            p_d = jnp.where(keep, p, 0.0) / (1.0 - dropout_p)
-        else:
-            keep = None
-            p_d = p
-        # dV += P_d^T dO (P_d = dropped+rescaled probs, what fwd used)
-        dv_acc[:] += jax.lax.dot_general(
-            p_d.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_p > 0.0:
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
-        ds = p * (dp - delta_ref[0]) * scale
-        # dK += dS^T Q
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(q_idx == pl.num_programs(2) - 1)
+    @pl.when(last & (g_idx == group - 1))
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -379,104 +509,89 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                interpret, valid_len=None, dropout_p=0.0,
-               dropout_seed=None):
+               dropout_seed=None, block_diffusion=None):
     """Block-streamed FlashAttention-2 backward: O(S) memory, no (S, S)
     residual — P tiles are recomputed from (q, k, lse) per block (and
     the dropout keep mask from its counter hash)."""
-    import jax.experimental.pallas as pl
-
-    b, h, s_len, d = q.shape
-    bh = b * h
-    block_q = min(block_q, s_len)
-    block_k = min(block_k, s_len)
-    qr = q.reshape(bh, s_len, d)
-    kr = k.reshape(bh, s_len, d)
-    vr = v.reshape(bh, s_len, d)
-    do = g.reshape(bh, s_len, d)
-    orr = out.reshape(bh, s_len, d)
+    plan = _Plan(q, k, causal, block_q, block_k, valid_len, block_diffusion)
+    group, d = plan.group, plan.d
+    qr, kr, vr = plan.flat(q), plan.flat(k, True), plan.flat(v, True)
+    do, orr = plan.flat(g), plan.flat(out)
     # delta = rowsum(dO * O) — the softmax-grad correction term (with
     # dropout it still equals rowsum(P̂∘dP̂) since O = P_d V).
     # lse/delta ride as (bh, s_len, 1) columns so their (block_q, 1)
     # blocks satisfy Mosaic's last-two-dims tiling rule.
     delta = jnp.sum(do.astype(jnp.float32) * orr.astype(jnp.float32),
                     axis=-1)[..., None]             # (bh, s_len, 1)
-    lse = lse[..., None]                            # (bh, s_len, 1)
+    lse = lse.reshape(plan.bh, plan.s_len, 1)
     seed = _seed_arr(dropout_seed)
 
-    dq = _pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          valid_len=valid_len, dropout_p=dropout_p),
-        name="flash_attention_bwd_dq",
-        grid=(bh, s_len // block_q, s_len // block_k),
-        in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
-        scratch_shapes=[_scratch((block_q, d))],
-        interpret=interpret,
-    )(seed, qr, kr, vr, do, lse, delta)
+    rows = plan.schedule()
+    q_tile, k_tile, q_code, k_code = plan.specs(
+        lambda b, t: (b, _head_div(b, group)))
+    dq = plan.call(
+        _bwd_dq_kernel, "flash_attention_bwd_dq",
+        grid=(plan.bh, len(rows)),
+        in_specs=[q_tile(d), k_tile(d), k_tile(d), q_tile(d), q_tile(1),
+                  q_tile(1), q_code, k_code],
+        out_specs=q_tile(d),
+        out_shape=jax.ShapeDtypeStruct((plan.bh, plan.s_len, d), q.dtype),
+        scratch=[_scratch((plan.block_q, d))],
+        interpret=interpret, scale=scale, dropout_p=dropout_p,
+    )(seed, rows, qr, kr, vr, do, lse, delta, *plan.codes)
 
-    dk, dv = _pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          valid_len=valid_len, dropout_p=dropout_p),
-        name="flash_attention_bwd_dkv",
-        grid=(bh, s_len // block_k, s_len // block_q),
-        in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-        ],
+    by_key = plan.schedule(by_key=True)
+    q_tile, k_tile, q_code, k_code = plan.specs(
+        lambda b, t, j: (b * group + j, b))
+    dk, dv = plan.call(
+        _bwd_dkv_kernel, "flash_attention_bwd_dkv",
+        grid=(plan.bkv, len(by_key), group),
+        in_specs=[q_tile(d), k_tile(d), k_tile(d), q_tile(d), q_tile(1),
+                  q_tile(1), q_code, k_code],
+        out_specs=[k_tile(d), k_tile(d)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_len, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s_len, d), v.dtype),
+            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, d), k.dtype),
+            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, d), v.dtype),
         ],
-        scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
-        interpret=interpret,
-    )(seed, qr, kr, vr, do, lse, delta)
-    shape = (b, h, s_len, d)
-    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+        scratch=[_scratch((plan.block_k, d)), _scratch((plan.block_k, d))],
+        interpret=interpret, scale=scale, dropout_p=dropout_p, group=group,
+    )(seed, by_key, qr, kr, vr, do, lse, delta, *plan.codes)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, seed, causal, scale, block_q, block_k, interpret,
-           dropout_p=0.0, valid_len=None):
+           dropout_p=0.0, valid_len=None, block_diffusion=None):
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                        interpret, valid_len, dropout_p, seed)
+                        interpret, valid_len, dropout_p, seed,
+                        block_diffusion)
     return out
 
 
 def _flash_vjp_fwd(q, k, v, seed, causal, scale, block_q, block_k,
-                   interpret, dropout_p=0.0, valid_len=None):
+                   interpret, dropout_p=0.0, valid_len=None,
+                   block_diffusion=None):
+    from jax.ad_checkpoint import checkpoint_name
+
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                          interpret, valid_len, dropout_p, seed)
+                          interpret, valid_len, dropout_p, seed,
+                          block_diffusion)
+    # named, so that a checkpoint policy can keep the kernel's two results
+    # (SAVED_BY_NAME) and not run the forward kernel a second time
+    out = checkpoint_name(out, SAVED_BY_NAME[0])
+    lse = checkpoint_name(lse, SAVED_BY_NAME[1])
     return out, (q, k, v, seed, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, dropout_p,
-                   valid_len, res, g):
+                   valid_len, block_diffusion, res, g):
     import numpy as _onp
 
     q, k, v, seed, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q,
                             block_k, interpret, valid_len, dropout_p,
-                            seed)
+                            seed, block_diffusion)
     # integer seed takes a float0 cotangent
     return dq, dk, dv, _onp.zeros(seed.shape, jax.dtypes.float0)
 
@@ -487,13 +602,22 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 @register_op("flash_attention")
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                     block_k=128, interpret=None, dropout_p=0.0,
-                    dropout_seed=None):
-    """Fused multi-head attention: softmax(QK^T * scale) V.
+                    dropout_seed=None, block_diffusion=None):
+    """Fused multi-head attention: softmax(QK^T * scale + mask) V.
 
-    q/k/v: (B, H, S, D). Runs the Pallas kernel on TPU (or anywhere with
-    interpret=True); falls back to the jnp reference otherwise. Ragged S
-    is tile-padded and the kernel masks the padded keys (static
-    `valid_len`) — only a ragged head dim D takes the reference path.
+    q: (B, H, S, D); k/v: (B, Hkv, S, D), H a multiple of Hkv (grouped
+    queries: query head h reads key-value head h // (H // Hkv), and the
+    dK/dV kernel sums over the group).  Runs the Pallas kernel on TPU
+    (or anywhere with interpret=True); falls back to the jnp reference
+    otherwise. Ragged S is tile-padded and the kernel masks the padded
+    keys (static `valid_len`) — only a ragged head dim D takes the
+    reference path.
+
+    The mask is static: nothing, ``causal``, or ``block_diffusion=
+    (block length B, half length L)`` for a sequence of S = 2L positions
+    [noisy ; clean] (`_mask_codes` has the rule).  It is evaluated per
+    tile from two small code vectors, and the tiles it empties are left
+    out of the grid in the forward, dQ and dK/dV kernels alike.
 
     dropout_p > 0 with an int32 `dropout_seed` applies attention-prob
     dropout inside the kernel (numerator-masked, inverted scaling; the
@@ -505,11 +629,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
     dropout_p = float(dropout_p)
+    if block_diffusion is not None:
+        block_diffusion = tuple(int(x) for x in block_diffusion)
 
     def _fallback(qq, kk, vv):
         return attention_reference(qq, kk, vv, causal=causal, scale=scale,
                                    dropout_p=dropout_p,
-                                   dropout_seed=dropout_seed)
+                                   dropout_seed=dropout_seed,
+                                   block_diffusion=block_diffusion)
 
     if interpret is None:
         interpret = False
@@ -528,10 +655,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     seed = _seed_arr(dropout_seed)
     if s_pad == s_len:
         return _flash(q, k, v, seed, causal, scale, bq, bk, interpret,
-                      dropout_p)
+                      dropout_p, None, block_diffusion)
     pad = [(0, 0), (0, 0), (0, s_pad - s_len), (0, 0)]
     out = _flash(jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                 seed, causal, scale, bq, bk, interpret, dropout_p, s_len)
+                 seed, causal, scale, bq, bk, interpret, dropout_p, s_len,
+                 block_diffusion)
     return out[:, :, :s_len]
 
 

@@ -9,6 +9,11 @@ dispatch einsums into all-to-alls over ICI.
 The math follows the public GShard/Switch formulation (top-k gating with
 capacity and auxiliary load-balancing loss); the implementation is dense
 einsum routing, the layout XLA maps best onto the MXU.
+
+`dropless_moe` is the other expert layer: top-k routing that drops
+nothing, gated three-matrix experts, and a share of the experts — the
+layer is told which experts it holds, routes over all of them and
+computes its own experts' part of the result (docs/moe.md).
 """
 from __future__ import annotations
 
@@ -17,7 +22,8 @@ import jax.numpy as jnp
 
 _jit_cache = {}
 
-__all__ = ["top_k_routing", "moe_ffn", "moe_ffn_sharded", "init_moe_params"]
+__all__ = ["top_k_routing", "moe_ffn", "moe_ffn_sharded", "init_moe_params",
+           "route_top_k", "dropless_moe"]
 
 
 def top_k_routing(router_logits, num_experts, capacity, top_k=2):
@@ -119,3 +125,147 @@ def moe_ffn_sharded(params, x, mesh, axis="ep", capacity_factor=1.25,
 
     with mesh:
         return run(params, x)
+
+
+# -- dropless routing over a held share of the experts ----------------------
+
+def route_top_k(logits, top_k, normalize=True):
+    """(gates (N, k) float32, experts (N, k) int32) of router ``logits``
+    (N, E): softmax over all E in float32, the k largest, and — with
+    ``normalize`` — the gates divided by their sum over those k."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)
+    if normalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+def _take(x, idx, fill=True):
+    """Rows ``idx`` of ``x``.  An index past the end reads zeros; without
+    ``fill`` it reads the last row, for a buffer whose rows past the
+    routed ones nobody looks at — no pass over it to blank them."""
+    if fill:
+        return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+    return jnp.take(x, idx, axis=0, mode="clip")
+
+
+def _int_zero(a):
+    import numpy as onp
+
+    return onp.zeros(a.shape, jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _dispatch(x, token_of_row, slot):
+    """xs[r] = x[token_of_row[r]]: the tokens in expert order.  ``slot``
+    (N, k) is the row each assignment landed on (past the end: none), so
+    the transpose is k gathers too, never a scatter.  A row past the
+    routed ones holds some token's copy; it belongs to no group."""
+    return _take(x, token_of_row, fill=False)
+
+
+def _dispatch_fwd(x, token_of_row, slot):
+    return _take(x, token_of_row, fill=False), (token_of_row, slot)
+
+
+def _dispatch_bwd(res, dxs):
+    token_of_row, slot = res
+    dx = sum(_take(dxs, slot[:, j]).astype(jnp.float32)
+             for j in range(slot.shape[1]))
+    return dx.astype(dxs.dtype), _int_zero(token_of_row), _int_zero(slot)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, slot, assignment_of_row):
+    """out[n] = sum_j gates[n, j] * ys[slot[n, j]], summed in float32.
+    ``assignment_of_row`` is ``slot``'s inverse (row -> n * k + j, past
+    the end for a row nothing was routed to)."""
+    out = sum(gates[:, j:j + 1] * _take(ys, slot[:, j]).astype(jnp.float32)
+              for j in range(slot.shape[1]))
+    return out.astype(ys.dtype)
+
+
+def _combine_fwd(ys, gates, slot, assignment_of_row):
+    return (_combine(ys, gates, slot, assignment_of_row),
+            (ys, gates, slot, assignment_of_row))
+
+
+def _combine_bwd(res, dout):
+    ys, gates, slot, assignment_of_row = res
+    k = slot.shape[1]
+    gate_of_row = _take(gates.reshape(-1), assignment_of_row)
+    # a row nothing was routed to has gate 0
+    dys = (_take(dout, assignment_of_row // k, fill=False).astype(
+        jnp.float32) * gate_of_row[:, None]).astype(ys.dtype)
+    d32 = dout.astype(jnp.float32)
+    dgates = jnp.stack(
+        [jnp.sum(d32 * _take(ys, slot[:, j]).astype(jnp.float32), axis=-1)
+         for j in range(k)], axis=1)
+    return (dys, dgates.astype(gates.dtype), _int_zero(slot),
+            _int_zero(assignment_of_row))
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
+                 normalize=True):
+    """One chip's share of a dropless mixture of gated experts.
+
+    x: (N, D) tokens; router: (E, D), all E experts of the layer;
+    w_gate, w_up: (held, D, F) and w_down: (held, F, D), the experts
+    ``first_expert .. first_expert + held - 1`` that live here.  With
+    p = softmax_E(router x) in float32, S the ``top_k`` largest and
+    g_e = p_e / sum_S p (``normalize``):
+
+        out = sum over e in S that are held of
+              g_e * w_down[e](silu(w_gate[e] x) * w_up[e] x)
+
+    The sum over the held experts is this chip's part of the layer's
+    result; what an expert held elsewhere adds is left out (the exchange
+    that would add it belongs to the mesh, not to this function).  No
+    token is dropped and there is no capacity: the N * k assignments are
+    sorted by expert, the ones routed elsewhere last, and the grouped
+    products (`jax.lax.ragged_dot`) work on the rows actually routed
+    here — a row past their count belongs to no group and is neither
+    computed nor read back.
+
+    Returns (out (N, D), load (2,) float32): rows routed here, and the
+    largest held expert's rows over the mean of the held experts' rows.
+    """
+    n, _d = x.shape
+    held, _, f = w_gate.shape
+    with jax.named_scope("moe.router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            router.astype(jnp.float32).T,
+                            precision=jax.lax.Precision.HIGHEST)
+        gates, experts = route_top_k(logits, top_k, normalize)
+    with jax.named_scope("moe.dispatch"):
+        local = experts - first_expert
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(-1)         # (N * k,)
+        every = jnp.arange(n * top_k, dtype=jnp.int32)
+        _, order = jax.lax.sort((key, every), num_keys=1)      # stable
+        _, slot = jax.lax.sort((order, every), num_keys=1)     # its inverse
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=jnp.int32)[None],
+            axis=0, dtype=jnp.int32)
+        rows = jnp.sum(group_sizes)
+        assignment_of_row = jnp.where(every < rows, order, n * top_k)
+        slot = jnp.where(here, slot.reshape(n, top_k), n * top_k)
+        xs = _dispatch(x, assignment_of_row // top_k, slot)
+    with jax.named_scope("moe.experts"):
+        # gate and up side by side: the rows are read once, and their
+        # gradient comes back as one product instead of a sum of two
+        gu = jax.lax.ragged_dot(
+            xs, jnp.concatenate([w_gate, w_up], axis=2), group_sizes)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gu[:, :f]) * gu[:, f:], w_down,
+                                group_sizes)
+    with jax.named_scope("moe.combine"):
+        out = _combine(ys, gates, slot, assignment_of_row)
+        load = jnp.stack([rows, jnp.max(group_sizes) * held
+                          / jnp.maximum(rows, 1)]).astype(jnp.float32)
+    return out, load

@@ -34,6 +34,8 @@ __all__ = [
     "record_update_dispatch", "record_fused_bucket",
     "step_dispatch_total", "step_donated_bytes",
     "step_scalar_operands", "record_step_scalar_operands",
+    "moe_rows_routed_here", "moe_expert_load_max_over_mean",
+    "stage_moe_load", "flush_moe_load",
     "pass_applied_total", "pass_rewrite_ms", "graph_dedup_hits_total",
     "remat_policy", "record_pass", "record_dedup_hit",
     "record_remat_policy",
@@ -842,6 +844,50 @@ def record_step_dispatch(path, donated_bytes=0):
     step_dispatch_total.labels(path).inc()
     if donated_bytes:
         step_donated_bytes.inc(donated_bytes)
+
+
+moe_rows_routed_here = gauge(
+    "moe_rows_routed_here",
+    "Token-to-expert assignments of the last step that an expert layer "
+    "routed to the experts it holds (of tokens x experts-per-token in "
+    "all): the rows its grouped products worked on. Produced on the "
+    "device; set by flush_moe_load()", ["layer"])
+moe_expert_load_max_over_mean = gauge(
+    "moe_expert_load_max_over_mean",
+    "Rows of the busiest held expert over the mean of the held experts' "
+    "rows in the last step: 1 is an even load. Produced on the device; "
+    "set by flush_moe_load()", ["layer"])
+
+# layer -> the (2,) device array its last step produced; kept on the
+# device until somebody asks
+_staged_moe_load = {}
+
+
+def stage_moe_load(layer, load):
+    """An expert layer's step produced ``load`` = [rows routed here,
+    load max over mean] on the device.  Keeps the array, fetches
+    nothing: a step gains no host sync."""
+    if REGISTRY.enabled:
+        _staged_moe_load[layer] = load
+
+
+def flush_moe_load():
+    """Fetch what the last step staged and set the two gauges; returns
+    {layer: (rows routed here, load max over mean)}.  This is the one
+    device-to-host read, so call it where the loop reads the loss."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    out = {}
+    layers = sorted(_staged_moe_load)
+    loads = np.asarray(jnp.stack([_staged_moe_load[n] for n in layers])) \
+        if layers else ()
+    for layer, (rows, ratio) in zip(layers, loads):
+        rows, ratio = float(rows), float(ratio)
+        moe_rows_routed_here.labels(layer).set(rows)
+        moe_expert_load_max_over_mean.labels(layer).set(ratio)
+        out[layer] = (rows, ratio)
+    return out
 
 
 def record_step_scalar_operands(operands):

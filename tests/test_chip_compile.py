@@ -96,6 +96,29 @@ def test_flash_attention_compiles_for_v5e(spec, seq, case):
         assert calls == 3      # forward, dQ, dK/dV
 
 
+# -- flash attention: SDAR's 32 query over 4 key-value heads of 128, 2 x 4096
+#    positions under the block-diffusion mask, tiles of 512 ------------------
+
+@pytest.mark.parametrize("case", ["fwd", "grad"])
+def test_grouped_block_diffusion_attention_compiles_for_v5e(spec, case):
+    from mxnet_tpu.ops.pallas_attention import flash_attention
+
+    q = spec((1, 32, 8192, 128), jnp.bfloat16)
+    kv = spec((1, 4, 8192, 128), jnp.bfloat16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, block_q=512,
+                               block_k=512, block_diffusion=(4, 4096))
+
+    if case == "fwd":
+        assert _kernel_calls(attend, q, kv, kv) == 1
+    else:
+        calls = _kernel_calls(jax.grad(
+            lambda *a: attend(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), q, kv, kv)
+        assert calls == 3      # forward, dQ, dK/dV summed over the group
+
+
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 class _Lowered(Exception):
