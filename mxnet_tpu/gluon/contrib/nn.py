@@ -85,7 +85,8 @@ class DroplessMoE(HybridBlock):
     the device: [assignments routed to the held experts, busiest held
     expert's rows over their mean].  `telemetry.flush_moe_load()` reads
     it into the gauges ``moe_rows_routed_here`` and
-    ``moe_expert_load_max_over_mean``.
+    ``moe_expert_load_max_over_mean``, and sets ``moe_buffer_rows``: the
+    length of the row buffer that step ran on (docs/moe.md).
     """
 
     def __init__(self, in_units, hidden_units, num_experts, top_k, *,
@@ -114,10 +115,16 @@ class DroplessMoE(HybridBlock):
             init=weight_initializer)
         self.running_load = Parameter("running_load", shape=(2,), init="zeros",
                               grad_req="null", differentiable=False)
-        self.running_load.is_moe_load = True    # TrainStep stages it
+        # the buffer lengths of the shapes last traced: TrainStep stages
+        # the counters with them
+        self.running_load.moe_rungs = None
 
     def forward(self, x):
         from ...parallel import moe as _moe
+
+        rungs = self.running_load.moe_rungs = _moe.buffer_rungs(
+            x.size // x.shape[-1] * self._top_k,
+            self.router.shape[0] // self.gate_proj.shape[0])
 
         def pure(xv, r, g, u, d):
             out, load = _moe.dropless_moe(
@@ -138,7 +145,7 @@ class DroplessMoE(HybridBlock):
                 self.running_load.data_for(x)._assign_from(load.detach())
                 _telemetry.stage_moe_load(
                     getattr(self, "_scope_name", None)
-                    or type(self).__name__, load._data)
+                    or type(self).__name__, load._data, rungs)
         return out
 
     def __repr__(self):

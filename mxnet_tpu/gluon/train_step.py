@@ -722,11 +722,12 @@ class TrainStep:
                 target = p.data() if isinstance(p, Parameter) else p
                 target._data = v
                 target._version += 1
-                if getattr(p, "is_moe_load", False):
+                if getattr(p, "moe_rungs", None):
                     # an expert layer's counters: the array stays on the
                     # device until telemetry.flush_moe_load() is asked
                     _telemetry.stage_moe_load(
-                        self._name_of[id(p)].rpartition(".")[0], v)
+                        self._name_of[id(p)].rpartition(".")[0], v,
+                        p.moe_rungs)
         with _spans.span("train_step.bookkeeping"):
             # what the program's own instrumentation costs per step
             _telemetry.record_step_dispatch(

@@ -17,13 +17,16 @@ computes its own experts' part of the result (docs/moe.md).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 _jit_cache = {}
 
 __all__ = ["top_k_routing", "moe_ffn", "moe_ffn_sharded", "init_moe_params",
-           "route_top_k", "dropless_moe"]
+           "route_top_k", "dropless_moe", "buffer_rungs", "rung_index"]
 
 
 def top_k_routing(router_logits, num_experts, capacity, top_k=2):
@@ -140,75 +143,139 @@ def route_top_k(logits, top_k, normalize=True):
     return gates, experts.astype(jnp.int32)
 
 
-def _take(x, idx, fill=True):
-    """Rows ``idx`` of ``x``.  An index past the end reads zeros; without
-    ``fill`` it reads the last row, for a buffer whose rows past the
-    routed ones nobody looks at — no pass over it to blank them."""
-    if fill:
-        return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+def buffer_rungs(assignments, share):
+    """The lengths a share's row buffer may take: 1.5 and 3 times
+    ``assignments / share`` (what an even routing sends to one of ``share``
+    chips) while that stays under ``assignments``, and always
+    ``assignments`` itself — so nothing is ever dropped.  Few, because
+    every rung is a copy of the layer's program, its grouped-product
+    kernels included, that a process has to load before its first step."""
+    even = -(-assignments // share)
+    return tuple(even * m // 2 for m in (3, 6)
+                 if even * m // 2 < assignments) + (assignments,)
+
+
+def rung_index(rungs, rows):
+    """Index of the first of ``rungs`` that holds ``rows`` rows.  ``rows``
+    is a count traced on the device (`dropless_moe`) or one fetched to the
+    host (`telemetry.instruments.flush_moe_load`)."""
+    xp = jnp if isinstance(rows, jax.Array) else onp
+    return xp.sum(xp.asarray(rungs) < rows)
+
+
+def _take(x, idx):
+    """Rows ``idx`` of ``x``; every index is in range (no pass to blank
+    the rows of one that is not)."""
     return jnp.take(x, idx, axis=0, mode="clip")
 
 
-def _int_zero(a):
-    import numpy as onp
-
-    return onp.zeros(a.shape, jax.dtypes.float0)
-
-
-@jax.custom_vjp
-def _dispatch(x, token_of_row, slot):
-    """xs[r] = x[token_of_row[r]]: the tokens in expert order.  ``slot``
-    (N, k) is the row each assignment landed on (past the end: none), so
-    the transpose is k gathers too, never a scatter.  A row past the
-    routed ones holds some token's copy; it belongs to no group."""
-    return _take(x, token_of_row, fill=False)
+def _at_rung(rungs, body, group_sizes, *operands):
+    """``body(c, *operands)`` at the first rung ``c`` that holds the routed
+    rows, chosen on the device.  One rung is no conditional."""
+    branches = [functools.partial(body, c) for c in rungs]
+    if len(branches) == 1:
+        return branches[0](*operands)
+    return jax.lax.switch(rung_index(rungs, jnp.sum(group_sizes)), branches,
+                          *operands)
 
 
-def _dispatch_fwd(x, token_of_row, slot):
-    return _take(x, token_of_row, fill=False), (token_of_row, slot)
+def _experts(xs, wgu, w_down, group_sizes):
+    """down(silu(gate xs) * up xs) of each row by its group's expert."""
+    f = w_down.shape[1]
+    # gate and up side by side: the rows are read once, and their
+    # gradient comes back as one product instead of a sum of two
+    gu = jax.lax.ragged_dot(xs, wgu, group_sizes)
+    return jax.lax.ragged_dot(jax.nn.silu(gu[:, :f]) * gu[:, f:], w_down,
+                              group_sizes)
 
 
-def _dispatch_bwd(res, dxs):
-    token_of_row, slot = res
-    dx = sum(_take(dxs, slot[:, j]).astype(jnp.float32)
-             for j in range(slot.shape[1]))
-    return dx.astype(dxs.dtype), _int_zero(token_of_row), _int_zero(slot)
+def _rows(c, gates, order, group_sizes):
+    """Of the buffer's ``c`` rows, the first of the sorted order: each
+    row's assignment (n * k + j), its token, its gate, and whether
+    anything was routed to it (a row past the routed ones holds some
+    other chip's assignment; it belongs to no group)."""
+    a = order[:c]
+    routed = jnp.arange(c, dtype=jnp.int32) < jnp.sum(group_sizes)
+    gate = jnp.where(routed, _take(gates.reshape(-1), a), 0.0)
+    return a, a // gates.shape[1], gate, routed
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _sum_by_token(rows, token_of_row, n):
+    """out[t] = the float32 sum of the ``rows`` whose token is t."""
+    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[token_of_row].add(
+        rows)
 
 
-@jax.custom_vjp
-def _combine(ys, gates, slot, assignment_of_row):
-    """out[n] = sum_j gates[n, j] * ys[slot[n, j]], summed in float32.
-    ``assignment_of_row`` is ``slot``'s inverse (row -> n * k + j, past
-    the end for a row nothing was routed to)."""
-    out = sum(gates[:, j:j + 1] * _take(ys, slot[:, j]).astype(jnp.float32)
-              for j in range(slot.shape[1]))
-    return out.astype(ys.dtype)
+def _forward_at(c, x, gates, order, group_sizes, wgu, w_down):
+    _, tok, gate, routed = _rows(c, gates, order, group_sizes)
+    with jax.named_scope("moe.dispatch"):
+        xs = _take(x, tok)
+    with jax.named_scope("moe.experts"):
+        ys = _experts(xs, wgu, w_down, group_sizes)
+    with jax.named_scope("moe.combine"):
+        # what the grouped products left in a row of no group is not a
+        # number anybody may read: select, never multiply by 0
+        out = _sum_by_token(
+            jnp.where(routed[:, None],
+                      gate[:, None] * ys.astype(jnp.float32), 0.0),
+            tok, x.shape[0])
+    return out.astype(x.dtype)
 
 
-def _combine_fwd(ys, gates, slot, assignment_of_row):
-    return (_combine(ys, gates, slot, assignment_of_row),
-            (ys, gates, slot, assignment_of_row))
+def _backward_at(c, x, gates, order, group_sizes, wgu, w_down, dout):
+    a, tok, gate, routed = _rows(c, gates, order, group_sizes)
+    with jax.named_scope("moe.dispatch"):
+        xs = _take(x, tok)
+    with jax.named_scope("moe.experts"):
+        ys, pull = jax.vjp(
+            lambda xs, wgu, w_down: _experts(xs, wgu, w_down, group_sizes),
+            xs, wgu, w_down)
+    with jax.named_scope("moe.combine"):
+        dy = _take(dout, tok).astype(jnp.float32)
+        dys = (gate[:, None] * dy).astype(ys.dtype)
+        dgate = jnp.where(
+            routed, jnp.sum(ys.astype(jnp.float32) * dy, axis=-1), 0.0)
+        dgates = jnp.zeros(gates.size, jnp.float32).at[a].set(
+            dgate, unique_indices=True).reshape(gates.shape)
+    with jax.named_scope("moe.experts"):
+        dxs, dwgu, dw_down = pull(dys)
+    with jax.named_scope("moe.dispatch"):
+        dx = _sum_by_token(
+            jnp.where(routed[:, None], dxs.astype(jnp.float32), 0.0),
+            tok, x.shape[0])
+    return dx.astype(x.dtype), dgates.astype(gates.dtype), dwgu, dw_down
 
 
-def _combine_bwd(res, dout):
-    ys, gates, slot, assignment_of_row = res
-    k = slot.shape[1]
-    gate_of_row = _take(gates.reshape(-1), assignment_of_row)
-    # a row nothing was routed to has gate 0
-    dys = (_take(dout, assignment_of_row // k, fill=False).astype(
-        jnp.float32) * gate_of_row[:, None]).astype(ys.dtype)
-    d32 = dout.astype(jnp.float32)
-    dgates = jnp.stack(
-        [jnp.sum(d32 * _take(ys, slot[:, j]).astype(jnp.float32), axis=-1)
-         for j in range(k)], axis=1)
-    return (dys, dgates.astype(gates.dtype), _int_zero(slot),
-            _int_zero(assignment_of_row))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(rungs, x, gates, order, group_sizes, wgu, w_down):
+    """out[t] = sum over the rows r of token t of gate[r] * experts(x[t]),
+    in float32, at the first of ``rungs`` that holds the routed rows.
+    ``order`` lists the N * k assignments by held expert, the ones routed
+    elsewhere last.
+
+    The conditional sits inside this function and inside its backward
+    rule, never under JAX's transpose: a transposed `lax.switch` has every
+    branch hand back every other branch's residuals as zeros, N * k rows
+    each.  What is kept for the backward rule has one shape in every rung
+    (the operands themselves), and the rule computes the rows again at its
+    rung."""
+    return _at_rung(rungs, _forward_at, group_sizes,
+                    x, gates, order, group_sizes, wgu, w_down)
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+def _held_experts_fwd(rungs, *operands):
+    return _held_experts(rungs, *operands), operands
+
+
+def _held_experts_bwd(rungs, operands, dout):
+    _, _, order, group_sizes, _, _ = operands
+    dx, dgates, dwgu, dw_down = _at_rung(
+        rungs, _backward_at, group_sizes, *operands, dout)
+    return (dx, dgates, onp.zeros(order.shape, jax.dtypes.float0),
+            onp.zeros(group_sizes.shape, jax.dtypes.float0), dwgu, dw_down)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
@@ -228,16 +295,18 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
     result; what an expert held elsewhere adds is left out (the exchange
     that would add it belongs to the mesh, not to this function).  No
     token is dropped and there is no capacity: the N * k assignments are
-    sorted by expert, the ones routed elsewhere last, and the grouped
-    products (`jax.lax.ragged_dot`) work on the rows actually routed
-    here — a row past their count belongs to no group and is neither
-    computed nor read back.
+    sorted by expert, the ones routed elsewhere last, and the layer runs
+    on the sorted order's first C entries, C the first of
+    ``buffer_rungs(N * k, E / held)`` that holds the rows routed here —
+    chosen on the device, call by call; the last rung is N * k.  Gathers,
+    grouped products (`jax.lax.ragged_dot`) and the sums back to the
+    tokens all work on C rows.
 
     Returns (out (N, D), load (2,) float32): rows routed here, and the
     largest held expert's rows over the mean of the held experts' rows.
     """
-    n, _d = x.shape
-    held, _, f = w_gate.shape
+    n = x.shape[0]
+    held = w_gate.shape[0]
     with jax.named_scope("moe.router"):
         logits = jnp.matmul(x.astype(jnp.float32),
                             router.astype(jnp.float32).T,
@@ -245,27 +314,17 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
         gates, experts = route_top_k(logits, top_k, normalize)
     with jax.named_scope("moe.dispatch"):
         local = experts - first_expert
-        here = (local >= 0) & (local < held)
-        key = jnp.where(here, local, held).reshape(-1)         # (N * k,)
-        every = jnp.arange(n * top_k, dtype=jnp.int32)
-        _, order = jax.lax.sort((key, every), num_keys=1)      # stable
-        _, slot = jax.lax.sort((order, every), num_keys=1)     # its inverse
+        key = jnp.where((local >= 0) & (local < held), local,
+                        held).reshape(-1)                      # (N * k,)
+        _, order = jax.lax.sort(
+            (key, jnp.arange(n * top_k, dtype=jnp.int32)), num_keys=1)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=jnp.int32)[None],
             axis=0, dtype=jnp.int32)
         rows = jnp.sum(group_sizes)
-        assignment_of_row = jnp.where(every < rows, order, n * top_k)
-        slot = jnp.where(here, slot.reshape(n, top_k), n * top_k)
-        xs = _dispatch(x, assignment_of_row // top_k, slot)
-    with jax.named_scope("moe.experts"):
-        # gate and up side by side: the rows are read once, and their
-        # gradient comes back as one product instead of a sum of two
-        gu = jax.lax.ragged_dot(
-            xs, jnp.concatenate([w_gate, w_up], axis=2), group_sizes)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gu[:, :f]) * gu[:, f:], w_down,
-                                group_sizes)
-    with jax.named_scope("moe.combine"):
-        out = _combine(ys, gates, slot, assignment_of_row)
         load = jnp.stack([rows, jnp.max(group_sizes) * held
                           / jnp.maximum(rows, 1)]).astype(jnp.float32)
+    out = _held_experts(
+        buffer_rungs(n * top_k, router.shape[0] // held), x, gates, order,
+        group_sizes, jnp.concatenate([w_gate, w_up], axis=2), w_down)
     return out, load

@@ -35,7 +35,7 @@ __all__ = [
     "step_dispatch_total", "step_donated_bytes",
     "step_scalar_operands", "record_step_scalar_operands",
     "moe_rows_routed_here", "moe_expert_load_max_over_mean",
-    "stage_moe_load", "flush_moe_load",
+    "moe_buffer_rows", "stage_moe_load", "flush_moe_load",
     "pass_applied_total", "pass_rewrite_ms", "graph_dedup_hits_total",
     "remat_policy", "record_pass", "record_dedup_hit",
     "record_remat_policy",
@@ -857,35 +857,45 @@ moe_expert_load_max_over_mean = gauge(
     "Rows of the busiest held expert over the mean of the held experts' "
     "rows in the last step: 1 is an even load. Produced on the device; "
     "set by flush_moe_load()", ["layer"])
+moe_buffer_rows = gauge(
+    "moe_buffer_rows",
+    "Rows of the buffer an expert layer's last step worked on: the first "
+    "of the layer's static lengths (parallel.moe.buffer_rungs) that holds "
+    "moe_rows_routed_here. The layer takes it on the device; "
+    "flush_moe_load() works it out again from the fetched count", ["layer"])
 
-# layer -> the (2,) device array its last step produced; kept on the
-# device until somebody asks
+# layer -> (the (2,) device array its last step produced, the layer's
+# buffer lengths); the array stays on the device until somebody asks
 _staged_moe_load = {}
 
 
-def stage_moe_load(layer, load):
+def stage_moe_load(layer, load, rungs):
     """An expert layer's step produced ``load`` = [rows routed here,
-    load max over mean] on the device.  Keeps the array, fetches
-    nothing: a step gains no host sync."""
+    load max over mean] on the device, on a buffer of one of ``rungs``
+    rows.  Keeps the array, fetches nothing: a step gains no host sync."""
     if REGISTRY.enabled:
-        _staged_moe_load[layer] = load
+        _staged_moe_load[layer] = (load, rungs)
 
 
 def flush_moe_load():
-    """Fetch what the last step staged and set the two gauges; returns
+    """Fetch what the last step staged and set the three gauges; returns
     {layer: (rows routed here, load max over mean)}.  This is the one
     device-to-host read, so call it where the loop reads the loss."""
     import jax.numpy as jnp
     import numpy as np
 
+    from ..parallel.moe import rung_index
+
     out = {}
     layers = sorted(_staged_moe_load)
-    loads = np.asarray(jnp.stack([_staged_moe_load[n] for n in layers])) \
+    loads = np.asarray(jnp.stack([_staged_moe_load[n][0] for n in layers])) \
         if layers else ()
     for layer, (rows, ratio) in zip(layers, loads):
         rows, ratio = float(rows), float(ratio)
+        rungs = _staged_moe_load[layer][1]
         moe_rows_routed_here.labels(layer).set(rows)
         moe_expert_load_max_over_mean.labels(layer).set(ratio)
+        moe_buffer_rows.labels(layer).set(rungs[rung_index(rungs, rows)])
         out[layer] = (rows, ratio)
     return out
 

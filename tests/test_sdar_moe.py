@@ -2,6 +2,7 @@
 CPU: the model against the benchmark's plain reference, the flash kernel
 with grouped heads under the block-diffusion mask, the expert layer that
 holds a share, and the whole step with its counters and scopes."""
+import importlib.util
 import json
 import os
 import sys
@@ -294,18 +295,25 @@ def test_the_eight_shares_add_up_to_the_uncut_reference_layer(bench):
     assert rows == x.shape[0] * 4           # every assignment, once
 
 
+def _dense(first, top_k):
+    """The layer's formula, expert by expert over every token."""
+    def dense(x, r, wg, wu, wd):
+        g, idx = moe.route_top_k(x @ r.T, top_k)
+        out = 0.0
+        for e in range(wg.shape[0]):
+            ge = jnp.sum(jnp.where(idx == first + e, g, 0.0), -1)[:, None]
+            out = out + ge * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+        return out
+    return dense
+
+
 def test_a_shares_gradients_match_the_dense_formula():
     x, p = _layer_weights(1)
     args = (x, p["l.mlp.router"], p["l.mlp.gate_proj"][4:8],
             p["l.mlp.up_proj"][4:8], p["l.mlp.down_proj"][4:8])
 
-    def dense(x, r, wg, wu, wd):
-        g, idx = moe.route_top_k(x @ r.T, 4)
-        out = 0.0
-        for e in range(4):
-            ge = jnp.sum(jnp.where(idx == 4 + e, g, 0.0), -1)[:, None]
-            out = out + ge * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
-        return jnp.sum(out ** 2)
+    def dense(*a):
+        return jnp.sum(_dense(4, 4)(*a) ** 2)
 
     def sparse(*a):
         return jnp.sum(moe.dropless_moe(*a, top_k=4, first_expert=4)[0] ** 2)
@@ -333,6 +341,140 @@ def test_routing_drops_nothing_when_every_token_picks_one_expert():
         x, router, p["l.mlp.gate_proj"][8:12], p["l.mlp.up_proj"][8:12],
         p["l.mlp.down_proj"][8:12], top_k=1, first_expert=8)
     assert load[0] == 0 and not jnp.any(out)
+
+
+def _routed_layer(both, one, held=slice(4, 8), n=64, seed=3):
+    """Weights under which the first ``both`` tokens pick two held experts,
+    the next ``one`` tokens one held expert (the first) and one held
+    elsewhere, and every other token none: 2 * both + one rows routed
+    here, gates and values still the seed's."""
+    x, p = _layer_weights(seed, n=n)
+    router = p["l.mlp.router"] / 6                 # noise, std ~0.2
+    x = x.at[:, :3].set(0.0).at[:, 0].set(1.0)
+    x = x.at[:both, 1].set(1.0).at[both:both + one, 2].set(1.0)
+    here = jnp.zeros(router.shape[0], bool).at[held].set(True)
+    router = router.at[:, 0].set(jnp.where(here, 0.0, 3.0))
+    router = router.at[:, 1].set(jnp.where(here, 6.0, 0.0))
+    router = router.at[:, 2].set(0.0).at[held.start, 2].set(12.0)
+    return (x, router, p["l.mlp.gate_proj"][held], p["l.mlp.up_proj"][held],
+            p["l.mlp.down_proj"][held])
+
+
+@pytest.mark.parametrize("both,one,held,rung", [
+    (0, 0, slice(4, 8), 48),        # no row routed here: the first rung
+    (24, 0, slice(4, 8), 48),       # exactly a rung
+    (24, 1, slice(4, 8), 96),       # a rung + 1: the next one
+    (45, 7, slice(4, 8), 128),      # past the last rung under N * k
+    (64, 0, slice(4, 8), 128),      # every row routed here: N * k rows
+    (24, 5, slice(0, 16), 128),     # ep_size=1: one rung, all 16 held
+], ids=["none", "a-rung", "a-rung-plus-1", "over-half", "every-row",
+        "ep_size-1"])
+def test_every_rung_matches_the_dense_formula(both, one, held, rung):
+    args = _routed_layer(both, one, held)
+    first, count = held.start, held.stop - held.start
+    dense = _dense(first, 2)
+
+    def sparse(*a):
+        return moe.dropless_moe(*a, top_k=2, first_expert=first)
+
+    out, load = sparse(*args)
+    rows = 2 * 64 if count == 16 else 2 * both + one
+    assert load[0] == rows
+    rungs = moe.buffer_rungs(2 * 64, 16 // count)
+    assert rungs == ((48, 96, 128) if count == 4 else (128,))
+    assert rungs[int(moe.rung_index(rungs, load[0]))] == rung
+    onp.testing.assert_allclose(out, dense(*args), rtol=1e-5, atol=1e-6)
+    w = _layer_weights(9)[0]        # a cotangent that is not the output's
+    for got, want in zip(
+            jax.grad(lambda *a: jnp.sum(sparse(*a)[0] * w), range(5))(*args),
+            jax.grad(lambda *a: jnp.sum(dense(*a) * w), range(5))(*args)):
+        onp.testing.assert_allclose(
+            got, want, rtol=2e-4,
+            atol=1e-5 * max(float(jnp.abs(want).max()), 1e-3))
+
+
+def _arrays_of(jaxpr):
+    """Every array an equation of ``jaxpr`` makes, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _arrays_of(sub)
+
+
+def _conds(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _conds(sub)
+
+
+def test_only_the_last_rung_holds_a_buffer_of_every_assignment():
+    """Counts, not times: at a share of 8 the layer's forward and backward
+    are one conditional each, and no branch but the last makes an array of
+    N * k rows (the gates and their gradient, (N, k) and N * k numbers,
+    are what a rung may hold of that length); a layer that holds every
+    expert has no conditional at all."""
+    x, p = _layer_weights(4)
+    n, k = x.shape[0], 4
+    rungs = moe.buffer_rungs(n * k, 8)
+    assert rungs == (48, 96, 256)
+    assert rungs[int(moe.rung_index(rungs, n * k))] == n * k
+    assert int(moe.rung_index(rungs, 0)) == 0
+    assert [int(moe.rung_index(rungs, r)) for r in (48, 49, 97)] == [0, 1, 2]
+    assert moe.buffer_rungs(n * k, 1) == (n * k,)
+    assert moe.buffer_rungs(n * k, 2) == (192, 256)
+    assert len(moe.buffer_rungs(1 << 20, 128)) == 3     # never more
+
+    def loss(held, *a):
+        return jnp.sum(moe.dropless_moe(
+            a[0], a[1], *(w[:held] for w in a[2:]), top_k=k)[0] ** 2)
+
+    args = (x, p["l.mlp.router"], p["l.mlp.gate_proj"], p["l.mlp.up_proj"],
+            p["l.mlp.down_proj"])
+    share = jax.make_jaxpr(jax.grad(lambda *a: loss(2, *a), range(5)))(*args)
+    conds = list(_conds(share.jaxpr))
+    assert len(conds) == 2                          # forward, backward
+    for cond in conds:
+        *lower, top = cond.params["branches"]
+        assert len(lower) == 2
+        for rung, branch in zip(rungs, lower):
+            shapes = {a.shape for a in _arrays_of(branch.jaxpr)
+                      if getattr(a, "ndim", 0) > 1}
+            assert shapes and not any(s[0] == n * k for s in shapes), shapes
+            assert any(s[0] == rung for s in shapes)
+        assert any(a.shape[:1] == (n * k,) and a.ndim > 1
+                   for a in _arrays_of(top.jaxpr))
+    whole = jax.make_jaxpr(jax.grad(lambda *a: loss(16, *a), range(5)))(*args)
+    assert not list(_conds(whole.jaxpr))
+
+
+def test_the_flush_sets_the_buffers_rows_from_the_fetched_count():
+    from mxnet_tpu import autograd
+    from mxnet_tpu.telemetry import instruments as ti
+
+    ti.flush_moe_load()             # whatever an earlier test staged
+    ti.moe_buffer_rows.clear()
+    x, router, wg, wu, wd = _routed_layer(16, 1)
+    layer = DroplessMoE(16, 12, 16, 2, ep_size=4, ep_rank=1)
+    layer.initialize()
+    for param, value in ((layer.router, router), (layer.gate_proj, wg),
+                         (layer.up_proj, wu), (layer.down_proj, wd)):
+        param.set_data(NDArray(value))
+    with autograd.record():
+        layer(NDArray(x))
+    assert not ti.moe_buffer_rows.series()      # the step fetched nothing
+    assert ti.flush_moe_load()["DroplessMoE"][0] == 33
+    assert ti.moe_rows_routed_here.labels("DroplessMoE").value == 33
+    assert ti.moe_buffer_rows.labels("DroplessMoE").value == 48
+    # and the benchmark's reader divides the one by the other
+    spec = importlib.util.spec_from_file_location("reader", os.path.join(
+        BENCH, "layer_metrics", "moe_buffer_rows_over_routed.train.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({}, {}) == pytest.approx(48 / 33)
+    ti.moe_buffer_rows.clear()
+    assert reader.read({}, {}) is None          # a program without it
 
 
 def test_the_block_is_told_its_share():
@@ -369,6 +511,8 @@ def test_train_step_takes_it_whole_with_counters_and_scopes(toy):
     assert not onp.array_equal(
         before, net.lm_head.weight.data().asnumpy().astype("f"))
     # the counters: produced by the step, fetched only when asked
+    assert not [k for k, _ in ti.moe_buffer_rows.series()
+                if k[0].startswith("model.layers")]     # nothing fetched yet
     load = ti.flush_moe_load()
     layers = [f"model.layers.{i}.mlp"
               for i in range(cfg["num_hidden_layers"])]
@@ -378,6 +522,11 @@ def test_train_step_takes_it_whole_with_counters_and_scopes(toy):
         assert 0 < rows <= tokens and 1.0 <= ratio <= cfg["num_experts"]
         assert ti.moe_rows_routed_here.labels(layer).value == rows
         assert ti.moe_expert_load_max_over_mean.labels(layer).value == ratio
+        # the buffer's length that step took: the first rung to hold them
+        rungs = moe.buffer_rungs(tokens, cfg["ep_size"])
+        assert rungs == (tokens * 3 // 4, tokens)
+        assert ti.moe_buffer_rows.labels(layer).value == min(
+            r for r in rungs if r >= rows)
     assert ti.step_scalar_operands.value == 4
     # the scopes the compile registry resolves
     scopes = set()
