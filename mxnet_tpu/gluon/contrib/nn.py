@@ -8,17 +8,22 @@ capability (shard the expert dimension over an 'ep' mesh axis).
 `DroplessMoE` — the expert layer that holds a share: top-k routing that
 drops nothing over gated three-matrix experts, told which experts live
 here (`ep_rank` of `ep_size`); `parallel.moe.dropless_moe` is its
-functional part (docs/moe.md).
+functional part (docs/moe.md).  `GatedMLP` is one such expert as a plain
+block: a dense feed-forward layer, and the shared expert `DroplessMoE`
+adds beside the routed ones.
 """
 from __future__ import annotations
+
+import jax
 
 from ... import autograd as ag
 from ...ndarray.ndarray import apply_op
 from ...telemetry import instruments as _telemetry
 from ..block import HybridBlock, current_state_sink
+from ..nn import Dense
 from ..parameter import Parameter
 
-__all__ = ["MoEDense", "DroplessMoE"]
+__all__ = ["MoEDense", "GatedMLP", "DroplessMoE"]
 
 
 class MoEDense(HybridBlock):
@@ -66,6 +71,27 @@ class MoEDense(HybridBlock):
                 f"capacity_factor={self._cf})")
 
 
+class GatedMLP(HybridBlock):
+    """down(silu(gate x) * up x) over the last axis, no biases:
+    (..., in_units) -> (..., in_units) through ``hidden_units``."""
+
+    def __init__(self, in_units, hidden_units, dtype="float32"):
+        super().__init__()
+
+        def proj(out_units, in_units):
+            return Dense(out_units, use_bias=False, flatten=False,
+                         dtype=dtype, in_units=in_units)
+
+        self.gate_proj = proj(hidden_units, in_units)
+        self.up_proj = proj(hidden_units, in_units)
+        self.down_proj = proj(in_units, hidden_units)
+
+    def forward(self, x):
+        mid = apply_op(lambda g, u: jax.nn.silu(g) * u, self.gate_proj(x),
+                       self.up_proj(x), name="silu_mul")
+        return self.down_proj(mid)
+
+
 class DroplessMoE(HybridBlock):
     """A chip's share of a dropless mixture of gated experts.
 
@@ -81,29 +107,54 @@ class DroplessMoE(HybridBlock):
     all ``ep_size`` shares add up to the whole (tests/test_sdar_moe.py).
     No token is dropped; there is no capacity and no auxiliary loss.
 
+    The router's variants (`parallel.moe.route_top_k`):
+    ``scoring_func="sigmoid"`` scores each expert by a sigmoid of its own
+    logit; ``selection_bias=True`` adds the parameter ``router_bias``
+    (``num_experts``,) — float32, kept so by amp, no gradient — to the
+    scores for the choice of the ``top_k`` alone, never to a gate;
+    ``routed_scaling_factor`` multiplies every gate.  ``shared_units``
+    adds a shared expert ``shared``, a `GatedMLP` of that width that every
+    token passes: it is computed whole on every share, under the scope
+    ``moe.shared``, and summed outside the share's partial result, so it
+    counts once when the shares are added (tests/test_deepseek_v3.py).
+
     ``running_load`` (not trained) holds what the last training step counted on
     the device: [assignments routed to the held experts, busiest held
-    expert's rows over their mean].  `telemetry.flush_moe_load()` reads
-    it into the gauges ``moe_rows_routed_here`` and
-    ``moe_expert_load_max_over_mean``, and sets ``moe_buffer_rows``: the
-    length of the row buffer that step ran on (docs/moe.md).
+    expert's rows over their mean] and, with a selection bias, the share
+    of the assignments the bias changed.  `telemetry.flush_moe_load()`
+    reads it into the gauges ``moe_rows_routed_here``,
+    ``moe_expert_load_max_over_mean`` and ``moe_bias_moved_share``, and
+    sets ``moe_buffer_rows``: the length of the row buffer that step ran
+    on (docs/moe.md).
     """
 
     def __init__(self, in_units, hidden_units, num_experts, top_k, *,
                  ep_size=1, ep_rank=0, normalize_top_k=True,
+                 scoring_func="softmax", selection_bias=False,
+                 routed_scaling_factor=1.0, shared_units=None,
                  dtype="float32", weight_initializer=None):
         super().__init__()
         if num_experts % ep_size or not 0 <= ep_rank < ep_size:
             raise ValueError(
                 f"{num_experts} experts over ep_size={ep_size}, "
                 f"ep_rank={ep_rank}: not a share")
+        if scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func {scoring_func!r}: 'softmax' or "
+                             "'sigmoid'")
         held = num_experts // ep_size
         self._top_k = int(top_k)
         self._first = int(ep_rank) * held
         self._normalize = bool(normalize_top_k)
-        # the router stays float32 under amp.convert_hybrid_block
+        self._scoring = scoring_func
+        self._scale = float(routed_scaling_factor)
+        # the router, and its bias, stay float32 under
+        # amp.convert_hybrid_block
         self.router = Parameter("router", shape=(num_experts, in_units),
                                 init=weight_initializer)
+        self.router_bias = Parameter(
+            "router_bias", shape=(num_experts,), init="zeros",
+            grad_req="null", differentiable=False) if selection_bias \
+            else None
         self.gate_proj = Parameter(
             "gate_proj", shape=(held, in_units, hidden_units), dtype=dtype,
             init=weight_initializer)
@@ -113,11 +164,14 @@ class DroplessMoE(HybridBlock):
         self.down_proj = Parameter(
             "down_proj", shape=(held, hidden_units, in_units), dtype=dtype,
             init=weight_initializer)
-        self.running_load = Parameter("running_load", shape=(2,), init="zeros",
-                              grad_req="null", differentiable=False)
+        self.running_load = Parameter(
+            "running_load", shape=(3 if selection_bias else 2,),
+            init="zeros", grad_req="null", differentiable=False)
         # the buffer lengths of the shapes last traced: TrainStep stages
         # the counters with them
         self.running_load.moe_rungs = None
+        self.shared = GatedMLP(in_units, shared_units, dtype) \
+            if shared_units else None
 
     def forward(self, x):
         from ...parallel import moe as _moe
@@ -126,16 +180,19 @@ class DroplessMoE(HybridBlock):
             x.size // x.shape[-1] * self._top_k,
             self.router.shape[0] // self.gate_proj.shape[0])
 
-        def pure(xv, r, g, u, d):
+        def pure(xv, r, g, u, d, *bias):
             out, load = _moe.dropless_moe(
                 xv.reshape(-1, xv.shape[-1]), r, g, u, d,
                 top_k=self._top_k, first_expert=self._first,
-                normalize=self._normalize)
+                normalize=self._normalize, scoring=self._scoring,
+                bias=bias[0] if bias else None, scale=self._scale)
             return out.reshape(xv.shape), load
 
+        bias = () if self.router_bias is None \
+            else (self.router_bias.data_for(x),)
         out, load = apply_op(
             pure, x, self.router.data_for(x), self.gate_proj.data_for(x),
-            self.up_proj.data_for(x), self.down_proj.data_for(x),
+            self.up_proj.data_for(x), self.down_proj.data_for(x), *bias,
             name="dropless_moe")
         if ag.is_training():
             sink = current_state_sink()
@@ -146,6 +203,9 @@ class DroplessMoE(HybridBlock):
                 _telemetry.stage_moe_load(
                     getattr(self, "_scope_name", None)
                     or type(self).__name__, load._data, rungs)
+        if self.shared is not None:
+            with jax.named_scope("moe.shared"):
+                out = out + self.shared(x)
         return out
 
     def __repr__(self):

@@ -23,44 +23,17 @@ the router, the softmax statistics and the loss stay float32.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from ... import numpy_extension as npx
 from ...ndarray.ndarray import NDArray, apply_op
-from ...ops import nn as _nn
-from ...ops.pallas_attention import SAVED_BY_NAME
-from ...passes.remat import checkpoint_block
 from ..block import HybridBlock
 from ..contrib.nn import DroplessMoE
 from ..nn import Dense, Embedding, HybridSequential
-from ..parameter import Parameter
+from .decoder import RMSNorm, attend, head_loss, run_layers
 
 __all__ = ["RMSNorm", "GroupedQueryAttention", "SDARDecoderLayer",
            "SDARModel", "SDARForBlockDiffusion", "sdar_moe"]
-
-# q and k tile of the flash kernels (a shorter sequence takes one tile of
-# its own length): at 8192 positions under the block-diffusion mask 80 of
-# 256 tiles of 512 are live, and 512 x 512 float32 scores fit the kernels'
-# fast memory three times over
-ATTENTION_TILE = 512
-
-
-class RMSNorm(HybridBlock):
-    """x / rms(x) * gamma over the last axis, computed in float32 and
-    returned in x's type."""
-
-    def __init__(self, units, epsilon=1e-6):
-        super().__init__()
-        self._eps = float(epsilon)
-        self.gamma = Parameter("gamma", shape=(units,), init="ones")
-
-    def forward(self, x):
-        eps = self._eps
-        return apply_op(
-            lambda a, g: _nn.rms_norm(a.astype(jnp.float32), g,
-                                      eps=eps).astype(a.dtype),
-            x, self.gamma.data_for(x), name="rms_norm")
 
 
 class GroupedQueryAttention(HybridBlock):
@@ -91,8 +64,6 @@ class GroupedQueryAttention(HybridBlock):
         self.k_norm = RMSNorm(head_dim, epsilon)
 
     def forward(self, x, positions, block_diffusion=None):
-        from ...ops.pallas_attention import flash_attention
-
         b, s, _ = x.shape
         hd, theta = self._hd, self._theta
 
@@ -109,15 +80,7 @@ class GroupedQueryAttention(HybridBlock):
         q = rotated(heads(self.q_proj(x), self._heads), self.q_norm)
         k = rotated(heads(self.k_proj(x), self._kv_heads), self.k_norm)
         v = heads(self.v_proj(x), self._kv_heads).transpose((0, 2, 1, 3))
-        tile = min(ATTENTION_TILE, -(-s // 128) * 128)
-
-        def attend(q_, k_, v_):
-            with jax.named_scope("attention"):
-                return flash_attention(q_, k_, v_, block_q=tile,
-                                       block_k=tile,
-                                       block_diffusion=block_diffusion)
-
-        out = apply_op(attend, q, k, v, name="flash_attention")
+        out = attend(q, k, v, block_diffusion=block_diffusion)
         out = out.transpose((0, 2, 1, 3)).reshape((b, s, self._heads * hd))
         return self.o_proj(out)
 
@@ -164,16 +127,8 @@ class SDARModel(HybridBlock):
         self.norm = RMSNorm(units, epsilon)
 
     def forward(self, tokens, positions, block_diffusion=None):
-        x = self.embed_tokens(tokens)
-        for layer in self.layers:
-            if self._remat:
-                # recompute the layer on the way back, all but the flash
-                # kernel: its output and logsumexp are 1/16 of what the
-                # layer computes and the most expensive part to redo
-                x = checkpoint_block(layer, x, positions, block_diffusion,
-                                     save=SAVED_BY_NAME)
-            else:
-                x = layer(x, positions, block_diffusion)
+        x = run_layers(self.layers, self._remat, self.embed_tokens(tokens),
+                       positions, block_diffusion)
         return self.norm(x)
 
 
@@ -227,19 +182,8 @@ class SDARForBlockDiffusion(HybridBlock):
         both = jnp.arange(seq, dtype=jnp.int32)
         positions = NDArray(jnp.concatenate([both, both]))
         hidden = self.model(tokens, positions, (blen, seq))
-
-        def head_loss(h, w, target, weight_):
-            with jax.named_scope("lm_head"):
-                logits = jnp.einsum("bld,vd->blv", h[:, :seq], w,
-                                    preferred_element_type=jnp.float32)
-                lse = jax.scipy.special.logsumexp(logits, axis=-1)
-                picked = jnp.take_along_axis(
-                    logits, target.astype(jnp.int32)[..., None],
-                    axis=-1)[..., 0]
-                return jnp.sum(weight_ * (lse - picked), axis=1)
-
-        return apply_op(head_loss, hidden, self.lm_head.weight.data_for(x0),
-                        x0, weight, name="block_diffusion_loss")
+        return head_loss(hidden, self.lm_head.weight.data_for(x0), x0, weight,
+                         "block_diffusion_loss", positions=seq)
 
 
 def sdar_moe(vocab_size, units, num_layers, num_heads, num_kv_heads,

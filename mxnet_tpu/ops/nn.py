@@ -479,7 +479,7 @@ def rms_norm(x, gamma, axis=-1, eps=1e-6):
 
 
 @register_op("rotary_embedding")
-def rotary_embedding(x, positions, theta=10000.0):
+def rotary_embedding(x, positions, theta=10000.0, interleaved=False):
     """Rotary position embedding, rotate-half form: with the head's
     width d, frequencies theta ** (-2i / d) for i < d / 2 and the angle
     a = position * frequency laid out twice along the width,
@@ -489,7 +489,12 @@ def rotary_embedding(x, positions, theta=10000.0):
     x: (..., S, d); ``positions``: the S explicit position ids (or any
     shape that broadcasts against x's leading dimensions, e.g. (B, 1,
     S)).  Angles and the rotation are float32; the result has x's
-    type."""
+    type.
+
+    ``interleaved``: x pairs neighbours, (x[2i], x[2i + 1]) turning by
+    frequency i.  They are first moved into the rotate-half layout,
+    [x[0], x[2], ... ; x[1], x[3], ...], and the result stays in it: a
+    score q . k does not depend on a permutation common to both."""
     d = x.shape[-1]
     half = d // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
@@ -497,6 +502,8 @@ def rotary_embedding(x, positions, theta=10000.0):
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
     x32 = x.astype(jnp.float32)
+    if interleaved:
+        x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], axis=-1)
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos + rotated * sin).astype(x.dtype)
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -196,9 +197,10 @@ def attention_reference(q, k, v, causal=False, scale=None,
                         dropout_p=0.0, dropout_seed=None,
                         block_diffusion=None):
     """Plain jnp attention (the numeric oracle + off-TPU fallback).
-    q: (B, H, S, D); k/v: (B, Hkv, S, D) with H a multiple of Hkv (query
-    head h reads key-value head h // (H // Hkv)).  ``block_diffusion``
-    as in `flash_attention`.  dropout uses the same counter-hash mask as
+    q: (B, H, S, D); k: (B, Hkv, S, D); v: (B, Hkv, S, Dv) with H a
+    multiple of Hkv (query head h reads key-value head h // (H // Hkv));
+    the result is (B, H, S, Dv).  ``block_diffusion`` as in
+    `flash_attention`.  dropout uses the same counter-hash mask as
     the Pallas kernel, applied to the normalized probabilities
     (numerator only, inverted scaling) — bit-identical semantics to the
     kernel."""
@@ -319,17 +321,18 @@ class _Plan:
     An index map gets the grid indices, then the two prefetched scalar
     operands (dropout seed, schedule)."""
 
-    def __init__(self, q, k, causal, block_q, block_k, valid_len,
+    def __init__(self, q, k, v, causal, block_q, block_k, valid_len,
                  block_diffusion):
         import numpy as onp
 
-        b, h, s_len, d = q.shape
-        self.shape, self.kv_shape = q.shape, k.shape
+        b, h, s_len, dk = q.shape
         self.group = h // k.shape[1]
         if h != self.group * k.shape[1]:
             raise ValueError(f"{h} query heads over {k.shape[1]} key-value "
                              "heads: not a multiple")
-        self.bh, self.bkv, self.s_len, self.d = b * h, b * k.shape[1], s_len, d
+        self.bh, self.bkv, self.s_len = b * h, b * k.shape[1], s_len
+        # queries and keys share one width, values and the output another
+        self.dk, self.dv = dk, v.shape[-1]
         self.block_q, self.block_k = min(block_q, s_len), min(block_k, s_len)
         self.nq, self.nk = s_len // self.block_q, s_len // self.block_k
         codes = _mask_codes(causal, block_diffusion, s_len, valid_len)
@@ -394,29 +397,30 @@ class _Plan:
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                valid_len=None, dropout_p=0.0, dropout_seed=None,
                block_diffusion=None):
-    plan = _Plan(q, k, causal, block_q, block_k, valid_len, block_diffusion)
-    group, d = plan.group, plan.d
+    plan = _Plan(q, k, v, causal, block_q, block_k, valid_len,
+                 block_diffusion)
+    group, dk, dv = plan.group, plan.dk, plan.dv
     rows = plan.schedule()
     q_tile, k_tile, q_code, k_code = plan.specs(
         lambda b, t: (b, _head_div(b, group)))
     out, lse = plan.call(
         _fwd_kernel, "flash_attention_fwd",
         grid=(plan.bh, len(rows)),
-        in_specs=[q_tile(d), k_tile(d), k_tile(d), q_code, k_code],
-        out_specs=[q_tile(d), q_tile(1)],
+        in_specs=[q_tile(dk), k_tile(dk), k_tile(dv), q_code, k_code],
+        out_specs=[q_tile(dv), q_tile(1)],
         out_shape=[
-            jax.ShapeDtypeStruct((plan.bh, plan.s_len, d), q.dtype),
+            jax.ShapeDtypeStruct((plan.bh, plan.s_len, dv), q.dtype),
             jax.ShapeDtypeStruct((plan.bh, plan.s_len, 1), jnp.float32),
         ],
         scratch=[
             _scratch((plan.block_q, 1)),   # running max m
             _scratch((plan.block_q, 1)),   # running sum l
-            _scratch((plan.block_q, d)),   # output accumulator
+            _scratch((plan.block_q, dv)),  # output accumulator
         ],
         interpret=interpret, scale=scale, dropout_p=dropout_p,
     )(_seed_arr(dropout_seed), rows, plan.flat(q),
       plan.flat(k, True), plan.flat(v, True), *plan.codes)
-    return out.reshape(q.shape), lse[..., 0]
+    return out.reshape(q.shape[:-1] + (dv,)), lse[..., 0]
 
 
 def _recompute_p(q, k, lse_col, qc_ref, kc_ref, scale, masked, use_eq):
@@ -513,8 +517,9 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     """Block-streamed FlashAttention-2 backward: O(S) memory, no (S, S)
     residual — P tiles are recomputed from (q, k, lse) per block (and
     the dropout keep mask from its counter hash)."""
-    plan = _Plan(q, k, causal, block_q, block_k, valid_len, block_diffusion)
-    group, d = plan.group, plan.d
+    plan = _Plan(q, k, v, causal, block_q, block_k, valid_len,
+                 block_diffusion)
+    group, dk_, dv_ = plan.group, plan.dk, plan.dv
     qr, kr, vr = plan.flat(q), plan.flat(k, True), plan.flat(v, True)
     do, orr = plan.flat(g), plan.flat(out)
     # delta = rowsum(dO * O) — the softmax-grad correction term (with
@@ -532,11 +537,11 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     dq = plan.call(
         _bwd_dq_kernel, "flash_attention_bwd_dq",
         grid=(plan.bh, len(rows)),
-        in_specs=[q_tile(d), k_tile(d), k_tile(d), q_tile(d), q_tile(1),
-                  q_tile(1), q_code, k_code],
-        out_specs=q_tile(d),
-        out_shape=jax.ShapeDtypeStruct((plan.bh, plan.s_len, d), q.dtype),
-        scratch=[_scratch((plan.block_q, d))],
+        in_specs=[q_tile(dk_), k_tile(dk_), k_tile(dv_), q_tile(dv_),
+                  q_tile(1), q_tile(1), q_code, k_code],
+        out_specs=q_tile(dk_),
+        out_shape=jax.ShapeDtypeStruct((plan.bh, plan.s_len, dk_), q.dtype),
+        scratch=[_scratch((plan.block_q, dk_))],
         interpret=interpret, scale=scale, dropout_p=dropout_p,
     )(seed, rows, qr, kr, vr, do, lse, delta, *plan.codes)
 
@@ -546,14 +551,15 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     dk, dv = plan.call(
         _bwd_dkv_kernel, "flash_attention_bwd_dkv",
         grid=(plan.bkv, len(by_key), group),
-        in_specs=[q_tile(d), k_tile(d), k_tile(d), q_tile(d), q_tile(1),
-                  q_tile(1), q_code, k_code],
-        out_specs=[k_tile(d), k_tile(d)],
+        in_specs=[q_tile(dk_), k_tile(dk_), k_tile(dv_), q_tile(dv_),
+                  q_tile(1), q_tile(1), q_code, k_code],
+        out_specs=[k_tile(dk_), k_tile(dv_)],
         out_shape=[
-            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, d), k.dtype),
-            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, d), v.dtype),
+            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, dk_), k.dtype),
+            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, dv_), v.dtype),
         ],
-        scratch=[_scratch((plan.block_k, d)), _scratch((plan.block_k, d))],
+        scratch=[_scratch((plan.block_k, dk_)),
+                 _scratch((plan.block_k, dv_))],
         interpret=interpret, scale=scale, dropout_p=dropout_p, group=group,
     )(seed, by_key, qr, kr, vr, do, lse, delta, *plan.codes)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
@@ -605,13 +611,25 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                     dropout_seed=None, block_diffusion=None):
     """Fused multi-head attention: softmax(QK^T * scale + mask) V.
 
-    q: (B, H, S, D); k/v: (B, Hkv, S, D), H a multiple of Hkv (grouped
-    queries: query head h reads key-value head h // (H // Hkv), and the
-    dK/dV kernel sums over the group).  Runs the Pallas kernel on TPU
-    (or anywhere with interpret=True); falls back to the jnp reference
-    otherwise. Ragged S is tile-padded and the kernel masks the padded
-    keys (static `valid_len`) — only a ragged head dim D takes the
-    reference path.
+    q: (B, H, S, D); k: (B, Hkv, S, D); v: (B, Hkv, S, Dv), H a multiple
+    of Hkv (grouped queries: query head h reads key-value head
+    h // (H // Hkv), and the dK/dV kernel sums over the group).  Queries
+    and keys share the width D, values and the result (B, H, S, Dv) the
+    width Dv, and the two may differ (latent attention: 192 and 128):
+    every tile, accumulator and gradient has the width of its tensor, so
+    nothing is padded to the wider one.  The default scale is
+    1 / sqrt(D).  Runs the Pallas kernel on TPU (or anywhere with
+    interpret=True); off a TPU the jnp reference runs instead.  Ragged S
+    is tile-padded and the kernel masks the padded keys (static
+    `valid_len`).
+
+    What the kernel cannot tile takes the reference path LOUDLY — the
+    counter ``attention_kernel_fallback_total{reason}`` and a
+    RuntimeWarning — wherever the kernel was asked for (on a TPU, or with
+    interpret=True): ``reason="width"`` where D or Dv is not a multiple
+    of 8 (a tile's rows would not be whole sublanes), ``reason="tile"``
+    where ``block_q`` / ``block_k`` do not divide the padded length or
+    are not multiples of 8.
 
     The mask is static: nothing, ``causal``, or ``block_diffusion=
     (block length B, half length L)`` for a sequence of S = 2L positions
@@ -625,6 +643,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     and the reference path — see _dropout_keep).
     """
     d = q.shape[-1]
+    if k.shape[-1] != d or v.shape[:-1] != k.shape[:-1]:
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {v.shape}: q and k share their "
+            "last dimension, k and v every other")
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
@@ -632,7 +654,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     if block_diffusion is not None:
         block_diffusion = tuple(int(x) for x in block_diffusion)
 
-    def _fallback(qq, kk, vv):
+    def _fallback(qq, kk, vv, reason=None):
+        if reason is not None:
+            _record_fallback(reason, q.shape, v.shape[-1], block_q, block_k)
         return attention_reference(qq, kk, vv, causal=causal, scale=scale,
                                    dropout_p=dropout_p,
                                    dropout_seed=dropout_seed,
@@ -642,16 +666,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
         interpret = False
         if jax.devices()[0].platform != "tpu":
             return _fallback(q, k, v)
-    if d % 8:
-        # ragged head dim: blocks can't stay lane-aligned
-        return _fallback(q, k, v)
+    if d % 8 or v.shape[-1] % 8:
+        return _fallback(q, k, v, "width")
     s_len = q.shape[2]
     s_pad = _tile_pad_len(s_len, block_q)
     bq = min(block_q, s_pad)
     bk = min(block_k, s_pad)
     if s_pad % bq or s_pad % bk or bq % 8 or bk % 8:
-        # non-dividing custom block sizes: reference path
-        return _fallback(q, k, v)
+        return _fallback(q, k, v, "tile")
     seed = _seed_arr(dropout_seed)
     if s_pad == s_len:
         return _flash(q, k, v, seed, causal, scale, bq, bk, interpret,
@@ -661,6 +683,19 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                  seed, causal, scale, bq, bk, interpret, dropout_p, s_len,
                  block_diffusion)
     return out[:, :, :s_len]
+
+
+def _record_fallback(reason, q_shape, v_width, block_q, block_k):
+    """The kernel was asked for and the plain reference runs: counted,
+    and said."""
+    from ..telemetry import instruments as _telemetry
+
+    _telemetry.record_attention_fallback(reason)
+    warnings.warn(
+        f"flash_attention: q {tuple(q_shape)}, value width {v_width}, "
+        f"blocks ({block_q}, {block_k}) cannot be tiled ({reason}); the "
+        "plain reference, which materialises the scores, runs instead",
+        RuntimeWarning, stacklevel=3)
 
 
 def _tile_pad_len(s_len, block):

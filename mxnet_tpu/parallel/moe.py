@@ -26,7 +26,8 @@ import numpy as onp
 _jit_cache = {}
 
 __all__ = ["top_k_routing", "moe_ffn", "moe_ffn_sharded", "init_moe_params",
-           "route_top_k", "dropless_moe", "buffer_rungs", "rung_index"]
+           "route_top_k", "bias_moved_share", "dropless_moe", "buffer_rungs",
+           "rung_index"]
 
 
 def top_k_routing(router_logits, num_experts, capacity, top_k=2):
@@ -132,15 +133,49 @@ def moe_ffn_sharded(params, x, mesh, axis="ep", capacity_factor=1.25,
 
 # -- dropless routing over a held share of the experts ----------------------
 
-def route_top_k(logits, top_k, normalize=True):
+def _scores(logits, scoring):
+    """Each expert's float32 score from router ``logits`` (N, E):
+    ``"softmax"`` over all E, or an independent ``"sigmoid"`` each."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    if scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
+
+
+def route_top_k(logits, top_k, normalize=True, *, scoring="softmax",
+                bias=None, scale=1.0):
     """(gates (N, k) float32, experts (N, k) int32) of router ``logits``
-    (N, E): softmax over all E in float32, the k largest, and — with
-    ``normalize`` — the gates divided by their sum over those k."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, experts = jax.lax.top_k(probs, top_k)
+    (N, E): scores over all E in float32 (``scoring``: ``"softmax"``, or
+    a ``"sigmoid"`` of each logit), the k largest, and — with
+    ``normalize`` — the gates divided by their sum over those k, then
+    times ``scale``.  A ``bias`` (E,) selects and never weighs: the k
+    largest are taken of score + bias, the gates are the scores of the
+    chosen without it, and no gradient reaches the bias."""
+    scores = _scores(logits, scoring)
+    if bias is None:
+        gates, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
     if normalize:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        # a sigmoid's scores may all vanish; a softmax's k largest cannot
+        gates = gates / (total + 1e-20 if scoring == "sigmoid" else total)
+    if scale != 1.0:
+        gates = gates * scale
     return gates, experts.astype(jnp.int32)
+
+
+def bias_moved_share(logits, experts, scoring):
+    """The share of the assignments ``experts`` (N, k), chosen under a
+    selection bias, that the k largest plain scores would not have made:
+    0 says the bias did nothing."""
+    _, plain = jax.lax.top_k(_scores(logits, scoring), experts.shape[1])
+    kept = jnp.any(experts[:, :, None] == plain[:, None, :], axis=-1)
+    return 1.0 - jnp.mean(kept.astype(jnp.float32))
 
 
 def buffer_rungs(assignments, share):
@@ -279,7 +314,7 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
-                 normalize=True):
+                 normalize=True, scoring="softmax", bias=None, scale=1.0):
     """One chip's share of a dropless mixture of gated experts.
 
     x: (N, D) tokens; router: (E, D), all E experts of the layer;
@@ -290,6 +325,12 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
 
         out = sum over e in S that are held of
               g_e * w_down[e](silu(w_gate[e] x) * w_up[e] x)
+
+    ``scoring="sigmoid"`` scores each expert by itself, p_e =
+    sigmoid(router x)_e; a ``bias`` (E,) float32 makes S the ``top_k``
+    largest of p + bias while g stays p_e / sum_S p (`route_top_k`: the
+    bias selects, never weighs, and has no gradient); ``scale``
+    multiplies every g.
 
     The sum over the held experts is this chip's part of the layer's
     result; what an expert held elsewhere adds is left out (the exchange
@@ -302,8 +343,9 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
     grouped products (`jax.lax.ragged_dot`) and the sums back to the
     tokens all work on C rows.
 
-    Returns (out (N, D), load (2,) float32): rows routed here, and the
-    largest held expert's rows over the mean of the held experts' rows.
+    Returns (out (N, D), load float32): rows routed here, the largest
+    held expert's rows over the mean of the held experts' rows and, with
+    a ``bias``, `bias_moved_share` of this call — (2,), or (3,).
     """
     n = x.shape[0]
     held = w_gate.shape[0]
@@ -311,7 +353,8 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
         logits = jnp.matmul(x.astype(jnp.float32),
                             router.astype(jnp.float32).T,
                             precision=jax.lax.Precision.HIGHEST)
-        gates, experts = route_top_k(logits, top_k, normalize)
+        gates, experts = route_top_k(logits, top_k, normalize,
+                                     scoring=scoring, bias=bias, scale=scale)
     with jax.named_scope("moe.dispatch"):
         local = experts - first_expert
         key = jnp.where((local >= 0) & (local < held), local,
@@ -322,8 +365,10 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
             key[:, None] == jnp.arange(held, dtype=jnp.int32)[None],
             axis=0, dtype=jnp.int32)
         rows = jnp.sum(group_sizes)
-        load = jnp.stack([rows, jnp.max(group_sizes) * held
-                          / jnp.maximum(rows, 1)]).astype(jnp.float32)
+        load = [rows, jnp.max(group_sizes) * held / jnp.maximum(rows, 1)]
+        if bias is not None:
+            load.append(bias_moved_share(logits, experts, scoring))
+        load = jnp.stack(load).astype(jnp.float32)
     out = _held_experts(
         buffer_rungs(n * top_k, router.shape[0] // held), x, gates, order,
         group_sizes, jnp.concatenate([w_gate, w_up], axis=2), w_down)

@@ -21,7 +21,8 @@ from .registry import REGISTRY, counter, gauge, histogram
 
 __all__ = [
     "jit_compile_total", "jit_compile_seconds", "jit_trace_total",
-    "hybridize_fallback_total",
+    "hybridize_fallback_total", "attention_kernel_fallback_total",
+    "record_attention_fallback",
     "xla_compile_seconds_total", "xla_programs_total",
     "install_compile_listener",
     "transfer_total", "transfer_bytes_total",
@@ -35,7 +36,8 @@ __all__ = [
     "step_dispatch_total", "step_donated_bytes",
     "step_scalar_operands", "record_step_scalar_operands",
     "moe_rows_routed_here", "moe_expert_load_max_over_mean",
-    "moe_buffer_rows", "stage_moe_load", "flush_moe_load",
+    "moe_buffer_rows", "moe_bias_moved_share", "stage_moe_load",
+    "flush_moe_load",
     "pass_applied_total", "pass_rewrite_ms", "graph_dedup_hits_total",
     "remat_policy", "record_pass", "record_dedup_hit",
     "record_remat_policy",
@@ -151,6 +153,12 @@ hybridize_fallback_total = counter(
     "hybridize_fallback_total",
     "Hybridized blocks that fell back to imperative execution on a "
     "dynamic-output op (gluon/block.py)", ["block"])
+attention_kernel_fallback_total = counter(
+    "attention_kernel_fallback_total",
+    "flash_attention calls that asked for the kernel and ran the plain "
+    "reference because the kernel cannot tile them: reason=width (a head "
+    "width that is not a multiple of 8), reason=tile (blocks that do not "
+    "divide the padded length)", ["reason"])
 compile_flops = gauge(
     "compile_flops",
     "XLA cost_analysis flops of the latest executable per block variant "
@@ -785,6 +793,12 @@ def record_fallback(block):
     hybridize_fallback_total.labels(block).inc()
 
 
+def record_attention_fallback(reason):
+    if not REGISTRY.enabled:
+        return
+    attention_kernel_fallback_total.labels(reason).inc()
+
+
 def record_transfer(direction, nbytes):
     if not REGISTRY.enabled:
         return
@@ -864,38 +878,47 @@ moe_buffer_rows = gauge(
     "moe_rows_routed_here. The layer takes it on the device; "
     "flush_moe_load() works it out again from the fetched count", ["layer"])
 
-# layer -> (the (2,) device array its last step produced, the layer's
-# buffer lengths); the array stays on the device until somebody asks
+moe_bias_moved_share = gauge(
+    "moe_bias_moved_share",
+    "Of an expert layer whose router selects on score + bias: the share "
+    "of the last step's token-to-expert assignments that the largest "
+    "plain scores would not have made. 0 says the bias did nothing. "
+    "Produced on the device; set by flush_moe_load()", ["layer"])
+
+# layer -> (the device array its last step produced, (2,) or with a
+# selection bias (3,), the layer's buffer lengths); the array stays on the
+# device until somebody asks
 _staged_moe_load = {}
 
 
 def stage_moe_load(layer, load, rungs):
     """An expert layer's step produced ``load`` = [rows routed here,
-    load max over mean] on the device, on a buffer of one of ``rungs``
+    load max over mean] and, under a selection bias, [the share of the
+    assignments it moved] on the device, on a buffer of one of ``rungs``
     rows.  Keeps the array, fetches nothing: a step gains no host sync."""
     if REGISTRY.enabled:
         _staged_moe_load[layer] = (load, rungs)
 
 
 def flush_moe_load():
-    """Fetch what the last step staged and set the three gauges; returns
+    """Fetch what the last step staged and set the gauges; returns
     {layer: (rows routed here, load max over mean)}.  This is the one
     device-to-host read, so call it where the loop reads the loss."""
-    import jax.numpy as jnp
-    import numpy as np
+    import jax
 
     from ..parallel.moe import rung_index
 
     out = {}
     layers = sorted(_staged_moe_load)
-    loads = np.asarray(jnp.stack([_staged_moe_load[n][0] for n in layers])) \
-        if layers else ()
-    for layer, (rows, ratio) in zip(layers, loads):
+    loads = jax.device_get([_staged_moe_load[n][0] for n in layers])
+    for layer, (rows, ratio, *moved) in zip(layers, loads):
         rows, ratio = float(rows), float(ratio)
         rungs = _staged_moe_load[layer][1]
         moe_rows_routed_here.labels(layer).set(rows)
         moe_expert_load_max_over_mean.labels(layer).set(ratio)
         moe_buffer_rows.labels(layer).set(rungs[rung_index(rungs, rows)])
+        if moved:
+            moe_bias_moved_share.labels(layer).set(float(moved[0]))
         out[layer] = (rows, ratio)
     return out
 

@@ -119,6 +119,29 @@ def test_grouped_block_diffusion_attention_compiles_for_v5e(spec, case):
         assert calls == 3      # forward, dQ, dK/dV summed over the group
 
 
+# -- flash attention: latent attention's 32 heads with keys 192 and values
+#    128 wide, 8192 positions under the causal mask, tiles of 512 -----------
+
+@pytest.mark.parametrize("case", ["fwd", "grad"])
+def test_latent_attention_widths_compile_for_v5e(spec, case):
+    from mxnet_tpu.ops.pallas_attention import flash_attention
+
+    qk = spec((1, 32, 8192, 192), jnp.bfloat16)
+    v = spec((1, 32, 8192, 128), jnp.bfloat16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, block_q=512,
+                               block_k=512, causal=True)
+
+    if case == "fwd":
+        assert _kernel_calls(attend, qk, qk, v) == 1
+    else:
+        calls = _kernel_calls(jax.grad(
+            lambda *a: attend(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), qk, qk, v)
+        assert calls == 3      # forward, dQ (192 wide), dK/dV (192 and 128)
+
+
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 class _Lowered(Exception):
