@@ -1,6 +1,6 @@
 """What the zoo's decoder language models share (`sdar`, `deepseek_v3`):
-the norm, the call into the flash kernels with its tile, the per-layer
-checkpoint segments and the loss over the vocabulary rows held here."""
+the norm, the call into the flash kernels, the per-layer checkpoint
+segments and the loss over the vocabulary rows held here."""
 from __future__ import annotations
 
 import jax
@@ -13,13 +13,7 @@ from ...passes.remat import checkpoint_block
 from ..block import HybridBlock
 from ..parameter import Parameter
 
-__all__ = ["ATTENTION_TILE", "RMSNorm", "attend", "run_layers", "head_loss"]
-
-# q and k tile of the flash kernels (a shorter sequence takes one tile of
-# its own length): at 8192 positions under the block-diffusion mask 80 of
-# 256 tiles of 512 are live, under the causal mask 136, and 512 x 512
-# float32 scores fit the kernels' fast memory three times over
-ATTENTION_TILE = 512
+__all__ = ["RMSNorm", "attend", "run_layers", "head_loss"]
 
 
 class RMSNorm(HybridBlock):
@@ -41,16 +35,13 @@ class RMSNorm(HybridBlock):
 
 def attend(q, k, v, **mask):
     """`flash_attention` on (B, H, S, width) heads under the scope
-    ``attention``, its tile read off the sequence length; ``mask``:
+    ``attention``; the op reads its tiles off the shapes.  ``mask``:
     ``causal=True`` or ``block_diffusion=(block, half)``."""
     from ...ops.pallas_attention import flash_attention
 
-    tile = min(ATTENTION_TILE, -(-q.shape[2] // 128) * 128)
-
     def kernel(q_, k_, v_):
         with jax.named_scope("attention"):
-            return flash_attention(q_, k_, v_, block_q=tile, block_k=tile,
-                                   **mask)
+            return flash_attention(q_, k_, v_, **mask)
 
     return apply_op(kernel, q, k, v, name="flash_attention")
 

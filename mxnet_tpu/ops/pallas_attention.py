@@ -4,10 +4,12 @@ The hot op of the transformer family (BERT zoo, ring/Ulysses sequence
 parallelism): fused QK^T → online-softmax → PV with O(S) memory instead of
 materializing the (S, S) score matrix in HBM. Reference framework analog:
 the fused attention the reference lacked (its transformer era predated it);
-TPU design per /opt/skills/guides/pallas_guide.md — q blocks stay resident
-in VMEM, k/v blocks stream through the grid's inner dimension, the MXU sees
-(block_q, d) x (d, block_k) matmuls, and the online-softmax running max /
-sum live in VMEM scratch across the inner grid steps.
+TPU design per /opt/skills/guides/pallas_guide.md — a q tile stays resident
+in VMEM, spans of k/v sub-tiles stream through the grid's inner dimension
+(the span schedule: `_span_schedule`, `_walk`), the MXU sees (rows, d) x
+(d, sub-tile) matmuls, and the online-softmax running max / sum live in
+VMEM scratch across the inner grid steps.  The op reads its tiles off the
+shapes (`_choose_tile`) and builds the plan of a signature once (`_plan`).
 
 `flash_attention` is differentiable via custom_vjp with a block-streamed
 Pallas backward (FlashAttention-2): the forward saves only (out, lse);
@@ -22,6 +24,7 @@ compile failure raises.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import warnings
@@ -143,36 +146,84 @@ def _keep(qc, kc, use_eq=True):
     return keep
 
 
-def _tile_schedule(codes, nq, nk, block_q, block_k, by_key=False):
-    """The live (q tile, k tile) pairs of a mask as one int32 per grid
-    step, in q-major order (forward, dQ) or k-major (dK/dV): bits 17..
-    the q tile, bits 2..16 the k tile, bit 1 set on the first pair of
-    its row (column), bit 0 on the last.  A tile the mask empties is not
-    in the list, so the grid never visits it; every row and every column
-    keeps at least one pair, so every output block is written."""
+# a sub-tile's class in the span schedule: what the mask keeps of its pairs
+_DEAD, _FREE, _MASKED = 0, 1, 2   # nothing (skipped), all (no mask), some
+_MAX_SPAN = 4                     # sub-tiles a schedule word has class bits for
+
+
+def _classes(codes, s_len, unit_q, unit_k):
+    """The class of every (unit_q queries, unit_k keys) sub-tile of a
+    mask as an (s_len // unit_q, s_len // unit_k) array of ``_DEAD`` /
+    ``_FREE`` / ``_MASKED``, from the codes' minima and maxima over each
+    unit: interval arithmetic on 2 * s_len numbers, never a dense (S, S)
+    mask.  ``_FREE`` promises that every pair is kept and ``_DEAD`` that
+    none is; the masks ``_mask_codes`` builds (code values that rise with
+    the position) are classified exactly wherever a unit does not
+    straddle the noisy / clean boundary."""
     import numpy as onp
 
-    if nq >= 1 << 14 or nk >= 1 << 15:
-        raise ValueError(f"too many tiles for the schedule: {nq} x {nk}")
+    nq, nk = s_len // unit_q, s_len // unit_k
     if codes is None:
-        live = onp.ones((nq, nk), bool)
-    else:
-        qc = codes[0].astype(onp.int64).reshape(nq, block_q, 2)
-        kc = codes[1].astype(onp.int64).reshape(2, nk, block_k)
-        q_eq, k_eq = qc[:, :, 0], kc[0]
-        q_lo = onp.where(q_eq >= 0, q_eq, _BIG).min(1)
-        q_hi = onp.where(q_eq >= 0, q_eq, -_BIG).max(1)
-        k_lo = onp.where(k_eq >= 0, k_eq, _BIG).min(1)
-        k_hi = onp.where(k_eq >= 0, k_eq, -_BIG).max(1)
-        live = ((k_lo[None] <= q_hi[:, None]) & (k_hi[None] >= q_lo[:, None])
-                | (kc[1].min(1)[None] <= qc[:, :, 1].max(1)[:, None]))
-        live[:, 0] |= ~live.any(1)
-        live[0, :] |= ~live.any(0)
-    qi, kj = onp.nonzero(live.T)[::-1] if by_key else onp.nonzero(live)
-    major = kj if by_key else qi
-    edge = onp.concatenate([[True], major[1:] != major[:-1], [True]])
-    return ((qi << 17) | (kj << 2) | (edge[:-1] << 1) | edge[1:]).astype(
-        onp.int32)
+        return onp.full((nq, nk), _FREE, onp.int8)
+    qc = codes[0].astype(onp.int64).reshape(nq, unit_q, 2)
+    kc = codes[1].astype(onp.int64).reshape(2, nk, unit_k)
+    q_eq, q_thr, k_eq, k_thr = qc[:, :, 0], qc[:, :, 1], kc[0], kc[1]
+
+    def held(eq):           # lowest and highest code a unit holds (< 0: none)
+        return (onp.where(eq >= 0, eq, _BIG).min(1),
+                onp.where(eq >= 0, eq, -_BIG).max(1))
+
+    (q_lo, q_hi), (k_lo, k_hi) = held(q_eq), held(k_eq)
+    some_eq = (k_lo[None] <= q_hi[:, None]) & (k_hi[None] >= q_lo[:, None])
+    # every pair by the equality term: one code on each side, the same
+    one_q = (q_eq.min(1) == q_hi) & (q_hi >= 0)
+    one_k = (k_eq.min(1) == k_hi) & (k_hi >= 0)
+    all_eq = one_q[:, None] & one_k[None] & (q_hi[:, None] == k_hi[None])
+    some_thr = k_thr.min(1)[None] <= q_thr.max(1)[:, None]
+    all_thr = k_thr.max(1)[None] <= q_thr.min(1)[:, None]
+    return onp.where(all_thr | all_eq, _FREE,
+                     onp.where(some_thr | some_eq, _MASKED, _DEAD)
+                     ).astype(onp.int8)
+
+
+def _span_schedule(classes, span, by_key=False):
+    """The grid steps of a kernel as one int32 each.  ``classes`` holds a
+    row per resident tile (q tiles for the forward and dQ, k tiles for
+    dK/dV: pass the transposed array and ``by_key``) and a column per
+    streamed sub-tile; a step is a resident tile and a block of ``span``
+    consecutive sub-tiles of which at least one is live, in tile-major
+    order.  Bits 20.. hold the q index, bits 10..19 the k index (the
+    tile's, or the block's in units of ``span`` sub-tiles), bits 2..9
+    the classes of the block's sub-tiles, two bits each, bit 1 is set on
+    the first step of its tile and bit 0 on the last.  A block the mask
+    empties is not in the list, so the grid never visits it; a tile the
+    mask empties keeps one sub-tile as ``_MASKED`` (which computes zeros),
+    so every output block is written."""
+    import numpy as onp
+
+    n_tiles, n_sub = classes.shape
+    if not 1 <= span <= _MAX_SPAN or n_sub % span:
+        raise ValueError(f"a span of {span} over {n_sub} sub-tiles")
+    n_blocks = n_sub // span
+    nq, nk = (n_blocks, n_tiles) if by_key else (n_tiles, n_blocks)
+    if nq >= 1 << 11 or nk >= 1 << 10:
+        raise ValueError(f"too many tiles for the schedule: {nq} x {nk}")
+    classes = classes.astype(onp.int32)
+    classes[~classes.any(1), 0] = _MASKED
+    blocks = classes.reshape(n_tiles, n_blocks, span)
+    tile, block = onp.nonzero(blocks.any(2))
+    edge = onp.concatenate([[True], tile[1:] != tile[:-1], [True]])
+    bits = sum(blocks[tile, block, j] << (2 + 2 * j) for j in range(span))
+    qi, kj = (block, tile) if by_key else (tile, block)
+    return ((qi << 20) | (kj << 10) | bits | (edge[:-1] << 1)
+            | edge[1:]).astype(onp.int32)
+
+
+def _word_classes(words, span):
+    """(steps, span) classes of a schedule's sub-tiles, from its bits."""
+    import numpy as onp
+
+    return onp.stack([(words >> (2 + 2 * j)) & 3 for j in range(span)], 1)
 
 
 def _head_div(bh, group):
@@ -181,16 +232,40 @@ def _head_div(bh, group):
 
 
 def _tiles_of(e):
-    """(q tile, k tile) of one word of the schedule."""
-    return (jax.lax.shift_right_logical(e, 17),
-            jax.lax.shift_right_logical(e, 2) & 0x7FFF)
+    """(q index, k index) of one word of the schedule."""
+    return e >> 20, (e >> 10) & 0x3FF
 
 
-def _step_of(sched_ref, t):
-    """(q tile, k tile, first of its row, last of its row) of grid step
-    ``t``: scalar arithmetic on one prefetched word."""
-    e = sched_ref[t]
-    return _tiles_of(e) + ((e & 2) != 0, (e & 1) != 0)
+def _walk(word, span, rows, chunk, classes, body):
+    """``body(j, r0, masked)`` on every chunk of ``chunk`` query rows
+    (the first is row ``r0`` of ``rows``) of every live sub-tile ``j`` of
+    a grid step's span, in order.  Rolled loops:
+    an iteration of the outer one reads its sub-tile's class off the
+    schedule word and takes the mask-free or the masked body, whose own
+    loop walks the chunks; so a kernel traces each body once whatever
+    the tile and the span, only the bodies its schedule uses
+    (``classes``), and the device code of a body is a chunk's."""
+    import jax.experimental.pallas as pl
+
+    def chunks(j, masked):
+        if chunk == rows:
+            body(j, 0, masked)
+        else:
+            jax.lax.fori_loop(
+                0, rows // chunk, lambda c, _: (body(
+                    j, pl.multiple_of(c * chunk, chunk), masked), _)[1], 0)
+
+    def visit(j):
+        cls = (word >> (2 + 2 * j)) & 3
+        for c in classes:
+            pl.when(cls == c)(functools.partial(chunks, j, c == _MASKED))
+
+    if span > 1:
+        jax.lax.fori_loop(0, span, lambda j, c: (visit(j), c)[1], 0)
+    elif len(classes) > 1:
+        visit(0)
+    else:       # one sub-tile a step, one class: every listed step is live
+        chunks(0, classes[0] == _MASKED)
 
 
 def attention_reference(q, k, v, causal=False, scale=None,
@@ -225,80 +300,83 @@ def attention_reference(q, k, v, causal=False, scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
 
 
-def _scores(q, k, qc_ref, kc_ref, scale, masked, use_eq):
-    """One tile of QK^T * scale with the mask's dead pairs at -inf."""
+def _scores(q, k, qc, kc, scale, masked, use_eq):
+    """One sub-tile of QK^T * scale; with ``masked`` the mask's dead pairs
+    at -inf, from the (rows, 2) and (2, columns) codes."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
+        preferred_element_type=jnp.float32) * scale
     if masked:
-        s = jnp.where(_keep(qc_ref[...], kc_ref[...], use_eq), s, -jnp.inf)
+        s = jnp.where(_keep(qc, kc, use_eq), s, -jnp.inf)
     return s
 
 
-def _tile_keep(seed_ref, bh, q_idx, kv_idx, block_q, block_k, shape,
-               dropout_p):
-    """The dropout keep mask of one tile, the same in all three
-    kernels."""
-    q_pos = q_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _pair_keep(seed_ref, bh, q_start, k_start, shape, dropout_p):
+    """The dropout keep mask of the sub-tile whose first pair is global
+    position (q_start, k_start): the same in all three kernels."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return _dropout_keep(seed_ref[0], bh, q_pos, k_pos, dropout_p)
 
 
 def _fwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref, kc_ref,
                 o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                scale, masked, use_eq, block_q, block_k, dropout_p=0.0):
+                scale, classes, use_eq, tile, sub, span, chunk,
+                dropout_p=0.0):
     import jax.experimental.pallas as pl
 
     # hoisted: program_id inside pl.when bodies breaks interpret mode
     bh_idx = pl.program_id(0)
-    q_idx, kv_idx, first, last = _step_of(sched_ref, pl.program_id(1))
+    word = sched_ref[pl.program_id(1)]
+    q_idx, k_blk = _tiles_of(word)
 
-    @pl.when(first)
+    @pl.when((word & 2) != 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    v = v_ref[0]
-    s = _scores(q_ref[0], k_ref[0], qc_ref, kc_ref, scale, masked, use_eq)
+    def sub_tile(j, r0, masked):
+        rows = pl.ds(r0, chunk)
+        v = v_ref[0, j]
+        s = _scores(q_ref[0, 0, rows], k_ref[0, j], qc_ref[0, rows],
+                    kc_ref[j], scale, masked, use_eq)  # (chunk, sub)
+        m_prev = m_ref[rows]                           # (chunk, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a row with nothing live so far (a sub-tile the mask half
+        # empties) has m_new = -inf: exp(-inf - 0) = 0 is what it adds
+        m_safe = m_new
+        if masked:
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m_prev - m_safe)
+        l_ref[rows] = alpha * l_ref[rows] + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+        # dropout masks the numerator only (the softmax denominator l
+        # stays un-dropped): out = sum M.p.v / (l.(1-p)) as FlashAttention
+        if dropout_p > 0.0:
+            keep = _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
+                              (k_blk * span + j) * sub, p.shape, dropout_p)
+            p = jnp.where(keep, p, 0.0)
+        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[rows] = m_new
 
-    m_prev = m_ref[:]                                # (block_q, 1)
-    l_prev = l_ref[:]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    # guard rows with nothing live so far (a tile the mask half empties)
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe)
-    p = jnp.where(jnp.isfinite(m_new), p, 0.0)
-    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    # dropout masks the numerator only (the softmax denominator l stays
-    # un-dropped): out = Σ M·p·v / (l·(1−p)) — FlashAttention dropout
-    p_v = p
-    if dropout_p > 0.0:
-        keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx, block_q,
-                          block_k, p.shape, dropout_p)
-        p_v = jnp.where(keep, p, 0.0)
-    acc = acc_ref[:] * alpha + jax.lax.dot_general(
-        p_v.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[:] = m_new
-    l_ref[:] = l_new
-    acc_ref[:] = acc
+    _walk(word, span, tile, chunk, classes, sub_tile)
 
-    @pl.when(last)
+    @pl.when((word & 1) != 0)
     def _finish():
         denom = jnp.maximum(l_ref[:], 1e-30)
         # lse records the TRUE softmax normalizer (backward recomputes
-        # p̂ from it); only the output division carries the inverted
+        # p-hat from it); only the output division carries the inverted
         # dropout scale
         o_denom = denom * (1.0 - dropout_p) if dropout_p > 0.0 else denom
-        o_ref[0] = (acc_ref[:] / o_denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[:] / o_denom).astype(o_ref.dtype)
         # logsumexp per row: m + log l (-inf for fully-masked rows).
-        # Stored as a (block_q, 1) column — the trailing singleton keeps
-        # the block's last two dims (block_q, 1) legal for Mosaic tiling
-        # (block_q % 8 == 0; 1 == array dim), where a 2-D (1, block_q)
-        # block is not (sublane dim 1 is neither 8-aligned nor full).
+        # Stored as a (tile, 1) column: the trailing singleton keeps the
+        # block's last two dims legal for Mosaic tiling (tile % 8 == 0;
+        # 1 == array dim), where a (1, tile) block is not.
         lse_ref[0] = jnp.where(jnp.isfinite(m_ref[:]),
                                m_ref[:] + jnp.log(denom), -jnp.inf)
 
@@ -315,78 +393,169 @@ def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-class _Plan:
-    """What the three kernels share for one call: shapes, tile sizes,
-    the mask's codes and the BlockSpecs that follow the tile schedule.
-    An index map gets the grid indices, then the two prefetched scalar
-    operands (dropout seed, schedule)."""
+# what the tiles the op chooses may plan to use of a core's fast memory:
+# the compiler gives a kernel 16 MiB of it unless told otherwise
+_VMEM_BUDGET = 12 * 2 ** 20
+_LANES = 128
+_MAX_TILE = 1024
+_SPAN = 2
+_ROWS = 512     # query rows a body works on at a time: its device code
 
-    def __init__(self, q, k, v, causal, block_q, block_k, valid_len,
-                 block_diffusion):
+
+def _chunk_of(rows):
+    """Query rows of one pass of a body over ``rows`` of them."""
+    return _ROWS if rows % _ROWS == 0 else rows
+
+
+def _working_set(tile, sub, span, dk, dv, itemsize):
+    """Bytes of fast memory a grid step plans for: three float32
+    temporaries of a chunk of rows by a tile of columns (scores,
+    probabilities, their gradient), the resident rows and the streamed
+    span of both widths, double-buffered, and the float32 accumulators."""
+    return (3 * 4 * max(_chunk_of(tile), _chunk_of(sub)) * max(tile, sub)
+            + 2 * itemsize * (tile + span * sub) * (dk + dv)
+            + 4 * tile * (dk + dv))
+
+
+def _span_for(s_len, tile, sub, dk, dv, itemsize):
+    """Sub-tiles a grid step streams: as many as divide the sequence and
+    fit the budget, ``_SPAN`` at most."""
+    for span in range(_SPAN, 1, -1):
+        if (s_len // sub) % span == 0 and _working_set(
+                tile, sub, span, dk, dv, itemsize) <= _VMEM_BUDGET:
+            return span
+    return 1
+
+
+def _choose_tile(s_pad, dk, dv, itemsize):
+    """The q and k tile for a padded length when the caller names none:
+    the sequence itself where it is one tile, else the largest multiple
+    of the lane width up to ``_MAX_TILE`` that divides it and whose
+    working set fits the budget."""
+    for tile in ([s_pad] if s_pad <= _MAX_TILE else []) + list(
+            range(_MAX_TILE, _LANES, -_LANES)):
+        if s_pad % tile == 0 and _working_set(
+                tile, tile, 1, dk, dv, itemsize) <= _VMEM_BUDGET:
+            return tile
+    return min(s_pad, _LANES)
+
+
+# one kernel's half of a plan: the resident tile's rows, a streamed
+# sub-tile's, the sub-tiles a grid step streams, the schedule, the classes
+# its steps use and the query rows of one pass of a body
+_Side = collections.namedtuple("_Side", "tile sub span words classes chunk")
+
+
+def _maskfree_share(side):
+    """Of the sub-tiles a side's schedule visits, the share that takes
+    the mask-free body."""
+    classes = _word_classes(side.words, side.span)
+    return float((classes == _FREE).sum()) / (classes != _DEAD).sum()
+
+
+class _Plan:
+    """What the three kernels of one signature share, built once
+    (`_plan`): tile sizes, the mask's codes, both span schedules with
+    their classes.  The forward and dQ hold a q tile and stream spans of
+    k sub-tiles (``rows``); dK/dV holds a k tile and streams spans of q
+    sub-tiles (``cols``).  ``block_q`` is the q tile and the q sub-tile,
+    ``block_k`` the k tile and the k sub-tile."""
+
+    def __init__(self, q_shape, k_shape, v_shape, itemsize, causal,
+                 block_q, block_k, valid_len, block_diffusion):
+        b, h, s_len, dk = q_shape
+        self.group = h // k_shape[1]
+        if h != self.group * k_shape[1]:
+            raise ValueError(f"{h} query heads over {k_shape[1]} key-value "
+                             "heads: not a multiple")
+        self.bh, self.bkv, self.s_len = b * h, b * k_shape[1], s_len
+        # queries and keys share one width, values and the output another
+        self.dk, self.dv = dk, v_shape[-1]
+        block_q, block_k = min(block_q, s_len), min(block_k, s_len)
+        codes = _mask_codes(causal, block_diffusion, s_len, valid_len)
+        self.use_eq = block_diffusion is not None
+        self.codes = codes if codes is not None else _mask_codes(
+            False, None, s_len, s_len)     # nothing reads them: all kept
+        self.rows = self._side(codes, block_q, block_k, itemsize)
+        self.cols = self._side(codes, block_k, block_q, itemsize,
+                               by_key=True)
+
+    def _side(self, codes, tile, sub, itemsize, by_key=False):
         import numpy as onp
 
-        b, h, s_len, dk = q.shape
-        self.group = h // k.shape[1]
-        if h != self.group * k.shape[1]:
-            raise ValueError(f"{h} query heads over {k.shape[1]} key-value "
-                             "heads: not a multiple")
-        self.bh, self.bkv, self.s_len = b * h, b * k.shape[1], s_len
-        # queries and keys share one width, values and the output another
-        self.dk, self.dv = dk, v.shape[-1]
-        self.block_q, self.block_k = min(block_q, s_len), min(block_k, s_len)
-        self.nq, self.nk = s_len // self.block_q, s_len // self.block_k
-        codes = _mask_codes(causal, block_diffusion, s_len, valid_len)
-        self.masked = codes is not None
-        self.use_eq = block_diffusion is not None
-        self._codes = codes
-        self.codes = codes if codes is not None else (
-            onp.zeros((s_len, 2), onp.int32), onp.zeros((2, s_len), onp.int32))
+        span = _span_for(self.s_len, tile, sub, self.dk, self.dv, itemsize)
+        classes = _classes(codes, self.s_len, *(
+            (sub, tile) if by_key else (tile, sub)))
+        words = _span_schedule(classes.T if by_key else classes, span,
+                               by_key)
+        used = onp.unique(_word_classes(words, span))
+        return _Side(tile, sub, span, words,
+                     tuple(int(c) for c in used if c != _DEAD),
+                     _chunk_of(sub if by_key else tile))
 
-    def schedule(self, by_key=False):
-        return jnp.asarray(_tile_schedule(
-            self._codes, self.nq, self.nk, self.block_q, self.block_k,
-            by_key))
+    def units(self, x, unit, kv=False):
+        """(heads, units, rows of a unit, width): a tensor over the
+        sequence cut into tiles or sub-tiles (no copy)."""
+        return x.reshape(self.bkv if kv else self.bh, self.s_len // unit,
+                         unit, -1)
 
-    def flat(self, x, kv=False):
-        return x.reshape(self.bkv if kv else self.bh, self.s_len, -1)
+    def code_units(self, unit_q, unit_k):
+        """The codes cut the same way: (units, rows, 2), (units, 2, rows)."""
+        qc, kc = self.codes
+        return (qc.reshape(-1, unit_q, 2),
+                kc.reshape(2, -1, unit_k).transpose(1, 0, 2))
 
-    def specs(self, head_of):
-        """(q-sized block, k-sized block, q codes, k codes) BlockSpecs
-        for a grid whose axis 1 walks the schedule; ``head_of(*grid
-        indices)`` gives (query head row, key-value head row).  The first
-        two take the block's width."""
+    def specs(self, side, head_of):
+        """BlockSpecs for a grid whose axis 1 walks ``side``'s schedule:
+        (q-indexed tensor of a width, k-indexed tensor of a width, q
+        codes, k codes, q-indexed column).  The resident side's block is
+        one tile, the streamed side's a span of sub-tiles;
+        ``head_of(*grid indices)`` gives (query head row, key-value head
+        row).  An index map gets the grid indices, then the two
+        prefetched scalar operands (dropout seed, schedule).  A column
+        (lse, delta) stays (heads, S, 1), its block the step's rows: it
+        is padded to a lane width in memory, and cutting it like the
+        others is a relayout XLA spends megabytes of code on."""
         import jax.experimental.pallas as pl
 
-        def q_of(ids, sched):
-            return _tiles_of(sched[ids[1]])[0]
+        tile, sub, span = side[:3]
+        by_key = side is self.cols
+        q_rows, k_rows = ((span, sub), (1, tile)) if by_key else (
+            (1, tile), (span, sub))
 
-        def k_of(ids, sched):
-            return _tiles_of(sched[ids[1]])[1]
+        def q_of(a):
+            return _tiles_of(a[-1][a[1]])[0]
 
-        def q_tile(width):
+        def k_of(a):
+            return _tiles_of(a[-1][a[1]])[1]
+
+        def q_block(width):
             return pl.BlockSpec(
-                (1, self.block_q, width),
-                lambda *a: (head_of(*a[:-2])[0], q_of(a, a[-1]), 0))
+                (1,) + q_rows + (width,),
+                lambda *a: (head_of(*a[:-2])[0], q_of(a), 0, 0))
 
-        def k_tile(width):
+        def k_block(width):
             return pl.BlockSpec(
-                (1, self.block_k, width),
-                lambda *a: (head_of(*a[:-2])[1], k_of(a, a[-1]), 0))
+                (1,) + k_rows + (width,),
+                lambda *a: (head_of(*a[:-2])[1], k_of(a), 0, 0))
 
-        return (q_tile, k_tile,
-                pl.BlockSpec((self.block_q, 2),
-                             lambda *a: (q_of(a, a[-1]), 0)),
-                pl.BlockSpec((2, self.block_k),
-                             lambda *a: (0, k_of(a, a[-1]))))
+        return (q_block, k_block,
+                pl.BlockSpec(q_rows + (2,), lambda *a: (q_of(a), 0, 0)),
+                pl.BlockSpec((k_rows[0], 2, k_rows[1]),
+                             lambda *a: (k_of(a), 0, 0)),
+                pl.BlockSpec(
+                    (1, q_rows[0] * q_rows[1], 1),
+                    lambda *a: (head_of(*a[:-2])[0], q_of(a), 0)))
 
-    def call(self, kernel, name, grid, in_specs, out_specs, out_shape,
+    def call(self, kernel, name, side, grid, in_specs, out_specs, out_shape,
              scratch, interpret, **static):
         from jax.experimental.pallas import tpu as pltpu
 
         return _pallas_call(
             functools.partial(
-                kernel, masked=self.masked, use_eq=self.use_eq,
-                block_q=self.block_q, block_k=self.block_k, **static),
+                kernel, classes=side.classes, use_eq=self.use_eq,
+                tile=side.tile, sub=side.sub, span=side.span,
+                chunk=side.chunk, **static),
             name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
@@ -394,174 +563,245 @@ class _Plan:
             out_shape=out_shape, interpret=interpret)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               valid_len=None, dropout_p=0.0, dropout_seed=None,
-               block_diffusion=None):
-    plan = _Plan(q, k, v, causal, block_q, block_k, valid_len,
+@functools.lru_cache(maxsize=256)
+def _plan(q_shape, k_shape, v_shape, dtype, causal, block_q, block_k,
+          valid_len, block_diffusion):
+    """The plan of one signature, built once: N layers, three kernels a
+    layer and remat's replays share one host computation.  Sets the gauge
+    ``attention_maskfree_share{kernel}`` from the schedules' class bits."""
+    from ..telemetry import instruments as _telemetry
+
+    plan = _Plan(q_shape, k_shape, v_shape, jnp.dtype(dtype).itemsize,
+                 causal, block_q, block_k, valid_len, block_diffusion)
+    rows, cols = _maskfree_share(plan.rows), _maskfree_share(plan.cols)
+    _telemetry.set_attention_maskfree_share({
+        "flash_attention_fwd": rows, "flash_attention_bwd_dq": rows,
+        "flash_attention_bwd_dkv": cols})
+    return plan
+
+
+def _plan_of(q, k, v, causal, block_q, block_k, valid_len, block_diffusion):
+    return _plan(q.shape, k.shape, v.shape, jnp.dtype(q.dtype).name,
+                 bool(causal), int(block_q), int(block_k), valid_len,
                  block_diffusion)
+
+
+@functools.lru_cache(maxsize=256)
+def _shared(fn, plan, *static):
+    """``fn(plan, *static, *operands)`` as one jitted function a
+    signature: the layers of a model trace and lower one kernel, not one
+    each."""
+    def call(*operands):
+        return fn(plan, *static, *operands)
+
+    call.__name__ = fn.__name__.strip("_")
+    return jax.jit(call)
+
+
+def _flash_fwd_call(plan, scale, dropout_p, interpret, seed, q, k, v):
+    tile, sub, words = plan.rows.tile, plan.rows.sub, plan.rows.words
     group, dk, dv = plan.group, plan.dk, plan.dv
-    rows = plan.schedule()
-    q_tile, k_tile, q_code, k_code = plan.specs(
-        lambda b, t: (b, _head_div(b, group)))
+    q_block, k_block, q_code, k_code, column = plan.specs(
+        plan.rows, lambda b, t: (b, _head_div(b, group)))
     out, lse = plan.call(
-        _fwd_kernel, "flash_attention_fwd",
-        grid=(plan.bh, len(rows)),
-        in_specs=[q_tile(dk), k_tile(dk), k_tile(dv), q_code, k_code],
-        out_specs=[q_tile(dv), q_tile(1)],
+        _fwd_kernel, "flash_attention_fwd", plan.rows,
+        grid=(plan.bh, len(words)),
+        in_specs=[q_block(dk), k_block(dk), k_block(dv), q_code, k_code],
+        out_specs=[q_block(dv), column],
         out_shape=[
-            jax.ShapeDtypeStruct((plan.bh, plan.s_len, dv), q.dtype),
+            jax.ShapeDtypeStruct((plan.bh, plan.s_len // tile, tile, dv),
+                                 q.dtype),
             jax.ShapeDtypeStruct((plan.bh, plan.s_len, 1), jnp.float32),
         ],
         scratch=[
-            _scratch((plan.block_q, 1)),   # running max m
-            _scratch((plan.block_q, 1)),   # running sum l
-            _scratch((plan.block_q, dv)),  # output accumulator
+            _scratch((tile, 1)),   # running max m
+            _scratch((tile, 1)),   # running sum l
+            _scratch((tile, dv)),  # output accumulator
         ],
         interpret=interpret, scale=scale, dropout_p=dropout_p,
-    )(_seed_arr(dropout_seed), rows, plan.flat(q),
-      plan.flat(k, True), plan.flat(v, True), *plan.codes)
-    return out.reshape(q.shape[:-1] + (dv,)), lse[..., 0]
+    )(seed, jnp.asarray(words), plan.units(q, tile),
+      plan.units(k, sub, True), plan.units(v, sub, True),
+      *plan.code_units(tile, sub))
+    return out, lse
 
 
-def _recompute_p(q, k, lse_col, qc_ref, kc_ref, scale, masked, use_eq):
-    """exp(QK^T * scale - lse) for one (q block, k block) tile.
-    lse_col: (block_q, 1) column (see _finish in _fwd_kernel)."""
-    s = _scores(q, k, qc_ref, kc_ref, scale, masked, use_eq)
-    return jnp.where(jnp.isfinite(lse_col), jnp.exp(s - lse_col), 0.0)
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               valid_len=None, dropout_p=0.0, dropout_seed=None,
+               block_diffusion=None):
+    plan = _plan_of(q, k, v, causal, block_q, block_k, valid_len,
+                    block_diffusion)
+    out, lse = _shared(_flash_fwd_call, plan, float(scale), dropout_p,
+                       interpret)(_seed_arr(dropout_seed), q, k, v)
+    return out.reshape(q.shape[:-1] + (plan.dv,)), lse[..., 0]
+
+
+def _recompute_p(q, k, lse_col, qc, kc, scale, masked, use_eq):
+    """exp(QK^T * scale - lse) for one (q rows, k rows) sub-tile.
+    lse_col: (rows, 1) column (see _finish in _fwd_kernel).  A row of a
+    mask-free sub-tile keeps every key of it, so its lse is finite."""
+    p = jnp.exp(_scores(q, k, qc, kc, scale, masked, use_eq) - lse_col)
+    return jnp.where(jnp.isfinite(lse_col), p, 0.0) if masked else p
 
 
 def _bwd_dq_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, qc_ref, kc_ref, dq_ref, dq_acc, *,
-                   scale, masked, use_eq, block_q, block_k, dropout_p=0.0):
+                   scale, classes, use_eq, tile, sub, span, chunk,
+                   dropout_p=0.0):
     import jax.experimental.pallas as pl
 
     bh_idx = pl.program_id(0)
-    q_idx, kv_idx, first, last = _step_of(sched_ref, pl.program_id(1))
+    word = sched_ref[pl.program_id(1)]
+    q_idx, k_blk = _tiles_of(word)
 
-    @pl.when(first)
+    @pl.when((word & 2) != 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0], qc_ref, kc_ref,
-                     scale, masked, use_eq)
-    dp = jax.lax.dot_general(
-        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)    # (bq, bk)
-    if dropout_p > 0.0:
-        # dP̂ = M/(1−p)·(dO V^T); delta already equals
-        # rowsum(P̂∘dP̂) because delta = rowsum(dO∘O)
-        keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx,
-                          block_q, block_k, p.shape, dropout_p)
-        dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
-    ds = p * (dp - delta_ref[0]) * scale
-    dq_acc[:] += jax.lax.dot_general(
-        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    def sub_tile(j, r0, masked):
+        rows = pl.ds(r0, chunk)
+        k = k_ref[0, j]
+        p = _recompute_p(q_ref[0, 0, rows], k, lse_ref[0, rows],
+                         qc_ref[0, rows], kc_ref[j], scale, masked, use_eq)
+        dp = jax.lax.dot_general(
+            do_ref[0, 0, rows], v_ref[0, j], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)    # (chunk, sub)
+        if dropout_p > 0.0:
+            # dP-hat = M/(1-p).(dO V^T); delta already equals
+            # rowsum(P-hat . dP-hat) because delta = rowsum(dO . O)
+            keep = _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
+                              (k_blk * span + j) * sub, p.shape, dropout_p)
+            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
+        # dS without its factor ``scale``: _finish applies it to the sum
+        ds = p * (dp - delta_ref[0, rows])
+        dq_acc[rows] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(last)
+    _walk(word, span, tile, chunk, classes, sub_tile)
+
+    @pl.when((word & 1) != 0)
     def _finish():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, qc_ref, kc_ref, dk_ref, dv_ref,
-                    dk_acc, dv_acc, *, scale, masked, use_eq, block_q,
-                    block_k, group, dropout_p=0.0):
+                    dk_acc, dv_acc, *, scale, classes, use_eq, tile, sub,
+                    span, chunk, group, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
-    # grid: (key-value head, live pair in k-major order, query head of
-    # the group): the pairs of one k tile and, inside each, the query
-    # heads that share this key-value head all add into one dK and dV
+    # grid: (key-value head, step of the k-major schedule, query head of
+    # the group): the spans of q sub-tiles of one k tile and, inside
+    # each, the query heads that share this key-value head all add into
+    # one dK and dV
     g_idx = pl.program_id(2)
     bh_idx = pl.program_id(0) * group + g_idx
-    q_idx, kv_idx, first, last = _step_of(sched_ref, pl.program_id(1))
+    word = sched_ref[pl.program_id(1)]
+    q_blk, k_idx = _tiles_of(word)
 
-    @pl.when(first & (g_idx == 0))
+    @pl.when(((word & 2) != 0) & (g_idx == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0], qc_ref, kc_ref,
-                     scale, masked, use_eq)
-    if dropout_p > 0.0:
-        keep = _tile_keep(seed_ref, bh_idx, q_idx, kv_idx,
-                          block_q, block_k, p.shape, dropout_p)
-        p_d = jnp.where(keep, p, 0.0) / (1.0 - dropout_p)
-    else:
-        keep = None
+    def sub_tile(j, r0, masked):
+        rows = pl.ds(r0, chunk)
+        # lse and delta: one column over the span, not cut into sub-tiles
+        span_rows = pl.ds(pl.multiple_of(j * sub + r0, chunk), chunk)
+        q, do = q_ref[0, j, rows], do_ref[0, j, rows]
+        p = _recompute_p(q, k_ref[0, 0], lse_ref[0, span_rows],
+                         qc_ref[j, rows], kc_ref[0], scale, masked,
+                         use_eq)                             # (chunk, tile)
+        dp = jax.lax.dot_general(
+            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
         p_d = p
-    # dV += P_d^T dO (P_d = dropped+rescaled probs, what fwd used)
-    dv_acc[:] += jax.lax.dot_general(
-        p_d.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(
-        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if dropout_p > 0.0:
-        dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
-    ds = p * (dp - delta_ref[0]) * scale
-    # dK += dS^T Q
-    dk_acc[:] += jax.lax.dot_general(
-        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        if dropout_p > 0.0:
+            keep = _pair_keep(seed_ref, bh_idx,
+                              (q_blk * span + j) * sub + r0,
+                              k_idx * tile, p.shape, dropout_p)
+            p_d = jnp.where(keep, p, 0.0) / (1.0 - dropout_p)
+            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
+        # dV += P_d^T dO (P_d = dropped+rescaled probs, what fwd used)
+        dv_acc[:] += jax.lax.dot_general(
+            p_d.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # dK += dS^T Q, dS without its factor ``scale`` (see _finish)
+        ds = p * (dp - delta_ref[0, span_rows])
+        dk_acc[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(last & (g_idx == group - 1))
+    _walk(word, span, sub, chunk, classes, sub_tile)
+
+    @pl.when(((word & 1) != 0) & (g_idx == group - 1))
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
+                    lse, g):
+    group, dk_, dv_ = plan.group, plan.dk, plan.dv
+    # delta = rowsum(dO * O): the softmax-grad correction term (with
+    # dropout it still equals rowsum(P-hat . dP-hat) since O = P_d V).
+    # lse/delta ride as (rows, 1) columns so their blocks satisfy
+    # Mosaic's last-two-dims tiling rule.
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(plan.bh, plan.s_len, 1)
+    lse = lse.reshape(delta.shape)
+
+    tile, sub, words = plan.rows.tile, plan.rows.sub, plan.rows.words
+    q_block, k_block, q_code, k_code, column = plan.specs(
+        plan.rows, lambda b, t: (b, _head_div(b, group)))
+    dq = plan.call(
+        _bwd_dq_kernel, "flash_attention_bwd_dq", plan.rows,
+        grid=(plan.bh, len(words)),
+        in_specs=[q_block(dk_), k_block(dk_), k_block(dv_), q_block(dv_),
+                  column, column, q_code, k_code],
+        out_specs=q_block(dk_),
+        out_shape=jax.ShapeDtypeStruct(
+            (plan.bh, plan.s_len // tile, tile, dk_), q.dtype),
+        scratch=[_scratch((tile, dk_))],
+        interpret=interpret, scale=scale, dropout_p=dropout_p,
+    )(seed, jnp.asarray(words), plan.units(q, tile),
+      plan.units(k, sub, True), plan.units(v, sub, True),
+      plan.units(g, tile), lse, delta, *plan.code_units(tile, sub))
+
+    tile, sub, words = plan.cols.tile, plan.cols.sub, plan.cols.words
+    q_block, k_block, q_code, k_code, column = plan.specs(
+        plan.cols, lambda b, t, j: (b * group + j, b))
+    dk, dv = plan.call(
+        _bwd_dkv_kernel, "flash_attention_bwd_dkv", plan.cols,
+        grid=(plan.bkv, len(words), group),
+        in_specs=[q_block(dk_), k_block(dk_), k_block(dv_), q_block(dv_),
+                  column, column, q_code, k_code],
+        out_specs=[k_block(dk_), k_block(dv_)],
+        out_shape=[
+            jax.ShapeDtypeStruct((plan.bkv, plan.s_len // tile, tile, dk_),
+                                 k.dtype),
+            jax.ShapeDtypeStruct((plan.bkv, plan.s_len // tile, tile, dv_),
+                                 v.dtype),
+        ],
+        scratch=[_scratch((tile, dk_)), _scratch((tile, dv_))],
+        interpret=interpret, scale=scale, dropout_p=dropout_p, group=group,
+    )(seed, jnp.asarray(words), plan.units(q, sub), plan.units(k, tile, True),
+      plan.units(v, tile, True), plan.units(g, sub), lse, delta,
+      *plan.code_units(sub, tile))
+    return dq, dk, dv
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                interpret, valid_len=None, dropout_p=0.0,
                dropout_seed=None, block_diffusion=None):
     """Block-streamed FlashAttention-2 backward: O(S) memory, no (S, S)
-    residual — P tiles are recomputed from (q, k, lse) per block (and
-    the dropout keep mask from its counter hash)."""
-    plan = _Plan(q, k, v, causal, block_q, block_k, valid_len,
-                 block_diffusion)
-    group, dk_, dv_ = plan.group, plan.dk, plan.dv
-    qr, kr, vr = plan.flat(q), plan.flat(k, True), plan.flat(v, True)
-    do, orr = plan.flat(g), plan.flat(out)
-    # delta = rowsum(dO * O) — the softmax-grad correction term (with
-    # dropout it still equals rowsum(P̂∘dP̂) since O = P_d V).
-    # lse/delta ride as (bh, s_len, 1) columns so their (block_q, 1)
-    # blocks satisfy Mosaic's last-two-dims tiling rule.
-    delta = jnp.sum(do.astype(jnp.float32) * orr.astype(jnp.float32),
-                    axis=-1)[..., None]             # (bh, s_len, 1)
-    lse = lse.reshape(plan.bh, plan.s_len, 1)
-    seed = _seed_arr(dropout_seed)
-
-    rows = plan.schedule()
-    q_tile, k_tile, q_code, k_code = plan.specs(
-        lambda b, t: (b, _head_div(b, group)))
-    dq = plan.call(
-        _bwd_dq_kernel, "flash_attention_bwd_dq",
-        grid=(plan.bh, len(rows)),
-        in_specs=[q_tile(dk_), k_tile(dk_), k_tile(dv_), q_tile(dv_),
-                  q_tile(1), q_tile(1), q_code, k_code],
-        out_specs=q_tile(dk_),
-        out_shape=jax.ShapeDtypeStruct((plan.bh, plan.s_len, dk_), q.dtype),
-        scratch=[_scratch((plan.block_q, dk_))],
-        interpret=interpret, scale=scale, dropout_p=dropout_p,
-    )(seed, rows, qr, kr, vr, do, lse, delta, *plan.codes)
-
-    by_key = plan.schedule(by_key=True)
-    q_tile, k_tile, q_code, k_code = plan.specs(
-        lambda b, t, j: (b * group + j, b))
-    dk, dv = plan.call(
-        _bwd_dkv_kernel, "flash_attention_bwd_dkv",
-        grid=(plan.bkv, len(by_key), group),
-        in_specs=[q_tile(dk_), k_tile(dk_), k_tile(dv_), q_tile(dv_),
-                  q_tile(1), q_tile(1), q_code, k_code],
-        out_specs=[k_tile(dk_), k_tile(dv_)],
-        out_shape=[
-            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, dk_), k.dtype),
-            jax.ShapeDtypeStruct((plan.bkv, plan.s_len, dv_), v.dtype),
-        ],
-        scratch=[_scratch((plan.block_k, dk_)),
-                 _scratch((plan.block_k, dv_))],
-        interpret=interpret, scale=scale, dropout_p=dropout_p, group=group,
-    )(seed, by_key, qr, kr, vr, do, lse, delta, *plan.codes)
+    residual: P sub-tiles are recomputed from (q, k, lse) (and the
+    dropout keep mask from its counter hash)."""
+    plan = _plan_of(q, k, v, causal, block_q, block_k, valid_len,
+                    block_diffusion)
+    dq, dk, dv = _shared(_flash_bwd_call, plan, float(scale), dropout_p,
+                         interpret)(
+        _seed_arr(dropout_seed), q, k, v, out, lse, g)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -606,8 +846,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 @register_op("flash_attention")
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, interpret=None, dropout_p=0.0,
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, interpret=None, dropout_p=0.0,
                     dropout_seed=None, block_diffusion=None):
     """Fused multi-head attention: softmax(QK^T * scale + mask) V.
 
@@ -619,28 +859,54 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     every tile, accumulator and gradient has the width of its tensor, so
     nothing is padded to the wider one.  The default scale is
     1 / sqrt(D).  Runs the Pallas kernel on TPU (or anywhere with
-    interpret=True); off a TPU the jnp reference runs instead.  Ragged S
-    is tile-padded and the kernel masks the padded keys (static
-    `valid_len`).
+    interpret=True); off a TPU the jnp reference runs instead.
 
-    What the kernel cannot tile takes the reference path LOUDLY — the
+    **Tiles.**  A caller that names no ``block_q`` / ``block_k`` gets the
+    tile the op reads off what it sees (`_choose_tile`): the sequence is
+    padded to whole sublanes (up to 128 positions) or whole lane widths;
+    up to 1024 positions it is one tile, above that the tile is the
+    largest multiple of 128 up to 1024 that divides it and whose working
+    set at the operands' widths and type fits the fast-memory budget (the
+    head group does not enter: the key-value tile dK/dV holds is shared
+    by its query heads).  A named block is honoured: ``block_q`` is the
+    q tile (and dK/dV's q sub-tile), ``block_k`` the k sub-tile (and
+    dK/dV's k tile); the sequence is padded to a multiple of ``block_q``
+    and the kernel masks the padded keys (static ``valid_len``).
+
+    What the kernel cannot tile takes the reference path LOUDLY, with the
     counter ``attention_kernel_fallback_total{reason}`` and a
-    RuntimeWarning — wherever the kernel was asked for (on a TPU, or with
+    RuntimeWarning, wherever the kernel was asked for (on a TPU, or with
     interpret=True): ``reason="width"`` where D or Dv is not a multiple
     of 8 (a tile's rows would not be whole sublanes), ``reason="tile"``
     where ``block_q`` / ``block_k`` do not divide the padded length or
     are not multiples of 8.
 
-    The mask is static: nothing, ``causal``, or ``block_diffusion=
-    (block length B, half length L)`` for a sequence of S = 2L positions
-    [noisy ; clean] (`_mask_codes` has the rule).  It is evaluated per
-    tile from two small code vectors, and the tiles it empties are left
-    out of the grid in the forward, dQ and dK/dV kernels alike.
+    **The span schedule.**  The mask is static: nothing, ``causal``, or
+    ``block_diffusion=(block length B, half length L)`` for a sequence of
+    S = 2L positions [noisy ; clean] (`_mask_codes` has the rule).  A
+    grid step of the forward and dQ kernels holds one q tile and streams
+    a span of consecutive k sub-tiles (dK/dV: a k tile and a span of q
+    sub-tiles); the host lists the steps once (`_span_schedule`) and
+    gives each sub-tile a class from the codes' minima and maxima:
+    dead (skipped; a span of dead sub-tiles is not in the grid at all),
+    mask-free (every pair kept: no codes read, no compare, no select) or
+    masked.  The kernel walks the span with one rolled loop that takes
+    the mask-free or the masked body by the class, so each body is traced
+    once a kernel whatever the span.  An unmasked call is the case where
+    every sub-tile is mask-free, padding one more masked class at the
+    edge.  The gauge ``attention_maskfree_share{kernel}`` is the share of
+    visited sub-tiles that take the mask-free body.
+
+    **Before the first step** the plan of a signature (codes, both
+    schedules, classes, the gauge) is built once on the host in about a
+    millisecond (`_plan`), and the layers of a model that share the
+    signature share one traced and lowered copy of each kernel
+    (`_shared`).
 
     dropout_p > 0 with an int32 `dropout_seed` applies attention-prob
     dropout inside the kernel (numerator-masked, inverted scaling; the
     counter-hash mask regenerates identically in the backward kernels
-    and the reference path — see _dropout_keep).
+    and the reference path; see _dropout_keep).
     """
     d = q.shape[-1]
     if k.shape[-1] != d or v.shape[:-1] != k.shape[:-1]:
@@ -669,11 +935,18 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     if d % 8 or v.shape[-1] % 8:
         return _fallback(q, k, v, "width")
     s_len = q.shape[2]
-    s_pad = _tile_pad_len(s_len, block_q)
-    bq = min(block_q, s_pad)
-    bk = min(block_k, s_pad)
-    if s_pad % bq or s_pad % bk or bq % 8 or bk % 8:
-        return _fallback(q, k, v, "tile")
+    if block_q is None and block_k is None:
+        # whole sublanes up to one lane width, whole lane widths above
+        s_pad = _tile_pad_len(s_len, _LANES)
+        bq = bk = _choose_tile(s_pad, d, v.shape[-1],
+                               jnp.dtype(q.dtype).itemsize)
+    else:
+        block_q = block_q or block_k
+        block_k = block_k or block_q
+        s_pad = _tile_pad_len(s_len, block_q)
+        bq, bk = min(block_q, s_pad), min(block_k, s_pad)
+        if s_pad % bq or s_pad % bk or bq % 8 or bk % 8:
+            return _fallback(q, k, v, "tile")
     seed = _seed_arr(dropout_seed)
     if s_pad == s_len:
         return _flash(q, k, v, seed, causal, scale, bq, bk, interpret,

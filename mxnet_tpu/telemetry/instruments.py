@@ -23,6 +23,7 @@ __all__ = [
     "jit_compile_total", "jit_compile_seconds", "jit_trace_total",
     "hybridize_fallback_total", "attention_kernel_fallback_total",
     "record_attention_fallback",
+    "attention_maskfree_share", "set_attention_maskfree_share",
     "xla_compile_seconds_total", "xla_programs_total",
     "install_compile_listener",
     "transfer_total", "transfer_bytes_total",
@@ -159,6 +160,13 @@ attention_kernel_fallback_total = counter(
     "reference because the kernel cannot tile them: reason=width (a head "
     "width that is not a multiple of 8), reason=tile (blocks that do not "
     "divide the padded length)", ["reason"])
+attention_maskfree_share = gauge(
+    "attention_maskfree_share",
+    "Of the sub-tiles of scores a flash kernel's span schedule visits, the "
+    "share that takes the mask-free body (every pair kept: no codes read, "
+    "no compare, no select); 1 is an unmasked call. Set on the host when "
+    "the plan of a signature is built (ops.pallas_attention._plan), from "
+    "the schedule's class bits; the latest signature's", ["kernel"])
 compile_flops = gauge(
     "compile_flops",
     "XLA cost_analysis flops of the latest executable per block variant "
@@ -797,6 +805,13 @@ def record_attention_fallback(reason):
     if not REGISTRY.enabled:
         return
     attention_kernel_fallback_total.labels(reason).inc()
+
+
+def set_attention_maskfree_share(by_kernel):
+    if not REGISTRY.enabled:
+        return
+    for kernel, share in by_kernel.items():
+        attention_maskfree_share.labels(kernel).set(share)
 
 
 def record_transfer(direction, nbytes):

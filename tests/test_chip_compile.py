@@ -97,18 +97,24 @@ def test_flash_attention_compiles_for_v5e(spec, seq, case):
 
 
 # -- flash attention: SDAR's 32 query over 4 key-value heads of 128, 2 x 4096
-#    positions under the block-diffusion mask, tiles of 512 ------------------
+#    positions under the block-diffusion mask, at explicit tiles of 512 and
+#    at the tiles the op chooses (1024, spans of two, chunks of 512 rows) ----
 
+def _tiles(tile):
+    return {} if tile is None else {"block_q": tile, "block_k": tile}
+
+
+@pytest.mark.parametrize("tile", [512, None], ids=["tile512", "chosen"])
 @pytest.mark.parametrize("case", ["fwd", "grad"])
-def test_grouped_block_diffusion_attention_compiles_for_v5e(spec, case):
+def test_grouped_block_diffusion_attention_compiles_for_v5e(spec, case, tile):
     from mxnet_tpu.ops.pallas_attention import flash_attention
 
     q = spec((1, 32, 8192, 128), jnp.bfloat16)
     kv = spec((1, 4, 8192, 128), jnp.bfloat16)
 
     def attend(q, k, v):
-        return flash_attention(q, k, v, interpret=False, block_q=512,
-                               block_k=512, block_diffusion=(4, 4096))
+        return flash_attention(q, k, v, interpret=False, **_tiles(tile),
+                               block_diffusion=(4, 4096))
 
     if case == "fwd":
         assert _kernel_calls(attend, q, kv, kv) == 1
@@ -120,18 +126,19 @@ def test_grouped_block_diffusion_attention_compiles_for_v5e(spec, case):
 
 
 # -- flash attention: latent attention's 32 heads with keys 192 and values
-#    128 wide, 8192 positions under the causal mask, tiles of 512 -----------
+#    128 wide, 8192 positions under the causal mask, both tilings -----------
 
+@pytest.mark.parametrize("tile", [512, None], ids=["tile512", "chosen"])
 @pytest.mark.parametrize("case", ["fwd", "grad"])
-def test_latent_attention_widths_compile_for_v5e(spec, case):
+def test_latent_attention_widths_compile_for_v5e(spec, case, tile):
     from mxnet_tpu.ops.pallas_attention import flash_attention
 
     qk = spec((1, 32, 8192, 192), jnp.bfloat16)
     v = spec((1, 32, 8192, 128), jnp.bfloat16)
 
     def attend(q, k, v):
-        return flash_attention(q, k, v, interpret=False, block_q=512,
-                               block_k=512, causal=True)
+        return flash_attention(q, k, v, interpret=False, **_tiles(tile),
+                               causal=True)
 
     if case == "fwd":
         assert _kernel_calls(attend, qk, qk, v) == 1
@@ -140,6 +147,98 @@ def test_latent_attention_widths_compile_for_v5e(spec, case):
             lambda *a: attend(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2)), qk, qk, v)
         assert calls == 3      # forward, dQ (192 wide), dK/dV (192 and 128)
+
+
+# -- the flash kernels' set-up (ISSUE 40): one plan and one lowered kernel a
+#    signature, a plan that costs a millisecond, bodies that stay small -------
+
+# (q, k, v shapes, mask, the parent's serialised body bytes by kernel: its
+# 512 x 512 tiles lowered for this described v5e at commit ef34035)
+_CELL_KERNELS = {
+    "sdar": ((1, 32, 8192, 128), (1, 4, 8192, 128), (1, 4, 8192, 128),
+             {"block_diffusion": (4, 4096)},
+             {"flash_attention_fwd": 11384, "flash_attention_bwd_dq": 6720,
+              "flash_attention_bwd_dkv": 7636}),
+    "kanana2": ((1, 32, 8192, 192), (1, 32, 8192, 192), (1, 32, 8192, 128),
+                {"causal": True},
+                {"flash_attention_fwd": 10876, "flash_attention_bwd_dq": 6492,
+                 "flash_attention_bwd_dkv": 7568}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_KERNELS))
+def test_layers_of_one_signature_share_the_plan_and_the_kernels(
+        spec, cell, monkeypatch):
+    """Four layers at a cell's shapes, forward and backward, lowered for the
+    described v5e: the plan is built once, the module holds three Mosaic
+    calls (the parent's held three a layer) and each serialised body is
+    under twice the parent's bytes."""
+    import re
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    qs, ks, vs, mask, parent_bytes = _CELL_KERNELS[cell]
+    layers, built = 4, []
+    init = pa._Plan.__init__
+    monkeypatch.setattr(
+        pa._Plan, "__init__",
+        lambda self, *a: (built.append(a), init(self, *a))[1])
+    pa._plan.cache_clear()
+
+    def loss(q, k, v):
+        total = 0.0
+        for i in range(layers):
+            with jax.named_scope(f"layer{i}"), jax.named_scope("attention"):
+                out = pa.flash_attention(q, k, v, interpret=False, **mask)
+            total = total + out.astype(jnp.float32).sum()
+            q = q + out.mean().astype(q.dtype)
+        return total
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        spec(qs, jnp.bfloat16), spec(ks, jnp.bfloat16),
+        spec(vs, jnp.bfloat16)).as_text()
+    assert len(built) == 1
+    calls = re.findall(r"stablehlo.custom_call @tpu_custom_call.*", text)
+    assert len(calls) == 3 <= 3 * layers
+    for line in calls:
+        name = re.search(r'kernel_name = "([^"]+)"', line).group(1)
+        body = re.search(r'backend_config = "([^"]*)"', line).group(1)
+        assert len(body) < 2 * parent_bytes[name], (name, len(body))
+    # every layer still calls its kernels under its own scope
+    assert text.count("call @flash_fwd_call") == layers
+    assert text.count("call @flash_bwd_call") == layers
+
+
+@pytest.mark.parametrize("mask", [{"causal": True},
+                                  {"block_diffusion": (4, 4096)}],
+                         ids=["causal", "block_diffusion"])
+def test_the_plan_for_8192_positions_costs_a_millisecond(mask):
+    """Codes, both schedules, classes and the gauge for 8192 positions:
+    well under 50 ms on the host, and no (S, S) array on the way (the
+    dense mask would be 64 MiB of booleans; the plan's peak is the codes
+    in 64 bits)."""
+    import time
+    import tracemalloc
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    shape = (2, 32, 8192, 128)
+    args = (shape, shape, shape, "bfloat16", mask.get("causal", False),
+            1024, 1024, None, mask.get("block_diffusion"))
+    pa._plan.cache_clear()
+    pa._plan(*args)                              # imports, first numpy calls
+    pa._plan.cache_clear()
+    tracemalloc.start()
+    t = time.perf_counter()
+    plan = pa._plan(*args)
+    seconds = time.perf_counter() - t
+    peak = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
+    assert seconds < 0.05, seconds
+    assert peak < 4 * 2 ** 20, peak
+    assert plan.rows[:3] == plan.cols[:3] == (1024, 1024, 2)
+    assert pa._plan(*args) is plan
+    pa._plan.cache_clear()
 
 
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------

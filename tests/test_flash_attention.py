@@ -320,3 +320,207 @@ def test_pallas_calls_carry_stable_names(grad, expect):
     fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
     names = _pallas_names(jax.make_jaxpr(fn)(q, q, q))
     assert sorted(set(names)) == sorted(expect)
+
+
+# -- the span schedule: classes, steps, the tile the op chooses (ISSUE 40) ----
+
+from mxnet_tpu.ops import pallas_attention as pa  # noqa: E402
+
+# (name, _mask_codes arguments after the length, length)
+_MASKS = {
+    "causal": ((True, None), 256),
+    "block_diffusion": ((False, (4, 128)), 256),
+    "wide_blocks": ((False, (64, 128)), 256),     # a block holds sub-tiles
+    "padded": ((False, None, 200), 256),
+    "causal_padded": ((True, None, 200), 256),
+    "unmasked": ((False, None), 256),
+}
+
+
+def _codes(mask):
+    (causal, blockdiff, *valid), s_len = _MASKS[mask]
+    return pa._mask_codes(causal, blockdiff, s_len, *valid), s_len
+
+
+@pytest.mark.parametrize("by_key", [False, True], ids=["q_major", "k_major"])
+@pytest.mark.parametrize("tile,sub,span", [
+    (64, 64, 2), (128, 32, 4), (32, 64, 1), (64, 16, 2), (256, 64, 4)])
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+def test_span_schedule_classes_say_what_the_dense_mask_says(mask, tile, sub,
+                                                            span, by_key):
+    """Every sub-tile's class against the dense mask (dead <=> no pair
+    kept, mask-free <=> all kept); every resident tile is written (a
+    first and a last step, in order) and the grid holds exactly the spans
+    with a live sub-tile."""
+    codes, s_len = _codes(mask)
+    dense = (onp.ones((s_len, s_len), bool) if codes is None
+             else onp.asarray(pa._keep(*codes, use_eq=True)))
+    unit_q, unit_k = (sub, tile) if by_key else (tile, sub)
+    classes = pa._classes(codes, s_len, unit_q, unit_k)
+    cells = dense.reshape(s_len // unit_q, unit_q, s_len // unit_k, unit_k)
+    assert onp.array_equal(classes == pa._DEAD, ~cells.any(axis=(1, 3)))
+    assert onp.array_equal(classes == pa._FREE, cells.all(axis=(1, 3)))
+
+    by_tile = classes.T if by_key else classes
+    words = pa._span_schedule(by_tile, span, by_key)
+    qi, kj = words >> 20, (words >> 10) & 0x3FF
+    major, minor = (kj, qi) if by_key else (qi, kj)
+    assert onp.array_equal(onp.unique(major), onp.arange(s_len // tile))
+    assert onp.all(onp.diff(major) >= 0)
+    first, last = (words & 2) != 0, (words & 1) != 0
+    edge = major[1:] != major[:-1]
+    assert onp.array_equal(first[1:], edge) and first[0]
+    assert onp.array_equal(last[:-1], edge) and last[-1]
+    # the classes the kernel will read are the classes of those sub-tiles;
+    # a tile the mask empties (keys that are all padding) keeps one masked
+    by_tile = by_tile.copy()
+    by_tile[~by_tile.any(1), 0] = pa._MASKED
+    seen = onp.full_like(by_tile, pa._DEAD)
+    got = pa._word_classes(words, span)
+    for j in range(span):
+        seen[major, minor * span + j] = got[:, j]
+    assert onp.array_equal(seen, by_tile)
+    assert (got != pa._DEAD).any(1).all()       # no step of dead sub-tiles
+
+
+def test_a_tile_the_mask_empties_is_still_written():
+    """Padding that empties whole k tiles: dK/dV visits them once, as a
+    masked sub-tile (which adds zeros), and the forward never does."""
+    codes = pa._mask_codes(False, None, 256, 100)
+    by_key = pa._classes(codes, 256, 64, 64).T
+    assert not by_key[2:].any()                 # k tiles 2, 3: all padding
+    words = pa._span_schedule(by_key, 2, True)
+    kj = (words >> 10) & 0x3FF
+    assert sorted(kj) == [0, 0, 1, 1, 2, 3]
+    classes = pa._word_classes(words, 2)
+    assert classes[kj >= 2].tolist() == [[pa._MASKED, pa._DEAD]] * 2
+    rows = pa._span_schedule(pa._classes(codes, 256, 64, 64), 2)
+    assert sorted((rows >> 10) & 0x3FF) == [0] * 4
+
+
+def test_classes_stay_safe_where_a_unit_straddles_the_noisy_clean_boundary():
+    """Units that do not divide the half: a class may be coarser than the
+    dense mask (masked where all is kept) but never promises too much."""
+    codes = pa._mask_codes(False, (8, 24), 48)
+    cells = onp.asarray(pa._keep(*codes)).reshape(6, 8, 3, 16).transpose(
+        0, 2, 1, 3)
+    classes = pa._classes(codes, 48, 8, 16)
+    assert not cells[classes == pa._DEAD].any()
+    assert cells[classes == pa._FREE].all()
+    assert (classes == pa._MASKED)[cells.all(axis=(2, 3))].any()
+
+
+@pytest.mark.parametrize("s_len,dk,dv,itemsize,padded,tile", [
+    (8192, 128, 128, 2, 8192, 1024),    # the SDAR cell
+    (8192, 192, 128, 2, 8192, 1024),    # the kanana-2 cell
+    (384, 64, 64, 2, 384, 384),         # BERT at SQuAD's length: one tile
+    (100, 16, 16, 4, 104, 104),         # whole sublanes below a lane width
+    (1000, 64, 64, 2, 1024, 1024),      # whole lane widths above
+    (1152, 64, 64, 2, 1152, 384),       # 9 x 128: the largest divisor
+    (2176, 64, 64, 2, 2176, 128),       # 17 x 128
+    (8192, 256, 256, 2, 8192, 1024),    # wider heads: still 1024, span 1
+    (8192, 192, 128, 4, 8192, 512),     # float32 operands: the budget
+    (8192, 640, 640, 4, 8192, 256),
+    (8192, 2048, 2048, 4, 8192, 128),
+])
+def test_the_tile_is_read_off_the_shapes(s_len, dk, dv, itemsize, padded,
+                                         tile):
+    assert pa._tile_pad_len(s_len, 128) == padded
+    assert pa._choose_tile(padded, dk, dv, itemsize) == tile
+    span = pa._span_for(padded, tile, tile, dk, dv, itemsize)
+    assert (padded // tile) % span == 0 and 1 <= span <= pa._SPAN
+    assert pa._working_set(tile, tile, span, dk, dv,
+                           itemsize) <= pa._VMEM_BUDGET < 16 * 2 ** 20
+
+
+@pytest.mark.parametrize("blocks", [None, (32, 16), (64, 64)],
+                         ids=["chosen", "q32_k16", "tile64"])
+@pytest.mark.parametrize("case", [
+    "causal", "block_diffusion", "padded", "unmasked", "latent_widths",
+    "causal_dropout", "block_diffusion_dropout"])
+def test_span_kernels_match_the_reference(case, blocks):
+    """Forward and all three gradients, interpreted, against the plain
+    reference with grouped heads: at the tile the op chooses and at
+    explicit ones (whose spans hold dead, mask-free and masked
+    sub-tiles)."""
+    s_len = 120 if case == "padded" else 128
+    dk, dv = (192, 128) if case == "latent_widths" else (16, 16)
+    mask = {}
+    if "causal" in case or case == "latent_widths":
+        mask["causal"] = True
+    if "block_diffusion" in case:
+        mask["block_diffusion"] = (4, 64)
+    if "dropout" in case:
+        mask.update(dropout_p=0.2, dropout_seed=jnp.asarray([11], jnp.int32))
+    rs = onp.random.RandomState(3)
+    q, k, v, w = (jnp.asarray(rs.randn(1, h, s_len, d).astype("f")) * 0.5
+                  for h, d in ((4, dk), (2, dk), (2, dv), (4, dv)))
+    tiles = {} if blocks is None else dict(block_q=blocks[0],
+                                           block_k=blocks[1])
+
+    def kernel(q, k, v):
+        return pa.flash_attention(q, k, v, interpret=True, **tiles, **mask)
+
+    def plain(q, k, v):
+        return pa.attention_reference(q, k, v, **mask)
+
+    onp.testing.assert_allclose(kernel(q, k, v), plain(q, k, v),
+                                rtol=1e-5, atol=2e-6)
+    got = jax.grad(lambda *a: (kernel(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, "qkv"):
+        onp.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5,
+                                    err_msg="d" + name)
+
+
+@pytest.mark.parametrize("mask", [{"causal": True},
+                                  {"block_diffusion": (4, 1024)}],
+                         ids=["causal", "block_diffusion"])
+def test_the_chosen_tiles_span_a_long_sequence(mask):
+    """2048 positions: the op takes tiles of 1024 and spans of two, so a
+    grid step walks dead, masked and mask-free sub-tiles of 1024 x 1024;
+    bf16 operands as the cells have them."""
+    rs = onp.random.RandomState(5)
+    q, k, v = (jnp.asarray(rs.randn(1, h, 2048, 16).astype("f") * 0.5,
+                           jnp.bfloat16) for h in (2, 1, 1))
+    plan = pa._plan_of(q, k, v, mask.get("causal", False), 1024, 1024, None,
+                       mask.get("block_diffusion"))
+    assert plan.rows[:3] == plan.cols[:3] == (1024, 1024, 2)
+    assert set(plan.rows.classes) == ({pa._FREE, pa._MASKED} if "causal" in mask
+                                 else {pa._MASKED})
+
+    def loss(fn):
+        return lambda *a: fn(*a).astype(jnp.float32).sum()
+
+    kernel = lambda *a: pa.flash_attention(*a, interpret=True, **mask)  # noqa: E731
+    plain = lambda *a: pa.attention_reference(*a, **mask)  # noqa: E731
+    onp.testing.assert_allclose(kernel(q, k, v).astype("f"),
+                                plain(q, k, v).astype("f"),
+                                rtol=2e-2, atol=2e-2)
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        onp.testing.assert_allclose(g.astype("f"), r.astype("f"),
+                                    rtol=5e-2, atol=5e-2)
+
+
+def test_the_gauge_reads_the_schedules_class_bits():
+    """`attention_maskfree_share{kernel}` is set when a plan is built: the
+    cells' two masks at their tiles, and an unmasked call reads 1."""
+    from mxnet_tpu.telemetry import instruments as ti
+
+    def shares(**mask):
+        pa._plan.cache_clear()
+        ti.attention_maskfree_share.clear()
+        shape = (1, 1, 8192, 128)
+        pa._plan(shape, shape, shape, "bfloat16", mask.get("causal", False),
+                 1024, 1024, None, mask.get("block_diffusion"))
+        return {k[0]: c.value for k, c in
+                ti.attention_maskfree_share.series()}
+
+    kernels = {"flash_attention_fwd", "flash_attention_bwd_dq",
+               "flash_attention_bwd_dkv"}
+    assert shares(causal=True) == dict.fromkeys(kernels, 28 / 36)
+    assert shares(block_diffusion=(4, 4096)) == dict.fromkeys(kernels, 0.5)
+    assert shares() == dict.fromkeys(kernels, 1.0)
+    pa._plan.cache_clear()

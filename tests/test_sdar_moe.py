@@ -225,8 +225,9 @@ def test_the_tile_schedule_visits_every_live_tile_and_no_more(
         half, blen, tile, live, by_key):
     n = 2 * half // tile
     codes = pa._mask_codes(False, (blen, half), 2 * half)
-    sched = pa._tile_schedule(codes, n, n, tile, tile, by_key)
-    qi, kj = sched >> 17, (sched >> 2) & 0x7FFF
+    classes = pa._classes(codes, 2 * half, tile, tile)
+    sched = pa._span_schedule(classes.T if by_key else classes, 1, by_key)
+    qi, kj = sched >> 20, (sched >> 10) & 0x3FF
     keep = onp.asarray(pa._keep(*codes)).reshape(n, tile, n, tile)
     truth = keep.any(axis=(1, 3))
     visited = onp.zeros((n, n), bool)
