@@ -21,6 +21,13 @@ Usage mirrors the reference:
 """
 from __future__ import annotations
 
+import sys as _sys
+import time as _time
+
+# the span `startup.import` runs from here to the bottom of this file
+_import_t0 = _time.perf_counter()
+_jax_preloaded = "jax" in _sys.modules
+
 __version__ = "0.1.0"
 
 # 64-bit dtype contract (reference: mshadow DType dispatch supports real
@@ -132,6 +139,10 @@ def seed(s, ctx="all"):
 from .ops.aliases import install_aliases as _install_aliases  # noqa: E402
 
 _install_aliases()
+
+diagnostics.spans.record(
+    "startup.import", "startup", _import_t0,
+    _time.perf_counter() - _import_t0, jax_preloaded=_jax_preloaded)
 
 __all__ = [
     "NDArray",

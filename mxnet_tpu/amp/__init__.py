@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as _np
 
 from ..base import normalize_dtype
+from ..diagnostics import spans as _spans
 from ..ndarray.ndarray import NDArray
 
 from . import lists  # noqa: E402  (reference: amp/lists/ cast tables)
@@ -208,9 +209,10 @@ def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,
         return net
     from ..gluon.parameter import cast_params
 
-    cast_params([p for p in net.collect_params().values()
-                 if (p._data_map is not None or p.shape is not None)
-                 and not _keeps_fp32(p)], dtype)
+    with _spans.span("amp.convert", cat="compile"):
+        cast_params([p for p in net.collect_params().values()
+                     if (p._data_map is not None or p.shape is not None)
+                     and not _keeps_fp32(p)], dtype)
     net._clear_cached()
     if not getattr(net, "amp_casts_inputs", True):
         # the block's floating inputs are not activations (noise levels,

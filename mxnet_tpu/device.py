@@ -13,6 +13,7 @@ their inputs" rule (src/imperative/imperative_utils.h GetContext).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import warnings
 
@@ -31,6 +32,22 @@ _DEVTYPE_ALIASES = {
 # Accelerator device types: all resolve to the TPU. 'gpu'/'cuda' are accepted
 # for reference-API compatibility (models written `ctx=mx.gpu(0)`).
 _ACCEL_TYPES = ("tpu", "gpu", "cuda")
+
+
+_backend_taken = False
+
+
+def _taking_backend():
+    """The span ``startup.backend`` around the process's FIRST device
+    resolution, where JAX takes its client (seconds on a TPU; ~0 where the
+    caller asked JAX for its devices before); nothing afterwards."""
+    global _backend_taken
+    if _backend_taken:
+        return contextlib.nullcontext()
+    _backend_taken = True
+    from .diagnostics import spans
+
+    return spans.span("startup.backend", cat="startup")
 
 
 class Device:
@@ -84,24 +101,25 @@ class Device:
         # non-addressable; mx.cpu(0)/mx.tpu(0) always mean THIS process's
         # devices (the reference's per-worker ctx semantics).
         dt = self.device_type
-        if dt not in _ACCEL_TYPES:
-            devs = jax.local_devices(backend=dt)
-        elif jax.config.jax_platforms == "cpu":
-            if dt not in Device._warned_fallback:
-                Device._warned_fallback.add(dt)
-                warnings.warn(
-                    f"process is pinned to the CPU (JAX_PLATFORMS=cpu); "
-                    f"device type '{dt}' resolves to CPU devices",
-                    stacklevel=2,
-                )
-            devs = jax.local_devices()
-        else:
-            try:
-                devs = jax.local_devices(backend="tpu")
-            except RuntimeError as e:
-                raise MXNetError(
-                    f"{self!r} asked for a TPU and none is available: {e}"
-                ) from e
+        with _taking_backend():
+            if dt not in _ACCEL_TYPES:
+                devs = jax.local_devices(backend=dt)
+            elif jax.config.jax_platforms == "cpu":
+                if dt not in Device._warned_fallback:
+                    Device._warned_fallback.add(dt)
+                    warnings.warn(
+                        f"process is pinned to the CPU (JAX_PLATFORMS=cpu); "
+                        f"device type '{dt}' resolves to CPU devices",
+                        stacklevel=2,
+                    )
+                devs = jax.local_devices()
+            else:
+                try:
+                    devs = jax.local_devices(backend="tpu")
+                except RuntimeError as e:
+                    raise MXNetError(
+                        f"{self!r} asked for a TPU and none is available: "
+                        f"{e}") from e
         return devs[self.device_id % len(devs)]
 
     # -- default-device stack --------------------------------------------
@@ -142,10 +160,11 @@ def gpu(device_id=0):
 
 
 def _accel_count():
-    try:
-        return len(jax.devices("tpu"))
-    except RuntimeError:
-        return 0
+    with _taking_backend():
+        try:
+            return len(jax.devices("tpu"))
+        except RuntimeError:
+            return 0
 
 
 def num_gpus():
@@ -173,7 +192,8 @@ def default_device():
     """Process default: the first accelerator if present, else cpu."""
     global _default
     if _default is None:
-        backend = jax.default_backend()
+        with _taking_backend():
+            backend = jax.default_backend()
         _default = Device("tpu" if backend == "tpu" else "cpu", 0)
     return _default
 

@@ -17,7 +17,7 @@ MXTPU_WATCHDOG_FILE, MXTPU_WATCHDOG_RAISE.
 """
 from __future__ import annotations
 
-from . import introspect, spans, watchdog
+from . import introspect, spans, startup, watchdog
 from .introspect import (
     capture_compile,
     compile_registry,
@@ -26,6 +26,7 @@ from .introspect import (
     update_device_memory_gauge,
 )
 from .report import report
+from .startup import format_startup_table, startup_report
 from .spans import (
     all_stacks,
     current_stack,
@@ -44,12 +45,15 @@ __all__ = [
     "capture_compile", "compile_registry", "format_compile_table",
     "device_memory", "update_device_memory_gauge",
     "guard", "report", "reset",
-    "spans", "introspect", "watchdog",
+    "startup_report", "format_startup_table",
+    "spans", "introspect", "startup", "watchdog",
 ]
 
 
 def reset():
-    """Clear spans, the compile registry, and watchdog state (tests)."""
+    """Clear spans, the compile registry, the kept start-up reductions
+    and watchdog state (tests)."""
     spans.reset()
     introspect.reset()
+    startup.reset()
     watchdog.reset()

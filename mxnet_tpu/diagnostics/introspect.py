@@ -37,6 +37,8 @@ import os
 import re
 import threading
 
+from . import spans as _spans
+
 __all__ = [
     "capture_compile", "compile_registry", "reset", "op_scopes",
     "device_memory", "update_device_memory_gauge",
@@ -104,10 +106,18 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
     if not capture_enabled():
         return None
     try:
-        lowered = jitted.lower(*args, **(kwargs or {}))
-        compiled = lowered.compile()
+        # each step of the capture under its own span: whether the second
+        # lowering and the second look at the cache cost anything is read
+        # off these (docs/diagnostics.md, "The spans of start-up")
+        with _spans.span("compile_capture.lower", cat="compile"):
+            lowered = jitted.lower(*args, **(kwargs or {}))
+        with _spans.span("compile_capture.compile", cat="compile"):
+            compiled = lowered.compile()
         cost = compiled.cost_analysis() or {}
-        text = compiled.as_text()
+        with _spans.span("compile_capture.text", cat="compile"):
+            text = compiled.as_text()
+        with _spans.span("compile_capture.op_scopes", cat="compile"):
+            scopes = op_scopes(text)
         entry = {
             "block": str(block), "variant": str(variant),
             "flops": float(cost.get("flops", 0.0) or 0.0),
@@ -126,7 +136,7 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
             # the map from a device trace's instruction names to the
             # scopes of the program (forward / backward / optimizer /
             # block), for whoever reduces a trace of this program
-            "op_scopes": op_scopes(text),
+            "op_scopes": scopes,
         }
         try:
             mem = compiled.memory_analysis()

@@ -1,15 +1,36 @@
 """Span-based step tracer: nested, thread-safe host spans in a ring buffer.
 
 The telemetry registry (ISSUE 1) answers "how many / how much"; spans
-answer "WHERE did this step's milliseconds go". Every instrumented layer
-wraps its hot region in ``diagnostics.span(name, cat=phase)``:
+answer "WHERE did this step's milliseconds go", and where the seconds
+before the first step went. Every instrumented layer wraps its region in
+``diagnostics.span(name, cat=phase)``:
 
-  * gluon/trainer.py     step / collective(allreduce) / optimizer phases
-  * gluon/block.py       the CachedOp call path (``fwd`` phase, compile)
+  * gluon/train_step.py  ``train_step`` around one TrainStep call and its
+                         parts: ``.prologue``, ``.operands``, ``whole_step``
+                         (the compiled call, ``fwd``), ``.compile_capture``
+                         (only on a step that compiled), ``.writeback``,
+                         ``.bookkeeping``; ``train_step.build`` once
+  * gluon/trainer.py     step / collective(allreduce) / optimizer phases;
+                         ``trainer.create_states`` when state is made
+  * gluon/block.py       the CachedOp call path (``fwd`` phase, compile);
+                         ``block.initialize``
+  * amp                  ``amp.convert`` (the offline cast of the weights)
+  * introspect.py        ``compile_capture.lower`` / ``.compile`` /
+                         ``.text`` / ``.op_scopes`` under a capture
   * autograd.backward    the ``bwd`` phase
   * engine.py            waitall / wait_to_read (``sync`` phase)
   * kvstore + parallel   collective dispatch (``collective`` phase)
   * gluon/data loader    batch fetch (``data`` phase)
+  * checkpoint, serving  ``checkpoint`` and ``serve`` phases
+
+and two sites write finished records with :func:`record`, because what
+they time has ended when they hear of it: ``mxnet_tpu/__init__.py``
+(``startup.import``) and the one jax.monitoring listener of
+telemetry/instruments.py (``xla.trace`` / ``xla.lower`` / ``xla.backend``
+/ ``xla.cache_load``, one per program and stage, with the program's name
+as ``fun``); device.py spans the process's first device resolution
+(``startup.backend``).  diagnostics/startup.py reduces those to the
+start-up report (docs/diagnostics.md, "The spans of start-up").
 
 Records land in a bounded ring (``MXTPU_DIAG_RING_CAPACITY``, default
 4096 — old spans fall off, memory stays bounded on infinite loops), each
@@ -39,7 +60,7 @@ import time
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = [
-    "span", "enabled", "enable", "disable", "reset",
+    "span", "record", "enabled", "enable", "disable", "reset",
     "records", "set_ring_capacity", "ring_capacity",
     "current_stack", "all_stacks",
     "mark_step", "current_step",
@@ -177,8 +198,9 @@ class span:
 
     Thread-safe; one branch when disabled.  The ring record keeps wall
     times from ``time.perf_counter()``, the nesting depth, the enclosing
-    span's name (``parent``) and the current step index; the profiler
-    annotation (module docstring) carries ``kv`` as event stats.
+    span's name (``parent``), the current step index and ``kv`` (a fixed
+    field wins over a ``kv`` of its name); the profiler annotation
+    (module docstring) carries ``kv`` as event stats.
     ``step_num`` marks the outermost span of a training step: the
     profiler then groups the device's work under that step."""
 
@@ -226,6 +248,8 @@ class span:
             "parent": st[-1][0] if st else None,
             "step": self._step,
         }
+        if self.kv:
+            rec = {**self.kv, **rec}
         if _trace_ctx:
             rec.update(_trace_ctx)
         with _ring_lock:
@@ -233,8 +257,39 @@ class span:
         return False
 
 
+def record(name, cat, t0, dur, **kv):
+    """Put a FINISHED record on the ring: ``dur`` seconds that began at
+    ``t0`` (``time.perf_counter()``'s clock), for a caller that hears of
+    the work only when it has ended (a jax.monitoring duration event, the
+    package's own import).  It carries ``kv``, ``backdated: True``, and as
+    ``depth`` / ``parent`` / ``step`` what is open on the calling thread
+    NOW, i.e. at the end of what it timed.  No profiler annotation: one
+    cannot be entered late, so a back-dated record is on the ring only
+    and never in a device trace."""
+    if not _enabled:
+        return
+    st = getattr(_tls, "stack", None)
+    rec = {
+        **kv,
+        "name": name, "cat": cat, "t0": t0, "dur": dur,
+        "tid": threading.get_ident(),
+        "depth": len(st) if st else 0,
+        "parent": st[-1][0] if st else None,
+        "step": _step[0],
+        "backdated": True,
+    }
+    if _trace_ctx:
+        rec.update(_trace_ctx)
+    with _ring_lock:
+        _ring.append(rec)
+
+
 def records():
-    """Snapshot of the ring, oldest first."""
+    """Snapshot of the ring, oldest first BY TIME OF WRITING -- which for
+    every record is the end of what it timed: a span is written when it
+    closes, a back-dated record (:func:`record`) when its work ended.  So
+    ``t0 + dur`` never decreases along one thread's records, a child comes
+    before the span that encloses it, and ``t0`` alone is in no order."""
     with _ring_lock:
         return list(_ring)
 
@@ -278,26 +333,29 @@ def all_stacks():
 def step_table(recs=None):
     """Pivot span records into {step: {phase: seconds}}.
 
-    Only depth-0 spans of each category are summed (a ``fwd`` span nested
-    under another ``fwd`` span would double-count its parent's time).
-    Categories outside PHASES accumulate under ``other``; the span
-    around a whole step (``STEP_CAT``) is left out, its children are in.
+    Only the OUTERMOST spans of each category are summed, per step and
+    thread (a ``fwd`` span nested under another ``fwd`` span would
+    double-count its parent's time; ``trainer.create_states`` lies inside
+    ``train_step.build``, both ``compile``).  Categories outside PHASES
+    accumulate under ``other``; the span around a whole step
+    (``STEP_CAT``) is left out, its children are in.
+    Back-dated records (``xla.*``, ``startup.import``) are left out too:
+    their seconds lie inside whatever span was open around them -- the
+    stages of a step's program inside ``whole_step``'s ``fwd`` time -- so
+    no second is counted twice in a step's row.
     """
-    recs = records() if recs is None else recs
-    # innermost-per-category: keep a span unless an enclosing span of the
-    # SAME category covers it (nested fwd under fwd); cheap approximation:
-    # group by (step, cat) over minimum depth seen for that pair
-    min_depth = {}
-    for r in recs:
-        key = (r["step"], r["cat"], r["tid"])
-        d = min_depth.get(key)
-        if d is None or r["depth"] < d:
-            min_depth[key] = r["depth"]
+    recs = [r for r in (records() if recs is None else recs)
+            if not r.get("backdated") and r["cat"] != STEP_CAT]
     table = {}
-    for r in recs:
-        if r["cat"] == STEP_CAT or \
-                r["depth"] != min_depth[(r["step"], r["cat"], r["tid"])]:
+    # one thread's spans nest properly: walking them by start, a span
+    # that begins before the last counted span of its (step, category,
+    # thread) has ended lies inside it
+    covered_until = {}
+    for r in sorted(recs, key=lambda r: (r["t0"], -r["dur"])):
+        key = (r["step"], r["cat"], r["tid"])
+        if r["t0"] < covered_until.get(key, float("-inf")):
             continue
+        covered_until[key] = r["t0"] + r["dur"]
         phase = r["cat"] if r["cat"] in PHASES else "other"
         row = table.setdefault(r["step"], {})
         row[phase] = row.get(phase, 0.0) + r["dur"]

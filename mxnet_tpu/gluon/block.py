@@ -201,11 +201,13 @@ class Block:
                    force_reinit=False, ctx=None):  # noqa: ARG002
         """Initialize all parameters (reference: Block.initialize)."""
         device = device if device is not None else ctx
-        for name, p in self.collect_params().items():
-            p._structured_name = name  # full path for Load/Mixed routing
-            p.initialize(init=None, device=device,
-                         default_init=init or _default_init(),
-                         force_reinit=force_reinit)
+        # one span for the whole tree (collect_params reaches every leaf)
+        with _spans.span("block.initialize", cat="compile"):
+            for name, p in self.collect_params().items():
+                p._structured_name = name  # full path for Load/Mixed routing
+                p.initialize(init=None, device=device,
+                             default_init=init or _default_init(),
+                             force_reinit=force_reinit)
         self._clear_cached()
         return self
 

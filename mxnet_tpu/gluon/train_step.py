@@ -56,6 +56,7 @@ from .. import _random
 from .. import autograd as ag
 from ..diagnostics import introspect as _introspect
 from ..diagnostics import spans as _spans
+from ..diagnostics import startup as _startup
 from ..diagnostics import watchdog as _watchdog
 from ..ndarray.ndarray import NDArray
 from ..optimizer.optimizer import (Optimizer, _cache_size, _donate_enabled,
@@ -608,7 +609,8 @@ class TrainStep:
         if not self._eligible():
             return self._phased(batch, batch_size)
         if not self._built:
-            self._build()
+            with _spans.span("train_step.build", cat="compile"):
+                self._build()
         return self._whole(batch, batch_size)
 
     def _phased(self, batch, batch_size):
@@ -701,6 +703,9 @@ class TrainStep:
                         compile_seconds=compile_seconds)
                 finally:
                     self._introspecting = False
+            # a step compiled: keep what the ring says of the time up to
+            # here, for a run that outlives the ring
+            _startup.take()
         with _spans.span("train_step.writeback"):
             if nmode != "off":
                 self._numerics_boundary(

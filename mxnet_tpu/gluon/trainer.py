@@ -164,8 +164,9 @@ class Trainer:
         items = [(i, w) for i, w in items if not self._states_created[i]]
         if not items:
             return
-        states = self._optimizer.create_states_multi_precision(
-            *zip(*items))
+        with _spans.span("trainer.create_states", cat="compile"):
+            states = self._optimizer.create_states_multi_precision(
+                *zip(*items))
         for (i, weight), state in zip(items, states):
             self._states[i] = state
             self._states_created[i] = True

@@ -16,7 +16,9 @@ base units), unprefixed — one process, one framework.
 from __future__ import annotations
 
 import threading
+import time
 
+from ..diagnostics import spans as _spans
 from .registry import REGISTRY, counter, gauge, histogram
 
 __all__ = [
@@ -141,11 +143,12 @@ jit_trace_total = counter(
 # user: fed by ONE jax.monitoring listener (install_compile_listener)
 xla_compile_seconds_total = counter(
     "xla_compile_seconds_total",
-    "Seconds JAX spent per compile stage, over every program of the "
-    "process: trace (jaxpr), lower (to MLIR), backend (XLA compile OR "
-    "the persistent-cache lookup and load — JAX times both under one "
-    "event), cache_load (the load alone; backend - cache_load is what "
-    "XLA spent building)", ["stage"])
+    "Seconds JAX spent per compile stage, summed over every program of "
+    "the process (per program and in time: the span ring's xla.<stage> "
+    "records, diagnostics.startup_report()): trace (jaxpr), lower (to "
+    "MLIR), backend (XLA compile OR the persistent-cache lookup and "
+    "load — JAX times both under one event), cache_load (the load "
+    "alone; backend - cache_load is what XLA spent building)", ["stage"])
 xla_programs_total = counter(
     "xla_programs_total",
     "Programs JAX obtained an executable for: how=loaded from the "
@@ -627,35 +630,68 @@ _XLA_STAGE_OF_EVENT = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
 }
 _xla_tls = threading.local()
+# JAX fires the trace event around a CACHED function: a hit takes
+# microseconds and traced nothing, and the jnp calls inside one whole-step
+# trace make thousands of them (4,146 events in a toy run, 4,044 under a
+# millisecond), which would roll the ring.  The counter adds up all of
+# them; the ring gets the traces that took at least this long.
+_TRACE_RECORD_FLOOR_S = 1e-3
+
+
+def _fun_of(fun_name):
+    """JAX names a program ``whole_step`` when it traces it and
+    ``jit(whole_step)`` when it lowers or compiles it: one name for both."""
+    name = str(fun_name)
+    if name.endswith(")") and "(" in name:
+        return name[name.index("(") + 1:-1]
+    return name
 
 
 def _on_xla_duration(event, duration, fun_name=None, **_kw):
     stage = _XLA_STAGE_OF_EVENT.get(event)
-    if stage is None or not REGISTRY.enabled:
+    if stage is None:
         return
-    xla_compile_seconds_total.labels(stage).inc(duration)
+    # the event fires at the END of what it timed: back-date the record
+    # onto the clock of every other span
+    t0 = time.perf_counter() - duration
+    counting = REGISTRY.enabled
+    if counting:
+        xla_compile_seconds_total.labels(stage).inc(duration)
     # JAX (0.9) reports a cache hit's retrieval time from INSIDE the
-    # region it times as backend_compile_duration, on the same thread:
-    # the backend event that follows a load is that load, not a build
+    # region it times as backend_compile_duration, on the same thread and
+    # without the program's name: the backend event that follows a load is
+    # that load, not a build, and gives the load its name
     if stage == "cache_load":
-        _xla_tls.load_seconds = duration
-    elif stage == "backend":
-        load_seconds = getattr(_xla_tls, "load_seconds", None)
-        _xla_tls.load_seconds = None
-        how = "built" if load_seconds is None else "loaded"
+        _xla_tls.load = (t0, duration)
+        return
+    if stage != "backend":
+        if stage != "trace" or duration >= _TRACE_RECORD_FLOOR_S:
+            _spans.record("xla." + stage, "compile", t0, duration,
+                          fun=_fun_of(fun_name))
+        return
+    load = getattr(_xla_tls, "load", None)
+    _xla_tls.load = None
+    how = "built" if load is None else "loaded"
+    fun = _fun_of(fun_name)
+    if load is not None:
+        _spans.record("xla.cache_load", "compile", *load, fun=fun)
+    _spans.record("xla.backend", "compile", t0, duration, fun=fun, how=how)
+    if counting:
         xla_programs_total.labels(how).inc()
         # the black box keeps WHICH program arrived (JAX's `fun_name`,
         # `jit(whole_step)`) and WHEN (perf_counter `pc`, the spans'
         # clock): a reader can tell set-up's compiles from those of a
         # later phase of the process, and name the ones built anew
         _flight_record("xla_compile", name=fun_name, how=how,
-                       seconds=duration, load_seconds=load_seconds or 0.0)
+                       seconds=duration,
+                       load_seconds=load[1] if load else 0.0)
 
 
 def install_compile_listener():
     """Register the one jax.monitoring listener behind
-    xla_compile_seconds_total / xla_programs_total (idempotent; called
-    at import of mxnet_tpu, before anything compiles)."""
+    xla_compile_seconds_total / xla_programs_total and the span ring's
+    xla.<stage> records (idempotent; called at import of mxnet_tpu,
+    before anything compiles)."""
     if getattr(_on_xla_duration, "installed", False):
         return
     import jax

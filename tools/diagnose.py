@@ -2,7 +2,7 @@
 diagnostics report (docs/diagnostics.md explains every section).
 
 Usage:  python tools/diagnose.py [--steps N] [--batch B] [--hidden H]
-                                 [--json] [--watchdog-demo]
+                                 [--json] [--watchdog-demo] [--startup]
         python tools/diagnose.py --live HOST:PORT [--json]
 
 Runs N training steps of a small hybridized MLP with every diagnostics
@@ -14,6 +14,11 @@ device memory, and the sync/collective telemetry series.
 
 `--json` emits the same content as one machine-readable JSON object
 (step_table + compile_registry + device_memory + telemetry dump).
+
+`--startup` adds the start-up report (`diagnostics.startup_report()`):
+seconds from `import mxnet_tpu` to the first finished step by phase, the
+programs JAX traced, lowered, loaded or built by name, and what fills
+the persistent compile cache by module name.
 
 `--watchdog-demo` arms the watchdog with a short deadline around a
 deliberate stall so you can see exactly what a hang dump looks like
@@ -457,6 +462,10 @@ def main(argv=None):
                     help="machine-readable JSON instead of the text report")
     ap.add_argument("--watchdog-demo", action="store_true",
                     help="stall on purpose and show the watchdog dump")
+    ap.add_argument("--startup", action="store_true",
+                    help="add the start-up report: seconds to the first "
+                         "finished step by phase and program, and the "
+                         "compile cache's contents by module name")
     ap.add_argument("--passes", action="store_true",
                     help="run the graph-pass demo (dedup + pipeline AMP) "
                          "and print the pass/dedup/remat report section")
@@ -506,6 +515,8 @@ def main(argv=None):
             "passes": _passes_report(),
             "device_memory": diagnostics.device_memory(),
             "telemetry": telemetry.dump(),
+            **({"startup": diagnostics.startup_report(cache=True)}
+               if args.startup else {}),
         }, default=str))
     else:
         print(diagnostics.report())
@@ -513,6 +524,10 @@ def main(argv=None):
         print("\n".join(_whole_step_report_lines(_whole_step_report())))
         if args.passes:
             print("\n".join(_passes_report_lines(_passes_report())))
+        if args.startup:
+            print("\n== start-up (s) " + "=" * 54 + "\n")
+            print(diagnostics.format_startup_table(
+                diagnostics.startup_report(cache=True)))
 
 
 if __name__ == "__main__":
