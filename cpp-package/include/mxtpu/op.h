@@ -7984,8 +7984,8 @@ inline std::vector<PackedTensor> flash_attention(
     const PackedTensor& v,
     bool causal = false,
     const char* scale_json = nullptr,
-    long long block_q = 128,
-    long long block_k = 128,
+    const char* block_q_json = nullptr,
+    const char* block_k_json = nullptr,
     const char* interpret_json = nullptr,
     double dropout_p = 0.0,
     const char* dropout_seed_json = nullptr,
@@ -7997,8 +7997,8 @@ inline std::vector<PackedTensor> flash_attention(
   detail::JsonBuilder a_;
   a_.put_bool("causal", causal);
   if (scale_json) a_.raw("scale", scale_json);
-  a_.put_int("block_q", block_q);
-  a_.put_int("block_k", block_k);
+  if (block_q_json) a_.raw("block_q", block_q_json);
+  if (block_k_json) a_.raw("block_k", block_k_json);
   if (interpret_json) a_.raw("interpret", interpret_json);
   a_.put_num("dropout_p", dropout_p);
   if (dropout_seed_json) a_.raw("dropout_seed", dropout_seed_json);
@@ -9606,6 +9606,25 @@ inline std::vector<PackedTensor> rms_norm(
   return rt.invoke("rms_norm", ins_, a_.str());
 }
 
+inline std::vector<PackedTensor> rms_norm_rotary(
+    PyRuntime& rt,
+    const PackedTensor& x,
+    const PackedTensor& gamma,
+    const PackedTensor& positions,
+    double theta = 10000.0,
+    long long num_heads = 1,
+    double eps = 1e-06) {
+  std::vector<PackedTensor> ins_;
+  ins_.push_back(x);
+  ins_.push_back(gamma);
+  ins_.push_back(positions);
+  detail::JsonBuilder a_;
+  a_.put_num("theta", theta);
+  a_.put_int("num_heads", num_heads);
+  a_.put_num("eps", eps);
+  return rt.invoke("rms_norm_rotary", ins_, a_.str());
+}
+
 inline std::vector<PackedTensor> rmsprop_update(
     PyRuntime& rt,
     const PackedTensor& weight,
@@ -9670,12 +9689,14 @@ inline std::vector<PackedTensor> rotary_embedding(
     PyRuntime& rt,
     const PackedTensor& x,
     const PackedTensor& positions,
-    double theta = 10000.0) {
+    double theta = 10000.0,
+    bool interleaved = false) {
   std::vector<PackedTensor> ins_;
   ins_.push_back(x);
   ins_.push_back(positions);
   detail::JsonBuilder a_;
   a_.put_num("theta", theta);
+  a_.put_bool("interleaved", interleaved);
   return rt.invoke("rotary_embedding", ins_, a_.str());
 }
 

@@ -50,7 +50,7 @@ class GroupedQueryAttention(HybridBlock):
         super().__init__()
         self._heads, self._kv_heads, self._hd = num_heads, num_kv_heads, \
             head_dim
-        self._theta = float(rope_theta)
+        self._theta, self._eps = float(rope_theta), float(epsilon)
 
         def proj(out_units, in_units):
             return Dense(out_units, use_bias=False, flatten=False,
@@ -65,21 +65,18 @@ class GroupedQueryAttention(HybridBlock):
 
     def forward(self, x, positions, block_diffusion=None):
         b, s, _ = x.shape
-        hd, theta = self._hd, self._theta
+        hd = self._hd
 
-        def heads(t, n):
-            return t.reshape((b, s, n, hd))
+        def prepared(t, norm, n):
+            # the head's norm, the rotation and the move to (B, n, S, hd)
+            # are row-wise: one op, straight from the projection's layout
+            return npx.rms_norm_rotary(t, norm.gamma.data_for(t), positions,
+                                       self._theta, n, self._eps)
 
-        def rotated(t, norm):
-            # norm and rotation are row-wise: both before the transpose,
-            # in the layout the projection wrote
-            return npx.rotary_embedding(
-                norm(t), positions.reshape((s, 1)), theta
-            ).transpose((0, 2, 1, 3))
-
-        q = rotated(heads(self.q_proj(x), self._heads), self.q_norm)
-        k = rotated(heads(self.k_proj(x), self._kv_heads), self.k_norm)
-        v = heads(self.v_proj(x), self._kv_heads).transpose((0, 2, 1, 3))
+        q = prepared(self.q_proj(x), self.q_norm, self._heads)
+        k = prepared(self.k_proj(x), self.k_norm, self._kv_heads)
+        v = self.v_proj(x).reshape((b, s, self._kv_heads, hd)).transpose(
+            (0, 2, 1, 3))
         out = attend(q, k, v, block_diffusion=block_diffusion)
         out = out.transpose((0, 2, 1, 3)).reshape((b, s, self._heads * hd))
         return self.o_proj(out)

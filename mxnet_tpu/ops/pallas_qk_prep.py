@@ -1,0 +1,244 @@
+"""Query / key preparation for grouped-query attention in one pass.
+
+A decoder layer that normalises each head of its queries and keys and
+turns them by rotary positions (Qwen3, SDAR) hands the flash kernel a
+tensor that went through three row-wise steps: RMSNorm over each head's
+width, the rotation, and the move from the projection's layout (B, S,
+H * D) to the head-major (B, H, S, D) the kernel reads.  As three XLA
+ops that is several float32 passes over the tensor, forward and back.
+`rms_norm_rotary` is the three as ONE op: a Pallas kernel reads a
+(rows, D) block of one head straight out of the projection's layout —
+lane block ``h`` of the last dimension, aligned because D is a multiple
+of the lane width — normalises and rotates it in float32 and stores it
+into the head-major result, so the tensor is read once and written once.
+Rotate-half is a lane roll by D / 2 times a sine table with the sign
+folded in (no slice, no concatenate); the cos / sin tables are (S, D)
+float32 operands whose block does not move while the grid walks the
+heads, so a row tile fetches them once.  The backward is one kernel of
+the same grid: it recomputes each row's 1 / rms from x, turns the
+cotangent back by the same roll (a roll by half the width is its own
+inverse), writes dx in the projection's layout and leaves dgamma as
+per-grid-step partial rows that XLA sums.
+
+The op chooses by what it sees, with no knob: on a TPU, for D a multiple
+of 128 and S a multiple of 8, the kernels run; otherwise the composition
+``rms_norm`` -> ``rotary_embedding`` -> ``transpose`` runs, which is also
+the op's reference.  The kernels round to x's type once, after the
+rotation; the composition rounds after the norm too.
+"""
+from __future__ import annotations
+
+import collections
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import nn as _nn
+from .pallas_attention import _LANES, _VMEM_BUDGET, _pallas_call, _shared
+from .registry import register_op
+
+__all__ = ["rms_norm_rotary"]
+
+_MAX_ROWS = 2048
+
+# what the two kernels of one call share besides their operands' shapes:
+# heads in the last dimension, rows of a block, the norm's epsilon, and
+# whether Pallas interprets the kernels (tests, off a TPU)
+_Sig = collections.namedtuple("_Sig", "heads rows eps interpret")
+
+
+def _kernel_mode():
+    """False where the kernels compile (a TPU), None where none can run."""
+    return False if jax.devices()[0].platform == "tpu" else None
+
+
+def _row_tile(s_len, d, itemsize):
+    """Rows of a block: the sequence where it is one block, else the
+    largest power of two up to ``_MAX_ROWS`` whose blocks in the backward
+    — x, dy and dx and both float32 tables, double-buffered — fit the
+    fast-memory budget (the body works a register at a time: it keeps no
+    temporary of a block's size)."""
+    fit = _VMEM_BUDGET // (d * (6 * itemsize + 4 * 4))
+    rows = 1 << (max(8, min(_MAX_ROWS, fit)).bit_length() - 1)
+    return s_len if s_len <= rows else rows
+
+
+def _tables(positions, theta, d):
+    """cos and sign-folded sin of the rotate-half angles, (S, D) float32:
+    x * cos + roll(x, D / 2) * sin is `rotary_embedding`'s rotation."""
+    inv_freq = theta ** (-jnp.arange(d // 2, dtype=jnp.float32) * 2.0 / d)
+    angle = positions.astype(jnp.float32).reshape((-1, 1)) * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
+
+
+def _normed(x, eps):
+    """(x / rms(x), 1 / rms(x)) of float32 rows."""
+    r = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return x * r, r
+
+
+def _fwd_kernel(x_ref, g_ref, cos_ref, sin_ref, o_ref, *, eps):
+    from jax.experimental.pallas import tpu as pltpu
+
+    xh, _ = _normed(x_ref[0].astype(jnp.float32), eps)
+    n = xh * g_ref[...]
+    half = n.shape[-1] // 2
+    o_ref[0, 0] = (n * cos_ref[...] + pltpu.roll(n, half, 1) * sin_ref[...]
+                   ).astype(o_ref.dtype)
+
+
+def _bwd_kernel(x_ref, dy_ref, g_ref, cos_ref, sin_ref, dx_ref, dg_ref, *,
+                eps, s_len):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    xh, r = _normed(x_ref[0].astype(jnp.float32), eps)
+    dy = dy_ref[0, 0].astype(jnp.float32)
+    rows, d = dy.shape
+    # the rotation's transpose: the same roll, on the sine's side
+    dn = dy * cos_ref[...] + pltpu.roll(dy * sin_ref[...], d // 2, 1)
+    dgamma = dn * xh
+    if s_len % rows:
+        # the last block hangs over the sequence: its rows past the end
+        # hold whatever the padding holds
+        row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        dgamma = jnp.where(row < s_len - pl.program_id(1) * rows, dgamma, 0.0)
+    # eight partial rows: adds of whole registers, no cross-sublane reduce
+    dg_ref[0, 0, 0] = dgamma.reshape((rows // 8, 8, d)).sum(axis=0)
+    dxh = dn * g_ref[...]
+    dx_ref[0] = (r * (dxh - xh * jnp.mean(dxh * xh, axis=-1, keepdims=True))
+                 ).astype(dx_ref.dtype)
+
+
+def _specs(sig, d):
+    """The blocks both kernels share: x's (lane block h of the projection's
+    layout), the head-major one, gamma's and a table's."""
+    import jax.experimental.pallas as pl
+
+    rows = sig.rows
+    return (pl.BlockSpec((1, rows, d), lambda b, r, h: (b, r, h)),
+            pl.BlockSpec((1, 1, rows, d), lambda b, r, h: (b, h, r, 0)),
+            pl.BlockSpec((1, d), lambda b, r, h: (0, 0)),
+            pl.BlockSpec((rows, d), lambda b, r, h: (r, 0)))
+
+
+def _grid(sig, b, s_len):
+    # the head runs fastest: a table's block stays while it does
+    return (b, -(-s_len // sig.rows), sig.heads)
+
+
+def _qk_prep_fwd_call(sig, x, gamma, cos, sin):
+    b, s_len, width = x.shape
+    d = width // sig.heads
+    flat, major, scale, table = _specs(sig, d)
+    return _pallas_call(
+        functools.partial(_fwd_kernel, eps=sig.eps),
+        name="rms_norm_rotary_fwd", grid=_grid(sig, b, s_len),
+        in_specs=[flat, scale, table, table], out_specs=major,
+        out_shape=jax.ShapeDtypeStruct((b, sig.heads, s_len, d), x.dtype),
+        interpret=sig.interpret,
+    )(x, gamma.astype(jnp.float32).reshape((1, d)), cos, sin)
+
+
+def _qk_prep_bwd_call(sig, x, dy, gamma, cos, sin):
+    import jax.experimental.pallas as pl
+
+    b, s_len, width = x.shape
+    d = width // sig.heads
+    flat, major, scale, table = _specs(sig, d)
+    grid = _grid(sig, b, s_len)
+    dx, partial = _pallas_call(
+        functools.partial(_bwd_kernel, eps=sig.eps, s_len=s_len),
+        name="rms_norm_rotary_bwd", grid=grid,
+        in_specs=[flat, major, scale, table, table],
+        out_specs=[flat, pl.BlockSpec((1, 1, 1, 8, d),
+                                      lambda b, r, h: (b, r, h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(grid + (8, d), jnp.float32)],
+        interpret=sig.interpret,
+    )(x, dy, gamma.astype(jnp.float32).reshape((1, d)), cos, sin)
+    return dx, partial.sum(axis=(0, 1, 2, 3)).astype(gamma.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _prepared(x, gamma, cos, sin, sig):
+    # one traced and lowered copy of each kernel a signature (`_shared`):
+    # the layers of a model share it
+    return _shared(_qk_prep_fwd_call, sig)(x, gamma, cos, sin)
+
+
+def _prepared_fwd(x, gamma, cos, sin, sig):
+    # nothing float32 of the tensor's size is kept: the backward
+    # recomputes each row's 1 / rms from x
+    return _prepared(x, gamma, cos, sin, sig), (x, gamma, cos, sin)
+
+
+def _prepared_bwd(sig, res, dy):
+    x, gamma, cos, sin = res
+    dx, dgamma = _shared(_qk_prep_bwd_call, sig)(x, dy, gamma, cos, sin)
+    # the tables come from integer positions: nothing flows back to them
+    return dx, dgamma, jnp.zeros_like(cos), jnp.zeros_like(sin)
+
+
+_prepared.defvjp(_prepared_fwd, _prepared_bwd)
+
+
+def _composition(x, gamma, positions, theta, num_heads, eps):
+    """The three ops one after the other: what the kernels replace, and
+    the op's reference."""
+    b, s_len, width = x.shape
+    heads = x.reshape((b, s_len, num_heads, width // num_heads))
+    normed = _nn.rms_norm(heads.astype(jnp.float32), gamma,
+                          eps=eps).astype(x.dtype)
+    return _nn.rotary_embedding(normed, positions.reshape((s_len, 1)),
+                                theta).transpose((0, 2, 1, 3))
+
+
+@register_op("rms_norm_rotary")
+def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
+                    eps=1e-6):
+    """Per-head RMSNorm, rotate-half rotary positions and the move to the
+    head-major layout, as one op.
+
+    x: (B, S, num_heads * D), a projection's output; ``gamma``: the
+    norm's (D,) scale, shared by the heads; ``positions``: the S explicit
+    position ids, shared by the batch.  Returns (B, num_heads, S, D) in
+    x's type, what `flash_attention` reads:
+
+        n = x / sqrt(mean(x ** 2 over D) + eps) * gamma     (each head)
+        out = n * cos(a) + concat(-n[D/2:], n[:D/2]) * sin(a)
+
+    with the angles of `rotary_embedding`.  Norm, angles and rotation
+    are float32.
+
+    On a TPU, for D a multiple of 128 and S a multiple of 8, one Pallas
+    kernel does all three (x read once, the result written once, rounded
+    to x's type once, after the rotation), and its backward is one kernel
+    too (x and the cotangent read, dx written in x's layout, dgamma as
+    partial rows); the layers of a model share one lowered copy of each.  Anywhere else the composition ``rms_norm`` ->
+    ``rotary_embedding`` -> ``transpose`` runs (rounded after the norm
+    and after the rotation).  The gauge ``qk_prep_kernel_share`` says
+    which share of the traced call sites took the kernels."""
+    from ..telemetry import instruments as _telemetry
+
+    b, s_len, width = x.shape
+    d, rest = divmod(width, num_heads)
+    if rest or gamma.shape != (d,) or positions.size != s_len:
+        raise ValueError(
+            f"x {x.shape} as {num_heads} heads, gamma {gamma.shape}, "
+            f"{positions.size} positions: the last dimension is num_heads "
+            "heads of gamma's width, and there is a position a row")
+    interpret = _kernel_mode()
+    kernels = interpret is not None and d % _LANES == 0 and s_len % 8 == 0
+    _telemetry.record_qk_prep_site(kernels)
+    if not kernels:
+        return _composition(x, gamma, positions, theta, num_heads, eps)
+    cos, sin = _tables(positions, theta, d)
+    sig = _Sig(int(num_heads),
+               _row_tile(s_len, d, jnp.dtype(x.dtype).itemsize),
+               float(eps), interpret)
+    return _prepared(x, gamma, cos, sin, sig)

@@ -26,6 +26,7 @@ __all__ = [
     "hybridize_fallback_total", "attention_kernel_fallback_total",
     "record_attention_fallback",
     "attention_maskfree_share", "set_attention_maskfree_share",
+    "qk_prep_kernel_share", "record_qk_prep_site",
     "xla_compile_seconds_total", "xla_programs_total",
     "install_compile_listener",
     "transfer_total", "transfer_bytes_total",
@@ -170,6 +171,13 @@ attention_maskfree_share = gauge(
     "no compare, no select); 1 is an unmasked call. Set on the host when "
     "the plan of a signature is built (ops.pallas_attention._plan), from "
     "the schedule's class bits; the latest signature's", ["kernel"])
+qk_prep_kernel_share = gauge(
+    "qk_prep_kernel_share",
+    "Of the call sites of ops.pallas_qk_prep.rms_norm_rotary traced so far, "
+    "the share that took the fused kernels (a TPU, a head width that is a "
+    "multiple of 128, a length that is a multiple of 8); a site on the "
+    "composition rms_norm -> rotary_embedding -> transpose counts as 0. "
+    "Set on the host each time the op is traced")
 compile_flops = gauge(
     "compile_flops",
     "XLA cost_analysis flops of the latest executable per block variant "
@@ -848,6 +856,17 @@ def set_attention_maskfree_share(by_kernel):
         return
     for kernel, share in by_kernel.items():
         attention_maskfree_share.labels(kernel).set(share)
+
+
+_qk_prep_sites = [0, 0]      # traced call sites: on the kernels, in all
+
+
+def record_qk_prep_site(kernels):
+    if not REGISTRY.enabled:
+        return
+    _qk_prep_sites[0] += bool(kernels)
+    _qk_prep_sites[1] += 1
+    qk_prep_kernel_share.set(_qk_prep_sites[0] / _qk_prep_sites[1])
 
 
 def record_transfer(direction, nbytes):

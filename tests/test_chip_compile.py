@@ -241,6 +241,64 @@ def test_the_plan_for_8192_positions_costs_a_millisecond(mask):
     pa._plan.cache_clear()
 
 
+# -- query / key preparation (ISSUE 42): per-head norm, rotary positions and
+#    the head-major store as one kernel, its backward as one more -----------
+
+def _qk_prep_loss(heads, layers=1):
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    def loss(x, gamma, positions):
+        total = 0.0
+        for i in range(layers):
+            with jax.named_scope(f"layer{i}"):
+                out = qp.rms_norm_rotary(x, gamma, positions, 1e6, heads)
+            total = total + out.astype(jnp.float32).sum()
+            x = x + out.mean().astype(x.dtype)
+        return total
+
+    return loss
+
+
+@pytest.mark.parametrize("heads,seq,dtype", [
+    (32, 8192, jnp.bfloat16),      # SDAR's queries
+    (4, 8192, jnp.bfloat16),       # ... and keys
+    (4, 8200, jnp.bfloat16),       # the last block hangs over the sequence
+    (2, 24, jnp.bfloat16),         # one block, not whole bf16 sublane tiles
+    (2, 2056, jnp.float32),
+], ids=["q", "k", "edge", "short", "float32"])
+def test_query_key_preparation_compiles_for_v5e(spec, monkeypatch, heads,
+                                                seq, dtype):
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    x = spec((2, seq, heads * 128), dtype)
+    gamma, pos = spec((128,), jnp.float32), spec((seq,), jnp.int32)
+    assert _kernel_calls(_qk_prep_loss(heads), x, gamma, pos) == 1
+    assert _kernel_calls(jax.value_and_grad(_qk_prep_loss(heads), (0, 1)),
+                         x, gamma, pos) == 2
+
+
+def test_layers_share_one_copy_of_each_preparation_kernel(spec, monkeypatch):
+    """Four layers at the cell's query shape, forward and backward, lowered
+    for the described v5e: two Mosaic calls in the module, not two a
+    layer, and every layer calls them."""
+    import re
+
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    layers = 4
+    step = jax.jit(jax.value_and_grad(_qk_prep_loss(32, layers), (0, 1)))
+    text = step.lower(
+        spec((2, 8192, 4096), jnp.bfloat16), spec((128,), jnp.float32),
+        spec((8192,), jnp.int32)).as_text()
+    names = re.findall(r'stablehlo.custom_call @tpu_custom_call.*'
+                       r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == ["rms_norm_rotary_bwd", "rms_norm_rotary_fwd"]
+    assert text.count("call @qk_prep_fwd_call") == layers
+    assert text.count("call @qk_prep_bwd_call") == layers
+
+
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 class _Lowered(Exception):

@@ -80,14 +80,8 @@ def _loss_and_grads(net, batch):
     return per, grads
 
 
-# -- the model against the plain reference ----------------------------------
-
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_loss_and_every_gradient_match_the_plain_reference(bench, toy,
-                                                           remat):
-    ref, _ = bench
-    cfg, weights, batch = toy
-    per, grads = _loss_and_grads(_net(cfg, weights, remat), batch)
+def _assert_matches_the_reference(ref, cfg, weights, batch, per, grads):
+    """Per-sample losses and every gradient against the plain reference's."""
     train = {n: w for n, w in weights.items() if ref.trainable(n)}
     frozen = {n: w for n, w in weights.items() if n not in train}
 
@@ -103,6 +97,40 @@ def test_loss_and_every_gradient_match_the_plain_reference(bench, toy,
         onp.testing.assert_allclose(
             onp.asarray(grads[name]) / scale, onp.asarray(g) / scale,
             atol=2e-4, err_msg=name)
+
+
+# -- the model against the plain reference ----------------------------------
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_loss_and_every_gradient_match_the_plain_reference(bench, toy,
+                                                           remat):
+    ref, _ = bench
+    cfg, weights, batch = toy
+    per, grads = _loss_and_grads(_net(cfg, weights, remat), batch)
+    _assert_matches_the_reference(ref, cfg, weights, batch, per, grads)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_with_the_preparation_kernels_it_matches_the_plain_reference(
+        bench, toy, remat, monkeypatch):
+    """Heads of 128, so that `npx.rms_norm_rotary` takes its kernels
+    (interpreted here): the loss and every gradient, the norms' scales
+    among them, against the reference, with and without the per-layer
+    checkpoint that replays the forward kernel."""
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+    from mxnet_tpu.telemetry import instruments as ti
+
+    ref, wmod = bench
+    cfg = dict(toy[0], head_dim=128)
+    weights = wmod.make_weights(ref.param_specs(cfg), 7, "float32")
+    batch = toy[2]
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: True)
+    monkeypatch.setattr(ti, "_qk_prep_sites", [0, 0])
+    per, grads = _loss_and_grads(_net(cfg, weights, remat), batch)
+    # queries and keys of both layers, every site on the kernels
+    assert ti._qk_prep_sites == [4, 4]
+    assert ti.qk_prep_kernel_share.value == 1.0
+    _assert_matches_the_reference(ref, cfg, weights, batch, per, grads)
 
 
 def test_eager_hybrid_and_remat_give_one_loss(toy):
