@@ -169,10 +169,11 @@ def unscale(trainer):
 def _keeps_fp32(p):
     # norms' scale/shift and running stats stay fp32 (cast-list analog),
     # and so does an expert layer's router: its top-k is decided on
-    # float32 probabilities
+    # float32 probabilities; and a looped model's exit gate, whose
+    # sigmoid weighs every exit's loss
     name = p.name.lower()
     return any(k in name for k in ("gamma", "beta", "running", "moving",
-                                   "router"))
+                                   "router", "exit_gate"))
 
 
 def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,

@@ -25,61 +25,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ... import numpy_extension as npx
 from ...ndarray.ndarray import NDArray, apply_op
 from ..block import HybridBlock
 from ..contrib.nn import DroplessMoE
 from ..nn import Dense, Embedding, HybridSequential
-from .decoder import RMSNorm, attend, head_loss, run_layers
+from .decoder import GroupedQueryAttention, RMSNorm, head_loss, run_layers
 
 __all__ = ["RMSNorm", "GroupedQueryAttention", "SDARDecoderLayer",
            "SDARModel", "SDARForBlockDiffusion", "sdar_moe"]
-
-
-class GroupedQueryAttention(HybridBlock):
-    """Self-attention with ``num_kv_heads`` key-value heads under
-    ``num_heads`` query heads (query head h reads key-value head
-    h // (num_heads // num_kv_heads)), an RMSNorm over each query and key
-    head's ``head_dim`` and rotary positions, through the flash kernel.
-    ``forward(x, positions, block_diffusion)``: x (B, S, units),
-    ``positions`` the S position ids, ``block_diffusion`` the static mask
-    (block length, half length) or None for full attention."""
-
-    def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 rope_theta=10000.0, epsilon=1e-6, dtype="float32"):
-        super().__init__()
-        self._heads, self._kv_heads, self._hd = num_heads, num_kv_heads, \
-            head_dim
-        self._theta, self._eps = float(rope_theta), float(epsilon)
-
-        def proj(out_units, in_units):
-            return Dense(out_units, use_bias=False, flatten=False,
-                         dtype=dtype, in_units=in_units)
-
-        self.q_proj = proj(num_heads * head_dim, units)
-        self.k_proj = proj(num_kv_heads * head_dim, units)
-        self.v_proj = proj(num_kv_heads * head_dim, units)
-        self.o_proj = proj(units, num_heads * head_dim)
-        self.q_norm = RMSNorm(head_dim, epsilon)
-        self.k_norm = RMSNorm(head_dim, epsilon)
-
-    def forward(self, x, positions, block_diffusion=None):
-        b, s, _ = x.shape
-        hd = self._hd
-
-        def prepared(t, norm, n):
-            # the head's norm, the rotation and the move to (B, n, S, hd)
-            # are row-wise: one op, straight from the projection's layout
-            return npx.rms_norm_rotary(t, norm.gamma.data_for(t), positions,
-                                       self._theta, n, self._eps)
-
-        q = prepared(self.q_proj(x), self.q_norm, self._heads)
-        k = prepared(self.k_proj(x), self.k_norm, self._kv_heads)
-        v = self.v_proj(x).reshape((b, s, self._kv_heads, hd)).transpose(
-            (0, 2, 1, 3))
-        out = attend(q, k, v, block_diffusion=block_diffusion)
-        out = out.transpose((0, 2, 1, 3)).reshape((b, s, self._heads * hd))
-        return self.o_proj(out)
 
 
 class SDARDecoderLayer(HybridBlock):

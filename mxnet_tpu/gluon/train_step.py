@@ -733,6 +733,9 @@ class TrainStep:
                     _telemetry.stage_moe_load(
                         self._name_of[id(p)].rpartition(".")[0], v,
                         p.moe_rungs)
+                elif getattr(p, "stages_exit_mass", False):
+                    # likewise, until telemetry.flush_exit_mass()
+                    _telemetry.stage_exit_mass(v)
         with _spans.span("train_step.bookkeeping"):
             # what the program's own instrumentation costs per step
             _telemetry.record_step_dispatch(

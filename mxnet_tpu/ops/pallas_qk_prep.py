@@ -44,9 +44,10 @@ __all__ = ["rms_norm_rotary"]
 _MAX_ROWS = 2048
 
 # what the two kernels of one call share besides their operands' shapes:
-# heads in the last dimension, rows of a block, the norm's epsilon, and
-# whether Pallas interprets the kernels (tests, off a TPU)
-_Sig = collections.namedtuple("_Sig", "heads rows eps interpret")
+# heads in the last dimension, rows of a block, the norm's epsilon,
+# whether Pallas interprets the kernels (tests, off a TPU), and whether
+# there is a norm at all (no gamma: the rotation and the store alone)
+_Sig = collections.namedtuple("_Sig", "heads rows eps interpret norm")
 
 
 def _kernel_mode():
@@ -81,26 +82,40 @@ def _normed(x, eps):
     return x * r, r
 
 
-def _fwd_kernel(x_ref, g_ref, cos_ref, sin_ref, o_ref, *, eps):
+def _fwd_kernel(x_ref, *refs, eps, norm):
     from jax.experimental.pallas import tpu as pltpu
 
-    xh, _ = _normed(x_ref[0].astype(jnp.float32), eps)
-    n = xh * g_ref[...]
+    *g_ref, cos_ref, sin_ref, o_ref = refs
+    n = x_ref[0].astype(jnp.float32)
+    if norm:
+        xh, _ = _normed(n, eps)
+        n = xh * g_ref[0][...]
     half = n.shape[-1] // 2
     o_ref[0, 0] = (n * cos_ref[...] + pltpu.roll(n, half, 1) * sin_ref[...]
                    ).astype(o_ref.dtype)
 
 
+def _rotated_back(dy_ref, cos_ref, sin_ref):
+    """The rotation's transpose on a block of cotangents: the same roll,
+    on the sine's side."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    dy = dy_ref[0, 0].astype(jnp.float32)
+    return dy * cos_ref[...] + pltpu.roll(dy * sin_ref[...],
+                                          dy.shape[-1] // 2, 1)
+
+
+def _bwd_rotation_kernel(dy_ref, cos_ref, sin_ref, dx_ref):
+    dx_ref[0] = _rotated_back(dy_ref, cos_ref, sin_ref).astype(dx_ref.dtype)
+
+
 def _bwd_kernel(x_ref, dy_ref, g_ref, cos_ref, sin_ref, dx_ref, dg_ref, *,
                 eps, s_len):
     import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     xh, r = _normed(x_ref[0].astype(jnp.float32), eps)
-    dy = dy_ref[0, 0].astype(jnp.float32)
-    rows, d = dy.shape
-    # the rotation's transpose: the same roll, on the sine's side
-    dn = dy * cos_ref[...] + pltpu.roll(dy * sin_ref[...], d // 2, 1)
+    dn = _rotated_back(dy_ref, cos_ref, sin_ref)
+    rows, d = dn.shape
     dgamma = dn * xh
     if s_len % rows:
         # the last block hangs over the sequence: its rows past the end
@@ -131,36 +146,53 @@ def _grid(sig, b, s_len):
     return (b, -(-s_len // sig.rows), sig.heads)
 
 
+def _scale(gamma, d):
+    return gamma.astype(jnp.float32).reshape((1, d))
+
+
 def _qk_prep_fwd_call(sig, x, gamma, cos, sin):
     b, s_len, width = x.shape
     d = width // sig.heads
     flat, major, scale, table = _specs(sig, d)
+    in_specs, operands = [flat, table, table], [x, cos, sin]
+    if sig.norm:
+        in_specs.insert(1, scale)
+        operands.insert(1, _scale(gamma, d))
     return _pallas_call(
-        functools.partial(_fwd_kernel, eps=sig.eps),
+        functools.partial(_fwd_kernel, eps=sig.eps, norm=sig.norm),
         name="rms_norm_rotary_fwd", grid=_grid(sig, b, s_len),
-        in_specs=[flat, scale, table, table], out_specs=major,
+        in_specs=in_specs, out_specs=major,
         out_shape=jax.ShapeDtypeStruct((b, sig.heads, s_len, d), x.dtype),
         interpret=sig.interpret,
-    )(x, gamma.astype(jnp.float32).reshape((1, d)), cos, sin)
+    )(*operands)
 
 
 def _qk_prep_bwd_call(sig, x, dy, gamma, cos, sin):
+    """(dx, dgamma); without a norm x and gamma are None, dx is the
+    cotangent turned back and stored in the projection's layout, and
+    there is no dgamma."""
     import jax.experimental.pallas as pl
 
-    b, s_len, width = x.shape
-    d = width // sig.heads
+    b, _, s_len, d = dy.shape
     flat, major, scale, table = _specs(sig, d)
     grid = _grid(sig, b, s_len)
+    dx_shape = jax.ShapeDtypeStruct((b, s_len, sig.heads * d), dy.dtype)
+    if not sig.norm:
+        return _pallas_call(
+            _bwd_rotation_kernel, name="rms_norm_rotary_bwd", grid=grid,
+            in_specs=[major, table, table], out_specs=flat,
+            out_shape=dx_shape, interpret=sig.interpret,
+        )(dy, cos, sin), None
     dx, partial = _pallas_call(
         functools.partial(_bwd_kernel, eps=sig.eps, s_len=s_len),
         name="rms_norm_rotary_bwd", grid=grid,
         in_specs=[flat, major, scale, table, table],
         out_specs=[flat, pl.BlockSpec((1, 1, 1, 8, d),
                                       lambda b, r, h: (b, r, h, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=[dx_shape,
                    jax.ShapeDtypeStruct(grid + (8, d), jnp.float32)],
         interpret=sig.interpret,
-    )(x, dy, gamma.astype(jnp.float32).reshape((1, d)), cos, sin)
+    )(x, dy, _scale(gamma, d), cos, sin)
     return dx, partial.sum(axis=(0, 1, 2, 3)).astype(gamma.dtype)
 
 
@@ -173,8 +205,9 @@ def _prepared(x, gamma, cos, sin, sig):
 
 def _prepared_fwd(x, gamma, cos, sin, sig):
     # nothing float32 of the tensor's size is kept: the backward
-    # recomputes each row's 1 / rms from x
-    return _prepared(x, gamma, cos, sin, sig), (x, gamma, cos, sin)
+    # recomputes each row's 1 / rms from x (and needs no x without a norm)
+    return (_prepared(x, gamma, cos, sin, sig),
+            (x if sig.norm else None, gamma, cos, sin))
 
 
 def _prepared_bwd(sig, res, dy):
@@ -188,13 +221,14 @@ _prepared.defvjp(_prepared_fwd, _prepared_bwd)
 
 
 def _composition(x, gamma, positions, theta, num_heads, eps):
-    """The three ops one after the other: what the kernels replace, and
-    the op's reference."""
+    """The three ops one after the other (two without a norm): what the
+    kernels replace, and the op's reference."""
     b, s_len, width = x.shape
     heads = x.reshape((b, s_len, num_heads, width // num_heads))
-    normed = _nn.rms_norm(heads.astype(jnp.float32), gamma,
-                          eps=eps).astype(x.dtype)
-    return _nn.rotary_embedding(normed, positions.reshape((s_len, 1)),
+    if gamma is not None:
+        heads = _nn.rms_norm(heads.astype(jnp.float32), gamma,
+                             eps=eps).astype(x.dtype)
+    return _nn.rotary_embedding(heads, positions.reshape((s_len, 1)),
                                 theta).transpose((0, 2, 1, 3))
 
 
@@ -205,8 +239,11 @@ def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
     head-major layout, as one op.
 
     x: (B, S, num_heads * D), a projection's output; ``gamma``: the
-    norm's (D,) scale, shared by the heads; ``positions``: the S explicit
-    position ids, shared by the batch.  Returns (B, num_heads, S, D) in
+    norm's (D,) scale, shared by the heads, or None for a layer that
+    rotates its heads without a norm (n = x below; the same two kernels
+    with the norm compiled out, and a backward that reads the cotangent
+    alone); ``positions``: the S explicit position ids, shared by the
+    batch.  Returns (B, num_heads, S, D) in
     x's type, what `flash_attention` reads:
 
         n = x / sqrt(mean(x ** 2 over D) + eps) * gamma     (each head)
@@ -227,9 +264,11 @@ def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
 
     b, s_len, width = x.shape
     d, rest = divmod(width, num_heads)
-    if rest or gamma.shape != (d,) or positions.size != s_len:
+    if rest or positions.size != s_len or (
+            gamma is not None and gamma.shape != (d,)):
         raise ValueError(
-            f"x {x.shape} as {num_heads} heads, gamma {gamma.shape}, "
+            f"x {x.shape} as {num_heads} heads, gamma "
+            f"{None if gamma is None else gamma.shape}, "
             f"{positions.size} positions: the last dimension is num_heads "
             "heads of gamma's width, and there is a position a row")
     interpret = _kernel_mode()
@@ -240,5 +279,5 @@ def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
     cos, sin = _tables(positions, theta, d)
     sig = _Sig(int(num_heads),
                _row_tile(s_len, d, jnp.dtype(x.dtype).itemsize),
-               float(eps), interpret)
+               float(eps), interpret, gamma is not None)
     return _prepared(x, gamma, cos, sin, sig)

@@ -27,6 +27,8 @@ __all__ = [
     "record_attention_fallback",
     "attention_maskfree_share", "set_attention_maskfree_share",
     "qk_prep_kernel_share", "record_qk_prep_site",
+    "looped_stack_copies", "ut_steps", "set_looped_stack", "exit_mass",
+    "stage_exit_mass", "flush_exit_mass",
     "xla_compile_seconds_total", "xla_programs_total",
     "install_compile_listener",
     "transfer_total", "transfer_bytes_total",
@@ -178,6 +180,23 @@ qk_prep_kernel_share = gauge(
     "multiple of 128, a length that is a multiple of 8); a site on the "
     "composition rms_norm -> rotary_embedding -> transpose counts as 0. "
     "Set on the host each time the op is traced")
+looped_stack_copies = gauge(
+    "looped_stack_copies",
+    "Copies of the layer stack that the traced program of a model which "
+    "runs its stack several times on shared weights holds: 1 says the "
+    "passes are one rolled loop (gluon.model_zoo.decoder.run_looped), the "
+    "number of passes that they were unrolled. Set on the host while the "
+    "step is traced")
+ut_steps = gauge(
+    "ut_steps",
+    "Passes of the layer stack (loop steps on shared weights) in the "
+    "program traced last; set with looped_stack_copies")
+exit_mass = gauge(
+    "exit_mass",
+    "Of a model with an exit after every pass of its looped stack: the "
+    "mean over the last step's positions of the probability that the exit "
+    "gate gives each loop step (1-based); the steps' values sum to 1. "
+    "Produced on the device; set by flush_exit_mass()", ["step"])
 compile_flops = gauge(
     "compile_flops",
     "XLA cost_analysis flops of the latest executable per block variant "
@@ -867,6 +886,43 @@ def record_qk_prep_site(kernels):
     _qk_prep_sites[0] += bool(kernels)
     _qk_prep_sites[1] += 1
     qk_prep_kernel_share.set(_qk_prep_sites[0] / _qk_prep_sites[1])
+
+
+def set_looped_stack(steps):
+    """A program was traced whose layer stack runs ``steps`` times as one
+    rolled loop: one copy of the stack."""
+    if not REGISTRY.enabled:
+        return
+    looped_stack_copies.set(1)
+    ut_steps.set(steps)
+
+
+# the device array the last step's exit objective produced, (steps,); it
+# stays on the device until somebody asks
+_staged_exit_mass = []
+
+
+def stage_exit_mass(mass):
+    """A step's exit objective produced ``mass``, the mean exit
+    probability of each loop step, on the device.  Keeps the array,
+    fetches nothing, like `stage_moe_load`."""
+    if REGISTRY.enabled:
+        _staged_exit_mass[:] = [mass]
+
+
+def flush_exit_mass():
+    """Fetch what the last step staged and set ``exit_mass{step}``;
+    returns the list of the loop steps' masses, or None where no step
+    staged any.  One device-to-host read: call it where the loop reads
+    the loss."""
+    if not _staged_exit_mass:
+        return None
+    import jax
+
+    mass = [float(m) for m in jax.device_get(_staged_exit_mass[0])]
+    for t, m in enumerate(mass):
+        exit_mass.labels(str(t + 1)).set(m)
+    return mass
 
 
 def record_transfer(direction, nbytes):

@@ -299,6 +299,106 @@ def test_layers_share_one_copy_of_each_preparation_kernel(spec, monkeypatch):
     assert text.count("call @qk_prep_bwd_call") == layers
 
 
+# -- a looped decoder (ISSUE 43): 16 heads of 128 under the causal mask, the
+#    rotation without a per-head norm, and the whole rolled step --------------
+
+@pytest.mark.parametrize("case", ["fwd", "grad"])
+def test_multi_head_causal_attention_compiles_for_v5e(spec, case):
+    """The flash kernels' third signature among the cells: 16 query and 16
+    key-value heads, keys and values 128 wide, 8192 positions, causal."""
+    from mxnet_tpu.ops.pallas_attention import flash_attention
+
+    q = spec((1, 16, 8192, 128), jnp.bfloat16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, causal=True)
+
+    if case == "fwd":
+        assert _kernel_calls(attend, q, q, q) == 1
+    else:
+        calls = _kernel_calls(jax.grad(
+            lambda *a: attend(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), q, q, q)
+        assert calls == 3      # forward, dQ, dK/dV
+
+
+@pytest.mark.parametrize("heads,seq,dtype", [
+    (16, 8192, jnp.bfloat16),      # the looped cell's queries and keys
+    (16, 8200, jnp.bfloat16),      # the last block hangs over the sequence
+    (2, 24, jnp.bfloat16),         # one block, not whole bf16 sublane tiles
+    (2, 2056, jnp.float32),
+], ids=["qk", "edge", "short", "float32"])
+def test_norm_free_preparation_compiles_for_v5e(spec, monkeypatch, heads,
+                                                seq, dtype):
+    """`rms_norm_rotary(x, None, ...)`: the rotation and the head-major
+    store through the same two kernels with the norm compiled out; the
+    backward reads the cotangent alone and has no dgamma."""
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    x, pos = spec((1, seq, heads * 128), dtype), spec((seq,), jnp.int32)
+
+    def loss(x, positions):
+        out = qp.rms_norm_rotary(x, None, positions, 1e6, heads)
+        return out.astype(jnp.float32).sum()
+
+    assert _kernel_calls(loss, x, pos) == 1
+    assert _kernel_calls(jax.value_and_grad(loss), x, pos) == 2
+
+
+def test_the_looped_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The looped cell's step as gluon.TrainStep builds it — every
+    published width, 8192 positions, 4 loop steps, the whole vocabulary,
+    bf16 under Adam with masters, remat; ONE of its six layers — compiled
+    for the described v5e: the passes are one rolled loop (a layer's nine
+    Mosaic calls once, not once a loop step: flash forward, dQ, dK/dV, and
+    the rotation of q and k forward, replayed and back), the four exits'
+    logits go by blocks, and arguments and temporaries fit the chip."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, gluon
+    from mxnet_tpu.gluon.model_zoo.ouro import ouro
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    kernel = pa.flash_attention
+    monkeypatch.setattr(pa, "flash_attention", lambda *a, **kw: kernel(
+        *a, **{**kw, "interpret": False}))
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    net = ouro(49152, 2048, 1, 16, 16, 128, 5632, 4, remat=True)
+    net.initialize(init=mx.initializer.Zero())
+    amp.convert_hybrid_block(net, target_dtype="bfloat16")
+    net.hybridize()
+    trainer = gluon.Trainer(
+        net.collect_params(), "adam",
+        {"learning_rate": 1e-5, "multi_precision": True}, kvstore="tpu_dist")
+    step = gluon.TrainStep(net, None, trainer, n_data=1)
+    jitted = step._jitted
+
+    def intercept(donate):
+        fn = jitted(donate)
+
+        def lower_only(*a):
+            described = jax.tree_util.tree_map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                               sharding=one_chip)
+                if hasattr(v, "shape") else v, a)
+            raise _Lowered(fn.lower(*described).compile())
+        return lower_only
+
+    step._jitted = intercept
+    with pytest.raises(_Lowered) as caught:
+        step(mx.np.zeros((1, 8192), dtype="int32"))
+    compiled = caught.value.args[0]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert "f64[" not in text
+    # no (.., 8192, 49152) float32 logits anywhere: blocks of 256 positions
+    assert "f32[4,8192,49152]" not in text and "f32[1,8192,49152]" not in text
+    assert "f32[4,256,49152]" in text
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12 * 2 ** 30
+
+
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 class _Lowered(Exception):

@@ -1,0 +1,48 @@
+"""tools/compare_step_hlo.py: two optimized HLO texts of one program from
+two checkouts compare equal once source locations are cut out."""
+import importlib.util
+import os
+
+import pytest
+
+TOOL = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "tools", "compare_step_hlo.py")
+
+
+@pytest.fixture(scope="module")
+def tool():
+    spec = importlib.util.spec_from_file_location("compare_step_hlo", TOOL)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+TEXT = '''HloModule jit_whole_step
+
+FileNames
+1 "{root}/mxnet_tpu/gluon/model_zoo/decoder.py"
+
+StackFrames
+1 {{file_location_id=1 parent_frame_id=1}}
+
+ENTRY main {{
+  p = f32[8]{{0}} parameter(0), metadata={{op_name="x" source_file="{root}/a.py" source_line={line} stack_frame_id=3}}
+  k = f32[8]{{0}} custom-call(p), custom_call_target="tpu_custom_call", backend_config={{"custom_call_config":{{"body":"{body}"}}}}
+  ROOT r = f32[8]{{0}} add(p, k), metadata={{op_name="{op}" source_file="{root}/b.py" source_line=7}}
+}}
+'''
+
+
+def test_source_locations_and_kernel_bodies_are_cut_out(tool):
+    a = TEXT.format(root="/root/repo", line=12, body="QUJD", op="add")
+    b = TEXT.format(root="/root/scratch/parent", line=340, body="WFla",
+                    op="add")
+    assert a != b
+    assert tool.outside_kernels(a) == tool.outside_kernels(b)
+    assert any("custom_call_target" in l for l in tool.outside_kernels(a))
+
+
+def test_another_instruction_is_a_difference(tool):
+    a = TEXT.format(root="/root/repo", line=12, body="QUJD", op="add")
+    b = TEXT.format(root="/root/repo", line=12, body="QUJD", op="ut_step/add")
+    assert tool.outside_kernels(a) != tool.outside_kernels(b)
