@@ -14,9 +14,17 @@ shapes (`_choose_tile`) and builds the plan of a signature once (`_plan`).
 `flash_attention` is differentiable via custom_vjp with a block-streamed
 Pallas backward (FlashAttention-2): the forward saves only (out, lse);
 backward recomputes P tiles per block from (q, k, lse), so training is
-O(S) memory end to end — dQ accumulates over streaming K/V blocks, dK/dV
-over streaming Q blocks, and delta = rowsum(dO*O) supplies the softmax
-correction.
+O(S) memory end to end, and delta = rowsum(dO*O) supplies the softmax
+correction.  The backward is ONE kernel (`_bwd_kernel`) wherever a head's
+keys, values and their float32 gradients fit the core's fast memory: it
+walks the forward's schedule, computes P, dP and dS once a sub-tile and
+feeds dQ (summed over the spans of a resident q tile), dK and dV (summed
+in VMEM scratch of the whole sequence over every q tile and every query
+head of the group) from them — five products a sub-tile.  Where they do
+not fit (`_Plan.fused`: a rule on the sequence length, the two widths and
+the operands' type) it is the two kernels it replaces, dQ over streaming
+K/V spans and dK/dV over streaming Q spans, which each recompute the
+scores: seven products.
 
 Off a TPU the jnp reference implementation runs instead (tests run the
 kernel in interpret mode for numerics); on a TPU the kernel runs and a
@@ -41,15 +49,22 @@ __all__ = ["flash_attention", "attention_reference", "SAVED_BY_NAME"]
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
-def _pallas_call(kernel, *, name, **kwargs):
+def _pallas_call(kernel, *, name, vmem_limit=None, **kwargs):
     """`pl.pallas_call` under a stable `name` (what a device trace and
     the HLO show instead of `jvp__.N`), whose kernel body and BlockSpec
     index maps trace with x64 off.  The package turns `jax_enable_x64`
     on for user arrays (the 64-bit dtype contract); under it a Python
     `0` in an index map is an i64, which Mosaic refuses to legalize.
     Kernel operands are bf16/f32/int32, so nothing 64-bit crosses this
-    boundary."""
+    boundary.  ``vmem_limit``: the bytes of fast memory the kernel may
+    take where its plan needs more than the compiler's 16 MiB default."""
     import jax.experimental.pallas as pl
+
+    if vmem_limit is not None:
+        from jax.experimental.pallas import tpu as pltpu
+
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=int(vmem_limit))
 
     def call(*operands):
         with jax.enable_x64(False):
@@ -68,7 +83,7 @@ def _dropout_keep(seed, bh, q_pos, k_pos, dropout_p):
     hash of (seed, batch·head, global q position, global k position).
 
     Pure uint32 jnp arithmetic, so the SAME mask materializes inside
-    Pallas kernel tiles (fwd and both bwd passes), in interpret mode,
+    Pallas kernel tiles (fwd and every bwd kernel), in interpret mode,
     and on the full matrix of the jnp reference path — dropout is
     exactly reproducible across all of them."""
     h = (q_pos.astype(jnp.uint32) * jnp.uint32(_H1)
@@ -313,7 +328,7 @@ def _scores(q, k, qc, kc, scale, masked, use_eq):
 
 def _pair_keep(seed_ref, bh, q_start, k_start, shape, dropout_p):
     """The dropout keep mask of the sub-tile whose first pair is global
-    position (q_start, k_start): the same in all three kernels."""
+    position (q_start, k_start): the same in every kernel."""
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return _dropout_keep(seed_ref[0], bh, q_pos, k_pos, dropout_p)
@@ -407,14 +422,42 @@ def _chunk_of(rows):
     return _ROWS if rows % _ROWS == 0 else rows
 
 
-def _working_set(tile, sub, span, dk, dv, itemsize):
+def _working_set(tile, sub, span, dk, dv, itemsize, resident=0):
     """Bytes of fast memory a grid step plans for: three float32
     temporaries of a chunk of rows by a tile of columns (scores,
     probabilities, their gradient), the resident rows and the streamed
-    span of both widths, double-buffered, and the float32 accumulators."""
-    return (3 * 4 * max(_chunk_of(tile), _chunk_of(sub)) * max(tile, sub)
-            + 2 * itemsize * (tile + span * sub) * (dk + dv)
-            + 4 * tile * (dk + dv))
+    span of both widths, double-buffered, and the float32 accumulators.
+    With ``resident`` key positions held for a whole head (the fused
+    backward, which streams no span): K, V, dK and dV of all of them,
+    double-buffered, and the float32 accumulators of both gradients;
+    the dQ block beside q and dO; and the lane-padded columns of the
+    resident rows (lse, delta, the query codes), which at a tile of 1024
+    rows are 3 MiB that the 12 MiB plans leave to the compiler's slack."""
+    total = (3 * 4 * max(_chunk_of(tile), _chunk_of(sub)) * max(tile, sub)
+             + 2 * itemsize * (tile + span * sub) * (dk + dv)
+             + 4 * tile * (dk + dv))
+    if resident:
+        total += (resident * (dk + dv) * (4 * itemsize + 4)
+                  + 2 * itemsize * tile * dk + 3 * 2 * 4 * tile * _LANES)
+    return total
+
+
+# the fused backward holds a head's keys, values and their gradients in
+# fast memory where that working set is at most this share of the core's;
+# the kernel is then given the working set and a third more as its limit
+_FUSED_VMEM_SHARE = 0.6
+_V5E_VMEM = 128 * 2 ** 20
+
+
+def _vmem_capacity():
+    """Bytes of fast memory of the core the kernels run on: read from the
+    chip where there is one, the v5e's where the kernel is lowered for a
+    described chip or interpreted."""
+    if jax.devices()[0].platform != "tpu":
+        return _V5E_VMEM
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.get_tpu_info().vmem_capacity_bytes
 
 
 def _span_for(s_len, tile, sub, dk, dv, itemsize):
@@ -454,12 +497,16 @@ def _maskfree_share(side):
 
 
 class _Plan:
-    """What the three kernels of one signature share, built once
-    (`_plan`): tile sizes, the mask's codes, both span schedules with
-    their classes.  The forward and dQ hold a q tile and stream spans of
-    k sub-tiles (``rows``); dK/dV holds a k tile and streams spans of q
-    sub-tiles (``cols``).  ``block_q`` is the q tile and the q sub-tile,
-    ``block_k`` the k tile and the k sub-tile."""
+    """What the kernels of one signature share, built once (`_plan`):
+    tile sizes, the mask's codes, the span schedules with their classes
+    and the backward's memory plan.  The forward, dQ and the fused
+    backward hold a q tile and walk spans of k sub-tiles (``rows``);
+    dK/dV holds a k tile and streams spans of q sub-tiles (``cols``).
+    ``block_q`` is the q tile and the q sub-tile, ``block_k`` the k tile
+    and the k sub-tile.  ``vmem_limit`` is the fast memory the fused
+    backward is given, or None where a head's keys, values and their
+    gradients do not fit it (``fused`` says which): the backward is then
+    the dQ and the dK/dV kernel, and only then is ``cols`` built."""
 
     def __init__(self, q_shape, k_shape, v_shape, itemsize, causal,
                  block_q, block_k, valid_len, block_diffusion):
@@ -477,8 +524,16 @@ class _Plan:
         self.codes = codes if codes is not None else _mask_codes(
             False, None, s_len, s_len)     # nothing reads them: all kept
         self.rows = self._side(codes, block_q, block_k, itemsize)
-        self.cols = self._side(codes, block_k, block_q, itemsize,
-                               by_key=True)
+        need = _working_set(block_q, block_k, 0, self.dk, self.dv, itemsize,
+                            resident=s_len)
+        self.vmem_limit = need + need // 3 if (
+            need <= _FUSED_VMEM_SHARE * _vmem_capacity()) else None
+        self.cols = None if self.fused else self._side(
+            codes, block_k, block_q, itemsize, by_key=True)
+
+    @property
+    def fused(self):
+        return self.vmem_limit is not None
 
     def _side(self, codes, tile, sub, itemsize, by_key=False):
         import numpy as onp
@@ -505,11 +560,11 @@ class _Plan:
         return (qc.reshape(-1, unit_q, 2),
                 kc.reshape(2, -1, unit_k).transpose(1, 0, 2))
 
-    def specs(self, side, head_of):
-        """BlockSpecs for a grid whose axis 1 walks ``side``'s schedule:
-        (q-indexed tensor of a width, k-indexed tensor of a width, q
-        codes, k codes, q-indexed column).  The resident side's block is
-        one tile, the streamed side's a span of sub-tiles;
+    def specs(self, side, head_of, step_axis=1):
+        """BlockSpecs for a grid whose axis ``step_axis`` walks ``side``'s
+        schedule: (q-indexed tensor of a width, k-indexed tensor of a
+        width, q codes, k codes, q-indexed column).  The resident side's
+        block is one tile, the streamed side's a span of sub-tiles;
         ``head_of(*grid indices)`` gives (query head row, key-value head
         row).  An index map gets the grid indices, then the two
         prefetched scalar operands (dropout seed, schedule).  A column
@@ -524,10 +579,10 @@ class _Plan:
             (1, tile), (span, sub))
 
         def q_of(a):
-            return _tiles_of(a[-1][a[1]])[0]
+            return _tiles_of(a[-1][a[step_axis]])[0]
 
         def k_of(a):
-            return _tiles_of(a[-1][a[1]])[1]
+            return _tiles_of(a[-1][a[step_axis]])[1]
 
         def q_block(width):
             return pl.BlockSpec(
@@ -547,8 +602,17 @@ class _Plan:
                     (1, q_rows[0] * q_rows[1], 1),
                     lambda *a: (head_of(*a[:-2])[0], q_of(a), 0)))
 
+    def head_block(self, sub, width):
+        """BlockSpec of a key-value head's WHOLE sequence in sub-tiles of
+        ``sub`` rows, for a grid whose axis 0 walks the key-value heads:
+        fetched (or written back) once a head."""
+        import jax.experimental.pallas as pl
+
+        return pl.BlockSpec((1, self.s_len // sub, sub, width),
+                            lambda b, *_: (b, 0, 0, 0))
+
     def call(self, kernel, name, side, grid, in_specs, out_specs, out_shape,
-             scratch, interpret, **static):
+             scratch, interpret, vmem_limit=None, **static):
         from jax.experimental.pallas import tpu as pltpu
 
         return _pallas_call(
@@ -556,7 +620,7 @@ class _Plan:
                 kernel, classes=side.classes, use_eq=self.use_eq,
                 tile=side.tile, sub=side.sub, span=side.span,
                 chunk=side.chunk, **static),
-            name=name,
+            name=name, vmem_limit=vmem_limit,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=scratch),
@@ -566,17 +630,21 @@ class _Plan:
 @functools.lru_cache(maxsize=256)
 def _plan(q_shape, k_shape, v_shape, dtype, causal, block_q, block_k,
           valid_len, block_diffusion):
-    """The plan of one signature, built once: N layers, three kernels a
-    layer and remat's replays share one host computation.  Sets the gauge
-    ``attention_maskfree_share{kernel}`` from the schedules' class bits."""
+    """The plan of one signature, built once: N layers, the kernels of a
+    layer and remat's replays share one host computation.  Sets the gauges
+    ``attention_maskfree_share{kernel}`` from the schedules' class bits
+    and ``attention_fused_backward_share`` from the memory plan."""
     from ..telemetry import instruments as _telemetry
 
     plan = _Plan(q_shape, k_shape, v_shape, jnp.dtype(dtype).itemsize,
                  causal, block_q, block_k, valid_len, block_diffusion)
-    rows, cols = _maskfree_share(plan.rows), _maskfree_share(plan.cols)
-    _telemetry.set_attention_maskfree_share({
-        "flash_attention_fwd": rows, "flash_attention_bwd_dq": rows,
-        "flash_attention_bwd_dkv": cols})
+    rows = _maskfree_share(plan.rows)
+    _telemetry.set_attention_maskfree_share(
+        {"flash_attention_fwd": rows, "flash_attention_bwd": rows}
+        if plan.fused else
+        {"flash_attention_fwd": rows, "flash_attention_bwd_dq": rows,
+         "flash_attention_bwd_dkv": _maskfree_share(plan.cols)})
+    _telemetry.record_attention_backward_plan(plan.fused)
     return plan
 
 
@@ -643,6 +711,40 @@ def _recompute_p(q, k, lse_col, qc, kc, scale, masked, use_eq):
     return jnp.where(jnp.isfinite(lse_col), p, 0.0) if masked else p
 
 
+def _p_ds(q, k, v, do, lse_col, delta_col, qc, kc, scale, masked, use_eq,
+          dropout_p, keep):
+    """What the backward makes of one (q rows, k rows) sub-tile before
+    its products: ``(p, drop, ds)`` — the probabilities recomputed from
+    the saved logsumexp, dropout as a function of a tile (the identity
+    without it: P_d = drop(P) is what the forward multiplied V by), and
+    dS = P . (drop(dO V^T) - delta) without its factor ``scale``, which
+    the kernels apply to the finished sums.  ``keep(shape)`` is the
+    sub-tile's keep mask (`_pair_keep`), asked for under dropout only;
+    delta already equals rowsum(P_d . dP_d) because delta = rowsum(dO . O).
+    """
+    p = _recompute_p(q, k, lse_col, qc, kc, scale, masked, use_eq)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (q rows, k rows)
+
+    def drop(x):
+        return x
+
+    if dropout_p > 0.0:
+        kept = keep(p.shape)
+
+        def drop(x):
+            return jnp.where(kept, x, 0.0) / (1.0 - dropout_p)
+
+    return p, drop, p * (drop(dp) - delta_col)
+
+
+def _t_dot(a, b):
+    """a^T b in float32: (rows, m), (rows, n) -> (m, n)."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _bwd_dq_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, qc_ref, kc_ref, dq_ref, dq_acc, *,
                    scale, classes, use_eq, tile, sub, span, chunk,
@@ -660,19 +762,13 @@ def _bwd_dq_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
     def sub_tile(j, r0, masked):
         rows = pl.ds(r0, chunk)
         k = k_ref[0, j]
-        p = _recompute_p(q_ref[0, 0, rows], k, lse_ref[0, rows],
-                         qc_ref[0, rows], kc_ref[j], scale, masked, use_eq)
-        dp = jax.lax.dot_general(
-            do_ref[0, 0, rows], v_ref[0, j], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # (chunk, sub)
-        if dropout_p > 0.0:
-            # dP-hat = M/(1-p).(dO V^T); delta already equals
-            # rowsum(P-hat . dP-hat) because delta = rowsum(dO . O)
-            keep = _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
-                              (k_blk * span + j) * sub, p.shape, dropout_p)
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
-        # dS without its factor ``scale``: _finish applies it to the sum
-        ds = p * (dp - delta_ref[0, rows])
+        _, _, ds = _p_ds(
+            q_ref[0, 0, rows], k, v_ref[0, j], do_ref[0, 0, rows],
+            lse_ref[0, rows], delta_ref[0, rows], qc_ref[0, rows],
+            kc_ref[j], scale, masked, use_eq, dropout_p,
+            lambda shape: _pair_keep(
+                seed_ref, bh_idx, q_idx * tile + r0,
+                (k_blk * span + j) * sub, shape, dropout_p))
         dq_acc[rows] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -709,28 +805,15 @@ def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
         # lse and delta: one column over the span, not cut into sub-tiles
         span_rows = pl.ds(pl.multiple_of(j * sub + r0, chunk), chunk)
         q, do = q_ref[0, j, rows], do_ref[0, j, rows]
-        p = _recompute_p(q, k_ref[0, 0], lse_ref[0, span_rows],
-                         qc_ref[j, rows], kc_ref[0], scale, masked,
-                         use_eq)                             # (chunk, tile)
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        p_d = p
-        if dropout_p > 0.0:
-            keep = _pair_keep(seed_ref, bh_idx,
-                              (q_blk * span + j) * sub + r0,
-                              k_idx * tile, p.shape, dropout_p)
-            p_d = jnp.where(keep, p, 0.0) / (1.0 - dropout_p)
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_p)
-        # dV += P_d^T dO (P_d = dropped+rescaled probs, what fwd used)
-        dv_acc[:] += jax.lax.dot_general(
-            p_d.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dK += dS^T Q, dS without its factor ``scale`` (see _finish)
-        ds = p * (dp - delta_ref[0, span_rows])
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        p, drop, ds = _p_ds(
+            q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, span_rows],
+            delta_ref[0, span_rows], qc_ref[j, rows], kc_ref[0], scale,
+            masked, use_eq, dropout_p,
+            lambda shape: _pair_keep(
+                seed_ref, bh_idx, (q_blk * span + j) * sub + r0,
+                k_idx * tile, shape, dropout_p))         # (chunk, tile)
+        dv_acc[:] += _t_dot(drop(p).astype(do.dtype), do)
+        dk_acc[:] += _t_dot(ds.astype(q.dtype), q)
 
     _walk(word, span, sub, chunk, classes, sub_tile)
 
@@ -738,6 +821,71 @@ def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
     def _finish():
         dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, qc_ref, kc_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                dk_acc, dv_acc, *, scale, classes, use_eq, tile, sub, span,
+                chunk, group, dropout_p=0.0):
+    """dQ, dK and dV from one computation of P, dP and dS a sub-tile.
+
+    Grid: (key-value head, query head of its group, step of the q-major
+    schedule).  A q tile is resident and dQ sums over its spans as in
+    `_bwd_dq_kernel`; K and V are blocks of the WHOLE sequence of the
+    key-value head (fetched once a head, cut into sub-tiles on their
+    leading axis), and dK and dV sum over every query tile and every
+    query head of the group in float32 scratch of the whole sequence,
+    zeroed at the head's first step and stored at its last."""
+    import jax.experimental.pallas as pl
+
+    g_idx, step = pl.program_id(1), pl.program_id(2)
+    bh_idx = pl.program_id(0) * group + g_idx
+    word = sched_ref[step]
+    q_idx, k_blk = _tiles_of(word)
+    n_sub = dk_acc.shape[0]
+
+    def each_sub_tile(fn):
+        jax.lax.fori_loop(0, n_sub, lambda i, c: (fn(i), c)[1], 0)
+
+    @pl.when((g_idx == 0) & (step == 0))
+    def _init_kv():
+        @each_sub_tile
+        def _(i):
+            dk_acc[i] = jnp.zeros(dk_acc.shape[1:], dk_acc.dtype)
+            dv_acc[i] = jnp.zeros(dv_acc.shape[1:], dv_acc.dtype)
+
+    @pl.when((word & 2) != 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def sub_tile(j, r0, masked):
+        rows = pl.ds(r0, chunk)
+        kj = k_blk * span + j                    # the sub-tile's own index
+        q, do, k = q_ref[0, 0, rows], do_ref[0, 0, rows], k_ref[0, kj]
+        p, drop, ds = _p_ds(
+            q, k, v_ref[0, kj], do, lse_ref[0, rows], delta_ref[0, rows],
+            qc_ref[0, rows], kc_ref[j], scale, masked, use_eq, dropout_p,
+            lambda shape: _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
+                                     kj * sub, shape, dropout_p))
+        ds = ds.astype(k.dtype)                  # (chunk, sub), once
+        dq_acc[rows] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[kj] += _t_dot(ds, q)
+        dv_acc[kj] += _t_dot(drop(p).astype(do.dtype), do)
+
+    _walk(word, span, tile, chunk, classes, sub_tile)
+
+    @pl.when((word & 1) != 0)
+    def _finish():
+        dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+    @pl.when((g_idx == group - 1) & (step == sched_ref.shape[0] - 1))
+    def _finish_kv():
+        @each_sub_tile
+        def _(i):
+            dk_ref[0, i] = (dk_acc[i] * scale).astype(dk_ref.dtype)
+            dv_ref[0, i] = dv_acc[i].astype(dv_ref.dtype)
 
 
 def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
@@ -752,6 +900,30 @@ def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
     lse = lse.reshape(delta.shape)
 
     tile, sub, words = plan.rows.tile, plan.rows.sub, plan.rows.words
+    dq_shape = jax.ShapeDtypeStruct(
+        (plan.bh, plan.s_len // tile, tile, dk_), q.dtype)
+    k_units, v_units = plan.units(k, sub, True), plan.units(v, sub, True)
+    operands = (seed, jnp.asarray(words), plan.units(q, tile), k_units,
+                v_units, plan.units(g, tile), lse, delta,
+                *plan.code_units(tile, sub))
+    if plan.fused:
+        q_block, _, q_code, k_code, column = plan.specs(
+            plan.rows, lambda b, j, t: (b * group + j, b), step_axis=2)
+        k_head, v_head = plan.head_block(sub, dk_), plan.head_block(sub, dv_)
+        return plan.call(
+            _bwd_kernel, "flash_attention_bwd", plan.rows,
+            grid=(plan.bkv, group, len(words)),
+            in_specs=[q_block(dk_), k_head, v_head, q_block(dv_), column,
+                      column, q_code, k_code],
+            out_specs=[q_block(dk_), k_head, v_head],
+            out_shape=[dq_shape,
+                       jax.ShapeDtypeStruct(k_units.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v_units.shape, v.dtype)],
+            scratch=[_scratch((tile, dk_)), _scratch(k_units.shape[1:]),
+                     _scratch(v_units.shape[1:])],
+            interpret=interpret, vmem_limit=plan.vmem_limit, scale=scale,
+            dropout_p=dropout_p, group=group)(*operands)
+
     q_block, k_block, q_code, k_code, column = plan.specs(
         plan.rows, lambda b, t: (b, _head_div(b, group)))
     dq = plan.call(
@@ -759,14 +931,10 @@ def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
         grid=(plan.bh, len(words)),
         in_specs=[q_block(dk_), k_block(dk_), k_block(dv_), q_block(dv_),
                   column, column, q_code, k_code],
-        out_specs=q_block(dk_),
-        out_shape=jax.ShapeDtypeStruct(
-            (plan.bh, plan.s_len // tile, tile, dk_), q.dtype),
+        out_specs=q_block(dk_), out_shape=dq_shape,
         scratch=[_scratch((tile, dk_))],
         interpret=interpret, scale=scale, dropout_p=dropout_p,
-    )(seed, jnp.asarray(words), plan.units(q, tile),
-      plan.units(k, sub, True), plan.units(v, sub, True),
-      plan.units(g, tile), lse, delta, *plan.code_units(tile, sub))
+    )(*operands)
 
     tile, sub, words = plan.cols.tile, plan.cols.sub, plan.cols.words
     q_block, k_block, q_code, k_code, column = plan.specs(
@@ -853,7 +1021,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     q: (B, H, S, D); k: (B, Hkv, S, D); v: (B, Hkv, S, Dv), H a multiple
     of Hkv (grouped queries: query head h reads key-value head
-    h // (H // Hkv), and the dK/dV kernel sums over the group).  Queries
+    h // (H // Hkv), and dK and dV sum over the group).  Queries
     and keys share the width D, values and the result (B, H, S, Dv) the
     width Dv, and the two may differ (latent attention: 192 and 128):
     every tile, accumulator and gradient has the width of its tensor, so
@@ -885,8 +1053,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     ``block_diffusion=(block length B, half length L)`` for a sequence of
     S = 2L positions [noisy ; clean] (`_mask_codes` has the rule).  A
     grid step of the forward and dQ kernels holds one q tile and streams
-    a span of consecutive k sub-tiles (dK/dV: a k tile and a span of q
-    sub-tiles); the host lists the steps once (`_span_schedule`) and
+    a span of consecutive k sub-tiles (the fused backward walks the same
+    steps over keys it holds; dK/dV: a k tile and a span of q sub-tiles);
+    the host lists the steps once (`_span_schedule`) and
     gives each sub-tile a class from the codes' minima and maxima:
     dead (skipped; a span of dead sub-tiles is not in the grid at all),
     mask-free (every pair kept: no codes read, no compare, no select) or
@@ -897,10 +1066,29 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     edge.  The gauge ``attention_maskfree_share{kernel}`` is the share of
     visited sub-tiles that take the mask-free body.
 
-    **Before the first step** the plan of a signature (codes, both
-    schedules, classes, the gauge) is built once on the host in about a
-    millisecond (`_plan`), and the layers of a model that share the
-    signature share one traced and lowered copy of each kernel
+    **The backward's memory plan.**  One fused kernel,
+    ``flash_attention_bwd``, where the working set of a key-value head
+    held whole — K, V, dK and dV blocks of the entire sequence,
+    double-buffered, their float32 accumulators, the resident q tile's
+    side and the temporaries of a chunk (`_working_set(resident=S)`: 44 MB
+    at 8192 positions of 192 / 128 bf16) — is at most 0.6 of the core's
+    fast memory (`pltpu.get_tpu_info()` on a TPU, the v5e's 128 MiB where
+    the kernel is lowered for a described chip or interpreted); the kernel
+    is given that working set and a third more as ``vmem_limit_bytes``.
+    It walks the forward's schedule on the grid (key-value head, query
+    head of the group, step), fetches K and V once a key-value head,
+    computes P, dP and dS once a sub-tile and makes dQ, dK and dV from
+    them.  Beyond that (192 / 128 bf16: past 16k positions) the backward
+    is ``flash_attention_bwd_dq`` and ``flash_attention_bwd_dkv``, which
+    stream what the other holds and each recompute the scores.  Both
+    give the same float32 terms; dK and dV sum them in another order.
+    The gauge ``attention_fused_backward_share`` is the share of planned
+    signatures that fused.
+
+    **Before the first step** the plan of a signature (codes, schedules,
+    classes, the memory plan, the gauges) is built once on the host in
+    about a millisecond (`_plan`), and the layers of a model that share
+    the signature share one traced and lowered copy of each kernel
     (`_shared`).
 
     dropout_p > 0 with an int32 `dropout_seed` applies attention-prob

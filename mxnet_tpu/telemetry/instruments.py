@@ -26,6 +26,7 @@ __all__ = [
     "hybridize_fallback_total", "attention_kernel_fallback_total",
     "record_attention_fallback",
     "attention_maskfree_share", "set_attention_maskfree_share",
+    "attention_fused_backward_share", "record_attention_backward_plan",
     "qk_prep_kernel_share", "record_qk_prep_site",
     "looped_stack_copies", "ut_steps", "set_looped_stack", "exit_mass",
     "stage_exit_mass", "flush_exit_mass",
@@ -172,7 +173,18 @@ attention_maskfree_share = gauge(
     "share that takes the mask-free body (every pair kept: no codes read, "
     "no compare, no select); 1 is an unmasked call. Set on the host when "
     "the plan of a signature is built (ops.pallas_attention._plan), from "
-    "the schedule's class bits; the latest signature's", ["kernel"])
+    "the schedule's class bits; the latest signature's. kernel is "
+    "flash_attention_fwd and, by the backward's memory plan, either "
+    "flash_attention_bwd (fused) or flash_attention_bwd_dq and "
+    "flash_attention_bwd_dkv", ["kernel"])
+attention_fused_backward_share = gauge(
+    "attention_fused_backward_share",
+    "Of the flash_attention signatures planned so far, the share whose "
+    "backward is the one fused kernel (dQ, dK and dV from one pass over "
+    "the scores: a head's keys, values and their float32 gradients fit "
+    "the stated share of the core's fast memory); a signature whose "
+    "backward is the dQ and the dK/dV kernel counts as 0. Set on the host "
+    "when the plan of a signature is built (ops.pallas_attention._plan)")
 qk_prep_kernel_share = gauge(
     "qk_prep_kernel_share",
     "Of the call sites of ops.pallas_qk_prep.rms_norm_rotary traced so far, "
@@ -875,6 +887,18 @@ def set_attention_maskfree_share(by_kernel):
         return
     for kernel, share in by_kernel.items():
         attention_maskfree_share.labels(kernel).set(share)
+
+
+_attention_plans = [0, 0]    # signatures planned: fused backward, in all
+
+
+def record_attention_backward_plan(fused):
+    if not REGISTRY.enabled:
+        return
+    _attention_plans[0] += bool(fused)
+    _attention_plans[1] += 1
+    attention_fused_backward_share.set(
+        _attention_plans[0] / _attention_plans[1])
 
 
 _qk_prep_sites = [0, 0]      # traced call sites: on the kernels, in all

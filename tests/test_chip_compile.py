@@ -93,7 +93,7 @@ def test_flash_attention_compiles_for_v5e(spec, seq, case):
 
         calls = _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)),
                               q, q, q, seed)
-        assert calls == 3      # forward, dQ, dK/dV
+        assert calls == 2      # forward, the fused backward
 
 
 # -- flash attention: SDAR's 32 query over 4 key-value heads of 128, 2 x 4096
@@ -122,7 +122,7 @@ def test_grouped_block_diffusion_attention_compiles_for_v5e(spec, case, tile):
         calls = _kernel_calls(jax.grad(
             lambda *a: attend(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2)), q, kv, kv)
-        assert calls == 3      # forward, dQ, dK/dV summed over the group
+        assert calls == 2      # forward; dQ, dK, dV summed over the group
 
 
 # -- flash attention: latent attention's 32 heads with keys 192 and values
@@ -146,14 +146,15 @@ def test_latent_attention_widths_compile_for_v5e(spec, case, tile):
         calls = _kernel_calls(jax.grad(
             lambda *a: attend(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2)), qk, qk, v)
-        assert calls == 3      # forward, dQ (192 wide), dK/dV (192 and 128)
+        assert calls == 2      # forward; dQ, dK (192 wide) and dV (128)
 
 
 # -- the flash kernels' set-up (ISSUE 40): one plan and one lowered kernel a
 #    signature, a plan that costs a millisecond, bodies that stay small -------
 
-# (q, k, v shapes, mask, the parent's serialised body bytes by kernel: its
-# 512 x 512 tiles lowered for this described v5e at commit ef34035)
+# (q, k, v shapes, mask, an earlier tree's serialised body bytes by kernel:
+# 512 x 512 tiles lowered for this described v5e at commit ef34035; the
+# looped cell's signature at commit af1c3c2, tiles of 1024 in chunks of 512)
 _CELL_KERNELS = {
     "sdar": ((1, 32, 8192, 128), (1, 4, 8192, 128), (1, 4, 8192, 128),
              {"block_diffusion": (4, 4096)},
@@ -163,27 +164,20 @@ _CELL_KERNELS = {
                 {"causal": True},
                 {"flash_attention_fwd": 10876, "flash_attention_bwd_dq": 6492,
                  "flash_attention_bwd_dkv": 7568}),
+    "ouro": ((1, 16, 8192, 128), (1, 16, 8192, 128), (1, 16, 8192, 128),
+             {"causal": True},
+             {"flash_attention_fwd": 13888, "flash_attention_bwd_dq": 8348,
+              "flash_attention_bwd_dkv": 9408}),
 }
 
 
-@pytest.mark.parametrize("cell", sorted(_CELL_KERNELS))
-def test_layers_of_one_signature_share_the_plan_and_the_kernels(
-        spec, cell, monkeypatch):
-    """Four layers at a cell's shapes, forward and backward, lowered for the
-    described v5e: the plan is built once, the module holds three Mosaic
-    calls (the parent's held three a layer) and each serialised body is
-    under twice the parent's bytes."""
+def _lowered_kernels(pa, spec, cell, layers):
+    """(module text, {kernel name: serialised body bytes}) of ``layers``
+    layers at a cell's shapes, forward and backward, lowered for the
+    described v5e."""
     import re
 
-    from mxnet_tpu.ops import pallas_attention as pa
-
-    qs, ks, vs, mask, parent_bytes = _CELL_KERNELS[cell]
-    layers, built = 4, []
-    init = pa._Plan.__init__
-    monkeypatch.setattr(
-        pa._Plan, "__init__",
-        lambda self, *a: (built.append(a), init(self, *a))[1])
-    pa._plan.cache_clear()
+    qs, ks, vs, mask, _ = _CELL_KERNELS[cell]
 
     def loss(q, k, v):
         total = 0.0
@@ -197,26 +191,87 @@ def test_layers_of_one_signature_share_the_plan_and_the_kernels(
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         spec(qs, jnp.bfloat16), spec(ks, jnp.bfloat16),
         spec(vs, jnp.bfloat16)).as_text()
-    assert len(built) == 1
     calls = re.findall(r"stablehlo.custom_call @tpu_custom_call.*", text)
-    assert len(calls) == 3 <= 3 * layers
-    for line in calls:
-        name = re.search(r'kernel_name = "([^"]+)"', line).group(1)
-        body = re.search(r'backend_config = "([^"]*)"', line).group(1)
-        assert len(body) < 2 * parent_bytes[name], (name, len(body))
+    names = [re.search(r'kernel_name = "([^"]+)"', c).group(1) for c in calls]
+    assert len(set(names)) == len(names), names
+    return text, {n: len(re.search(r'backend_config = "([^"]*)"', c).group(1))
+                  for n, c in zip(names, calls)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_KERNELS))
+def test_layers_of_one_signature_share_the_plan_and_the_kernels(
+        spec, cell, monkeypatch):
+    """Four layers at a cell's shapes, forward and backward, lowered for the
+    described v5e: the plan is built once and fuses the backward, the
+    module holds two Mosaic calls (three before the backward was one
+    kernel, three a layer before PR 40), the forward's serialised body is
+    under twice the earlier tree's bytes and the fused body under the sum
+    of the two it replaces — the same tree's dQ and dK/dV bodies, lowered
+    for a core whose fast memory holds no head, which stay under twice the
+    earlier tree's."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    earlier = _CELL_KERNELS[cell][4]
+    layers, built = 4, []
+    init = pa._Plan.__init__
+    monkeypatch.setattr(
+        pa._Plan, "__init__",
+        lambda self, *a: (init(self, *a), built.append(self))[0])
+    pa._plan.cache_clear()
+    pa._shared.cache_clear()
+    text, fused = _lowered_kernels(pa, spec, cell, layers)
+    assert len(built) == 1 and built[0].fused
+    assert built[0].vmem_limit < pa._V5E_VMEM == pa._vmem_capacity()
+    assert sorted(fused) == ["flash_attention_bwd", "flash_attention_fwd"]
+    assert fused["flash_attention_fwd"] < 2 * earlier["flash_attention_fwd"]
     # every layer still calls its kernels under its own scope
     assert text.count("call @flash_fwd_call") == layers
     assert text.count("call @flash_bwd_call") == layers
+
+    monkeypatch.setattr(pa, "_vmem_capacity", lambda: 0)
+    pa._plan.cache_clear()
+    pa._shared.cache_clear()
+    _, two = _lowered_kernels(pa, spec, cell, 1)
+    pa._plan.cache_clear()
+    pa._shared.cache_clear()
+    assert len(built) == 2 and not built[1].fused
+    assert sorted(two) == sorted(earlier)
+    for name, size in two.items():
+        assert size < 2 * earlier[name], (name, size)
+    assert fused["flash_attention_bwd"] < (
+        two["flash_attention_bwd_dq"] + two["flash_attention_bwd_dkv"])
+
+
+@pytest.mark.parametrize("seq,fused", [(16384, True), (32768, False)],
+                         ids=["16k_fused", "32k_two_kernels"])
+def test_the_memory_plan_at_long_sequences_compiles_for_v5e(spec, seq,
+                                                             fused):
+    """Keys 192 and values 128 wide: 16k positions are the longest power of
+    two whose head (keys, values, both gradients and their float32 sums)
+    the plan holds in the v5e's fast memory, and Mosaic takes the limit it
+    asks for; at 32k the plan is the dQ and the dK/dV kernel."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    qk = spec((1, 2, seq, 192), jnp.bfloat16)
+    v = spec((1, 2, seq, 128), jnp.bfloat16)
+    pa._plan.cache_clear()
+    plan = pa._plan_of(qk, qk, v, True, 1024, 1024, None, None)
+    assert plan.fused == fused
+    calls = _kernel_calls(jax.grad(
+        lambda *a: pa.flash_attention(*a, interpret=False, causal=True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)), qk, qk, v)
+    pa._plan.cache_clear()
+    assert calls == (2 if fused else 3)
 
 
 @pytest.mark.parametrize("mask", [{"causal": True},
                                   {"block_diffusion": (4, 4096)}],
                          ids=["causal", "block_diffusion"])
 def test_the_plan_for_8192_positions_costs_a_millisecond(mask):
-    """Codes, both schedules, classes and the gauge for 8192 positions:
-    well under 50 ms on the host, and no (S, S) array on the way (the
-    dense mask would be 64 MiB of booleans; the plan's peak is the codes
-    in 64 bits)."""
+    """Codes, the schedule, classes, the memory plan and the gauges for
+    8192 positions: well under 50 ms on the host, and no (S, S) array on
+    the way (the dense mask would be 64 MiB of booleans; the plan's peak
+    is the codes in 64 bits)."""
     import time
     import tracemalloc
 
@@ -236,7 +291,7 @@ def test_the_plan_for_8192_positions_costs_a_millisecond(mask):
     tracemalloc.stop()
     assert seconds < 0.05, seconds
     assert peak < 4 * 2 ** 20, peak
-    assert plan.rows[:3] == plan.cols[:3] == (1024, 1024, 2)
+    assert plan.rows[:3] == (1024, 1024, 2) and plan.fused
     assert pa._plan(*args) is plan
     pa._plan.cache_clear()
 
@@ -319,7 +374,7 @@ def test_multi_head_causal_attention_compiles_for_v5e(spec, case):
         calls = _kernel_calls(jax.grad(
             lambda *a: attend(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2)), q, q, q)
-        assert calls == 3      # forward, dQ, dK/dV
+        assert calls == 2      # forward, the fused backward
 
 
 @pytest.mark.parametrize("heads,seq,dtype", [
@@ -350,9 +405,10 @@ def test_the_looped_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     """The looped cell's step as gluon.TrainStep builds it — every
     published width, 8192 positions, 4 loop steps, the whole vocabulary,
     bf16 under Adam with masters, remat; ONE of its six layers — compiled
-    for the described v5e: the passes are one rolled loop (a layer's nine
-    Mosaic calls once, not once a loop step: flash forward, dQ, dK/dV, and
-    the rotation of q and k forward, replayed and back), the four exits'
+    for the described v5e: the passes are one rolled loop (a layer's
+    eight Mosaic calls once, not once a loop step: flash forward and the
+    fused backward, and the rotation of q and k forward, replayed and back),
+    the four exits'
     logits go by blocks, and arguments and temporaries fit the chip."""
     import mxnet_tpu as mx
     from mxnet_tpu import amp, gluon
@@ -390,7 +446,7 @@ def test_the_looped_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
         step(mx.np.zeros((1, 8192), dtype="int32"))
     compiled = caught.value.args[0]
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
     assert "f64[" not in text
     # no (.., 8192, 49152) float32 logits anywhere: blocks of 256 positions
     assert "f32[4,8192,49152]" not in text and "f32[1,8192,49152]" not in text

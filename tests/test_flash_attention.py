@@ -14,6 +14,37 @@ from mxnet_tpu.ops.pallas_attention import (attention_reference,
                                             flash_attention)
 
 
+def _forget_plans():
+    """Plans and their jitted kernels are memoised by signature: a test
+    that changes what a plan is built from starts and ends without them."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    pa._plan.cache_clear()
+    pa._shared.cache_clear()
+
+
+@pytest.fixture(params=["fused", "two_kernels"])
+def backward_path(request, monkeypatch):
+    """Both memory plans of the backward at one shape (ISSUE 44): the plan
+    takes the one fused kernel where a head's keys, values and their
+    float32 gradients fit the core's fast memory, and the dQ and dK/dV
+    kernels where they do not — reached here by a core with none.  Every
+    plan the test builds must have taken the path it names."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    built, init = [], pa._Plan.__init__
+    monkeypatch.setattr(
+        pa._Plan, "__init__",
+        lambda self, *a: (init(self, *a), built.append(self))[0])
+    if request.param == "two_kernels":
+        monkeypatch.setattr(pa, "_vmem_capacity", lambda: 0)
+    _forget_plans()
+    yield request.param
+    _forget_plans()
+    assert built and {plan.fused for plan in built} == {
+        request.param == "fused"}
+
+
 def _qkv(b=2, h=3, s=256, d=64, seed=0):
     rs = onp.random.RandomState(seed)
     mk = lambda: jnp.asarray(rs.rand(b, h, s, d).astype("f") - 0.5)  # noqa: E731
@@ -47,7 +78,7 @@ class TestFlashKernel:
         ref = attention_reference(q, k, v)
         onp.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
-    def test_gradients(self):
+    def test_gradients(self, backward_path):
         q, k, v = _qkv(s=128, d=32)
 
         def loss_flash(q_, k_, v_):
@@ -77,7 +108,7 @@ class TestFlashKernel:
         ref = attention_reference(q, k, v)
         onp.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
-    def test_ragged_length_causal_grads(self):
+    def test_ragged_length_causal_grads(self, backward_path):
         # padded keys must be invisible to the backward kernels too
         q, k, v = _qkv(s=52, d=16)
 
@@ -135,9 +166,10 @@ class TestBertIntegration:
         assert not onp.allclose(o1, o2)
 
 
-def test_flash_backward_kernels_match_reference_grads():
-    """The block-streamed Pallas backward (dQ/dK/dV kernels + lse
-    residual) must match autodiff through the reference math."""
+def test_flash_backward_kernels_match_reference_grads(backward_path):
+    """The block-streamed Pallas backward (the fused kernel, or the dQ and
+    dK/dV kernels, + lse residual) must match autodiff through the
+    reference math."""
     import jax
     import jax.numpy as jnp
 
@@ -237,10 +269,10 @@ class TestFlashDropout:
         onp.testing.assert_allclose(onp.asarray(o_k), onp.asarray(o_r),
                                     rtol=1e-5, atol=2e-5)
 
-    def test_dropout_grads_match_reference_autodiff(self):
-        """The hand bwd kernels must equal jax autodiff of the identical
-        reference function (same mask): exact gradient check, all three
-        inputs."""
+    def test_dropout_grads_match_reference_autodiff(self, backward_path):
+        """The hand bwd kernels (both memory plans) must equal jax
+        autodiff of the identical reference function (same mask): exact
+        gradient check, all three inputs."""
         import jax.numpy as jnp
 
         from mxnet_tpu.ops import pallas_attention as fa
@@ -306,12 +338,20 @@ def _pallas_names(closed):
     return out
 
 
-@pytest.mark.parametrize("grad,expect", [
-    (False, ["flash_attention_fwd"]),
-    (True, ["flash_attention_fwd", "flash_attention_bwd_dq",
-            "flash_attention_bwd_dkv"]),
-], ids=["flash_fwd", "flash_bwd"])
-def test_pallas_calls_carry_stable_names(grad, expect):
+@pytest.mark.parametrize("grad,vmem,expect", [
+    (False, None, ["flash_attention_fwd"]),
+    (True, None, ["flash_attention_fwd", "flash_attention_bwd"]),
+    (True, 0, ["flash_attention_fwd", "flash_attention_bwd_dq",
+               "flash_attention_bwd_dkv"]),
+], ids=["flash_fwd", "flash_bwd", "flash_bwd_two_kernels"])
+def test_pallas_calls_carry_stable_names(grad, vmem, expect, monkeypatch):
+    """One backward kernel where the plan fuses, two on a core whose fast
+    memory does not hold a head's keys, values and gradients."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    if vmem is not None:
+        monkeypatch.setattr(pa, "_vmem_capacity", lambda: vmem)
+    _forget_plans()
     q = jnp.ones((1, 2, 128, 64), jnp.float32)
 
     def f(q, k, v):
@@ -319,6 +359,7 @@ def test_pallas_calls_carry_stable_names(grad, expect):
 
     fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
     names = _pallas_names(jax.make_jaxpr(fn)(q, q, q))
+    _forget_plans()
     assert sorted(set(names)) == sorted(expect)
 
 
@@ -438,11 +479,11 @@ def test_the_tile_is_read_off_the_shapes(s_len, dk, dv, itemsize, padded,
 @pytest.mark.parametrize("case", [
     "causal", "block_diffusion", "padded", "unmasked", "latent_widths",
     "causal_dropout", "block_diffusion_dropout"])
-def test_span_kernels_match_the_reference(case, blocks):
+def test_span_kernels_match_the_reference(case, blocks, backward_path):
     """Forward and all three gradients, interpreted, against the plain
     reference with grouped heads: at the tile the op chooses and at
     explicit ones (whose spans hold dead, mask-free and masked
-    sub-tiles)."""
+    sub-tiles), through the fused backward and through the two kernels."""
     s_len = 120 if case == "padded" else 128
     dk, dv = (192, 128) if case == "latent_widths" else (16, 16)
     mask = {}
@@ -476,16 +517,19 @@ def test_span_kernels_match_the_reference(case, blocks):
 @pytest.mark.parametrize("mask", [{"causal": True},
                                   {"block_diffusion": (4, 1024)}],
                          ids=["causal", "block_diffusion"])
-def test_the_chosen_tiles_span_a_long_sequence(mask):
+def test_the_chosen_tiles_span_a_long_sequence(mask, backward_path):
     """2048 positions: the op takes tiles of 1024 and spans of two, so a
     grid step walks dead, masked and mask-free sub-tiles of 1024 x 1024;
-    bf16 operands as the cells have them."""
+    bf16 operands as the cells have them.  The fused backward walks the
+    forward's schedule (no k-major one is built); the two kernels theirs."""
     rs = onp.random.RandomState(5)
     q, k, v = (jnp.asarray(rs.randn(1, h, 2048, 16).astype("f") * 0.5,
                            jnp.bfloat16) for h in (2, 1, 1))
     plan = pa._plan_of(q, k, v, mask.get("causal", False), 1024, 1024, None,
                        mask.get("block_diffusion"))
-    assert plan.rows[:3] == plan.cols[:3] == (1024, 1024, 2)
+    assert plan.rows[:3] == (1024, 1024, 2)
+    assert (plan.cols is None if backward_path == "fused"
+            else plan.cols[:3] == (1024, 1024, 2))
     assert set(plan.rows.classes) == ({pa._FREE, pa._MASKED} if "causal" in mask
                                  else {pa._MASKED})
 
@@ -504,10 +548,19 @@ def test_the_chosen_tiles_span_a_long_sequence(mask):
                                     rtol=5e-2, atol=5e-2)
 
 
-def test_the_gauge_reads_the_schedules_class_bits():
+@pytest.mark.parametrize("kernels", [
+    {"flash_attention_fwd", "flash_attention_bwd"},
+    {"flash_attention_fwd", "flash_attention_bwd_dq",
+     "flash_attention_bwd_dkv"}], ids=["fused", "two_kernels"])
+def test_the_gauge_reads_the_schedules_class_bits(kernels, monkeypatch):
     """`attention_maskfree_share{kernel}` is set when a plan is built: the
-    cells' two masks at their tiles, and an unmasked call reads 1."""
+    cells' two masks at their tiles, and an unmasked call reads 1.  A
+    process that has built only fused plans holds two series, the forward's
+    and the one backward kernel's, both the q-major schedule's share."""
     from mxnet_tpu.telemetry import instruments as ti
+
+    if len(kernels) == 3:
+        monkeypatch.setattr(pa, "_vmem_capacity", lambda: 0)
 
     def shares(**mask):
         pa._plan.cache_clear()
@@ -518,9 +571,135 @@ def test_the_gauge_reads_the_schedules_class_bits():
         return {k[0]: c.value for k, c in
                 ti.attention_maskfree_share.series()}
 
-    kernels = {"flash_attention_fwd", "flash_attention_bwd_dq",
-               "flash_attention_bwd_dkv"}
     assert shares(causal=True) == dict.fromkeys(kernels, 28 / 36)
     assert shares(block_diffusion=(4, 4096)) == dict.fromkeys(kernels, 0.5)
     assert shares() == dict.fromkeys(kernels, 1.0)
     pa._plan.cache_clear()
+    ti.attention_maskfree_share.clear()
+
+
+# -- one backward kernel (ISSUE 44): the memory plan, the gauge, both paths ---
+
+def _grads_by(vmem, fn, *operands):
+    """Gradients of ``fn`` with the backward planned for a core of ``vmem``
+    bytes of fast memory (None: the one the process sees)."""
+    with pytest.MonkeyPatch.context() as patch:
+        if vmem is not None:
+            patch.setattr(pa, "_vmem_capacity", lambda: vmem)
+        _forget_plans()
+        try:
+            return jax.grad(fn, (0, 1, 2))(*operands)
+        finally:
+            _forget_plans()
+
+
+@pytest.mark.parametrize("case", ["causal_mha", "grouped_block_diffusion",
+                                  "latent_widths_dropout"])
+def test_fused_and_two_kernel_backward_agree(case):
+    """The same float32 terms: dQ sums them in the same order on both
+    paths (equal), dK and dV with the query heads of a group outermost
+    (equal to float32 rounding)."""
+    heads, dk, dv, mask = {
+        "causal_mha": ((2, 2), 32, 32, {"causal": True}),
+        "grouped_block_diffusion": ((8, 1), 16, 16,
+                                    {"block_diffusion": (4, 64)}),
+        "latent_widths_dropout": ((4, 2), 192, 128, {
+            "causal": True, "dropout_p": 0.2,
+            "dropout_seed": jnp.asarray([5], jnp.int32)}),
+    }[case]
+    rs = onp.random.RandomState(7)
+    q, k, v, w = (jnp.asarray(rs.randn(1, h, 128, d).astype("f")) * 0.5
+                  for h, d in ((heads[0], dk), (heads[1], dk),
+                               (heads[1], dv), (heads[0], dv)))
+
+    def loss(q, k, v):
+        return (pa.flash_attention(q, k, v, interpret=True, block_q=32,
+                                   block_k=32, **mask) * w).sum()
+
+    fused = _grads_by(None, loss, q, k, v)
+    two = _grads_by(0, loss, q, k, v)
+    onp.testing.assert_array_equal(fused[0], two[0])
+    for a, b, name in zip(fused[1:], two[1:], "kv"):
+        onp.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                                    err_msg="d" + name)
+
+
+def test_a_key_sub_tile_no_query_visits_gets_zero_gradients(backward_path):
+    """Padding that empties whole key sub-tiles (40 of 128 positions valid,
+    sub-tiles of 32): the forward's schedule never visits them, so the
+    fused backward leaves their dK and dV at the zeros it starts from (the
+    dK/dV kernel visits each once as a masked sub-tile, which adds zeros);
+    the valid keys' gradients are the reference's."""
+    rs = onp.random.RandomState(9)
+    q, k, v = (jnp.asarray(rs.randn(1, 2, 128, 16).astype("f")) * 0.5
+               for _ in range(3))
+    valid, seed = 40, jnp.zeros((1,), jnp.int32)
+
+    def kernel(q, k, v):
+        out = pa._flash(q, k, v, seed, False, 0.25, 32, 32, True, 0.0,
+                        valid, None)
+        return (out[:, :, :valid] ** 2).sum()
+
+    def plain(q, k, v):
+        return (pa.attention_reference(q, k, v, scale=0.25) ** 2).sum()
+
+    got = jax.grad(kernel, (0, 1, 2))(q, k, v)
+    want = jax.grad(plain, (0, 1, 2))(
+        q[:, :, :valid], k[:, :, :valid], v[:, :, :valid])
+    for g, r, name in zip(got, want, "qkv"):
+        onp.testing.assert_allclose(g[:, :, :valid], r, rtol=1e-4, atol=1e-5,
+                                    err_msg="d" + name)
+        assert not onp.asarray(g[:, :, valid:]).any(), "d" + name
+
+
+@pytest.mark.parametrize("s_len,dk,dv,itemsize,fused", [
+    (8192, 128, 128, 2, True),      # the SDAR and the looped cell
+    (8192, 192, 128, 2, True),      # the kanana-2 cell
+    (16384, 192, 128, 2, True),
+    (32768, 192, 128, 2, False),    # a head's gradients alone are 42 MB
+    (32768, 128, 128, 2, False),
+    (8192, 192, 128, 4, True),      # float32 operands: tiles of 512
+    (384, 64, 64, 2, True),         # BERT at SQuAD's length
+])
+def test_the_backwards_memory_plan_is_read_off_the_shapes(s_len, dk, dv,
+                                                          itemsize, fused):
+    """Fused where the sequence-resident working set is at most the stated
+    share of the core's fast memory (the v5e's 128 MiB off a TPU); the
+    limit the kernel is given covers it and stays under the capacity."""
+    tile = pa._choose_tile(s_len, dk, dv, itemsize)
+    dtype = {2: "bfloat16", 4: "float32"}[itemsize]
+    pa._plan.cache_clear()
+    plan = pa._plan((1, 2, s_len, dk), (1, 2, s_len, dk), (1, 2, s_len, dv),
+                    dtype, True, tile, tile, None, None)
+    pa._plan.cache_clear()
+    need = pa._working_set(tile, tile, 0, dk, dv, itemsize, resident=s_len)
+    assert plan.fused == fused == (
+        need <= pa._FUSED_VMEM_SHARE * pa._vmem_capacity())
+    if fused:
+        assert plan.cols is None
+        assert need < plan.vmem_limit < pa._vmem_capacity() == 128 * 2 ** 20
+    else:
+        assert plan.vmem_limit is None and plan.cols[:2] == (tile, tile)
+
+
+def test_the_fused_share_counts_plans(monkeypatch):
+    """`attention_fused_backward_share`: 1 after a fused plan, falls with a
+    two-kernel plan (a 32k-position signature) and rises again."""
+    from mxnet_tpu.telemetry import instruments as ti
+
+    monkeypatch.setattr(ti, "_attention_plans", [0, 0])
+    pa._plan.cache_clear()
+
+    def plan(s_len):
+        shape = (1, 1, s_len, 192)
+        pa._plan(shape, shape, shape[:3] + (128,), "bfloat16", True, 1024,
+                 1024, None, None)
+        return ti.attention_fused_backward_share.value
+
+    assert plan(8192) == 1.0
+    assert plan(32768) == 0.5
+    assert plan(4096) == pytest.approx(2 / 3)
+    assert plan(4096) == pytest.approx(2 / 3)      # cached: planned once
+    pa._plan.cache_clear()
+    ti.attention_fused_backward_share.clear()
+    ti.attention_maskfree_share.clear()
