@@ -2,7 +2,8 @@
 """The control of ``correct``: the plain reference, put in the program's
 place and computed in the nearest precision BELOW the one the
 configuration states (``control_precision``), compared with the float32
-reference by the very numbers a run compares.  It has to come out as not
+reference by the very numbers a run compares, each against the
+configuration's own limit (`verdict`).  It has to come out as not
 correct.  Not part of a benchmark run: it is read on the chip when a
 limit is set (PERF.md gives the readings) and kept at a toy size in
 tests/.
@@ -50,6 +51,21 @@ def control_numbers(workload, cfg, seed, precision=None):
     return out
 
 
+def verdict(cfg, nums):
+    """``correct`` as a run would read it: the control's numbers through
+    `compare.Checks`, each beside the configuration's limit.  (correct,
+    the names over their limits)."""
+    from compare import Checks
+
+    limits, checks = cfg["limits"]["train_step"], Checks()
+    for name, value in nums.items():
+        if name.startswith("_") or name.endswith(".leaf"):
+            continue
+        base, _, k = name.partition(".step")
+        checks.add(name, value, limits[base][int(k) - 1] if k else limits[name])
+    return checks.ok, [r["name"] for r in checks.rows if not r["ok"]]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -74,9 +90,12 @@ def main():
                     args.dump, f"control.{args.workload}.{seed}.json"),
                     "w") as f:
                 json.dump(leaves, f)
+        correct, over = verdict(cfg, nums)
         print(json.dumps({"control": args.workload, "seed": seed,
                           "precision": args.precision
-                          or cfg["control_precision"], **nums}), flush=True)
+                          or cfg["control_precision"], **nums,
+                          "correct": correct, "over_limit": over}),
+              flush=True)
 
 
 if __name__ == "__main__":

@@ -21,9 +21,10 @@ def control_numbers(workload, cfg, seed, precision=None):
     ref = importlib.import_module("reference." + cfg["builder"])
     precision = precision or cfg["control_precision"]
     specs = ref.param_specs(cfg)
+    w_seed = wmod.weights_seed(cfg, seed)
 
     def make():
-        return wmod.make_weights(specs, seed, cfg["dtype"])
+        return wmod.make_weights(specs, w_seed, cfg["dtype"])
 
     batches = importlib.import_module(
         "drivers." + workload["driver"]).reference_batches(

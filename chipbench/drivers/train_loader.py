@@ -107,8 +107,10 @@ def check_feed(h, pool, host_batches, labels_drawn, steps):
 
 def run(h):
     cfg, tp, ref = h.cfg, h.traffic, h.reference
+    w_seed = wmod.weights_seed(cfg, h.seed)
+    h.note(weights_seed=w_seed)
     with h.span("make_weights"):
-        weights = wmod.make_weights(ref.param_specs(cfg), h.seed,
+        weights = wmod.make_weights(ref.param_specs(cfg), w_seed,
                                     cfg["dtype"])
     with h.span("make_pool"):
         pool = make_pool(cfg, tp, h.seed)

@@ -52,8 +52,16 @@ def program_state(net, trainer, opt, jax, NDArray):
 
 
 def reference_batches(cfg, traffic, seed, n, ref):
-    """The ``n`` batches the first steps of this driver's cell see."""
-    return wmod.make_batches(ref.input_specs(cfg, traffic["batch"]), seed, n)
+    """The first ``n`` batches a run of this driver's cell sees: drawn from
+    ``--seed``; or, where the traffic states its pool (``pool_seed``), that
+    pool's batches in the order ``--seed`` draws, the compared steps' own
+    batches first."""
+    specs = ref.input_specs(cfg, traffic["batch"])
+    if "pool_seed" not in traffic:
+        return wmod.make_batches(specs, seed, n)
+    pool = wmod.make_batches(specs, traffic["pool_seed"], traffic["pool"])
+    order = wmod.pool_order(seed, traffic["pool"], REF_STEPS)
+    return [pool[k] for k in order[:n]]
 
 
 class Program:
@@ -232,8 +240,13 @@ def run(h):
     if pool < REF_STEPS:
         raise ValueError(f"pool {pool} < {REF_STEPS}: the first steps need "
                          "batches that all differ")
+    w_seed = wmod.weights_seed(cfg, h.seed)
+    h.note(weights_seed=w_seed)
+    if "pool_seed" in traffic:
+        h.note(pool_seed=traffic["pool_seed"],
+               pool_order=wmod.pool_order(h.seed, pool, REF_STEPS))
     with h.span("make_weights"):
-        weights = wmod.make_weights(ref.param_specs(cfg), h.seed,
+        weights = wmod.make_weights(ref.param_specs(cfg), w_seed,
                                     cfg["dtype"])
         batches = reference_batches(cfg, traffic, h.seed, pool, ref)
     with h.span("build"):

@@ -10,8 +10,9 @@ holds weights and optimizer state twice.  Here the seeded weights are
 dropped once the net holds them (the first steps compare against a copy
 in the configuration's own type, which holds a rounded leaf exactly),
 that copy is dropped before the window, and after the program is freed
-the reference makes the weights again from ``--seed``
-(`reference/train_ref_large.py`).
+the reference makes the weights again from the same seed
+(`weights.weights_seed`: the configuration's one stated draw, else
+``--seed``; `reference/train_ref_large.py`).
 
 A traced run also notes, for the per-layer readers: the rows that the
 plain reference's own routing sends to the held experts on the pool's
@@ -83,9 +84,14 @@ def run(h):
         raise ValueError(f"pool {pool} < {REF_STEPS}: the first steps need "
                          "batches that all differ")
     specs = ref.param_specs(cfg)
+    w_seed = wmod.weights_seed(cfg, h.seed)
+    h.note(weights_seed=w_seed)
+    if "pool_seed" in traffic:
+        h.note(pool_seed=traffic["pool_seed"],
+               pool_order=wmod.pool_order(h.seed, pool, REF_STEPS))
 
     def make_weights():
-        return wmod.make_weights(specs, h.seed, cfg["dtype"])
+        return wmod.make_weights(specs, w_seed, cfg["dtype"])
 
     with h.span("make_weights"):
         weights = make_weights()

@@ -187,10 +187,11 @@ def test_the_cells_files_say_the_cut():
             cfg["num_experts_per_tok"]) == (2048, 128, 32, 4, 768, 8)
     assert cfg["num_hidden_layers"] >= 4 and "8 chips" in cfg["deployment"]
     assert (wl["driver"], wl["chips"], wl["traffic_params"]) == (
-        "train_step_large", 1, {"batch": 2, "pool": 4})
+        "train_step_large", 1,
+        {"batch": 2, "pool": 5, "pool_seed": 4600000100})
     assert (cfg["seq"], cfg["block_length"]) == (4096, 4)
     for words in ("batch 2 sequences", "L = 4096 clean tokens each",
-                  "block length 4", "pool of 4 seeded resident batches",
+                  "block length 4", "pool of 5 resident batches",
                   "loss fetched every 10th step", "~1,024 rows a step",
                   "eight times its share"):
         assert words in wl["why"], words

@@ -1,5 +1,10 @@
 """Seeded weights and inputs, made on the device in one jitted call.
 
+What ``--seed`` draws: the traffic always (the pool's batches; where the
+traffic states its pool, ``pool_seed``, the order in which the run meets
+them, the same batches first: `pool_order`); the weights too, unless the configuration states ONE
+draw of them (`weights_seed`), as a checkpoint is one set of routers.
+
 The benchmark owns the weights: the program under test and the plain
 reference are both handed the arrays made here, so the reference takes
 nothing the program has produced.  A leaf the configuration serves in a
@@ -10,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 
 def root_key(seed):
@@ -50,6 +56,13 @@ def _make(key, specs, low_dtype):
     return out
 
 
+def weights_seed(cfg, seed):
+    """The seed of a run's weights: ``--seed``, unless the configuration
+    states ONE draw of them, ``weights_seed``, as a checkpoint is one set
+    of routers."""
+    return int(cfg.get("weights_seed", seed))
+
+
 def make_weights(specs, seed, low_dtype):
     """``specs``: a tuple of (name, shape, kind, arg, low) as a
     reference's ``param_specs`` gives them.  Returns {name: float32}."""
@@ -79,3 +92,18 @@ def make_batches(input_specs, seed, pool):
     key = jax.random.fold_in(root_key(seed), 2)
     return [_make_inputs(jax.random.fold_in(key, b), tuple(input_specs))
             for b in range(pool)]
+
+
+def pool_order(seed, pool, head):
+    """The order in which a run meets a STATED pool's batches
+    (``traffic_params.pool_seed``), drawn from ``--seed`` on the host: the
+    pool's first ``head`` batches in a drawn order, then the others in a
+    drawn order.  Every seed runs the same set of work in another order,
+    and the same batches take the optimizer's first steps (``head`` is how
+    many a run compares): which batches those are sets which way the
+    routers drift, and with it how much work the later steps are."""
+    seed = int(seed)
+    rng = onp.random.default_rng([seed & 0x7FFFFFFF,
+                                  (seed >> 31) & 0x7FFFFFFF])
+    return ([int(i) for i in rng.permutation(head)]
+            + [head + int(i) for i in rng.permutation(pool - head)])
