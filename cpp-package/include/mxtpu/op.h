@@ -8132,6 +8132,17 @@ inline std::vector<PackedTensor> gammaln(
   return rt.invoke("gammaln", ins_, a_.str());
 }
 
+inline std::vector<PackedTensor> gated_short_conv(
+    PyRuntime& rt,
+    const PackedTensor& bcx,
+    const PackedTensor& w) {
+  std::vector<PackedTensor> ins_;
+  ins_.push_back(bcx);
+  ins_.push_back(w);
+  detail::JsonBuilder a_;
+  return rt.invoke("gated_short_conv", ins_, a_.str());
+}
+
 inline std::vector<PackedTensor> gather_nd(
     PyRuntime& rt,
     const PackedTensor& data,

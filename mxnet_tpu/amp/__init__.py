@@ -170,10 +170,11 @@ def _keeps_fp32(p):
     # norms' scale/shift and running stats stay fp32 (cast-list analog),
     # and so does an expert layer's router: its top-k is decided on
     # float32 probabilities; and a looped model's exit gate, whose
-    # sigmoid weighs every exit's loss
+    # sigmoid weighs every exit's loss; and a short convolution's taps,
+    # a few numbers a channel that multiply in float32 inside the mix
     name = p.name.lower()
     return any(k in name for k in ("gamma", "beta", "running", "moving",
-                                   "router", "exit_gate"))
+                                   "router", "exit_gate", "conv_taps"))
 
 
 def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,

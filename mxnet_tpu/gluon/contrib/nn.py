@@ -11,19 +11,25 @@ here (`ep_rank` of `ep_size`); `parallel.moe.dropless_moe` is its
 functional part (docs/moe.md).  `GatedMLP` is one such expert as a plain
 block: a dense feed-forward layer, and the shared expert `DroplessMoE`
 adds beside the routed ones.
+
+`GatedShortConv` — a token mixer that is not attention: one projection to
+three streams, `npx.gated_short_conv` (a depthwise causal convolution
+over the last few positions between two elementwise gates) and an output
+projection (docs/hybrid.md).
 """
 from __future__ import annotations
 
 import jax
 
 from ... import autograd as ag
+from ... import numpy_extension as npx
 from ...ndarray.ndarray import apply_op
 from ...telemetry import instruments as _telemetry
 from ..block import HybridBlock, current_state_sink
 from ..nn import Dense
 from ..parameter import Parameter
 
-__all__ = ["MoEDense", "GatedMLP", "DroplessMoE"]
+__all__ = ["MoEDense", "GatedMLP", "GatedShortConv", "DroplessMoE"]
 
 
 class MoEDense(HybridBlock):
@@ -92,6 +98,33 @@ class GatedMLP(HybridBlock):
         return self.down_proj(mid)
 
 
+class GatedShortConv(HybridBlock):
+    """out_proj(C * conv(B * x~)) with [B ; C ; x~] = in_proj(x), no
+    biases: (B, S, units) -> (B, S, units).  ``conv`` is depthwise and
+    causal over the last ``kernel`` positions (`npx.gated_short_conv`, one
+    op under the scope ``short_conv.mix``); its taps ``conv_taps`` (units,
+    kernel) — tap ``kernel - 1`` on the position itself — stay float32
+    under `amp.convert_hybrid_block`, like a norm's scale.  The whole
+    block runs under the scope ``short_conv``."""
+
+    def __init__(self, units, kernel=3, dtype="float32"):
+        super().__init__()
+
+        def proj(out_units, in_units):
+            return Dense(out_units, use_bias=False, flatten=False,
+                         dtype=dtype, in_units=in_units)
+
+        self.in_proj = proj(3 * units, units)
+        self.conv_taps = Parameter("conv_taps", shape=(units, kernel))
+        self.out_proj = proj(units, units)
+
+    def forward(self, x):
+        with jax.named_scope("short_conv"):
+            mixed = npx.gated_short_conv(self.in_proj(x),
+                                         self.conv_taps.data_for(x))
+            return self.out_proj(mixed)
+
+
 class DroplessMoE(HybridBlock):
     """A chip's share of a dropless mixture of gated experts.
 
@@ -112,7 +145,9 @@ class DroplessMoE(HybridBlock):
     logit; ``selection_bias=True`` adds the parameter ``router_bias``
     (``num_experts``,) — float32, kept so by amp, no gradient — to the
     scores for the choice of the ``top_k`` alone, never to a gate;
-    ``routed_scaling_factor`` multiplies every gate.  ``shared_units``
+    ``routed_scaling_factor`` multiplies every gate; ``normalize_eps`` is
+    what is added to the chosen gates' sum before they are divided by it
+    (None: `route_top_k`'s own rule).  ``shared_units``
     adds a shared expert ``shared``, a `GatedMLP` of that width that every
     token passes: it is computed whole on every share, under the scope
     ``moe.shared``, and summed outside the share's partial result, so it
@@ -132,7 +167,8 @@ class DroplessMoE(HybridBlock):
                  ep_size=1, ep_rank=0, normalize_top_k=True,
                  scoring_func="softmax", selection_bias=False,
                  routed_scaling_factor=1.0, shared_units=None,
-                 dtype="float32", weight_initializer=None):
+                 normalize_eps=None, dtype="float32",
+                 weight_initializer=None):
         super().__init__()
         if num_experts % ep_size or not 0 <= ep_rank < ep_size:
             raise ValueError(
@@ -147,6 +183,7 @@ class DroplessMoE(HybridBlock):
         self._normalize = bool(normalize_top_k)
         self._scoring = scoring_func
         self._scale = float(routed_scaling_factor)
+        self._normalize_eps = normalize_eps
         # the router, and its bias, stay float32 under
         # amp.convert_hybrid_block
         self.router = Parameter("router", shape=(num_experts, in_units),
@@ -185,7 +222,8 @@ class DroplessMoE(HybridBlock):
                 xv.reshape(-1, xv.shape[-1]), r, g, u, d,
                 top_k=self._top_k, first_expert=self._first,
                 normalize=self._normalize, scoring=self._scoring,
-                bias=bias[0] if bias else None, scale=self._scale)
+                bias=bias[0] if bias else None, scale=self._scale,
+                normalize_eps=self._normalize_eps)
             return out.reshape(xv.shape), load
 
         bias = () if self.router_bias is None \

@@ -432,7 +432,10 @@ def _working_set(tile, sub, span, dk, dv, itemsize, resident=0):
     double-buffered, and the float32 accumulators of both gradients;
     the dQ block beside q and dO; and the lane-padded columns of the
     resident rows (lse, delta, the query codes), which at a tile of 1024
-    rows are 3 MiB that the 12 MiB plans leave to the compiler's slack."""
+    rows are 3 MiB that the 12 MiB plans leave to the compiler's slack.
+    A width under a lane (64-wide heads) fills the lane in fast memory
+    and is planned as one."""
+    dk, dv = max(dk, _LANES), max(dv, _LANES)
     total = (3 * 4 * max(_chunk_of(tile), _chunk_of(sub)) * max(tile, sub)
              + 2 * itemsize * (tile + span * sub) * (dk + dv)
              + 4 * tile * (dk + dv))

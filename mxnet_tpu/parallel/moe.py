@@ -145,12 +145,14 @@ def _scores(logits, scoring):
 
 
 def route_top_k(logits, top_k, normalize=True, *, scoring="softmax",
-                bias=None, scale=1.0):
+                bias=None, scale=1.0, normalize_eps=None):
     """(gates (N, k) float32, experts (N, k) int32) of router ``logits``
     (N, E): scores over all E in float32 (``scoring``: ``"softmax"``, or
     a ``"sigmoid"`` of each logit), the k largest, and — with
-    ``normalize`` — the gates divided by their sum over those k, then
-    times ``scale``.  A ``bias`` (E,) selects and never weighs: the k
+    ``normalize`` — the gates divided by their sum over those k plus
+    ``normalize_eps`` (None: 1e-20 for a sigmoid, whose scores may all
+    vanish, nothing for a softmax, whose k largest cannot), then times
+    ``scale``.  A ``bias`` (E,) selects and never weighs: the k
     largest are taken of score + bias, the gates are the scores of the
     chosen without it, and no gradient reaches the bias."""
     scores = _scores(logits, scoring)
@@ -162,8 +164,9 @@ def route_top_k(logits, top_k, normalize=True, *, scoring="softmax",
         gates = jnp.take_along_axis(scores, experts, axis=-1)
     if normalize:
         total = jnp.sum(gates, axis=-1, keepdims=True)
-        # a sigmoid's scores may all vanish; a softmax's k largest cannot
-        gates = gates / (total + 1e-20 if scoring == "sigmoid" else total)
+        if normalize_eps is None:
+            normalize_eps = 1e-20 if scoring == "sigmoid" else 0.0
+        gates = gates / (total + normalize_eps if normalize_eps else total)
     if scale != 1.0:
         gates = gates * scale
     return gates, experts.astype(jnp.int32)
@@ -314,7 +317,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
-                 normalize=True, scoring="softmax", bias=None, scale=1.0):
+                 normalize=True, scoring="softmax", bias=None, scale=1.0,
+                 normalize_eps=None):
     """One chip's share of a dropless mixture of gated experts.
 
     x: (N, D) tokens; router: (E, D), all E experts of the layer;
@@ -330,7 +334,8 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
     sigmoid(router x)_e; a ``bias`` (E,) float32 makes S the ``top_k``
     largest of p + bias while g stays p_e / sum_S p (`route_top_k`: the
     bias selects, never weighs, and has no gradient); ``scale``
-    multiplies every g.
+    multiplies every g; ``normalize_eps`` is added to sum_S p
+    (`route_top_k`'s rule when None).
 
     The sum over the held experts is this chip's part of the layer's
     result; what an expert held elsewhere adds is left out (the exchange
@@ -354,7 +359,8 @@ def dropless_moe(x, router, w_gate, w_up, w_down, *, top_k, first_expert=0,
                             router.astype(jnp.float32).T,
                             precision=jax.lax.Precision.HIGHEST)
         gates, experts = route_top_k(logits, top_k, normalize,
-                                     scoring=scoring, bias=bias, scale=scale)
+                                     scoring=scoring, bias=bias, scale=scale,
+                                     normalize_eps=normalize_eps)
     with jax.named_scope("moe.dispatch"):
         local = experts - first_expert
         key = jnp.where((local >= 0) & (local < held), local,

@@ -30,6 +30,8 @@ __all__ = [
     "qk_prep_kernel_share", "record_qk_prep_site",
     "looped_stack_copies", "ut_steps", "set_looped_stack", "exit_mass",
     "stage_exit_mass", "flush_exit_mass",
+    "short_conv_sites", "decoder_layers", "record_short_conv_site",
+    "short_conv_sites_traced", "set_decoder_stack",
     "xla_compile_seconds_total", "xla_programs_total",
     "install_compile_listener",
     "transfer_total", "transfer_bytes_total",
@@ -209,6 +211,19 @@ exit_mass = gauge(
     "mean over the last step's positions of the probability that the exit "
     "gate gives each loop step (1-based); the steps' values sum to 1. "
     "Produced on the device; set by flush_exit_mass()", ["step"])
+short_conv_sites = gauge(
+    "short_conv_sites",
+    "Call sites of ops.short_conv.gated_short_conv (the gated short "
+    "convolution's mix, a token mixer that is not attention) in the decoder "
+    "stack traced last: one a layer whose operator is the convolution. Set "
+    "on the host while the step is traced, with decoder_layers")
+decoder_layers = gauge(
+    "decoder_layers",
+    "Layers of each kind in the decoder stack traced last, of a model "
+    "whose layers choose their token mixer and their feed-forward one by "
+    "one (gluon.model_zoo.lfm2_moe): operator is conv or attention, "
+    "feed_forward is dense or moe. Set on the host while the step is "
+    "traced", ["operator", "feed_forward"])
 compile_flops = gauge(
     "compile_flops",
     "XLA cost_analysis flops of the latest executable per block variant "
@@ -919,6 +934,31 @@ def set_looped_stack(steps):
         return
     looped_stack_copies.set(1)
     ut_steps.set(steps)
+
+
+_short_conv_sites = [0]     # call sites of the mix traced so far
+
+
+def record_short_conv_site():
+    _short_conv_sites[0] += 1
+
+
+def short_conv_sites_traced():
+    """Call sites of `gated_short_conv` traced so far in the process: a
+    model reads it before and after it traces its stack."""
+    return _short_conv_sites[0]
+
+
+def set_decoder_stack(kinds, sites_before):
+    """A stack of layers of several kinds was traced: ``kinds`` is
+    {(operator, feed_forward): layers}, ``sites_before`` what
+    `short_conv_sites_traced` read before the stack."""
+    if not REGISTRY.enabled:
+        return
+    decoder_layers.clear()
+    for (operator, feed_forward), n in kinds.items():
+        decoder_layers.labels(operator, feed_forward).set(n)
+    short_conv_sites.set(_short_conv_sites[0] - sites_before)
 
 
 # the device array the last step's exit objective produced, (steps,); it

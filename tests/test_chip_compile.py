@@ -65,6 +65,34 @@ def _kernel_calls(fn, *specs):
     return text.count('custom_call_target="tpu_custom_call"')
 
 
+class _Lowered(Exception):
+    pass
+
+
+def _compiled_step(step, one_chip, *batch):
+    """The whole step of a `gluon.TrainStep` exactly as it builds it,
+    compiled for the described chip instead of run: the step's own jitted
+    function is caught at its first call, lowered on the shapes of the
+    operands it was handed and compiled."""
+    jitted = step._jitted
+
+    def intercept(donate):
+        fn = jitted(donate)
+
+        def lower_only(*a):
+            described = jax.tree_util.tree_map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                               sharding=one_chip)
+                if hasattr(v, "shape") else v, a)
+            raise _Lowered(fn.lower(*described).compile())
+        return lower_only
+
+    step._jitted = intercept
+    with pytest.raises(_Lowered) as caught:
+        step(*batch)
+    return caught.value.args[0]
+
+
 # -- flash attention: BERT-base heads at the SQuAD and the 512 lengths ------
 
 @pytest.mark.parametrize("seq", [384, 512])
@@ -428,23 +456,8 @@ def test_the_looped_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
         net.collect_params(), "adam",
         {"learning_rate": 1e-5, "multi_precision": True}, kvstore="tpu_dist")
     step = gluon.TrainStep(net, None, trainer, n_data=1)
-    jitted = step._jitted
-
-    def intercept(donate):
-        fn = jitted(donate)
-
-        def lower_only(*a):
-            described = jax.tree_util.tree_map(
-                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
-                                               sharding=one_chip)
-                if hasattr(v, "shape") else v, a)
-            raise _Lowered(fn.lower(*described).compile())
-        return lower_only
-
-    step._jitted = intercept
-    with pytest.raises(_Lowered) as caught:
-        step(mx.np.zeros((1, 8192), dtype="int32"))
-    compiled = caught.value.args[0]
+    compiled = _compiled_step(step, one_chip,
+                              mx.np.zeros((1, 8192), dtype="int32"))
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 8
     assert "f64[" not in text
@@ -455,10 +468,165 @@ def test_the_looped_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12 * 2 ** 30
 
 
-# -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
+# -- a conv-attention hybrid (ISSUE 47): 64-wide heads, 32 query over 8
+#    key-value heads; the short convolution's mix; the whole step ------------
 
-class _Lowered(Exception):
-    pass
+@pytest.mark.parametrize("case", ["fwd", "grad"])
+def test_grouped_causal_attention_at_64_wide_heads_compiles_for_v5e(
+        spec, case):
+    """The flash kernels' fourth signature among the cells: 32 query heads
+    over 8 key-value heads, keys and values 64 wide — half a lane, planned
+    as a whole one — 8192 positions, causal.  The plan has no reason to
+    leave the kernels (no fallback recorded) and the backward is the one
+    fused kernel, inside its fast-memory limit."""
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.telemetry import instruments as ti
+
+    q = spec((2, 32, 8192, 64), jnp.bfloat16)
+    kv = spec((2, 8, 8192, 64), jnp.bfloat16)
+    before = {k: c.value for k, c in
+              ti.attention_kernel_fallback_total.series()}
+
+    def attend(q, k, v):
+        return pa.flash_attention(q, k, v, interpret=False, causal=True)
+
+    if case == "fwd":
+        assert _kernel_calls(attend, q, kv, kv) == 1
+    else:
+        calls = _kernel_calls(jax.grad(
+            lambda *a: attend(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), q, kv, kv)
+        assert calls == 2      # forward, the fused backward
+    assert before == {k: c.value for k, c in
+                      ti.attention_kernel_fallback_total.series()}
+    plan = pa._plan((2, 32, 8192, 64), (2, 8, 8192, 64), (2, 8, 8192, 64),
+                    "bfloat16", True, *(2 * [pa._choose_tile(
+                        8192, 64, 64, 2)]), None, None)
+    assert plan.fused and plan.group == 4
+    # what a 64-wide head is planned as is what a 128-wide one is
+    assert pa._working_set(1024, 1024, 0, 64, 64, 2, resident=8192) == \
+        pa._working_set(1024, 1024, 0, 128, 128, 2, resident=8192)
+
+
+def test_preparation_at_64_wide_heads_takes_the_composition(spec,
+                                                            monkeypatch):
+    """`rms_norm_rotary` at D = 64 (two heads a lane block) compiles as
+    XLA ops alone and counts its site as one the kernels did not take."""
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+    from mxnet_tpu.telemetry import instruments as ti
+
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(ti, "_qk_prep_sites", [0, 0])
+    x, g = spec((2, 8192, 32 * 64), jnp.bfloat16), spec((64,), jnp.float32)
+    pos = spec((8192,), jnp.int32)
+
+    def loss(x, gamma, positions):
+        out = qp.rms_norm_rotary(x, gamma, positions, 1e6, 32, 1e-5)
+        return out.astype(jnp.float32).sum()
+
+    assert _kernel_calls(jax.value_and_grad(loss, (0, 1)), x, g, pos) == 0
+    assert ti._qk_prep_sites == [0, 1]
+    assert ti.qk_prep_kernel_share.value == 0.0
+
+
+def _entry_work(text):
+    """The entry computation's instructions that do work."""
+    entry = text[text.index("ENTRY"):]
+    idle = ("parameter(", "bitcast(", "get-tuple-element(", " tuple(",
+            "constant(")
+    return [line for line in entry.splitlines()
+            if " = " in line and not any(k in line for k in idle)]
+
+
+def test_the_short_convolutions_mix_compiles_to_one_pass_for_v5e(spec):
+    """`gated_short_conv` at the hybrid cell's shapes: the forward is ONE
+    fusion over the tensor (and one over the taps' three columns) with no
+    temporary, the backward a few, and neither holds a convolution."""
+    from mxnet_tpu.ops.short_conv import gated_short_conv
+
+    bcx = spec((2, 8192, 3 * 2048), jnp.bfloat16)
+    w = spec((2048, 3), jnp.float32)
+    fwd = jax.jit(gated_short_conv).lower(bcx, w).compile()
+    work = _entry_work(fwd.as_text())
+    assert len([l for l in work if "8192" in l]) == 1, work
+    assert all(" fusion(" in l for l in work), work
+    assert fwd.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+    def loss(bcx, w):
+        return gated_short_conv(bcx, w).astype(jnp.float32).sum()
+
+    bwd = jax.jit(jax.grad(loss, (0, 1))).lower(bcx, w).compile()
+    text = bwd.as_text()
+    assert len([l for l in _entry_work(text) if "8192" in l]) <= 4
+    # what is stored between its passes is bf16, a few (B, S, D) tensors:
+    # nothing float32 of the tensor's size (128 MiB each)
+    assert bwd.memory_analysis().temp_size_in_bytes < 4 * 64 * 2 ** 20
+    assert not [l for l in _entry_work(text) if "= f32[2,8192," in l]
+    for t in (fwd.as_text(), text):
+        assert " convolution(" not in t and "tpu_custom_call" not in t
+
+
+def test_the_hybrid_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The hybrid cell's step as gluon.TrainStep builds it — every
+    published width, 2 x 8192 positions, the 8,192 rows of a tied
+    embedding, bf16 under Adam with masters, remat; THREE of its seven
+    layers, one of each kind it has (convolution + dense, attention +
+    experts, convolution + experts) — compiled for the described v5e.  The
+    mix brings no convolution of its own (a TPU prints every matrix
+    product as one, with no feature groups), the one
+    attention layer is two Mosaic calls (flash forward, the fused
+    backward) with no fallback, its preparation is XLA ops, and arguments
+    and temporaries fit the chip.  (`chipbench/compile_check_large.py`
+    compiles all seven: 8.445 GiB of arguments, 3.205 GiB of
+    temporaries.)"""
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, gluon
+    from mxnet_tpu.gluon.model_zoo.lfm2_moe import lfm2_moe
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+    from mxnet_tpu.telemetry import instruments as ti
+
+    kernel = pa.flash_attention
+    monkeypatch.setattr(pa, "flash_attention", lambda *a, **kw: kernel(
+        *a, **{**kw, "interpret": False}))
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(ti, "_qk_prep_sites", [0, 0])
+    fallbacks = {k: c.value for k, c in
+                 ti.attention_kernel_fallback_total.series()}
+    net = lfm2_moe(8192, 2048, ["conv", "full_attention", "conv"], 32, 8,
+                   11776, 1536, 64, 4, num_dense_layers=1, ep_size=8,
+                   remat=True)
+    net.initialize(init=mx.initializer.Zero())
+    amp.convert_hybrid_block(net, target_dtype="bfloat16")
+    net.hybridize()
+    trainer = gluon.Trainer(
+        net.collect_params(), "adam",
+        {"learning_rate": 1e-5, "multi_precision": True}, kvstore="tpu_dist")
+    step = gluon.TrainStep(net, None, trainer, n_data=1)
+    compiled = _compiled_step(step, one_chip,
+                              mx.np.zeros((2, 8192), dtype="int32"))
+    text = compiled.as_text()
+    assert "f64[" not in text and "feature_group_count" not in text
+    convolutions = [l for l in text.splitlines() if " convolution(" in l]
+    assert convolutions and not [l for l in convolutions
+                                 if "short_conv.mix" in l]
+    assert all("dot_general" in l for l in convolutions)
+    flash = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and "flash_attention" in l and " custom-call(" in l]
+    assert len(flash) == 2
+    assert not [l for l in text.splitlines() if "tpu_custom_call" in l
+                and "rms_norm_rotary" in l]
+    assert fallbacks == {k: c.value for k, c in
+                         ti.attention_kernel_fallback_total.series()}
+    assert ti._qk_prep_sites == [0, 2]
+    assert ti.short_conv_sites.value == 2
+    assert {k: g.value for k, g in ti.decoder_layers.series()} == {
+        ("conv", "dense"): 1, ("attention", "moe"): 1, ("conv", "moe"): 1}
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10 * 2 ** 30
+
+
+# -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 
 @pytest.mark.parametrize("optimizer,params", [
@@ -492,22 +660,6 @@ def test_whole_step_compiles_for_v5e_without_kernels_or_f64(
                             kvstore="tpu_dist")
     step = gluon.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                            trainer)
-    jitted = step._jitted
-
-    def intercept(donate):
-        fn = jitted(donate)
-
-        def lower_only(*a):
-            described = jax.tree_util.tree_map(
-                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
-                                               sharding=one_chip)
-                if hasattr(v, "shape") else v, a)
-            raise _Lowered(fn.lower(*described).compile().as_text())
-        return lower_only
-
-    step._jitted = intercept
-    with pytest.raises(_Lowered) as caught:
-        step(x, y)
-    text = caught.value.args[0]
+    text = _compiled_step(step, one_chip, x, y).as_text()
     assert "convolution" in text and "f64[" not in text
     assert "tpu_custom_call" not in text
