@@ -191,7 +191,8 @@ qk_prep_kernel_share = gauge(
     "qk_prep_kernel_share",
     "Of the call sites of ops.pallas_qk_prep.rms_norm_rotary traced so far, "
     "the share that took the fused kernels (a TPU, a head width that is a "
-    "multiple of 128, a length that is a multiple of 8); a site on the "
+    "multiple of 128 or 64-wide heads two to a lane block, a length that "
+    "is a multiple of 8); a site on the "
     "composition rms_norm -> rotary_embedding -> transpose counts as 0. "
     "Set on the host each time the op is traced")
 looped_stack_copies = gauge(

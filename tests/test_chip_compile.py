@@ -508,25 +508,68 @@ def test_grouped_causal_attention_at_64_wide_heads_compiles_for_v5e(
         pa._working_set(1024, 1024, 0, 128, 128, 2, resident=8192)
 
 
-def test_preparation_at_64_wide_heads_takes_the_composition(spec,
-                                                            monkeypatch):
-    """`rms_norm_rotary` at D = 64 (two heads a lane block) compiles as
-    XLA ops alone and counts its site as one the kernels did not take."""
+@pytest.mark.parametrize("heads", [32, 8], ids=["q", "k"])
+def test_preparation_at_64_wide_heads_takes_the_kernels(spec, monkeypatch,
+                                                        heads):
+    """`rms_norm_rotary` at D = 64, the hybrid cell's query and key shapes:
+    a 128-lane block read as two heads.  One Mosaic call forward and one
+    back a site, no 64-bit value in the program, and nothing float32 of
+    the tensor's size among the backward's temporaries: what it holds is
+    the head-major result (lane-padded, twice its data) and the tables."""
+    import re
+
     from mxnet_tpu.ops import pallas_qk_prep as qp
     from mxnet_tpu.telemetry import instruments as ti
 
     monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
     monkeypatch.setattr(ti, "_qk_prep_sites", [0, 0])
-    x, g = spec((2, 8192, 32 * 64), jnp.bfloat16), spec((64,), jnp.float32)
-    pos = spec((8192,), jnp.int32)
+    x = spec((2, 8192, heads * 64), jnp.bfloat16)
+    g, pos = spec((64,), jnp.float32), spec((8192,), jnp.int32)
 
     def loss(x, gamma, positions):
-        out = qp.rms_norm_rotary(x, gamma, positions, 1e6, 32, 1e-5)
+        out = qp.rms_norm_rotary(x, gamma, positions, 1e6, heads, 1e-5)
         return out.astype(jnp.float32).sum()
 
-    assert _kernel_calls(jax.value_and_grad(loss, (0, 1)), x, g, pos) == 0
-    assert ti._qk_prep_sites == [0, 1]
-    assert ti.qk_prep_kernel_share.value == 0.0
+    assert _kernel_calls(loss, x, g, pos) == 1
+    assert ti._qk_prep_sites == [1, 1]
+    assert ti.qk_prep_kernel_share.value == 1.0
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        x, g, pos).compile()
+    text = compiled.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'(rms_norm_rotary_\w+?)/', text)
+    assert sorted(names) == ["rms_norm_rotary_bwd", "rms_norm_rotary_fwd"]
+    assert "f64[" not in text and "s64[" not in text
+    padded = 2 * heads * 8192 * 128 * 2         # the result, a lane a head
+    tables = 2 * 8192 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < padded + tables + 2 ** 20
+
+
+def test_layers_share_one_copy_of_each_packed_preparation_kernel(
+        spec, monkeypatch):
+    """Two layers at each of the hybrid cell's head counts: four Mosaic
+    kernels in the module (a forward and a backward for 32 heads, the
+    same for 8), not two a site, and every site calls them."""
+    import re
+
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    q_loss, k_loss = _qk_prep_loss(32, 2), _qk_prep_loss(8, 2)
+    step = jax.jit(jax.value_and_grad(
+        lambda q, k, gamma, pos: q_loss(q, gamma, pos) + k_loss(k, gamma, pos),
+        (0, 1, 2)))
+    text = step.lower(
+        spec((2, 8192, 32 * 64), jnp.bfloat16),
+        spec((2, 8192, 8 * 64), jnp.bfloat16), spec((64,), jnp.float32),
+        spec((8192,), jnp.int32)).as_text()
+    names = re.findall(r'stablehlo.custom_call @tpu_custom_call.*'
+                       r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == 2 * ["rms_norm_rotary_bwd"] \
+        + 2 * ["rms_norm_rotary_fwd"]
+    assert len(re.findall(r"call @qk_prep_fwd_call", text)) == 4
+    assert len(re.findall(r"call @qk_prep_bwd_call", text)) == 4
 
 
 def _entry_work(text):
@@ -575,8 +618,9 @@ def test_the_hybrid_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     mix brings no convolution of its own (a TPU prints every matrix
     product as one, with no feature groups), the one
     attention layer is two Mosaic calls (flash forward, the fused
-    backward) with no fallback, its preparation is XLA ops, and arguments
-    and temporaries fit the chip.  (`chipbench/compile_check_large.py`
+    backward) with no fallback, its preparation two kernels of 32 and of
+    8 heads, two to a lane block (forward, replayed, backward: six Mosaic
+    calls), and arguments and temporaries fit the chip.  (`chipbench/compile_check_large.py`
     compiles all seven: 8.445 GiB of arguments, 3.205 GiB of
     temporaries.)"""
     import mxnet_tpu as mx
@@ -614,11 +658,13 @@ def test_the_hybrid_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     flash = [l for l in text.splitlines() if "tpu_custom_call" in l
              and "flash_attention" in l and " custom-call(" in l]
     assert len(flash) == 2
-    assert not [l for l in text.splitlines() if "tpu_custom_call" in l
-                and "rms_norm_rotary" in l]
+    prep = [l for l in text.splitlines() if "tpu_custom_call" in l
+            and "rms_norm_rotary" in l and " custom-call(" in l]
+    assert len(prep) == 6
+    assert len([l for l in prep if "rms_norm_rotary_bwd" in l]) == 2
     assert fallbacks == {k: c.value for k, c in
                          ti.attention_kernel_fallback_total.series()}
-    assert ti._qk_prep_sites == [0, 2]
+    assert ti._qk_prep_sites == [2, 2]
     assert ti.short_conv_sites.value == 2
     assert {k: g.value for k, g in ti.decoder_layers.series()} == {
         ("conv", "dense"): 1, ("attention", "moe"): 1, ("conv", "moe"): 1}
