@@ -9025,6 +9025,27 @@ inline std::vector<PackedTensor> mish(
   return rt.invoke("mish", ins_, a_.str());
 }
 
+inline std::vector<PackedTensor> mla_heads(
+    PyRuntime& rt,
+    const PackedTensor& q,
+    const PackedTensor& kv,
+    const PackedTensor& k_rope,
+    const PackedTensor& positions,
+    double theta = 10000.0,
+    long long num_heads = 1,
+    bool interleaved = false) {
+  std::vector<PackedTensor> ins_;
+  ins_.push_back(q);
+  ins_.push_back(kv);
+  ins_.push_back(k_rope);
+  ins_.push_back(positions);
+  detail::JsonBuilder a_;
+  a_.put_num("theta", theta);
+  a_.put_int("num_heads", num_heads);
+  a_.put_bool("interleaved", interleaved);
+  return rt.invoke("mla_heads", ins_, a_.str());
+}
+
 inline std::vector<PackedTensor> moments(
     PyRuntime& rt,
     const PackedTensor& x,

@@ -32,8 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ... import numpy_extension as npx
 from ...ndarray.ndarray import NDArray, apply_op
-from ...ops import nn as _nn
 from ..block import HybridBlock
 from ..contrib.nn import DroplessMoE, GatedMLP
 from ..nn import Dense, Embedding, HybridSequential
@@ -52,16 +52,14 @@ class MultiHeadLatentAttention(HybridBlock):
 
     Scopes, all under ``mla``: ``mla.q``, ``mla.kv_latent`` (down
     projection, latent norm, up projection), ``mla.rope`` (rotation and
-    the assembly of the heads), ``attention`` (the flash kernels) and
-    ``mla.out``."""
+    the assembly of the heads: `npx.mla_heads`, one pass on a TPU),
+    ``attention`` (the flash kernels) and ``mla.out``."""
 
     def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
                  qk_rope_head_dim, v_head_dim, rope_theta=10000.0,
                  rope_interleave=True, epsilon=1e-6, dtype="float32"):
         super().__init__()
-        self._heads, self._rank = num_heads, kv_lora_rank
-        self._nope, self._rope, self._v = qk_nope_head_dim, \
-            qk_rope_head_dim, v_head_dim
+        self._heads, self._rank, self._v = num_heads, kv_lora_rank, v_head_dim
         self._theta, self._interleave = float(rope_theta), \
             bool(rope_interleave)
 
@@ -79,27 +77,7 @@ class MultiHeadLatentAttention(HybridBlock):
 
     def forward(self, x, positions):
         b, s, _ = x.shape
-        h, rank, nope, v_dim = self._heads, self._rank, self._nope, self._v
-        theta, interleave = self._theta, self._interleave
-
-        def rotate(t, pos):
-            return _nn.rotary_embedding(t, pos.reshape((s, 1)), theta,
-                                        interleaved=interleave)
-
-        def heads(q_, kr_, kv_, pos):
-            """(B, S, ..) projections -> q, k (B, H, S, nope + rope) and
-            v (B, H, S, v): the one rotated key part beside every head's
-            own."""
-            q_ = q_.reshape((b, s, h, -1))
-            kv_ = kv_.reshape((b, s, h, nope + v_dim))
-            q_ = jnp.concatenate(
-                [q_[..., :nope], rotate(q_[..., nope:], pos)], axis=-1)
-            kr_ = jnp.broadcast_to(rotate(kr_[:, :, None, :], pos),
-                                   (b, s, h, kr_.shape[-1]))
-            k_ = jnp.concatenate([kv_[..., :nope], kr_], axis=-1)
-            return tuple(t.transpose((0, 2, 1, 3))
-                         for t in (q_, k_, kv_[..., nope:]))
-
+        h, rank, v_dim = self._heads, self._rank, self._v
         with jax.named_scope("mla"):
             with jax.named_scope("mla.q"):
                 q = self.q_proj(x)
@@ -110,8 +88,11 @@ class MultiHeadLatentAttention(HybridBlock):
                     name="split_latent")
                 kv = self.kv_b_proj(self.kv_a_norm(c))
             with jax.named_scope("mla.rope"):
-                q, k, v = apply_op(heads, q, k_rope, kv, positions,
-                                   name="mla_heads")
+                # the rotation, the one key part beside every head's own
+                # and the move to (B, H, S, ..) are row-wise: one op,
+                # straight from the projections' layout
+                q, k, v = npx.mla_heads(q, kv, k_rope, positions,
+                                        self._theta, h, self._interleave)
             out = attend(q, k, v, causal=True)
             with jax.named_scope("mla.out"):
                 return self.o_proj(

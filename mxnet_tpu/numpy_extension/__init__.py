@@ -13,6 +13,7 @@ from .. import _random
 from ..autograd import is_training
 from ..ndarray.ndarray import NDArray, apply_op
 from ..ops import nn as _nn
+from ..ops import pallas_mla_heads as _mla_heads
 from ..ops import pallas_qk_prep as _qk_prep
 from ..ops import short_conv as _short_conv
 
@@ -25,7 +26,7 @@ __all__ = [
     "activation", "leaky_relu", "relu", "sigmoid", "softmax", "log_softmax",
     "softmin", "fully_connected", "convolution", "deconvolution", "pooling",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "rms_norm",
-    "rotary_embedding", "rms_norm_rotary", "gated_short_conv",
+    "rotary_embedding", "rms_norm_rotary", "mla_heads", "gated_short_conv",
     "lrn", "dropout", "embedding", "one_hot", "pick", "topk", "sequence_mask",
     "sequence_last", "sequence_reverse", "l2_normalization", "upsampling",
     "moments", "gamma", "erf", "erfinv", "set_np", "reset_np", "is_np_array",
@@ -66,6 +67,7 @@ instance_norm = _op(_nn.instance_norm, 3)
 rms_norm = _op(_nn.rms_norm, 2)
 rotary_embedding = _op(_nn.rotary_embedding, 2)
 rms_norm_rotary = _op(_qk_prep.rms_norm_rotary, 3)
+mla_heads = _op(_mla_heads.mla_heads, 4)
 gated_short_conv = _op(_short_conv.gated_short_conv, 2)
 lrn = _op(_nn.lrn, 1)
 embedding = _op(_nn.embedding, 2)

@@ -28,6 +28,7 @@ __all__ = [
     "attention_maskfree_share", "set_attention_maskfree_share",
     "attention_fused_backward_share", "record_attention_backward_plan",
     "qk_prep_kernel_share", "record_qk_prep_site",
+    "mla_heads_kernel_share", "record_mla_heads_site",
     "looped_stack_copies", "ut_steps", "set_looped_stack", "exit_mass",
     "stage_exit_mass", "flush_exit_mass",
     "short_conv_sites", "decoder_layers", "record_short_conv_site",
@@ -194,6 +195,14 @@ qk_prep_kernel_share = gauge(
     "multiple of 128 or 64-wide heads two to a lane block, a length that "
     "is a multiple of 8); a site on the "
     "composition rms_norm -> rotary_embedding -> transpose counts as 0. "
+    "Set on the host each time the op is traced")
+mla_heads_kernel_share = gauge(
+    "mla_heads_kernel_share",
+    "Of the call sites of ops.pallas_mla_heads.mla_heads traced so far, the "
+    "share that took the fused kernels (a TPU, nope and v widths that are "
+    "multiples of 128, a rope width of 64, an even number of heads, a "
+    "length that is a multiple of 8); a site on the composition of XLA ops "
+    "(rotary_embedding, broadcast, concatenate, transpose) counts as 0. "
     "Set on the host each time the op is traced")
 looped_stack_copies = gauge(
     "looped_stack_copies",
@@ -920,12 +929,25 @@ def record_attention_backward_plan(fused):
 _qk_prep_sites = [0, 0]      # traced call sites: on the kernels, in all
 
 
-def record_qk_prep_site(kernels):
+def _record_kernel_site(sites, share, kernels):
+    """One more traced call site of an op that chooses between its fused
+    kernels and a composition of XLA ops: the tally and its gauge."""
     if not REGISTRY.enabled:
         return
-    _qk_prep_sites[0] += bool(kernels)
-    _qk_prep_sites[1] += 1
-    qk_prep_kernel_share.set(_qk_prep_sites[0] / _qk_prep_sites[1])
+    sites[0] += bool(kernels)
+    sites[1] += 1
+    share.set(sites[0] / sites[1])
+
+
+def record_qk_prep_site(kernels):
+    _record_kernel_site(_qk_prep_sites, qk_prep_kernel_share, kernels)
+
+
+_mla_heads_sites = [0, 0]    # traced call sites: on the kernels, in all
+
+
+def record_mla_heads_site(kernels):
+    _record_kernel_site(_mla_heads_sites, mla_heads_kernel_share, kernels)
 
 
 def set_looped_stack(steps):

@@ -572,6 +572,95 @@ def test_layers_share_one_copy_of_each_packed_preparation_kernel(
     assert len(re.findall(r"call @qk_prep_bwd_call", text)) == 4
 
 
+# -- latent attention's heads (ISSUE 49): the rotary part, the shared key's
+#    broadcast and the move to (B, H, S, 192 / 128) as two kernels, their
+#    backward as two more ------------------------------------------------------
+
+def _mla_heads_loss(heads, interleaved=True, layers=1):
+    from mxnet_tpu.ops import pallas_mla_heads as mh
+
+    def loss(q, kv, k_rope, positions):
+        total = 0.0
+        for i in range(layers):
+            with jax.named_scope(f"layer{i}"):
+                outs = mh.mla_heads(q, kv, k_rope, positions, 1e6, heads,
+                                    interleaved)
+            total = total + sum(o.astype(jnp.float32).sum() for o in outs)
+            q = q + outs[0].mean().astype(q.dtype)
+        return total
+
+    return loss
+
+
+def _mla_heads_specs(spec, heads, seq, dtype, nope=128, v=128):
+    return (spec((2, seq, heads * (nope + 64)), dtype),
+            spec((2, seq, heads * (nope + v)), dtype),
+            spec((2, seq, 64), dtype), spec((seq,), jnp.int32))
+
+
+@pytest.mark.parametrize("heads,seq,dtype,interleaved", [
+    (32, 8192, jnp.bfloat16, True),     # the kanana-2 cell's shape
+    (32, 8192, jnp.bfloat16, False),    # ... its rope lanes paired by halves
+    (4, 8200, jnp.bfloat16, True),      # the last block hangs over
+    (2, 24, jnp.bfloat16, True),        # one block, not whole sublane tiles
+    (2, 2056, jnp.float32, True),
+], ids=["cell", "halves", "edge", "short", "float32"])
+def test_latent_heads_assembly_compiles_for_v5e(spec, monkeypatch, heads, seq,
+                                                dtype, interleaved):
+    """`mla_heads` on its kernels: two Mosaic calls forward (q; k and v),
+    two more back, no 64-bit value in the program, and among the
+    backward's temporaries nothing float32 of a tensor's size — what it
+    holds is the three head-major results (q and k 192 wide, stored as
+    256 lanes) and the tables."""
+    import re
+
+    from mxnet_tpu.ops import pallas_mla_heads as mh
+    from mxnet_tpu.telemetry import instruments as ti
+
+    monkeypatch.setattr(mh, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(ti, "_mla_heads_sites", [0, 0])
+    operands = _mla_heads_specs(spec, heads, seq, dtype)
+    loss = _mla_heads_loss(heads, interleaved)
+    assert _kernel_calls(loss, *operands) == 2
+    assert ti._mla_heads_sites == [1, 1]
+    assert ti.mla_heads_kernel_share.value == 1.0
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'(mla_heads_\w+?)/', text)
+    assert sorted(names) == ["mla_heads_kv_bwd", "mla_heads_kv_fwd",
+                             "mla_heads_q_bwd", "mla_heads_q_fwd"]
+    assert "f64[" not in text and "s64[" not in text
+    rows = -(-seq // 16) * 16
+    padded = 2 * heads * rows * (256 + 256 + 128) * jnp.dtype(dtype).itemsize
+    tables = 2 * rows * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < padded + tables + 2 ** 20
+
+
+def test_layers_share_one_copy_of_each_assembly_kernel(spec, monkeypatch):
+    """Five layers at the cell's shape, forward and backward, lowered for
+    the described v5e: four Mosaic calls in the module, not four a layer,
+    and every layer calls them."""
+    import re
+
+    from mxnet_tpu.ops import pallas_mla_heads as mh
+
+    monkeypatch.setattr(mh, "_kernel_mode", lambda: False)
+    layers = 5
+    step = jax.jit(jax.value_and_grad(_mla_heads_loss(32, True, layers),
+                                      (0, 1, 2)))
+    text = step.lower(
+        *_mla_heads_specs(spec, 32, 8192, jnp.bfloat16)).as_text()
+    names = re.findall(r'stablehlo.custom_call @tpu_custom_call.*'
+                       r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == ["mla_heads_kv_bwd", "mla_heads_kv_fwd",
+                             "mla_heads_q_bwd", "mla_heads_q_fwd"]
+    assert text.count("call @heads_fwd_call") == layers
+    assert text.count("call @heads_bwd_call") == layers
+
+
 def _entry_work(text):
     """The entry computation's instructions that do work."""
     entry = text[text.index("ENTRY"):]

@@ -22,6 +22,7 @@ from mxnet_tpu.gluon.model_zoo.deepseek_v3 import (MultiHeadLatentAttention,
 from mxnet_tpu.ndarray.ndarray import NDArray
 from mxnet_tpu.ops import nn as ops_nn
 from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import pallas_mla_heads as mh
 from mxnet_tpu.parallel import moe
 from mxnet_tpu.telemetry import instruments as ti
 
@@ -70,6 +71,33 @@ def toy(bench):
     weights = wmod.make_weights(ref.param_specs(cfg), 7, "float32")
     batch = wmod.make_batches(ref.input_specs(cfg, 2), 7, 1)[0]
     return cfg, weights, batch
+
+
+@pytest.fixture(scope="module")
+def wide(bench):
+    """The toy configuration with two heads of the published widths (nope
+    128, rope 64, v 128): what `npx.mla_heads`'s kernels tile."""
+    ref, wmod, *_ = bench
+    cfg = dict(_toy("toy_deepseek_v3"), num_attention_heads=2,
+               num_key_value_heads=2, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, qk_head_dim=192, v_head_dim=128)
+    weights = wmod.make_weights(ref.param_specs(cfg), 7, "float32")
+    batch = wmod.make_batches(ref.input_specs(cfg, 2), 7, 1)[0]
+    return cfg, weights, batch
+
+
+@pytest.fixture(params=["composition", "kernels"])
+def heads(request, toy, wide, monkeypatch):
+    """(cfg, weights, batch, the share of `npx.mla_heads`'s sites on its
+    kernels): the toy widths on the composition of XLA ops, as every CPU
+    run takes it, and the published widths with the kernels forced,
+    interpreted."""
+    monkeypatch.setattr(ti, "_mla_heads_sites", [0, 0])
+    if request.param == "kernels":
+        monkeypatch.setattr(mh, "_kernel_mode", lambda: True)
+    yield (wide if request.param == "kernels" else toy) + (
+        float(request.param == "kernels"),)
+    ti.mla_heads_kernel_share.clear()
 
 
 def _net(bench, cfg, weights, remat=False, dtype="float32"):
@@ -187,9 +215,9 @@ def test_interleaved_rotary_is_the_pairwise_rotation_in_half_layout():
 
 # -- (b) latent attention and the whole model against the reference ---------
 
-def test_the_latent_attention_block_matches_the_reference(bench, toy):
+def test_the_latent_attention_block_matches_the_reference(bench, heads):
     ref = bench[0]
-    cfg, weights, _ = toy
+    cfg, weights, _, share = heads
     prefix = "model.layers.1."
     block = MultiHeadLatentAttention(
         cfg["hidden_size"], cfg["num_attention_heads"], cfg["kv_lora_rank"],
@@ -204,6 +232,7 @@ def test_the_latent_attention_block_matches_the_reference(bench, toy):
     got = block(NDArray(x), NDArray(pos)).asnumpy()
     want = ref._mla(cfg, weights, prefix, x, pos, "float32")
     _close(got, want, atol=2e-5)
+    assert ti.mla_heads_kernel_share.value == share
     # causal: a later token does not move an earlier output
     x2 = x.at[:, -1].add(1.0)
     again = block(NDArray(x2), NDArray(pos)).asnumpy()
@@ -226,11 +255,12 @@ def _loss_and_grads(net, batch):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_loss_and_every_gradient_match_the_plain_reference(bench, toy,
+def test_loss_and_every_gradient_match_the_plain_reference(bench, heads,
                                                            remat):
     ref = bench[0]
-    cfg, weights, batch = toy
+    cfg, weights, batch, share = heads
     per, grads = _loss_and_grads(_net(bench, cfg, weights, remat), batch)
+    assert ti.mla_heads_kernel_share.value == share
     train = {n: w for n, w in weights.items() if ref.trainable(n)}
     frozen = {n: w for n, w in weights.items() if n not in train}
 
