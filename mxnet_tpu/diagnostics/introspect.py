@@ -94,15 +94,6 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
     for ``args`` under ``(block, variant)``. Never raises: introspection must
     not be able to fail a training step. Returns the entry dict or None
     (disabled / analysis unavailable on this backend)."""
-    # the measurement plane hooks the same seam: every compiled program
-    # passes through here, so MXTPU_MEASURE=on_compile|cli registers it
-    # for micro-benchmarking even when compile capture itself is off
-    try:
-        from ..observability import measure as _measure
-
-        _measure.maybe_register(block, variant, jitted, args, kwargs)
-    except Exception:
-        pass
     if not capture_enabled():
         return None
     try:
@@ -163,61 +154,12 @@ def capture_compile(block, variant, jitted, args, kwargs=None,
             entry.update({"argument_bytes": 0, "output_bytes": 0,
                           "temp_bytes": 0, "generated_code_bytes": 0,
                           "peak_hbm_bytes": 0})
-        # backend-independent liveness peak (passes/memory.py): XLA's
-        # temp_size_in_bytes is a SUM of temp allocations on CPU, not a
-        # packed peak, so rematerialization wins only show up here.
-        # Costs an extra trace (plus a grad trace for train variants)
-        # per compile, so it only runs when something will read it: a
-        # remat policy is active, or MXTPU_DIAG_MEMORY=1 asks for it.
-        if _liveness_enabled():
-            try:
-                # "train" block variants are forward-only programs
-                # whose real residency cost is the fwd+bwd pair —
-                # estimate that; other programs (predict, whole_step)
-                # already ARE the program that runs
-                entry["peak_live_bytes"] = _peak_live_bytes(
-                    jitted, args, kwargs,
-                    training=str(variant) == "train")
-            except Exception:
-                entry["peak_live_bytes"] = None
-        else:
-            entry["peak_live_bytes"] = None
     except Exception:
         return None
     with _lock:
         _entries[(str(block), str(variant))] = entry
     _export_to_telemetry(entry)
     return entry
-
-
-def _liveness_enabled():
-    try:
-        from .. import env as _env
-
-        if _env.get("MXTPU_DIAG_MEMORY"):  # typed bool: 'off'/'false'=0
-            return True
-        return str(_env.get("MXTPU_REMAT_POLICY")).strip().lower() \
-            not in ("", "none")
-    except Exception:
-        return False
-
-
-def _peak_live_bytes(jitted, args, kwargs, training=False):
-    """Liveness-walk peak of the program about to run (trace-bump
-    suppressed — an introspection re-trace is not a user retrace)."""
-    import functools
-
-    import jax
-
-    from ..passes import _state as _pass_state
-    from ..passes import memory as _pass_memory
-
-    fn = functools.partial(jitted, **kwargs) if kwargs else jitted
-    with _pass_state.suppress_trace_bumps():
-        closed = jax.make_jaxpr(fn)(*args)
-    if training:
-        return int(_pass_memory.estimate_training_peak_bytes(closed))
-    return int(_pass_memory.estimate_peak_bytes(closed))
 
 
 def _export_to_telemetry(entry):

@@ -299,43 +299,15 @@ register(
     "MXTPU_PASSES", str, "auto",
     "Graph-pass pipeline master switch (mxnet_tpu/passes; "
     "docs/passes.md). 'auto' runs each block's registered passes plus "
-    "the env-driven policies; a comma list (e.g. 'amp,remat') "
+    "the env-driven policies; a comma list (e.g. 'amp,numerics') "
     "force-adds those named passes to every pipeline; '0' disables ALL "
     "graph passes so every seam compiles its captured program verbatim "
     "— bitwise-identical to the pre-pipeline framework.")
-register(
-    "MXTPU_REMAT_POLICY", str, "none",
-    "Rematerialization policy the remat pass applies to training "
-    "graphs: none | dots (sqrt-N segmented jax.checkpoint keeping "
-    "matmul/conv outputs) | full (segments save only boundary values) "
-    "| auto (estimate the fwd+bwd peak residency per policy via the "
-    "passes/memory.py liveness walk + the compile registry and pick "
-    "the cheapest one fitting MXTPU_REMAT_BUDGET_MB / device memory).")
-register(
-    "MXTPU_REMAT_BUDGET_MB", int, 0,
-    "HBM budget (MB) the remat 'auto' policy fits the training program "
-    "into. 0 = use the device's memory_stats bytes_limit; CPU reports "
-    "none, so 'auto' resolves to 'none' there without an explicit "
-    "budget.")
 register(
     "MXTPU_DIAG_COMPILE", bool, True,
     "Capture per-compile cost/memory analysis (flops, peak HBM, compile "
     "seconds) into the diagnostics compile registry at each block-seam "
     "build; 0 skips capture entirely (docs/diagnostics.md).")
-register(
-    "MXTPU_DIAG_MEMORY", bool, False,
-    "Record the backend-independent liveness peak (passes/memory.py "
-    "walk) into every compile-registry entry even when no remat policy "
-    "is active; costs an extra trace (plus a grad trace for train "
-    "variants) per compile. Any MXTPU_REMAT_POLICY other than 'none' "
-    "implies it.")
-register(
-    "MXTPU_GRAPH_DEDUP", bool, False,
-    "Cross-CachedOp structural dedup: canonicalize every block-seam "
-    "jaxpr (shapes/dtypes/equation graph, modulo variable names and "
-    "constant values) and share ONE compiled executable between "
-    "structurally identical blocks (multi-head models, serving "
-    "replicas). Reuses count in graph_dedup_hits_total.")
 register(
     "MXTPU_NUMERICS", str, "off",
     "In-graph numerics checking (observability.numerics; "
@@ -449,36 +421,3 @@ register(
     "Telemetry registry master switch (telemetry/registry.py): 0 turns "
     "every counter/gauge/histogram record into a single-branch no-op "
     "and /metrics serves an empty page.")
-register(
-    "MXTPU_MEASURE", str, "off",
-    "Measurement plane (observability/measure.py; docs/performance.md "
-    "'measured vs modeled'): 'off' (default) never touches a compile — "
-    "runs are bitwise-identical with zero extra traces or dispatches; "
-    "'on_compile' microbenchmarks every program at its compile-registry "
-    "seam (warmed, synchronized wall-clock runs on the live device) and "
-    "records it into the CostDB; 'cli' stashes programs for a deferred "
-    "measure.sweep() (what tools/costdb.py measure drives).")
-register(
-    "MXTPU_MEASURE_RUNS", int, 5,
-    "Timed executions per measured program (p50/p95 come from these).")
-register(
-    "MXTPU_MEASURE_WARMUP", int, 1,
-    "Untimed warmup executions before the timed runs of each measured "
-    "program (absorbs compilation and first-dispatch overhead).")
-register(
-    "MXTPU_COSTDB_PATH", str, "",
-    "CostDB JSON-lines file (observability/costdb.py). Empty = "
-    "<MXTPU_FLIGHTREC_DIR>/mxtpu_costdb.jsonl. Writes are atomic "
-    "(tmp+fsync+replace) and loads merge newest-wins, so many ranks "
-    "may share one path on a common filesystem.")
-register(
-    "MXTPU_COSTDB_AUTOSAVE", bool, True,
-    "Persist the CostDB after every recorded measurement. 0 keeps "
-    "measurements in memory until an explicit CostDB.save() "
-    "(tools/costdb.py or the postmortem path).")
-register(
-    "MXTPU_COSTDB_DRIFT_MAX", float, 8.0,
-    "Drift-auditor trip threshold: a program whose measured-vs-modeled "
-    "bandwidth ratio leaves [1/N, N] against the platform median "
-    "raises a cost_drift flight event and flags in /costdb, diagnose "
-    "--passes, and the fleetctl drift column.")

@@ -43,7 +43,8 @@ from ..device import Device, current_device
 from ..ndarray.ndarray import NDArray
 from .parameter import Constant, Parameter, cast_params
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock", "current_state_sink"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "checkpoint_block",
+           "current_state_sink"]
 
 
 # ---------------------------------------------------------------------------
@@ -78,6 +79,43 @@ class _push_sink:
     def __exit__(self, *exc):
         _sink_stack.pop()
         return False
+
+
+def checkpoint_block(block, *args, save=()):
+    """``block(*args)`` as one ``jax.checkpoint`` segment while a program
+    is being traced: the backward pass recomputes the block from its
+    inputs instead of keeping what it computed, except the values named
+    in ``save`` (`jax.ad_checkpoint.checkpoint_name`), which are kept.  A
+    model that is a stack of equal layers calls each layer through this
+    to hold one layer's activations at a time.  State the block writes
+    through the trace's sink (running statistics, an expert layer's
+    counters) leaves the segment as outputs and is recorded outside it.
+    Untraced, it is a plain call."""
+    nd_pos = [i for i, a in enumerate(args) if isinstance(a, NDArray)]
+    datas = [args[i]._data for i in nd_pos]
+    if not any(isinstance(d, jax.core.Tracer) for d in datas):
+        return block(*args)
+    outer, written = current_state_sink(), []
+
+    def segment(*xs):
+        call = list(args)
+        for i, x in zip(nd_pos, xs):
+            call[i] = NDArray(x)
+        inner = _StateSink()
+        with _push_sink(inner):
+            out = block(*call)
+        written[:] = inner.params
+        return (jax.tree_util.tree_map(
+            lambda a: a._data if isinstance(a, NDArray) else a, out,
+            is_leaf=lambda a: isinstance(a, NDArray)), tuple(inner.values))
+
+    policy = (jax.checkpoint_policies.save_only_these_names(*save)
+              if save else None)
+    out, values = jax.checkpoint(segment, policy=policy)(*datas)
+    if outer is not None:
+        for p, v in zip(written, values):
+            outer.record(p, v)
+    return jax.tree_util.tree_map(NDArray, out)
 
 
 # ---------------------------------------------------------------------------

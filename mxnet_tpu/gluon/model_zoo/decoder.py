@@ -14,9 +14,9 @@ from ... import numpy_extension as npx
 from ...ndarray.ndarray import NDArray, apply_op
 from ...ops import nn as _nn
 from ...ops.pallas_attention import SAVED_BY_NAME
-from ...passes.remat import checkpoint_block
 from ...telemetry import instruments as _telemetry
-from ..block import HybridBlock, _push_sink, _StateSink, current_state_sink
+from ..block import (HybridBlock, _push_sink, _StateSink, checkpoint_block,
+                     current_state_sink)
 from ..nn import Dense
 from ..parameter import Parameter
 
@@ -114,7 +114,7 @@ class GroupedQueryAttention(HybridBlock):
 def run_layers(layers, remat, x, *args):
     """x through ``layers``, each called with ``args``; with ``remat``
     each layer is one checkpoint segment of a training program
-    (`passes.remat.checkpoint_block`)."""
+    (`gluon.block.checkpoint_block`)."""
     for layer in layers:
         if remat:
             # recompute the layer on the way back, all but the flash

@@ -63,7 +63,7 @@ class SDARModel(HybridBlock):
     """Embedding, ``num_layers`` decoder layers, final norm:
     ``forward(tokens (B, S), positions (S,), block_diffusion)`` -> hidden
     states (B, S, units).  With ``remat`` each layer is one checkpoint
-    segment of a training program (`passes.remat.checkpoint_block`)."""
+    segment of a training program (`gluon.block.checkpoint_block`)."""
 
     def __init__(self, vocab_size, units, num_layers, remat=False,
                  epsilon=1e-6, dtype="float32", **layer):

@@ -9,68 +9,11 @@ net.cast('bfloat16').
 from __future__ import annotations
 
 from ... import nn
-from ....ndarray.ndarray import apply_op
 from ...block import HybridBlock
-from ...parameter import Parameter
 
 
 def _bn(layout, **kw):
     return nn.BatchNorm(axis=1 if layout[1] == "C" else -1, **kw)
-
-
-class SpaceToDepthStem(HybridBlock):
-    """7×7/s2 ResNet stem computed as a 4×4/s1 conv over 2×2
-    space-to-depth input (the MLPerf TPU trick).
-
-    The raw 7×7×3 conv leaves the MXU's 128-lane contraction dimension
-    ~97% idle (3 input channels). Repacking 2×2 input pixels into
-    channels gives an exactly equivalent conv with 12 input channels and
-    a 4×4 kernel (variance: out(i)=Σ_k w[k]·x[2i+k−3]; writing
-    k−3=2m+a splits the taps across s2d phase a and spatial offset m).
-
-    The parameter KEEPS the reference (O,7,7,C)/(O,C,7,7) shape so
-    checkpoints map 1:1; the repack runs inside the jitted step (9K
-    elements — free). Only 2×-stride 7×7 stems with even input sizes are
-    supported, which is the only place it's used.
-    """
-
-    def __init__(self, channels, in_channels=3, layout="NHWC"):
-        super().__init__()
-        if layout[-1] != "C":
-            raise ValueError("SpaceToDepthStem requires a channels-last "
-                             "layout (got %r)" % layout)
-        self._channels = channels
-        self.weight = Parameter("weight",
-                                shape=(channels, 7, 7, in_channels),
-                                allow_deferred_init=True)
-
-    def forward(self, x):
-        def _s2d_conv(x, w):
-            import jax.numpy as jnp
-            from jax import lax
-
-            n, h, wd, c = x.shape
-            o = w.shape[0]
-            # input: (N,H,W,C) -> (N,H/2,W/2,4C), packed (ah, aw, c)
-            xs = x.reshape(n, h // 2, 2, wd // 2, 2, c)
-            xs = xs.transpose(0, 1, 3, 2, 4, 5).reshape(
-                n, h // 2, wd // 2, 4 * c)
-            # kernel: (O,7,7,C) -> pad one leading zero tap per spatial
-            # dim (tap index kh+1 = 2·km+a) -> (O,4,4,4C), same packing
-            wp = jnp.pad(w, ((0, 0), (1, 0), (1, 0), (0, 0)))
-            wp = wp.reshape(o, 4, 2, 4, 2, c)
-            wp = wp.transpose(0, 1, 3, 2, 4, 5).reshape(o, 4, 4, 4 * c)
-            dn = lax.conv_dimension_numbers(
-                xs.shape, wp.shape, ("NHWC", "OHWI", "NHWC"))
-            return lax.conv_general_dilated(
-                xs, wp, window_strides=(1, 1),
-                padding=((2, 1), (2, 1)), dimension_numbers=dn)
-
-        if self.weight._is_deferred:
-            self.weight._finish_deferred_init(
-                (self._channels, 7, 7, x.shape[-1]))
-        return apply_op(_s2d_conv, x, self.weight.data_for(x),
-                        name="stem_s2d_conv")
 
 
 def _no_pretrained(pretrained):
@@ -210,7 +153,7 @@ class BottleneckV2(HybridBlock):
 
 class ResNetV1(HybridBlock):
     def __init__(self, block, layers, channels, classes=1000,
-                 thumbnail=False, layout="NCHW", stem_s2d=False):
+                 thumbnail=False, layout="NCHW"):
         super().__init__()
         assert len(layers) == len(channels) - 1
         self._layout = layout
@@ -219,12 +162,8 @@ class ResNetV1(HybridBlock):
             self.features.add(nn.Conv2D(channels[0], 3, 1, 1,
                                         use_bias=False, layout=layout))
         else:
-            if stem_s2d:
-                self.features.add(SpaceToDepthStem(channels[0],
-                                                   layout=layout))
-            else:
-                self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
-                                            use_bias=False, layout=layout))
+            self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
+                                        use_bias=False, layout=layout))
             self.features.add(_bn(layout))
             self.features.add(nn.Activation("relu"))
             self.features.add(nn.MaxPool2D(3, 2, 1, layout=layout))
@@ -252,7 +191,7 @@ class ResNetV1(HybridBlock):
 
 class ResNetV2(HybridBlock):
     def __init__(self, block, layers, channels, classes=1000,
-                 thumbnail=False, layout="NCHW", stem_s2d=False):
+                 thumbnail=False, layout="NCHW"):
         super().__init__()
         assert len(layers) == len(channels) - 1
         self._layout = layout
@@ -262,12 +201,8 @@ class ResNetV2(HybridBlock):
             self.features.add(nn.Conv2D(channels[0], 3, 1, 1,
                                         use_bias=False, layout=layout))
         else:
-            if stem_s2d:
-                self.features.add(SpaceToDepthStem(channels[0],
-                                                   layout=layout))
-            else:
-                self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
-                                            use_bias=False, layout=layout))
+            self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
+                                        use_bias=False, layout=layout))
             self.features.add(_bn(layout))
             self.features.add(nn.Activation("relu"))
             self.features.add(nn.MaxPool2D(3, 2, 1, layout=layout))

@@ -348,7 +348,7 @@ class TrainStep:
         from .. import passes as _passes
 
         # the forward body enters the whole-step program through the
-        # graph-pass pipeline (kind=whole_step_fwd): AMP / remat passes
+        # graph-pass pipeline (kind=whole_step_fwd): the passes
         # registered on the block rewrite exactly the part of the
         # program they understand, while optimizer state stays outside
         # their reach.  Explicit args (no closure captures) so the

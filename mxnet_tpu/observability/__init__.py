@@ -30,9 +30,7 @@ from __future__ import annotations
 
 import os
 
-from . import (  # noqa: F401
-    costdb, flight, measure, numerics, opsd, postmortem, reqtrace,
-)
+from . import flight, numerics, opsd, postmortem, reqtrace  # noqa: F401
 from .flight import (  # noqa: F401
     events, record, record_loss, set_identity, trace_id,
 )
@@ -40,8 +38,7 @@ from .numerics import NonFiniteError  # noqa: F401
 from .postmortem import dump, install_crash_hooks  # noqa: F401
 
 __all__ = [
-    "costdb", "flight", "measure", "numerics", "opsd", "postmortem",
-    "reqtrace",
+    "flight", "numerics", "opsd", "postmortem", "reqtrace",
     "record", "record_event", "record_loss", "events",
     "set_identity", "trace_id",
     "dump", "install_crash_hooks", "reset",
@@ -52,14 +49,11 @@ record_event = record
 
 
 def reset():
-    """Test hygiene: drop flight events, numerics trip bookkeeping,
-    request traces / SLO windows, and the measurement plane's in-memory
-    state (pending programs, site scores, the loaded CostDB)."""
+    """Test hygiene: drop flight events, numerics trip bookkeeping and
+    request traces / SLO windows."""
     flight.reset()
     numerics.reset()
     reqtrace.reset()
-    measure.reset()
-    costdb.reset()
 
 
 if os.environ.get("MXTPU_FLIGHTREC_CRASHDUMP", "").lower() \

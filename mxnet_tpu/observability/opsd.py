@@ -18,9 +18,6 @@ the TensorFlow paper's long-running training/serving-fleet framing):
   GET  /traces?n=N       newest N finished request traces (reqtrace.py:
                          phase spans, batch links, SLO table, per-phase
                          summary); &class= / &model= filter
-  GET  /costdb?n=N       measurement-plane view: CostDB summary, the
-                         drift auditor's predicted-vs-measured join,
-                         tripped programs, newest N entries
   GET  /steps            step-tracer phase table + last-step/step-rate
   GET  /identity         (job_id, rank, world) + pid/host/port — stamped
                          by kvstore.tpu_dist at collective init
@@ -206,34 +203,6 @@ def flight_payload(n=256, kind=None):
     }
 
 
-def costdb_payload(n=64):
-    """The measurement plane's live view: CostDB summary + the drift
-    auditor's join (calibration, per-program ratios, tripped programs)
-    + the newest ``n`` raw entries. ``n=0`` keeps just the summary —
-    what fleetctl polls per rank for its drift column."""
-    from . import costdb as _costdb
-    from . import flight as _flight
-    from . import measure as _measure
-
-    d = _costdb.db()
-    rep = _costdb.audit()
-    entries = d.entries()
-    n = max(0, int(n))
-    return {
-        "identity": _flight.identity(),
-        "mode": _measure.mode(),
-        "path": d.path,
-        "total": len(entries),
-        "platforms": sorted({str(e.get("platform")) for e in entries}),
-        "threshold": rep.get("threshold"),
-        "calibration": rep.get("calibration"),
-        "drift": rep.get("programs"),
-        "tripped": rep.get("tripped"),
-        "pending": _measure.pending(),
-        "entries": entries[-n:] if n else [],
-    }
-
-
 def traces_payload(n=32, cls=None, model=None):
     """Finished request traces + batch causality links + the live SLO
     table and per-phase latency breakdown (reqtrace.py). ``n=0`` keeps
@@ -343,15 +312,12 @@ class _Handler(BaseHTTPRequestHandler):
                 cls = q.get("class", [None])[0]
                 model = q.get("model", [None])[0]
                 self._send(200, traces_payload(n, cls=cls, model=model))
-            elif url.path == "/costdb":
-                n = int(q.get("n", ["64"])[0])
-                self._send(200, costdb_payload(n))
             elif url.path == "/":
                 self._send(200, {
                     "server": "mxtpu-opsd",
                     "endpoints": ["/metrics", "/healthz", "/readyz",
                                   "/steps", "/identity", "/flight",
-                                  "/traces", "/costdb",
+                                  "/traces",
                                   "POST /postmortem", "POST /profile"],
                 })
             else:

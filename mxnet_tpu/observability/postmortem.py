@@ -123,22 +123,6 @@ def build_bundle(reason, extra=None):
         bundle["req_traces"] = []
         bundle["req_batches"] = []
         bundle["slo"] = {"error": repr(e)}
-    try:
-        from . import costdb as _costdb
-        from . import measure as _measure
-
-        # the in-memory measurement cache + drift join ride along so a
-        # crash still carries what was measured and how far the byte
-        # model had drifted
-        d = _costdb.db()
-        bundle["costdb"] = {
-            "path": d.path,
-            "entries": d.entries(),
-            "drift": _costdb.drift_report(),
-            "pending": _measure.pending(),
-        }
-    except Exception as e:
-        bundle["costdb"] = {"error": repr(e)}
     if extra:
         bundle.update(extra)
     return bundle

@@ -4,10 +4,9 @@ Owns the seam between trace and compile: every jit the framework builds
 for a captured program (CachedOp variants, export, symbol lowering, the
 whole-step train program) flows through :func:`apply`, which runs the
 resolved passes jaxpr → jaxpr before XLA sees the graph.  Shipped
-passes: :class:`AmpPass` (auto mixed precision), :class:`RematPass`
-(segmented rematerialization with an `auto` cost-model policy) and
-cross-CachedOp structural dedup (MXTPU_GRAPH_DEDUP).  docs/passes.md
-covers the architecture and how to write a custom pass.
+passes: :class:`AmpPass` (auto mixed precision), and by name the
+numerics checker and the sharding pass.  docs/passes.md covers the
+architecture and how to write a custom pass.
 """
 from .manager import (  # noqa: F401
     GraphPass,
@@ -25,22 +24,9 @@ from .manager import (  # noqa: F401
     wrap_forward,
 )
 from .amp_pass import AmpPass  # noqa: F401
-from .remat import (  # noqa: F401
-    RematPass,
-    choose_policy,
-    segmented_remat,
-)
-from .dedup import (  # noqa: F401
-    DedupExecutable,
-    executable_cache_info,
-    reset_executable_cache,
-    structural_key,
-)
 from . import _state  # noqa: F401
-from . import memory  # noqa: F401
 
 register_named_pass("amp", AmpPass)
-register_named_pass("remat", RematPass)
 
 
 def _numerics_factory():
@@ -65,25 +51,17 @@ register_named_pass("sharding", _sharding_factory)
 
 __all__ = [
     "AmpPass",
-    "DedupExecutable",
     "GraphPass",
     "PassContext",
     "PassManager",
-    "RematPass",
     "apply",
     "apply_pipeline",
     "block_context",
-    "choose_policy",
-    "executable_cache_info",
-    "memory",
     "pipeline_enabled",
     "register_named_pass",
-    "reset_executable_cache",
     "resolve_passes",
     "retrace_flat",
     "run_passes",
-    "segmented_remat",
-    "structural_key",
     "trace_closed",
     "wrap_forward",
 ]

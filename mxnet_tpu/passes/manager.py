@@ -11,7 +11,7 @@ lowering, and the whole-step train program — routes through
 runs the registered passes jaxpr → jaxpr, and compiles the REWRITTEN
 program.  docs/passes.md is the user-facing tour.
 
-With no passes resolved (and dedup off), :func:`apply` returns a plain
+With no passes resolved, :func:`apply` returns a plain
 ``jax.jit(fn)`` — bitwise-identical to the pre-pipeline framework, and
 what ``MXTPU_PASSES=0`` forces unconditionally.
 """
@@ -50,7 +50,7 @@ __all__ = [
 #   symbol         SymbolBlock's lowered symbolic graph
 #   whole_step     the outer one-dispatch train program (fwd+bwd+update)
 #   whole_step_fwd the forward body embedded inside the whole-step
-#                  program (where AMP/remat act; the outer program also
+#                  program (where AMP acts; the outer program also
 #                  holds optimizer state, which passes must not touch)
 KINDS = ("block", "export", "symbol", "whole_step", "whole_step_fwd")
 
@@ -171,7 +171,7 @@ class PassManager:
         return f"PassManager({self.passes()!r})"
 
 
-# MXTPU_PASSES can name passes by string ("amp,remat"); factories
+# MXTPU_PASSES can name passes by string ("amp,numerics"); factories
 # register here (passes/__init__.py) so env config needs no imports.
 _NAMED = {}
 
@@ -190,10 +190,9 @@ def pipeline_enabled():
 
 
 def resolve_passes(ctx):
-    """The pipeline for one seam build: the block's registered passes,
-    any passes force-added by name via MXTPU_PASSES, and the env-driven
-    remat policy — filtered by :meth:`GraphPass.applies` and sorted
-    (priority, name)."""
+    """The pipeline for one seam build: the block's registered passes
+    and any passes force-added by name via MXTPU_PASSES — filtered by
+    :meth:`GraphPass.applies` and sorted (priority, name)."""
     if not pipeline_enabled():
         return []
     passes = []
@@ -213,11 +212,6 @@ def resolve_passes(ctx):
                     f"MXTPU_PASSES names unknown pass {name!r}; "
                     f"registered: {sorted(_NAMED)}")
             passes.append(factory())
-    policy = str(_env.get("MXTPU_REMAT_POLICY")).strip().lower()
-    if policy not in ("", "none") and not any(p.name == "remat"
-                                              for p in passes):
-        from .remat import RematPass
-        passes.append(RematPass(policy))
     # mode() is the ONE normalization of MXTPU_NUMERICS — TrainStep's
     # step-boundary poll reads the same function, so a value that
     # installs no pass here also triggers no polling there
@@ -240,14 +234,6 @@ def resolve_passes(ctx):
     return passes
 
 
-def _dedup_active(ctx):
-    # Dedup is scoped to block seams: export needs a real jax.jit for
-    # jax_export, and whole-step programs donate buffers (a shared
-    # executable must not donate one block's params for another).
-    return (ctx.kind == "block" and pipeline_enabled()
-            and bool(_env.get("MXTPU_GRAPH_DEDUP")))
-
-
 def trace_closed(fn, args):
     """``make_jaxpr`` with block trace-side-effects suppressed; returns
     (ClosedJaxpr, out_tree)."""
@@ -268,8 +254,8 @@ def run_passes(closed, passes, ctx):
 def retrace_flat(fn_flat, closed):
     """Re-trace a flat-args callable at ``closed``'s input signature.
     The pass contract is jaxpr → jaxpr; interpreter-style rewrites
-    (amp_rewrite, segmented remat) produce a callable and round-trip
-    back to a ClosedJaxpr through this."""
+    (amp_rewrite) produce a callable and round-trip back to a
+    ClosedJaxpr through this."""
     sds = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
            for v in closed.jaxpr.invars]
     return jax.make_jaxpr(lambda *xs: tuple(fn_flat(*xs)))(*sds)
@@ -314,24 +300,12 @@ def pipelined_callable(fn, passes, ctx):
 def apply(fn, ctx):
     """THE seam: compile ``fn`` through the pass pipeline.
 
-    Resolution order per build:
-      no passes, no dedup → plain ``jax.jit(fn)`` (bitwise main);
-      dedup on (block seams) → a :class:`~.dedup.DedupExecutable`
-      sharing structurally identical programs across blocks;
-      otherwise → ``jax.jit`` of the pipelined traceable — a REAL jit
-      object, so donation, ``.lower()`` (compile introspection) and
-      ``jax_export`` all work unchanged.
+    No passes → plain ``jax.jit(fn)`` (bitwise main); otherwise →
+    ``jax.jit`` of the pipelined traceable — a REAL jit object, so
+    donation, ``.lower()`` (compile introspection) and ``jax_export``
+    all work unchanged.
     """
-    passes = resolve_passes(ctx)
-    if _dedup_active(ctx):
-        from .dedup import DedupExecutable
-        return DedupExecutable(fn, passes, ctx)
-    if not passes:
-        return jax.jit(fn, donate_argnums=ctx.donate_argnums,
-                       **_jit_shardings(ctx))
-    return jax.jit(pipelined_callable(fn, passes, ctx),
-                   donate_argnums=ctx.donate_argnums,
-                   **_jit_shardings(ctx))
+    return apply_pipeline(fn, resolve_passes(ctx), ctx)
 
 
 def _jit_shardings(ctx):

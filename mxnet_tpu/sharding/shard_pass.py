@@ -24,8 +24,8 @@ numerics interposer.  For each seam kind it:
     plan's placement instead of inheriting whatever the operands had;
   * the jaxpr itself is returned UNCHANGED — sharding is a placement
     property, not an equation rewrite, so the rewritten program stays
-    structurally identical to the unsharded one (same dedup key, same
-    retrace behavior).
+    structurally identical to the unsharded one (same retrace
+    behavior).
 
 The whole-step seam deliberately keeps ``in_shardings`` unset: its
 argument list mixes host arrays (lrs/wds/ts) with pytrees, where

@@ -48,9 +48,7 @@ __all__ = [
     "moe_rows_routed_here", "moe_expert_load_max_over_mean",
     "moe_buffer_rows", "moe_bias_moved_share", "stage_moe_load",
     "flush_moe_load",
-    "pass_applied_total", "pass_rewrite_ms", "graph_dedup_hits_total",
-    "remat_policy", "record_pass", "record_dedup_hit",
-    "record_remat_policy",
+    "pass_applied_total", "pass_rewrite_ms", "record_pass",
     "data_prefetch_total", "data_prefetch_depth",
     "record_step_dispatch", "record_device_prefetch",
     "compile_flops", "compile_peak_hbm_bytes", "device_memory_bytes",
@@ -82,8 +80,6 @@ __all__ = [
     "record_sharding_apply", "record_sharding_stamp",
     "elastic_restart_total", "reshard_ms", "world_generation",
     "record_elastic_restart", "record_reshard", "set_world_generation",
-    "cost_measure_total", "cost_model_drift_ratio",
-    "record_cost_measure", "set_cost_drift",
     "DEVICE_PEAKS", "device_peaks",
 ]
 
@@ -346,18 +342,6 @@ pass_rewrite_ms = histogram(
     "Wall ms one graph pass spent rewriting one captured jaxpr "
     "(trace-time cost, amortized over every later dispatch)",
     ["pass"], buckets=_PASS_MS_BUCKETS)
-graph_dedup_hits_total = counter(
-    "graph_dedup_hits_total",
-    "Pipeline builds that matched a structurally identical program "
-    "already compiled for another block and reused its executable "
-    "(MXTPU_GRAPH_DEDUP=1)", ["block"])
-remat_policy = gauge(
-    "remat_policy",
-    "Rematerialization policy the remat pass last applied per seam "
-    "label: 0=none, 1=dots, 2=full (MXTPU_REMAT_POLICY; docs/passes.md)",
-    ["block"])
-
-REMAT_POLICY_CODES = {"none": 0, "dots": 1, "full": 2}
 
 # -- input pipeline (gluon/data/dataloader.py device_prefetch) --------------
 data_prefetch_total = counter(
@@ -627,39 +611,6 @@ def record_postmortem(reason):
     if not REGISTRY.enabled:
         return
     postmortem_dump_total.labels(reason).inc()
-
-
-# -- measurement plane ------------------------------------------------------
-cost_measure_total = counter(
-    "cost_measure_total",
-    "Programs microbenchmarked into the CostDB by the measurement "
-    "plane (observability/measure.py; MXTPU_MEASURE=on_compile|cli)",
-    ["block", "variant"])
-cost_model_drift_ratio = gauge(
-    "cost_model_drift_ratio",
-    "Predicted-vs-measured drift of the analytic byte model per "
-    "measured program (site='program'): the program's implied "
-    "bandwidth over the platform median, 1.0 = the model prices it "
-    "like everything else "
-    "(observability/costdb.py drift auditor)", ["site", "program"])
-
-
-def record_cost_measure(block, variant, wall_ms=None):
-    """One program measured into the CostDB; mirrored to the flight
-    recorder so postmortems show when measurement ran."""
-    _flight_record("cost_measure", block=str(block),
-                   variant=str(variant), wall_ms=wall_ms)
-    if not REGISTRY.enabled:
-        return
-    cost_measure_total.labels(block, variant).inc()
-
-
-def set_cost_drift(site, program, ratio):
-    """Publish one drift-auditor join result."""
-    if not REGISTRY.enabled:
-        return
-    cost_model_drift_ratio.labels(str(site), str(program)).set(
-        float(ratio))
 
 
 def _flight_record(kind, **fields):
@@ -1155,20 +1106,6 @@ def record_pass(name, ms):
         return
     pass_applied_total.labels(name).inc()
     pass_rewrite_ms.labels(name).observe(ms)
-
-
-def record_dedup_hit(block):
-    """One pipeline build reused another block's shared executable."""
-    if not REGISTRY.enabled:
-        return
-    graph_dedup_hits_total.labels(block).inc()
-
-
-def record_remat_policy(block, policy):
-    """The remat pass applied `policy` at seam `block`."""
-    if not REGISTRY.enabled:
-        return
-    remat_policy.labels(block).set(REMAT_POLICY_CODES.get(policy, -1))
 
 
 def record_device_prefetch(depth):

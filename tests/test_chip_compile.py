@@ -16,6 +16,7 @@ top-level call, no skipif condition, no parametrize argument), and the
 compiles run in the test's own process.
 """
 import os
+import re
 
 import pytest
 
@@ -69,28 +70,36 @@ class _Lowered(Exception):
     pass
 
 
-def _compiled_step(step, one_chip, *batch):
+def _lowered_step(step, *batch, describe=lambda operands: operands):
     """The whole step of a `gluon.TrainStep` exactly as it builds it,
-    compiled for the described chip instead of run: the step's own jitted
-    function is caught at its first call, lowered on the shapes of the
-    operands it was handed and compiled."""
+    lowered instead of run: the step's own jitted function is caught at
+    its first call and lowered on what ``describe`` makes of the operands
+    it was handed."""
     jitted = step._jitted
 
     def intercept(donate):
         fn = jitted(donate)
 
         def lower_only(*a):
-            described = jax.tree_util.tree_map(
-                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
-                                               sharding=one_chip)
-                if hasattr(v, "shape") else v, a)
-            raise _Lowered(fn.lower(*described).compile())
+            raise _Lowered(fn.lower(*describe(a)))
         return lower_only
 
     step._jitted = intercept
     with pytest.raises(_Lowered) as caught:
         step(*batch)
     return caught.value.args[0]
+
+
+def _compiled_step(step, one_chip, *batch):
+    """`_lowered_step` on the shapes of the operands, compiled for the
+    described chip."""
+    def describe(operands):
+        return jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                           sharding=one_chip)
+            if hasattr(v, "shape") else v, operands)
+
+    return _lowered_step(step, *batch, describe=describe).compile()
 
 
 # -- flash attention: BERT-base heads at the SQuAD and the 512 lengths ------
@@ -798,3 +807,50 @@ def test_whole_step_compiles_for_v5e_without_kernels_or_f64(
     text = _compiled_step(step, one_chip, x, y).as_text()
     assert "convolution" in text and "f64[" not in text
     assert "tpu_custom_call" not in text
+
+
+# -- the two expert cells' toy steps: no f64 in what is lowered (D10) -------
+
+@pytest.mark.parametrize("config,builder", [
+    ("toy_sdar_moe", "sdar_moe"), ("toy_deepseek_v3", "deepseek_v3"),
+], ids=["sdar", "kanana2"])
+def test_the_expert_cells_toy_whole_step_lowers_without_f64(config, builder):
+    """The guard the looped and the hybrid cell's steps carry, on SDAR's
+    and kanana-2's: the benchmark's toy configuration as its adapter
+    builds it, the whole step lowered where it stands (nothing compiled
+    for the chip, nothing run)."""
+    import importlib
+    import json
+    import sys
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    sys.path.insert(0, bench)
+    try:
+        wmod = importlib.import_module("weights")
+        model = importlib.import_module("models." + builder)
+        ref = importlib.import_module("reference." + builder)
+    finally:
+        sys.path.remove(bench)
+    weights = wmod.make_weights(ref.param_specs(cfg), 7, cfg["dtype"])
+    batch = wmod.make_batches(ref.input_specs(cfg, 2), 7, 1)[0]
+    net = model.build(mx, cfg, weights, mx.cpu())
+    opt = cfg["optimizer"]
+    trainer = gluon.Trainer(
+        net.collect_params(), opt["name"],
+        {k: v for k, v in opt.items() if k != "name"}, kvstore="tpu_dist")
+    step = gluon.TrainStep(net, None, trainer, n_data=model.loss(mx, cfg)[1])
+    text = _lowered_step(step, *[mx.nd.array(a) for a in batch]).as_text(
+        dialect="hlo")
+    # Before XLA folds them a Python float is still an `f64[]` operand of
+    # its convert: no f64 TENSOR is what the lowered text can show.  One
+    # is known (ROADMAP D10): `parallel.moe.dropless_moe` divides and
+    # stacks an expert layer's two or three counters as float64 before it
+    # casts them.  Anything wider is new.
+    assert "bf16[" in text
+    assert set(re.findall(r"f64\[(\d[\d,]*)\]", text)) <= {"1", "2", "3"}

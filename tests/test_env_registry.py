@@ -85,19 +85,28 @@ def test_docs_list_no_unregistered_knob(documented):
     assert listed == set(env.all_vars())
 
 
-# suffixes of the MXTPU_ variables whose subsystems PR 31 deleted, with a
-# value that used to switch each on
+# suffixes of the MXTPU_ variables whose subsystems were deleted (PR 31: the
+# Pallas-kernel, layout-pass and BatchNorm-compute switches; PR 50: the
+# jaxpr remat pass, the liveness model, dedup and the measurement plane),
+# with a value that used to switch each on
 _DELETED = {"KERNELS": "force", "KERNELS_INTERPRET": "1", "LAYOUT": "nhwc",
-            "LAYOUT_MIN_BYTES": "0", "BN_COMPUTE": "bf16"}
+            "LAYOUT_MIN_BYTES": "0", "BN_COMPUTE": "bf16",
+            "REMAT_POLICY": "full", "REMAT_BUDGET_MB": "1",
+            "DIAG_MEMORY": "1", "GRAPH_DEDUP": "1", "MEASURE": "on_compile",
+            "MEASURE_RUNS": "2", "MEASURE_WARMUP": "0",
+            "COSTDB_PATH": "costdb.jsonl", "COSTDB_AUTOSAVE": "1",
+            "COSTDB_DRIFT_MAX": "1.5"}
 
 
-def test_deleted_variables_change_no_program(monkeypatch):
-    """The Pallas-kernel, layout-pass and BatchNorm-compute switches
-    selected code that is gone: set, they raise nothing and BatchNorm's
-    training program is the one an empty environment traces."""
+@pytest.mark.parametrize("suffix", sorted(_DELETED))
+def test_deleted_variables_change_no_program(suffix, monkeypatch, tmp_path):
+    """A deleted switch selected code that is gone: set, it raises nothing,
+    leaves no file, and the training program of a BatchNorm block that the
+    trace-to-compile seam builds is the one an empty environment gives."""
     import jax
     import jax.numpy as jnp
 
+    from mxnet_tpu import passes
     from mxnet_tpu.ops.nn import batch_norm
 
     x = jnp.ones((8, 6, 6, 16), jnp.bfloat16)
@@ -108,10 +117,14 @@ def test_deleted_variables_change_no_program(monkeypatch):
         return out.astype(jnp.float32).sum() + mean.sum() + var.sum()
 
     def program():
-        return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, c, c))
+        ctx = passes.PassContext(label="deleted", kind="block",
+                                 training=True)
+        step = passes.apply(jax.grad(loss, argnums=(0, 1, 2)), ctx)
+        return step.lower(x, c, c).as_text()
 
     plain = program()
-    for suffix, value in _DELETED.items():
-        monkeypatch.setenv("MXTPU_" + suffix, value)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("MXTPU_" + suffix, _DELETED[suffix])
     assert program() == plain
-    assert not {"MXTPU_" + suffix for suffix in _DELETED} & set(env.all_vars())
+    assert not list(tmp_path.iterdir())
+    assert "MXTPU_" + suffix not in env.all_vars()

@@ -104,7 +104,6 @@ def test_diagnose_passes_smoke():
         env=ENV, capture_output=True, text=True, timeout=300)
     assert rc.returncode == 0, rc.stderr[-2000:]
     assert "== graph passes ==" in rc.stdout
-    assert "dedup HybridSequential" in rc.stdout
     assert "pass amp: applied" in rc.stdout
 
     rj = subprocess.run(
@@ -116,8 +115,6 @@ def test_diagnose_passes_smoke():
     pr = report["passes"]
     assert pr["pipeline_enabled"] is True
     assert pr["pass_applied"].get("amp", 0) >= 1
-    assert pr["executable_cache"]["hits"] >= 1
-    assert sum(pr["dedup_hits"].values()) >= 1
 
 
 def test_ckpt_cli_verify_smoke(tmp_path):

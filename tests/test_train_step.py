@@ -719,13 +719,10 @@ def test_wholestep_bf16_without_masters_stays_bf16(opt, kw, monkeypatch):
 
 
 def test_named_scope_leaves_program_identity_alone():
-    """Scopes are metadata: dedup's structural key and the measurement
-    plane's fingerprint of a body do not see them."""
+    """Scopes are metadata: a body traced under them has the equations of
+    the body traced plain, the name stack apart."""
     import jax
     import jax.numpy as jnp
-
-    from mxnet_tpu.observability import measure
-    from mxnet_tpu.passes import dedup
 
     def body(x, w):
         return jnp.tanh(x @ w).sum()
@@ -740,7 +737,4 @@ def test_named_scope_leaves_program_identity_alone():
         jax.make_jaxpr(scoped)(*args)
     assert "BatchNorm_bn2" in str(
         scoped_j.jaxpr.eqns[0].source_info.name_stack)
-    assert dedup.structural_key(plain_j) is not None
-    assert dedup.structural_key(plain_j) == dedup.structural_key(scoped_j)
-    assert measure.fingerprint_of(plain_j) == measure.fingerprint_of(
-        scoped_j)
+    assert str(plain_j) == str(scoped_j)

@@ -179,90 +179,36 @@ def _whole_step_report_lines(ws):
 
 
 def _passes_demo(hidden):
-    """Short graph-pass workload: two structurally identical Dense heads
-    under MXTPU_GRAPH_DEDUP=1 (the second build is a dedup hit) plus one
-    AMP-converted block through the pipeline, so the pass/dedup/remat
-    series below have something to show."""
+    """Short graph-pass workload: one AMP-converted block through the
+    pipeline, so the pass series below have something to show."""
     import mxnet_tpu as mx
     from mxnet_tpu import amp
     from mxnet_tpu.gluon import nn
 
-    prev = os.environ.get("MXTPU_GRAPH_DEDUP")
-    os.environ["MXTPU_GRAPH_DEDUP"] = "1"
-    try:
-        x = mx.np.ones((8, hidden))
-
-        def head():
-            net = nn.HybridSequential()
-            net.add(nn.Dense(hidden, activation="relu"), nn.Dense(4))
-            net.initialize()
-            net.hybridize()
-            return net
-
-        a, b = head(), head()
-        a(x)
-        b(x)  # structurally identical: shares a's compiled executable
-        c = head()
-        amp.convert_hybrid_block(c, graph_pass=True, example_inputs=(x,))
-        mx.waitall()
-    finally:
-        # the demo must not leave dedup on for everything built after it
-        if prev is None:
-            del os.environ["MXTPU_GRAPH_DEDUP"]
-        else:
-            os.environ["MXTPU_GRAPH_DEDUP"] = prev
+    x = mx.np.ones((8, hidden))
+    net = nn.HybridSequential()
+    net.add(nn.Dense(hidden, activation="relu"), nn.Dense(4))
+    net.initialize()
+    net.hybridize()
+    amp.convert_hybrid_block(net, graph_pass=True, example_inputs=(x,))
+    mx.waitall()
 
 
 def _passes_report():
     """Graph-pass pipeline state: resolved env config, per-pass apply
-    counts/rewrite timing, dedup hits, remat policy gauge, and the
-    process-wide shared-executable cache (docs/passes.md)."""
+    counts/rewrite timing and the sharding plan (docs/passes.md)."""
     from mxnet_tpu import env as _env
     from mxnet_tpu import passes
     from mxnet_tpu.telemetry import instruments as ti
 
-    policy_names = {v: k for k, v in ti.REMAT_POLICY_CODES.items()}
     return {
-        "config": {k: _env.get(k) for k in
-                   ("MXTPU_PASSES", "MXTPU_REMAT_POLICY",
-                    "MXTPU_REMAT_BUDGET_MB", "MXTPU_GRAPH_DEDUP")},
+        "config": {"MXTPU_PASSES": _env.get("MXTPU_PASSES")},
         "pipeline_enabled": passes.pipeline_enabled(),
         "pass_applied": {labels[0]: int(c.value)
                          for labels, c in ti.pass_applied_total.series()},
         "pass_rewrites": {labels[0]: int(h.count)
                           for labels, h in ti.pass_rewrite_ms.series()},
-        "dedup_hits": {labels[0]: int(c.value) for labels, c in
-                       ti.graph_dedup_hits_total.series()},
-        "remat_policy": {labels[0]: policy_names.get(int(g.value),
-                                                     int(g.value))
-                         for labels, g in ti.remat_policy.series()},
-        "executable_cache": passes.executable_cache_info(),
         "sharding": _sharding_report(),
-        "costdb": _costdb_report(),
-    }
-
-
-def _costdb_report():
-    """Measurement-plane state: resolved env config, CostDB size, and
-    the drift auditor's predicted-vs-measured join (docs/performance.md
-    'measured vs modeled')."""
-    from mxnet_tpu import env as _env
-    from mxnet_tpu.observability import costdb as _costdb
-    from mxnet_tpu.observability import measure as _measure
-
-    d = _costdb.db()
-    rep = _costdb.drift_report()
-    return {
-        "config": {k: _env.get(k) for k in
-                   ("MXTPU_MEASURE", "MXTPU_COSTDB_PATH",
-                    "MXTPU_COSTDB_DRIFT_MAX")},
-        "mode": _measure.mode(),
-        "path": d.path,
-        "entries": len(d),
-        "pending": _measure.pending(),
-        "calibration": rep["calibration"],
-        "drift": rep["programs"],
-        "tripped": [r["program"] for r in rep["tripped"]],
     }
 
 
@@ -295,14 +241,6 @@ def _passes_report_lines(pr):
             lines.append(f"  pass {name}: applied {n}x")
     else:
         lines.append("  (no passes applied)")
-    for block, n in sorted(pr["dedup_hits"].items()):
-        lines.append(f"  dedup {block}: {n} hit(s)")
-    for block, policy in sorted(pr["remat_policy"].items()):
-        lines.append(f"  remat {block}: policy={policy}")
-    cache = pr["executable_cache"]
-    lines.append(f"  executable cache: {cache['entries']} entries, "
-                 f"{cache['hits']} hits, {cache['misses']} misses, "
-                 f"{cache['unhashable']} unshareable")
     sh = pr.get("sharding") or {}
     sh_cfg = " ".join(f"{k}={v!r}" for k, v in
                       (sh.get("config") or {}).items())
@@ -326,25 +264,6 @@ def _passes_report_lines(pr):
             lines.append(f"    {row['param']:<40} {row['spec']:<25} "
                          f"{row['bytes_per_device']:>12} "
                          f"{row.get('state_bytes_per_device', '-'):>15}")
-    cd = pr.get("costdb") or {}
-    cd_cfg = " ".join(f"{k}={v!r}" for k, v in
-                      (cd.get("config") or {}).items())
-    lines.append(f"  costdb: {cd_cfg} entries={cd.get('entries', 0)}")
-    if cd.get("drift"):
-        lines.append("    program                                  "
-                     "platform  drift    p50 ms      predicted")
-        for row in cd["drift"]:
-            flag = "  TRIPPED" if row.get("tripped") else ""
-            p50 = row.get("wall_ms_p50")
-            lines.append(
-                f"    {row['program']:<40} {row['platform']:<8} "
-                f"{row['drift_ratio']:>6.2f}x "
-                f"{(f'{p50:.3f}' if p50 is not None else '?'):>9} "
-                f"{row.get('predicted_bytes', 0):>14}{flag}")
-    elif cd.get("mode") == "off":
-        lines.append("    (measurement off: MXTPU_MEASURE=off)")
-    else:
-        lines.append("    (no measurements recorded)")
     return lines
 
 
@@ -467,8 +386,8 @@ def main(argv=None):
                          "finished step by phase and program, and the "
                          "compile cache's contents by module name")
     ap.add_argument("--passes", action="store_true",
-                    help="run the graph-pass demo (dedup + pipeline AMP) "
-                         "and print the pass/dedup/remat report section")
+                    help="run the graph-pass demo (pipeline AMP) and "
+                         "print the pass report section")
     args = ap.parse_args(argv)
 
     if args.live:

@@ -9,8 +9,7 @@ Each training/serving rank started with ``MXTPU_OPS_PORT`` exposes the
 live ops plane (``mxnet_tpu/observability/opsd.py``; endpoint table in
 docs/observability.md). fleetctl polls every given endpoint's
 ``/identity`` + ``/healthz`` + ``/readyz`` + ``/steps`` (plus
-``/traces?n=0`` for the request-phase summary and ``/costdb?n=0`` for
-the cost-model drift column) and renders ONE table —
+``/traces?n=0`` for the request-phase summary) and renders ONE table —
 per-rank step, health, readiness, queue depth, SLO burn rate, and the
 pipeline phase where request latency goes — with straggler detection
 from step-gauge skew: a rank whose last step trails the fleet
@@ -112,18 +111,6 @@ def poll_rank(endpoint, timeout=3.0):
         row["phases"] = tr.get("phases") or {}
     except (urllib.error.URLError, OSError, ValueError):
         row["phases"] = {}
-    # measurement-plane drift summary (n=0: no raw entries). Older
-    # servers have no /costdb — leave it empty.
-    try:
-        cd = _get(base, "/costdb?n=0", timeout)
-        ratios = [r.get("drift_ratio") for r in (cd.get("drift") or [])
-                  if r.get("drift_ratio") is not None]
-        row["drift_max"] = max(ratios) if ratios else None
-        row["drift_tripped"] = [r.get("program")
-                                for r in (cd.get("tripped") or [])]
-    except (urllib.error.URLError, OSError, ValueError):
-        row["drift_max"] = None
-        row["drift_tripped"] = []
     return row
 
 
@@ -168,16 +155,6 @@ def _slo_cell(r):
     return f"{burn:.2f}x" + ("!" if r.get("slo_burning") else "")
 
 
-def _drift_cell(r):
-    """A rank's worst cost-model drift ratio, '!'-flagged while any
-    measured program trips the auditor (e.g. '9.21x!'); '-' when the
-    rank has no measurements (MXTPU_MEASURE=off or an older server)."""
-    worst = r.get("drift_max")
-    if worst is None:
-        return "-"
-    return f"{worst:.2f}x" + ("!" if r.get("drift_tripped") else "")
-
-
 def _phase_cell(r):
     """Where request latency goes on this rank: the heaviest pipeline
     phase by total time share, e.g. 'device 62%'."""
@@ -193,7 +170,7 @@ def _phase_cell(r):
 
 def fleet_table(rows):
     hdr = ["rank", "endpoint", "health", "ready", "step", "step_ms",
-           "ex/s", "queue", "slo", "phase", "drift", "mesh", "gen", ""]
+           "ex/s", "queue", "slo", "phase", "mesh", "gen", ""]
     table = [hdr]
     for r in sorted(rows, key=lambda r: (r["rank"] is None, r["rank"])):
         flag = "STRAGGLER" if r.get("straggler") else ""
@@ -214,7 +191,6 @@ def fleet_table(rows):
             "-" if r["queue"] is None else str(r["queue"]),
             _slo_cell(r),
             _phase_cell(r),
-            _drift_cell(r),
             _mesh_cell(r),
             # elastic world generation (docs/elasticity.md): a restarted
             # fleet shows gen>0 — mixed values mean a rank missed a
