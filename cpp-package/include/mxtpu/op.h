@@ -7989,7 +7989,8 @@ inline std::vector<PackedTensor> flash_attention(
     const char* interpret_json = nullptr,
     double dropout_p = 0.0,
     const char* dropout_seed_json = nullptr,
-    const char* block_diffusion_json = nullptr) {
+    const char* block_diffusion_json = nullptr,
+    const char* window_json = nullptr) {
   std::vector<PackedTensor> ins_;
   ins_.push_back(q);
   ins_.push_back(k);
@@ -8003,6 +8004,7 @@ inline std::vector<PackedTensor> flash_attention(
   a_.put_num("dropout_p", dropout_p);
   if (dropout_seed_json) a_.raw("dropout_seed", dropout_seed_json);
   if (block_diffusion_json) a_.raw("block_diffusion", block_diffusion_json);
+  if (window_json) a_.raw("window", window_json);
   return rt.invoke("flash_attention", ins_, a_.str());
 }
 

@@ -4,6 +4,7 @@ checkpoint segments, a stack run several times on its own output as one
 rolled loop, and the loss over the vocabulary rows held here."""
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -46,14 +47,18 @@ class RMSNorm(HybridBlock):
             x, self.gamma.data_for(x), name="rms_norm")
 
 
-def attend(q, k, v, **mask):
+def attend(q, k, v, scope=None, **mask):
     """`flash_attention` on (B, H, S, width) heads under the scope
     ``attention``; the op reads its tiles off the shapes.  ``mask``:
-    ``causal=True`` or ``block_diffusion=(block, half)``."""
+    ``causal=True``, ``block_diffusion=(block, half)`` or ``window=W``.
+    ``scope``: a second name inside ``attention`` for a stack whose
+    layers differ in mask (``attention.window``, ``attention.global``)."""
     from ...ops.pallas_attention import flash_attention
 
     def kernel(q_, k_, v_):
-        with jax.named_scope("attention"):
+        inner = contextlib.nullcontext() if scope is None \
+            else jax.named_scope(scope)
+        with jax.named_scope("attention"), inner:
             return flash_attention(q_, k_, v_, **mask)
 
     return apply_op(kernel, q, k, v, name="flash_attention")
@@ -68,15 +73,26 @@ class GroupedQueryAttention(HybridBlock):
     ``forward(x, positions, block_diffusion, causal)``: x (B, S, units),
     ``positions`` the S position ids, and the static mask:
     ``block_diffusion`` (block length, half length), ``causal``, or
-    neither for full attention."""
+    neither for full attention.
+
+    What a layer of a stack with two kinds of attention chooses (the
+    defaults leave the block as it was): ``window=W`` keeps, of the
+    causal pairs, key j for query i iff i - j < W (whatever ``forward``'s
+    mask); ``rotary=False`` carries no positions (the heads are normed and
+    not turned); ``output_gate=True`` adds a fifth projection ``gate_proj``
+    (units -> num_heads * head_dim) whose sigmoid multiplies the heads'
+    output before ``o_proj``, under the scope ``attention.gate``;
+    ``scope`` names the flash call inside the ``attention`` scope."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  rope_theta=10000.0, epsilon=1e-6, dtype="float32",
-                 head_norm=True):
+                 head_norm=True, window=None, rotary=True,
+                 output_gate=False, scope=None):
         super().__init__()
         self._heads, self._kv_heads, self._hd = num_heads, num_kv_heads, \
             head_dim
         self._theta, self._eps = float(rope_theta), float(epsilon)
+        self._window, self._rotary, self._scope = window, bool(rotary), scope
 
         def proj(out_units, in_units):
             return Dense(out_units, use_bias=False, flatten=False,
@@ -88,6 +104,8 @@ class GroupedQueryAttention(HybridBlock):
         self.o_proj = proj(units, num_heads * head_dim)
         self.q_norm = RMSNorm(head_dim, epsilon) if head_norm else None
         self.k_norm = RMSNorm(head_dim, epsilon) if head_norm else None
+        self.gate_proj = proj(num_heads * head_dim, units) \
+            if output_gate else None
 
     def forward(self, x, positions, block_diffusion=None, causal=False):
         b, s, _ = x.shape
@@ -97,17 +115,25 @@ class GroupedQueryAttention(HybridBlock):
             # the head's norm, the rotation and the move to (B, n, S, hd)
             # are row-wise: one op, straight from the projection's layout
             gamma = None if norm is None else norm.gamma.data_for(t)
-            return npx.rms_norm_rotary(t, gamma, positions, self._theta, n,
-                                       self._eps)
+            return npx.rms_norm_rotary(
+                t, gamma, positions if self._rotary else None, self._theta,
+                n, self._eps)
 
         q = prepared(self.q_proj(x), self.q_norm, self._heads)
         k = prepared(self.k_proj(x), self.k_norm, self._kv_heads)
         v = self.v_proj(x).reshape((b, s, self._kv_heads, hd)).transpose(
             (0, 2, 1, 3))
-        mask = {"causal": True} if causal \
+        mask = {"window": self._window} if self._window is not None \
+            else {"causal": True} if causal \
             else {"block_diffusion": block_diffusion}
-        out = attend(q, k, v, **mask)
+        out = attend(q, k, v, scope=self._scope, **mask)
         out = out.transpose((0, 2, 1, 3)).reshape((b, s, self._heads * hd))
+        if self.gate_proj is not None:
+            with jax.named_scope("attention.gate"):
+                out = apply_op(
+                    lambda o, g: (o.astype(jnp.float32) * jax.nn.sigmoid(
+                        g.astype(jnp.float32))).astype(o.dtype),
+                    out, self.gate_proj(x), name="attention_gate")
         return self.o_proj(out)
 
 

@@ -102,18 +102,26 @@ def _dropout_keep(seed, bh, q_pos, k_pos, dropout_p):
 _BIG = 2 ** 30
 
 
-def _mask_codes(causal, block_diffusion, s_len, valid_len=None):
-    """The mask as two static codes per position, or None when nothing
-    is masked: ``(qc, kc)``, int32 arrays of shape (s_len, 2) and
+def _mask_codes(causal, block_diffusion, s_len, valid_len=None, window=None):
+    """The mask as static codes per position, or None when nothing is
+    masked: ``(qc, kc)``, int32 arrays of shape (s_len, 2) and
     (2, s_len), with
 
         keep[i, j] = (kc[0, j] == qc[i, 0]) | (kc[1, j] <= qc[i, 1])
 
+    and, under a ``window``, a third query code, (s_len, 3), the
+    threshold term's lower bound:
+
+        keep[i, j] = (kc[1, j] <= qc[i, 1]) & (kc[1, j] > qc[i, 2])
+
     One rule for every mask the kernels know, evaluated from a (block_q,
-    2) column and a (2, block_k) row per tile:
+    2 or 3) column and a (2, block_k) row per tile:
 
     * padding (``valid_len``): a padded key's codes match no query;
     * ``causal``: the threshold term alone, position against position;
+    * ``window=W``, a causal band: query i keeps key j iff 0 <= i - j < W —
+      the threshold term between two bounds, j <= i and j > i - W.  A
+      band is causal, so ``causal`` beside it changes nothing;
     * ``block_diffusion=(B, L)``, the vectorised training mask of block
       diffusion (Arriola et al., arXiv:2503.09573) over the 2L positions
       [noisy ; clean] with block b(i) = (i mod L) // B: noisy->noisy iff
@@ -126,6 +134,16 @@ def _mask_codes(causal, block_diffusion, s_len, valid_len=None):
     pos = onp.arange(s_len)
     valid = pos < (s_len if valid_len is None else valid_len)
     never_q, never_k = onp.full(s_len, -1), onp.full(s_len, -2)
+    if window is not None:
+        if block_diffusion is not None:
+            raise ValueError("window and block_diffusion exclude each other")
+        if int(window) < 1:
+            raise ValueError(f"window={window}: a band keeps at least the "
+                             "query's own position")
+        k_thr = onp.where(valid, pos, _BIG)
+        return (onp.stack([never_q, pos, pos - int(window)], 1
+                          ).astype(onp.int32),
+                onp.stack([never_k, k_thr], 0).astype(onp.int32))
     if block_diffusion is not None:
         if causal:
             raise ValueError("causal and block_diffusion exclude each other")
@@ -153,9 +171,12 @@ def _mask_codes(causal, block_diffusion, s_len, valid_len=None):
 
 
 def _keep(qc, kc, use_eq=True):
-    """The rule of ``_mask_codes`` on (n, 2) query and (2, m) key codes
-    (jnp or numpy): an (n, m) boolean."""
+    """The rule of ``_mask_codes`` on (n, 2 or 3) query and (2, m) key
+    codes (jnp or numpy): an (n, m) boolean.  A third query code is a
+    band's lower bound (static: the codes' shape)."""
     keep = kc[1:2, :] <= qc[:, 1:2]
+    if qc.shape[-1] == 3:
+        keep = keep & (kc[1:2, :] > qc[:, 2:3])
     if use_eq:
         keep = keep | (kc[0:1, :] == qc[:, 0:1])
     return keep
@@ -174,13 +195,15 @@ def _classes(codes, s_len, unit_q, unit_k):
     mask.  ``_FREE`` promises that every pair is kept and ``_DEAD`` that
     none is; the masks ``_mask_codes`` builds (code values that rise with
     the position) are classified exactly wherever a unit does not
-    straddle the noisy / clean boundary."""
+    straddle the noisy / clean boundary; a band's sub-tile is dead above
+    the diagonal and below the band, free where its last key is at most
+    its first query and its first key inside its last query's window."""
     import numpy as onp
 
     nq, nk = s_len // unit_q, s_len // unit_k
     if codes is None:
         return onp.full((nq, nk), _FREE, onp.int8)
-    qc = codes[0].astype(onp.int64).reshape(nq, unit_q, 2)
+    qc = codes[0].astype(onp.int64).reshape(nq, unit_q, -1)
     kc = codes[1].astype(onp.int64).reshape(2, nk, unit_k)
     q_eq, q_thr, k_eq, k_thr = qc[:, :, 0], qc[:, :, 1], kc[0], kc[1]
 
@@ -196,6 +219,11 @@ def _classes(codes, s_len, unit_q, unit_k):
     all_eq = one_q[:, None] & one_k[None] & (q_hi[:, None] == k_hi[None])
     some_thr = k_thr.min(1)[None] <= q_thr.max(1)[:, None]
     all_thr = k_thr.max(1)[None] <= q_thr.min(1)[:, None]
+    if qc.shape[-1] == 3:       # a band: the threshold's lower bound
+        q_low = qc[:, :, 2]
+        k_real = onp.where(k_thr < _BIG, k_thr, -_BIG).max(1)   # no padding
+        some_thr &= k_real[None] > q_low.min(1)[:, None]
+        all_thr &= k_thr.min(1)[None] > q_low.max(1)[:, None]
     return onp.where(all_thr | all_eq, _FREE,
                      onp.where(some_thr | some_eq, _MASKED, _DEAD)
                      ).astype(onp.int8)
@@ -285,11 +313,11 @@ def _walk(word, span, rows, chunk, classes, body):
 
 def attention_reference(q, k, v, causal=False, scale=None,
                         dropout_p=0.0, dropout_seed=None,
-                        block_diffusion=None):
+                        block_diffusion=None, window=None):
     """Plain jnp attention (the numeric oracle + off-TPU fallback).
     q: (B, H, S, D); k: (B, Hkv, S, D); v: (B, Hkv, S, Dv) with H a
     multiple of Hkv (query head h reads key-value head h // (H // Hkv));
-    the result is (B, H, S, Dv).  ``block_diffusion`` as in
+    the result is (B, H, S, Dv).  ``block_diffusion`` and ``window`` as in
     `flash_attention`.  dropout uses the same counter-hash mask as
     the Pallas kernel, applied to the normalized probabilities
     (numerator only, inverted scaling) — bit-identical semantics to the
@@ -300,7 +328,7 @@ def attention_reference(q, k, v, causal=False, scale=None,
         k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k.astype(q.dtype)) * scale
-    codes = _mask_codes(causal, block_diffusion, s)
+    codes = _mask_codes(causal, block_diffusion, s, window=window)
     if codes is not None:
         scores = jnp.where(_keep(*codes), scores, -jnp.inf)
     p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
@@ -499,6 +527,33 @@ def _maskfree_share(side):
     return float((classes == _FREE).sum()) / (classes != _DEAD).sum()
 
 
+def _pairs_visited(side):
+    """Query-key pairs of one head in the sub-tiles a side's schedule
+    visits (every class but dead)."""
+    live = int((_word_classes(side.words, side.span) != _DEAD).sum())
+    return live * side.tile * side.sub
+
+
+def _pairs_kept(codes):
+    """Query-key pairs of one head the mask keeps, from the codes alone:
+    per query the keys between its thresholds (a sorted search) and the
+    keys of its equality code — the two terms never keep the same pair
+    under the masks ``_mask_codes`` builds."""
+    import numpy as onp
+
+    qc, kc = (c.astype(onp.int64) for c in codes)
+    k_thr = onp.sort(kc[1])
+    kept = onp.searchsorted(k_thr, qc[:, 1], "right")
+    if qc.shape[-1] == 3:
+        kept = kept - onp.searchsorted(k_thr, qc[:, 2], "right")
+    total = int(kept.sum())
+    q_eq, k_eq = qc[:, 0][qc[:, 0] >= 0], kc[0][kc[0] >= 0]
+    if q_eq.size and k_eq.size:
+        held = onp.bincount(k_eq, minlength=int(q_eq.max()) + 1)
+        total += int(held[q_eq].sum())
+    return total
+
+
 class _Plan:
     """What the kernels of one signature share, built once (`_plan`):
     tile sizes, the mask's codes, the span schedules with their classes
@@ -512,7 +567,7 @@ class _Plan:
     the dQ and the dK/dV kernel, and only then is ``cols`` built."""
 
     def __init__(self, q_shape, k_shape, v_shape, itemsize, causal,
-                 block_q, block_k, valid_len, block_diffusion):
+                 block_q, block_k, valid_len, block_diffusion, window=None):
         b, h, s_len, dk = q_shape
         self.group = h // k_shape[1]
         if h != self.group * k_shape[1]:
@@ -522,7 +577,17 @@ class _Plan:
         # queries and keys share one width, values and the output another
         self.dk, self.dv = dk, v_shape[-1]
         block_q, block_k = min(block_q, s_len), min(block_k, s_len)
-        codes = _mask_codes(causal, block_diffusion, s_len, valid_len)
+        codes = _mask_codes(causal, block_diffusion, s_len, valid_len,
+                            window)
+        # the kind of mask, the label of the gauge pair `_plan` sets
+        if window is not None:
+            self.mask = "window"
+        elif block_diffusion is not None:
+            self.mask = "block_diffusion"
+        elif causal:
+            self.mask = "causal"
+        else:
+            self.mask = "none" if valid_len is None else "padding"
         self.use_eq = block_diffusion is not None
         self.codes = codes if codes is not None else _mask_codes(
             False, None, s_len, s_len)     # nothing reads them: all kept
@@ -560,7 +625,7 @@ class _Plan:
     def code_units(self, unit_q, unit_k):
         """The codes cut the same way: (units, rows, 2), (units, 2, rows)."""
         qc, kc = self.codes
-        return (qc.reshape(-1, unit_q, 2),
+        return (qc.reshape(-1, unit_q, qc.shape[-1]),
                 kc.reshape(2, -1, unit_k).transpose(1, 0, 2))
 
     def specs(self, side, head_of, step_axis=1):
@@ -598,7 +663,8 @@ class _Plan:
                 lambda *a: (head_of(*a[:-2])[1], k_of(a), 0, 0))
 
         return (q_block, k_block,
-                pl.BlockSpec(q_rows + (2,), lambda *a: (q_of(a), 0, 0)),
+                pl.BlockSpec(q_rows + (self.codes[0].shape[-1],),
+                             lambda *a: (q_of(a), 0, 0)),
                 pl.BlockSpec((k_rows[0], 2, k_rows[1]),
                              lambda *a: (k_of(a), 0, 0)),
                 pl.BlockSpec(
@@ -632,15 +698,18 @@ class _Plan:
 
 @functools.lru_cache(maxsize=256)
 def _plan(q_shape, k_shape, v_shape, dtype, causal, block_q, block_k,
-          valid_len, block_diffusion):
+          valid_len, block_diffusion, window=None):
     """The plan of one signature, built once: N layers, the kernels of a
     layer and remat's replays share one host computation.  Sets the gauges
-    ``attention_maskfree_share{kernel}`` from the schedules' class bits
-    and ``attention_fused_backward_share`` from the memory plan."""
+    ``attention_maskfree_share{kernel}`` from the schedules' class bits,
+    ``attention_fused_backward_share`` from the memory plan and the pair
+    ``attention_pairs_visited{mask}`` / ``attention_pairs_kept{mask}``
+    from the forward's schedule and the codes."""
     from ..telemetry import instruments as _telemetry
 
     plan = _Plan(q_shape, k_shape, v_shape, jnp.dtype(dtype).itemsize,
-                 causal, block_q, block_k, valid_len, block_diffusion)
+                 causal, block_q, block_k, valid_len, block_diffusion,
+                 window)
     rows = _maskfree_share(plan.rows)
     _telemetry.set_attention_maskfree_share(
         {"flash_attention_fwd": rows, "flash_attention_bwd": rows}
@@ -648,13 +717,16 @@ def _plan(q_shape, k_shape, v_shape, dtype, causal, block_q, block_k,
         {"flash_attention_fwd": rows, "flash_attention_bwd_dq": rows,
          "flash_attention_bwd_dkv": _maskfree_share(plan.cols)})
     _telemetry.record_attention_backward_plan(plan.fused)
+    _telemetry.set_attention_pairs(plan.mask, _pairs_visited(plan.rows),
+                                   _pairs_kept(plan.codes))
     return plan
 
 
-def _plan_of(q, k, v, causal, block_q, block_k, valid_len, block_diffusion):
+def _plan_of(q, k, v, causal, block_q, block_k, valid_len, block_diffusion,
+             window=None):
     return _plan(q.shape, k.shape, v.shape, jnp.dtype(q.dtype).name,
                  bool(causal), int(block_q), int(block_k), valid_len,
-                 block_diffusion)
+                 block_diffusion, window)
 
 
 @functools.lru_cache(maxsize=256)
@@ -698,9 +770,9 @@ def _flash_fwd_call(plan, scale, dropout_p, interpret, seed, q, k, v):
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                valid_len=None, dropout_p=0.0, dropout_seed=None,
-               block_diffusion=None):
+               block_diffusion=None, window=None):
     plan = _plan_of(q, k, v, causal, block_q, block_k, valid_len,
-                    block_diffusion)
+                    block_diffusion, window)
     out, lse = _shared(_flash_fwd_call, plan, float(scale), dropout_p,
                        interpret)(_seed_arr(dropout_seed), q, k, v)
     return out.reshape(q.shape[:-1] + (plan.dv,)), lse[..., 0]
@@ -964,12 +1036,12 @@ def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
 
 def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                interpret, valid_len=None, dropout_p=0.0,
-               dropout_seed=None, block_diffusion=None):
+               dropout_seed=None, block_diffusion=None, window=None):
     """Block-streamed FlashAttention-2 backward: O(S) memory, no (S, S)
     residual: P sub-tiles are recomputed from (q, k, lse) (and the
     dropout keep mask from its counter hash)."""
     plan = _plan_of(q, k, v, causal, block_q, block_k, valid_len,
-                    block_diffusion)
+                    block_diffusion, window)
     dq, dk, dv = _shared(_flash_bwd_call, plan, float(scale), dropout_p,
                          interpret)(
         _seed_arr(dropout_seed), q, k, v, out, lse, g)
@@ -977,23 +1049,23 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, seed, causal, scale, block_q, block_k, interpret,
-           dropout_p=0.0, valid_len=None, block_diffusion=None):
+           dropout_p=0.0, valid_len=None, block_diffusion=None, window=None):
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
                         interpret, valid_len, dropout_p, seed,
-                        block_diffusion)
+                        block_diffusion, window)
     return out
 
 
 def _flash_vjp_fwd(q, k, v, seed, causal, scale, block_q, block_k,
                    interpret, dropout_p=0.0, valid_len=None,
-                   block_diffusion=None):
+                   block_diffusion=None, window=None):
     from jax.ad_checkpoint import checkpoint_name
 
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
                           interpret, valid_len, dropout_p, seed,
-                          block_diffusion)
+                          block_diffusion, window)
     # named, so that a checkpoint policy can keep the kernel's two results
     # (SAVED_BY_NAME) and not run the forward kernel a second time
     out = checkpoint_name(out, SAVED_BY_NAME[0])
@@ -1002,13 +1074,13 @@ def _flash_vjp_fwd(q, k, v, seed, causal, scale, block_q, block_k,
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, dropout_p,
-                   valid_len, block_diffusion, res, g):
+                   valid_len, block_diffusion, window, res, g):
     import numpy as _onp
 
     q, k, v, seed, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q,
                             block_k, interpret, valid_len, dropout_p,
-                            seed, block_diffusion)
+                            seed, block_diffusion, window)
     # integer seed takes a float0 cotangent
     return dq, dk, dv, _onp.zeros(seed.shape, jax.dtypes.float0)
 
@@ -1019,7 +1091,7 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 @register_op("flash_attention")
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, dropout_p=0.0,
-                    dropout_seed=None, block_diffusion=None):
+                    dropout_seed=None, block_diffusion=None, window=None):
     """Fused multi-head attention: softmax(QK^T * scale + mask) V.
 
     q: (B, H, S, D); k: (B, Hkv, S, D); v: (B, Hkv, S, Dv), H a multiple
@@ -1052,22 +1124,30 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     where ``block_q`` / ``block_k`` do not divide the padded length or
     are not multiples of 8.
 
-    **The span schedule.**  The mask is static: nothing, ``causal``, or
+    **The span schedule.**  The mask is static: nothing, ``causal``,
     ``block_diffusion=(block length B, half length L)`` for a sequence of
-    S = 2L positions [noisy ; clean] (`_mask_codes` has the rule).  A
+    S = 2L positions [noisy ; clean], or ``window=W``, a causal band —
+    query i keeps key j iff 0 <= i - j < W (a band is causal whatever
+    ``causal`` says; with ``block_diffusion`` it raises) — (`_mask_codes`
+    has the rule).  A
     grid step of the forward and dQ kernels holds one q tile and streams
     a span of consecutive k sub-tiles (the fused backward walks the same
     steps over keys it holds; dK/dV: a k tile and a span of q sub-tiles);
     the host lists the steps once (`_span_schedule`) and
     gives each sub-tile a class from the codes' minima and maxima:
-    dead (skipped; a span of dead sub-tiles is not in the grid at all),
+    dead (skipped; a span of dead sub-tiles is not in the grid at all —
+    above the diagonal and, under a window, below the band),
     mask-free (every pair kept: no codes read, no compare, no select) or
     masked.  The kernel walks the span with one rolled loop that takes
     the mask-free or the masked body by the class, so each body is traced
     once a kernel whatever the span.  An unmasked call is the case where
     every sub-tile is mask-free, padding one more masked class at the
     edge.  The gauge ``attention_maskfree_share{kernel}`` is the share of
-    visited sub-tiles that take the mask-free body.
+    visited sub-tiles that take the mask-free body; the pair
+    ``attention_pairs_visited{mask}`` / ``attention_pairs_kept{mask}``
+    gives, for the latest plan of each kind of mask, the pairs of one head
+    in the sub-tiles the forward's schedule visits and the pairs the mask
+    keeps.
 
     **The backward's memory plan.**  One fused kernel,
     ``flash_attention_bwd``, where the working set of a key-value head
@@ -1110,6 +1190,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     dropout_p = float(dropout_p)
     if block_diffusion is not None:
         block_diffusion = tuple(int(x) for x in block_diffusion)
+    if window is not None:
+        if block_diffusion is not None:
+            raise ValueError("window and block_diffusion exclude each other")
+        window = int(window)
 
     def _fallback(qq, kk, vv, reason=None):
         if reason is not None:
@@ -1117,7 +1201,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         return attention_reference(qq, kk, vv, causal=causal, scale=scale,
                                    dropout_p=dropout_p,
                                    dropout_seed=dropout_seed,
-                                   block_diffusion=block_diffusion)
+                                   block_diffusion=block_diffusion,
+                                   window=window)
 
     if interpret is None:
         interpret = False
@@ -1141,11 +1226,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     seed = _seed_arr(dropout_seed)
     if s_pad == s_len:
         return _flash(q, k, v, seed, causal, scale, bq, bk, interpret,
-                      dropout_p, None, block_diffusion)
+                      dropout_p, None, block_diffusion, window)
     pad = [(0, 0), (0, 0), (0, s_pad - s_len), (0, 0)]
     out = _flash(jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
                  seed, causal, scale, bq, bk, interpret, dropout_p, s_len,
-                 block_diffusion)
+                 block_diffusion, window)
     return out[:, :, :s_len]
 
 

@@ -61,10 +61,12 @@ _PACKED_WIDTHS = (64,)
 # what the two kernels of one call share besides their operands' shapes:
 # heads in the last dimension, rows of a block, the norm's epsilon,
 # whether Pallas interprets the kernels (tests, off a TPU), whether
-# there is a norm at all (no gamma: the rotation and the store alone), and
+# there is a norm at all (no gamma: the rotation and the store alone),
 # the heads a block holds (1: a head is whole lane blocks; 2: 64-wide
-# heads, two to a 128-lane block)
-_Sig = collections.namedtuple("_Sig", "heads rows eps interpret norm pack")
+# heads, two to a 128-lane block), and whether there is a rotation at all
+# (no positions: the norm and the store alone, no table among the operands)
+_Sig = collections.namedtuple(
+    "_Sig", "heads rows eps interpret norm pack rotate")
 
 
 def _kernel_mode():
@@ -134,15 +136,17 @@ def _normed(x, eps, d):
     return x * r, r
 
 
-def _fwd_kernel(x_ref, *refs, eps, norm):
-    *g_ref, cos_ref, sin_ref, o_ref = refs
+def _fwd_kernel(x_ref, *refs, eps, norm, rotate):
+    *refs, o_ref = refs
     pack, d = o_ref.shape[1], o_ref.shape[-1]
     n = x_ref[0].astype(jnp.float32)
     if norm:
         xh, _ = _normed(n, eps, d)
-        n = xh * g_ref[0][...]
-    out = (n * cos_ref[...] + _rotate_half(n, d) * sin_ref[...]
-           ).astype(o_ref.dtype)
+        n = xh * refs[0][...]
+    if rotate:
+        cos_ref, sin_ref = refs[-2:]
+        n = n * cos_ref[...] + _rotate_half(n, d) * sin_ref[...]
+    out = n.astype(o_ref.dtype)
     if pack == 1:
         o_ref[0, 0] = out
     else:
@@ -150,15 +154,18 @@ def _fwd_kernel(x_ref, *refs, eps, norm):
             o_ref[0, h] = out[:, h * d:(h + 1) * d]
 
 
-def _rotated_back(dy_ref, cos_ref, sin_ref):
+def _rotated_back(dy_ref, cos_ref=None, sin_ref=None):
     """The rotation's transpose on a block of cotangents: the same swap,
-    on the sine's side."""
+    on the sine's side; without tables the block as it is, float32, in
+    the projection's layout."""
     pack, d = dy_ref.shape[1], dy_ref.shape[-1]
     if pack == 1:
         dy = dy_ref[0, 0].astype(jnp.float32)
     else:
         dy = jnp.concatenate([dy_ref[0, h] for h in range(pack)],
                              axis=-1).astype(jnp.float32)
+    if cos_ref is None:
+        return dy
     return dy * cos_ref[...] + _rotate_half(dy * sin_ref[...], d)
 
 
@@ -166,13 +173,13 @@ def _bwd_rotation_kernel(dy_ref, cos_ref, sin_ref, dx_ref):
     dx_ref[0] = _rotated_back(dy_ref, cos_ref, sin_ref).astype(dx_ref.dtype)
 
 
-def _bwd_kernel(x_ref, dy_ref, g_ref, cos_ref, sin_ref, dx_ref, dg_ref, *,
-                eps, s_len):
+def _bwd_kernel(x_ref, dy_ref, g_ref, *refs, eps, s_len):
     import jax.experimental.pallas as pl
 
+    *tables, dx_ref, dg_ref = refs      # cos and sin, or neither
     d = dy_ref.shape[-1]
     xh, r = _normed(x_ref[0].astype(jnp.float32), eps, d)
-    dn = _rotated_back(dy_ref, cos_ref, sin_ref)
+    dn = _rotated_back(dy_ref, *tables)
     rows, lanes = dn.shape
     dgamma = dn * xh
     if s_len % rows:
@@ -215,12 +222,16 @@ def _qk_prep_fwd_call(sig, x, gamma, cos, sin):
     b, s_len, width = x.shape
     d = width // sig.heads
     flat, major, scale, table = _specs(sig, d)
-    in_specs, operands = [flat, table, table], [x, cos, sin]
+    in_specs, operands = [flat], [x]
     if sig.norm:
-        in_specs.insert(1, scale)
-        operands.insert(1, _scale(gamma, sig))
+        in_specs.append(scale)
+        operands.append(_scale(gamma, sig))
+    if sig.rotate:
+        in_specs += [table, table]
+        operands += [cos, sin]
     return _pallas_call(
-        functools.partial(_fwd_kernel, eps=sig.eps, norm=sig.norm),
+        functools.partial(_fwd_kernel, eps=sig.eps, norm=sig.norm,
+                          rotate=sig.rotate),
         name="rms_norm_rotary_fwd", grid=_grid(sig, b, s_len),
         in_specs=in_specs, out_specs=major,
         out_shape=jax.ShapeDtypeStruct((b, sig.heads, s_len, d), x.dtype),
@@ -245,16 +256,17 @@ def _qk_prep_bwd_call(sig, x, dy, gamma, cos, sin):
             out_shape=dx_shape, interpret=sig.interpret,
         )(dy, cos, sin), None
     lanes = sig.pack * d
+    tables = [cos, sin] if sig.rotate else []
     dx, partial = _pallas_call(
         functools.partial(_bwd_kernel, eps=sig.eps, s_len=s_len),
         name="rms_norm_rotary_bwd", grid=grid,
-        in_specs=[flat, major, scale, table, table],
+        in_specs=[flat, major, scale] + [table] * len(tables),
         out_specs=[flat, pl.BlockSpec((1, 1, 1, 8, lanes),
                                       lambda b, r, h: (b, r, h, 0, 0))],
         out_shape=[dx_shape,
                    jax.ShapeDtypeStruct(grid + (8, lanes), jnp.float32)],
         interpret=sig.interpret,
-    )(x, dy, _scale(gamma, sig), cos, sin)
+    )(x, dy, _scale(gamma, sig), *tables)
     dgamma = partial.sum(axis=(0, 1, 2, 3))
     if sig.pack > 1:
         # a lane block's heads share gamma: their partial sums add
@@ -280,22 +292,25 @@ def _prepared_bwd(sig, res, dy):
     x, gamma, cos, sin = res
     dx, dgamma = _shared(_qk_prep_bwd_call, sig)(x, dy, gamma, cos, sin)
     # the tables come from integer positions: nothing flows back to them
-    return dx, dgamma, jnp.zeros_like(cos), jnp.zeros_like(sin)
+    return (dx, dgamma, None if cos is None else jnp.zeros_like(cos),
+            None if sin is None else jnp.zeros_like(sin))
 
 
 _prepared.defvjp(_prepared_fwd, _prepared_bwd)
 
 
 def _composition(x, gamma, positions, theta, num_heads, eps):
-    """The three ops one after the other (two without a norm): what the
-    kernels replace, and the op's reference."""
+    """The three ops one after the other (two without a norm, or without
+    positions): what the kernels replace, and the op's reference."""
     b, s_len, width = x.shape
     heads = x.reshape((b, s_len, num_heads, width // num_heads))
     if gamma is not None:
         heads = _nn.rms_norm(heads.astype(jnp.float32), gamma,
                              eps=eps).astype(x.dtype)
-    return _nn.rotary_embedding(heads, positions.reshape((s_len, 1)),
-                                theta).transpose((0, 2, 1, 3))
+    if positions is not None:
+        heads = _nn.rotary_embedding(heads, positions.reshape((s_len, 1)),
+                                     theta)
+    return heads.transpose((0, 2, 1, 3))
 
 
 @register_op("rms_norm_rotary")
@@ -309,8 +324,10 @@ def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
     rotates its heads without a norm (n = x below; the same two kernels
     with the norm compiled out, and a backward that reads the cotangent
     alone); ``positions``: the S explicit position ids, shared by the
-    batch.  Returns (B, num_heads, S, D) in
-    x's type, what `flash_attention` reads:
+    batch, or None for a layer that carries no positions (out = n below:
+    the norm and the move alone, the rotation compiled out of the same
+    kernels and no table among their operands).  Returns (B, num_heads,
+    S, D) in x's type, what `flash_attention` reads:
 
         n = x / sqrt(mean(x ** 2 over D) + eps) * gamma     (each head)
         out = n * cos(a) + concat(-n[D/2:], n[:D/2]) * sin(a)
@@ -333,13 +350,17 @@ def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
 
     b, s_len, width = x.shape
     d, rest = divmod(width, num_heads)
-    if rest or positions.size != s_len or (
+    if gamma is None and positions is None:
+        raise ValueError("neither a norm (gamma) nor a rotation (positions): "
+                         "that is a reshape and a transpose")
+    if rest or (positions is not None and positions.size != s_len) or (
             gamma is not None and gamma.shape != (d,)):
         raise ValueError(
             f"x {x.shape} as {num_heads} heads, gamma "
             f"{None if gamma is None else gamma.shape}, "
-            f"{positions.size} positions: the last dimension is num_heads "
-            "heads of gamma's width, and there is a position a row")
+            f"{None if positions is None else positions.size} positions: "
+            "the last dimension is num_heads heads of gamma's width, and "
+            "there is a position a row")
     interpret = _kernel_mode()
     # heads of a block: one, or those of a tested narrower width that
     # fill a lane block; the kernels tile whole lane blocks of whole heads
@@ -349,8 +370,9 @@ def rms_norm_rotary(x, gamma, positions, theta=10000.0, num_heads=1,
     _telemetry.record_qk_prep_site(kernels)
     if not kernels:
         return _composition(x, gamma, positions, theta, num_heads, eps)
-    cos, sin = _tables(positions, theta, d, pack)
+    rotate = positions is not None
+    cos, sin = _tables(positions, theta, d, pack) if rotate else (None, None)
     sig = _Sig(int(num_heads),
                _row_tile(s_len, d, jnp.dtype(x.dtype).itemsize, pack),
-               float(eps), interpret, gamma is not None, pack)
+               float(eps), interpret, gamma is not None, pack, rotate)
     return _prepared(x, gamma, cos, sin, sig)

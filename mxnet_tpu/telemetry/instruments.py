@@ -27,6 +27,7 @@ __all__ = [
     "record_attention_fallback",
     "attention_maskfree_share", "set_attention_maskfree_share",
     "attention_fused_backward_share", "record_attention_backward_plan",
+    "attention_pairs_visited", "attention_pairs_kept", "set_attention_pairs",
     "qk_prep_kernel_share", "record_qk_prep_site",
     "mla_heads_kernel_share", "record_mla_heads_site",
     "looped_stack_copies", "ut_steps", "set_looped_stack", "exit_mass",
@@ -184,6 +185,20 @@ attention_fused_backward_share = gauge(
     "the stated share of the core's fast memory); a signature whose "
     "backward is the dQ and the dK/dV kernel counts as 0. Set on the host "
     "when the plan of a signature is built (ops.pallas_attention._plan)")
+attention_pairs_visited = gauge(
+    "attention_pairs_visited",
+    "Query-key pairs of one head in the sub-tiles the flash forward's span "
+    "schedule visits (every class but dead), for the latest plan of each "
+    "kind of mask: window, causal, block_diffusion, padding, none. Over "
+    "attention_pairs_kept it is the work the schedule spends per pair the "
+    "mathematics needs; set on the host when the plan of a signature is "
+    "built (ops.pallas_attention._plan)", ["mask"])
+attention_pairs_kept = gauge(
+    "attention_pairs_kept",
+    "Query-key pairs of one head that the static mask keeps, counted from "
+    "the mask's codes, for the latest plan of each kind of mask (a band of "
+    "W over S positions: W (W + 1) / 2 + (S - W) W); beside "
+    "attention_pairs_visited", ["mask"])
 qk_prep_kernel_share = gauge(
     "qk_prep_kernel_share",
     "Of the call sites of ops.pallas_qk_prep.rms_norm_rotary traced so far, "
@@ -875,6 +890,13 @@ def record_attention_backward_plan(fused):
     _attention_plans[1] += 1
     attention_fused_backward_share.set(
         _attention_plans[0] / _attention_plans[1])
+
+
+def set_attention_pairs(mask, visited, kept):
+    if not REGISTRY.enabled:
+        return
+    attention_pairs_visited.labels(mask).set(visited)
+    attention_pairs_kept.labels(mask).set(kept)
 
 
 _qk_prep_sites = [0, 0]      # traced call sites: on the kernels, in all

@@ -770,6 +770,129 @@ def test_the_hybrid_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10 * 2 ** 30
 
 
+# -- the window / global cell (ISSUE 51): the band, no positions, the step --
+
+@pytest.mark.parametrize("case", ["fwd", "grad"])
+@pytest.mark.parametrize("mask", [{"window": 2048}, {"causal": True}],
+                         ids=["window", "global"])
+def test_window_and_global_attention_at_16k_compile_for_v5e(spec, mask, case):
+    """The Trinity-Mini cell's two flash signatures — 32 query heads over
+    4 key-value heads of 128, 16,384 positions, bf16, under the band of
+    2,048 keys and under the causal mask — at the tile the op chooses:
+    one forward kernel, and ONE fused backward (a head's keys, values and
+    their gradients fit the described core's fast memory at 128 / 128)."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q = spec((1, 32, 16384, 128), jnp.bfloat16)
+    kv = spec((1, 4, 16384, 128), jnp.bfloat16)
+    pa._plan.cache_clear()
+
+    def loss(*a):
+        return pa.flash_attention(*a, interpret=False, **mask).astype(
+            jnp.float32).sum()
+
+    fn = loss if case == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    calls = _kernel_calls(fn, q, kv, kv)
+    plan = pa._plan_of(q, kv, kv, mask.get("causal", False), 1024, 1024,
+                       None, None, mask.get("window"))
+    pa._plan.cache_clear()
+    assert plan.fused and plan.rows[:3] == (1024, 1024, 2)
+    assert calls == (1 if case == "fwd" else 2)
+
+
+@pytest.mark.parametrize("heads,seq,dtype", [
+    (32, 16384, jnp.bfloat16),     # the global layer's queries
+    (4, 16384, jnp.bfloat16),      # ... and keys
+    (4, 8200, jnp.bfloat16),       # the last block hangs over the sequence
+    (2, 2056, jnp.float32),
+], ids=["q", "k", "edge", "float32"])
+def test_position_free_preparation_compiles_for_v5e(spec, monkeypatch, heads,
+                                                    seq, dtype):
+    """`rms_norm_rotary(x, gamma, None, ...)`: the norm and the head-major
+    store through the same two kernels with the rotation compiled out and
+    no table among the operands."""
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    x, gamma = spec((1, seq, heads * 128), dtype), spec((128,), jnp.float32)
+
+    def loss(x, gamma):
+        out = qp.rms_norm_rotary(x, gamma, None, 1e4, heads, 1e-5)
+        return out.astype(jnp.float32).sum()
+
+    assert _kernel_calls(loss, x, gamma) == 1
+    assert _kernel_calls(jax.value_and_grad(loss, (0, 1)), x, gamma) == 2
+
+
+def test_the_window_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The Trinity-Mini cell's step as gluon.TrainStep builds it — every
+    published width, 1 x 16,384 positions, the 25,024 rows held of
+    embedding and head, bf16 under Adam with masters, remat; TWO of its
+    five layers, one of each mask (sliding + dense, global + experts
+    beside the shared one) — compiled for the described v5e.  Each layer
+    is two Mosaic flash calls (forward, the fused backward) with no
+    fallback, its preparation of 32 and of 4 heads three each (forward,
+    replayed, backward), the global layer's with no table; arguments and
+    temporaries fit the chip.  (`chipbench/compile_check_large.py`
+    compiles all five: 6.571 GiB of arguments, 4.91 GiB of
+    temporaries.)"""
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, gluon
+    from mxnet_tpu.gluon.model_zoo.afmoe import afmoe
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import pallas_qk_prep as qp
+    from mxnet_tpu.telemetry import instruments as ti
+
+    kernel = pa.flash_attention
+    monkeypatch.setattr(pa, "flash_attention", lambda *a, **kw: kernel(
+        *a, **{**kw, "interpret": False}))
+    monkeypatch.setattr(qp, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(ti, "_qk_prep_sites", [0, 0])
+    pa._plan.cache_clear()
+    for g in (ti.attention_pairs_visited, ti.attention_pairs_kept):
+        g.clear()
+    fallbacks = {k: c.value for k, c in
+                 ti.attention_kernel_fallback_total.series()}
+    net = afmoe(25024, 2048, ["sliding_attention", "full_attention"], 32, 4,
+                128, 6144, 1024, 128, 8, 2048, num_dense_layers=1,
+                route_scale=2.826, ep_size=16, remat=True)
+    net.initialize(init=mx.initializer.Zero())
+    amp.convert_hybrid_block(net, target_dtype="bfloat16")
+    net.hybridize()
+    trainer = gluon.Trainer(
+        net.collect_params(), "adam",
+        {"learning_rate": 1e-5, "multi_precision": True}, kvstore="tpu_dist")
+    step = gluon.TrainStep(net, None, trainer, n_data=1)
+    compiled = _compiled_step(step, one_chip,
+                              mx.np.zeros((1, 16384), dtype="int32"))
+    text = compiled.as_text()
+    assert "f64[" not in text
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and " custom-call(" in l]
+    for scope in ("attention.window", "attention.global"):
+        flash = [l for l in calls if "flash_attention" in l and scope in l]
+        assert len(flash) == 2, scope
+        assert len([l for l in flash if "flash_attention_bwd" in l]) == 1
+    prep = [l for l in calls if "rms_norm_rotary" in l]
+    assert len(prep) == 12
+    assert len([l for l in prep if "rms_norm_rotary_bwd" in l]) == 4
+    assert fallbacks == {k: c.value for k, c in
+                         ti.attention_kernel_fallback_total.series()}
+    assert ti._qk_prep_sites == [4, 4]
+    assert {k: g.value for k, g in ti.decoder_layers.series()} == {
+        ("window", "dense"): 1, ("global", "moe"): 1}
+    visited, kept = ({k[0]: c.value for k, c in g.series()} for g in (
+        ti.attention_pairs_visited, ti.attention_pairs_kept))
+    assert visited == {"window": 45 * 1024 ** 2, "causal": 136 * 1024 ** 2}
+    assert kept == {"window": 31_458_304, "causal": 134_225_920}
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10 * 2 ** 30
+    pa._plan.cache_clear()
+    for g in (ti.attention_pairs_visited, ti.attention_pairs_kept,
+              ti.decoder_layers):
+        g.clear()
+
+
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 

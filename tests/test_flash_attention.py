@@ -375,6 +375,12 @@ _MASKS = {
     "padded": ((False, None, 200), 256),
     "causal_padded": ((True, None, 200), 256),
     "unmasked": ((False, None), 256),
+    # a causal band of W keys (ISSUE 51): under, equal to and over the
+    # sub-tiles the cases below cut it into, and beside padded keys
+    "window_24": ((False, None, None, 24), 256),
+    "window_64": ((True, None, None, 64), 256),
+    "window_100": ((False, None, None, 100), 256),
+    "window_padded": ((True, None, 200, 48), 256),
 }
 
 
@@ -703,3 +709,146 @@ def test_the_fused_share_counts_plans(monkeypatch):
     pa._plan.cache_clear()
     ti.attention_fused_backward_share.clear()
     ti.attention_maskfree_share.clear()
+
+
+# -- the band, the kernels' fourth static mask (ISSUE 51) ---------------------
+
+def test_the_bands_rule_is_two_thresholds_on_a_third_query_code():
+    """keep[i, j] = (j <= i) & (j > i - W): the dense rule against the
+    codes, whatever ``causal`` says; a window over the sequence is the
+    causal mask; with block diffusion it raises."""
+    i, j = onp.arange(48)[:, None], onp.arange(48)[None, :]
+    for causal in (False, True):
+        qc, kc = pa._mask_codes(causal, None, 48, window=10)
+        assert qc.shape == (48, 3) and kc.shape == (2, 48)
+        assert onp.array_equal(pa._keep(qc, kc), (j <= i) & (i - j < 10))
+    assert onp.array_equal(pa._keep(*pa._mask_codes(False, None, 48,
+                                                    window=48)), j <= i)
+    assert pa._mask_codes(True, None, 48)[0].shape == (48, 2)
+    q = jnp.zeros((1, 1, 48, 8))
+    for fn in (pa.attention_reference,
+               lambda *a, **kw: pa.flash_attention(*a, interpret=True, **kw)):
+        with pytest.raises(ValueError, match="window and block_diffusion"):
+            fn(q, q, q, window=8, block_diffusion=(4, 24))
+    with pytest.raises(ValueError, match="window=0"):
+        pa._mask_codes(False, None, 48, window=0)
+
+
+def test_classes_are_exact_on_a_band_counted_by_hand():
+    """256 positions in 64 x 64 sub-tiles under a window of 128: q tile t
+    meets sub-tile t - 2 half dead (its first key is in no query's
+    window), t - 1 whole, t on the diagonal — 6 masked, 3 mask-free, 7
+    dead; the schedule visits the 9 and the gauge pair reads their pairs
+    beside the band's W (W + 1) / 2 + (S - W) W."""
+    from mxnet_tpu.telemetry import instruments as ti
+
+    codes = pa._mask_codes(True, None, 256, window=128)
+    classes = pa._classes(codes, 256, 64, 64)
+    m, f, d = pa._MASKED, pa._FREE, pa._DEAD
+    assert classes.tolist() == [[m, d, d, d], [f, m, d, d], [m, f, m, d],
+                                [d, m, f, m]]
+    assert [(classes == c).sum() for c in (m, f, d)] == [6, 3, 7]
+    words = pa._span_schedule(classes, 2)
+    # q tiles 0 and 1 take one span, 2 and 3 two: the dead sub-tile of a
+    # visited span is skipped by its class, a dead span is not in the grid
+    assert (words >> 20).tolist() == [0, 1, 2, 2, 3, 3]
+    assert pa._word_classes(words, 2).tolist() == [
+        [m, d], [f, m], [m, f], [m, d], [d, m], [f, m]]
+    # by key: k tile t is met by q sub-tiles t, t + 1, t + 2
+    assert onp.array_equal(pa._classes(codes, 256, 64, 64).T,
+                           classes.T)
+    _forget_plans()
+    for g in (ti.attention_pairs_visited, ti.attention_pairs_kept):
+        g.clear()
+    shape = (1, 1, 256, 16)
+    pa._plan(shape, shape, shape, "float32", True, 64, 64, None, None, 128)
+    pa._plan(shape, shape, shape, "float32", True, 64, 64, None, None)
+    read = lambda g: {k[0]: c.value for k, c in g.series()}  # noqa: E731
+    assert read(ti.attention_pairs_visited) == {"window": 9 * 64 * 64,
+                                                "causal": 10 * 64 * 64}
+    assert read(ti.attention_pairs_kept) == {
+        "window": 128 * 129 // 2 + 128 * 128, "causal": 256 * 257 // 2}
+    _forget_plans()
+    for g in (ti.attention_pairs_visited, ti.attention_pairs_kept):
+        g.clear()
+
+
+def test_the_cells_band_visits_three_sub_tiles_for_the_two_it_needs():
+    """16,384 positions, a window of 2,048, the chosen tile of 1,024 (the
+    Trinity-Mini cell's sliding layers): 45 of 256 sub-tiles visited, 15
+    of them mask-free, 1.5 pairs visited a pair kept; the fused backward
+    holds a head's keys and values at 128 / 128 bf16."""
+    shape, kv = (1, 32, 16384, 128), (1, 4, 16384, 128)
+    _forget_plans()
+    plan = pa._plan(shape, kv, kv, "bfloat16", True, 1024, 1024, None, None,
+                    2048)
+    assert plan.mask == "window" and plan.rows[:3] == (1024, 1024, 2)
+    classes = pa._word_classes(plan.rows.words, 2)
+    assert (classes != pa._DEAD).sum() == 45
+    assert (classes == pa._FREE).sum() == 15
+    kept = pa._pairs_kept(plan.codes)
+    assert kept == 2048 * 2049 // 2 + (16384 - 2048) * 2048
+    assert pa._pairs_visited(plan.rows) / kept == pytest.approx(1.5, abs=1e-3)
+    assert plan.fused and plan.cols is None
+    full = pa._plan(shape, kv, kv, "bfloat16", True, 1024, 1024, None, None)
+    assert full.fused and pa._pairs_visited(full.rows) == 136 * 1024 ** 2
+    _forget_plans()
+
+
+@pytest.mark.parametrize("case,s_len,window,blocks,heads", [
+    ("under_the_tile", 128, 8, (32, 16), (4, 2)),
+    ("the_sub_tile", 128, 16, (32, 16), (4, 2)),
+    ("over_the_tile", 128, 40, (32, 16), (4, 2)),
+    ("not_a_divisor_padded", 120, 48, (32, 16), (4, 2)),
+    ("chosen_tile", 128, 64, None, (4, 2)),
+    ("grouped_8_to_1", 128, 24, (64, 32), (8, 1)),
+], ids=lambda c: c if isinstance(c, str) else None)
+def test_window_kernels_match_the_reference(case, s_len, window, blocks,
+                                            heads, backward_path):
+    """Forward and all three gradients under a band, interpreted, against
+    the plain reference: W under, equal to and over the sub-tile, a
+    sequence that W does not divide (padded to its tile), the tile the op
+    chooses, eight query heads to a key-value head; through the fused
+    backward and through the two kernels."""
+    h, kv = heads
+    rs = onp.random.RandomState(7)
+    q, k, v, w = (jnp.asarray(rs.randn(1, n, s_len, 16).astype("f")) * 0.5
+                  for n in (h, kv, kv, h))
+    tiles = {} if blocks is None else dict(block_q=blocks[0],
+                                           block_k=blocks[1])
+
+    def kernel(q, k, v):
+        return pa.flash_attention(q, k, v, interpret=True, window=window,
+                                  **tiles)
+
+    def plain(q, k, v):
+        return pa.attention_reference(q, k, v, window=window)
+
+    # the reference against the rule itself, once
+    i, j = onp.arange(s_len)[:, None], onp.arange(s_len)[None, :]
+    scores = onp.einsum("bhqd,bhkd->bhqk", q, onp.repeat(k, h // kv, 1)) / 4
+    scores = onp.where((j <= i) & (i - j < window), scores, -onp.inf)
+    p = onp.exp(scores - scores.max(-1, keepdims=True))
+    dense = onp.einsum("bhqk,bhkd->bhqd", p / p.sum(-1, keepdims=True),
+                       onp.repeat(v, h // kv, 1))
+    onp.testing.assert_allclose(plain(q, k, v), dense, rtol=1e-4, atol=1e-5)
+    onp.testing.assert_allclose(kernel(q, k, v), plain(q, k, v),
+                                rtol=1e-5, atol=2e-6)
+    got = jax.grad(lambda *a: (kernel(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, "qkv"):
+        onp.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5,
+                                    err_msg="d" + name)
+
+
+def test_a_band_over_the_sequence_is_the_causal_kernel(backward_path):
+    """W >= S keeps what the causal mask keeps: the same classes, the
+    same results."""
+    q, k, v = _qkv(1, 2, 128, 16, seed=9)
+    a = pa.flash_attention(q, k, v, interpret=True, window=128, block_q=32,
+                           block_k=32)
+    b = pa.flash_attention(q, k, v, interpret=True, causal=True, block_q=32,
+                           block_k=32)
+    onp.testing.assert_array_equal(a, b)
+    codes = [pa._mask_codes(True, None, 128, window=w) for w in (128, None)]
+    assert onp.array_equal(*(pa._classes(c, 128, 32, 32) for c in codes))

@@ -328,3 +328,49 @@ def test_a_parents_checkpoint_loads(tmp_path):
         onp.testing.assert_array_equal(
             fresh.collect_params()[name].data().asnumpy(),
             p.data().asnumpy())
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("d,heads,s_len,tile", [
+    (128, 8, 32, None), (128, 2, 40, 16), (64, 8, 32, None),
+    (64, 2, 40, 16)],
+    ids=["d128", "d128-edge", "d64", "d64-edge"])
+def test_the_position_free_form_matches_the_composition(mode, monkeypatch,
+                                                        dtype, d, heads,
+                                                        s_len, tile):
+    """``positions=None`` (a layer that carries no positions): the norm
+    and the head-major store alone, the rotation compiled out and no
+    table among the kernels' operands; forward, dx and dgamma.  Both
+    round once, so they agree to float32 rounding in either type; the
+    site counts in the gauge like any other."""
+    if tile:
+        monkeypatch.setattr(qp, "_MAX_ROWS", tile)
+    x, gamma, weight = _operands(2, s_len, heads, d, dtype)
+    mode(True)
+    got = _value_and_grads(qp.rms_norm_rotary, x, gamma, None, heads, weight)
+    want = _value_and_grads(qp._composition, x, gamma, None, heads, weight)
+    assert ti.qk_prep_kernel_share.value == 1.0
+    assert got[0].shape == (2, heads, s_len, d) and got[0].dtype == x.dtype
+    assert got[1].shape == x.shape and got[2].shape == (d,)
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+    for g, w in zip(got, want):
+        g, w = onp.asarray(g, "f"), onp.asarray(w, "f")
+        onp.testing.assert_allclose(g / onp.abs(w).max(),
+                                    w / onp.abs(w).max(), atol=tol)
+
+
+def test_the_position_free_form_is_the_unrotated_norm(mode):
+    """Against the formula itself, and off the kernels too: with
+    ``positions=None`` the result is rms_norm of each head, head-major;
+    neither a norm nor positions is refused."""
+    x, gamma, _ = _operands(1, 16, 2, 128, "float32")
+    heads = onp.asarray(x).reshape(1, 16, 2, 128)
+    want = (heads / onp.sqrt((heads ** 2).mean(-1, keepdims=True) + EPS)
+            * onp.asarray(gamma)).transpose(0, 2, 1, 3)
+    for value in (True, None):
+        mode(value)
+        got = qp.rms_norm_rotary(x, gamma, None, THETA, 2, EPS)
+        onp.testing.assert_allclose(onp.asarray(got), want, rtol=2e-5,
+                                    atol=2e-6)
+    with pytest.raises(ValueError, match="neither"):
+        qp.rms_norm_rotary(x, None, None, THETA, 2, EPS)
