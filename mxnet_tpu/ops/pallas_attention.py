@@ -7,7 +7,9 @@ the fused attention the reference lacked (its transformer era predated it);
 TPU design per /opt/skills/guides/pallas_guide.md — a q tile stays resident
 in VMEM, spans of k/v sub-tiles stream through the grid's inner dimension
 (the span schedule: `_span_schedule`, `_walk`), the MXU sees (rows, d) x
-(d, sub-tile) matmuls, and the online-softmax running max / sum live in
+(d, sub-tile) matmuls — (rows, d) x (d, half a sub-tile) inside a sub-tile
+the mask cuts, which is walked in quarters so that its dead quarter is
+never computed — and the online-softmax running max / sum live in
 VMEM scratch across the inner grid steps.  The op reads its tiles off the
 shapes (`_choose_tile`) and builds the plan of a signature once (`_plan`).
 
@@ -184,7 +186,10 @@ def _keep(qc, kc, use_eq=True):
 
 # a sub-tile's class in the span schedule: what the mask keeps of its pairs
 _DEAD, _FREE, _MASKED = 0, 1, 2   # nothing (skipped), all (no mask), some
-_MAX_SPAN = 4                     # sub-tiles a schedule word has class bits for
+# sub-tiles a schedule word has class bits for: 8 bits, two a sub-tile.  Which
+# quarters of a masked sub-tile are live does not fit beside them: a bit a
+# quarter rides in a second int32 a step (`_quarter_words`)
+_MAX_SPAN = 4
 
 
 def _classes(codes, s_len, unit_q, unit_k):
@@ -240,8 +245,11 @@ def _span_schedule(classes, span, by_key=False):
     the classes of the block's sub-tiles, two bits each, bit 1 is set on
     the first step of its tile and bit 0 on the last.  A block the mask
     empties is not in the list, so the grid never visits it; a tile the
-    mask empties keeps one sub-tile as ``_MASKED`` (which computes zeros),
-    so every output block is written."""
+    mask empties keeps one sub-tile as ``_MASKED`` (which adds nothing:
+    zeros, or no live quarter at all), so every output block is written.
+    What a ``_MASKED`` sub-tile holds at the size of a body — which of
+    its quarters are dead — is not in these words but in a second one a
+    step (`_quarter_words`); the steps are the same with or without it."""
     import numpy as onp
 
     n_tiles, n_sub = classes.shape
@@ -269,6 +277,32 @@ def _word_classes(words, span):
     return onp.stack([(words >> (2 + 2 * j)) & 3 for j in range(span)], 1)
 
 
+def _quarter_words(words, span, classes, n_chunks, n_keys, by_key):
+    """One int32 a grid step beside its schedule word: which quarters of
+    the step's ``_MASKED`` sub-tiles hold a kept pair.  ``classes`` is the
+    mask classified a second time (`_classes`), at a chunk of query rows by
+    a body's keys; a sub-tile holds ``n_chunks`` x ``n_keys`` such
+    quarters, and quarter (chunk c, keys h) of sub-tile j has bit
+    (j * n_chunks + c) * n_keys + h, so the quarters of one chunk of rows
+    are ``n_keys`` neighbouring bits.  Sub-tiles of another class leave
+    their bits 0: nothing reads them."""
+    import numpy as onp
+
+    qi, kj = (x.astype(onp.int64) for x in _tiles_of(words))
+    j, c, h = (onp.arange(n).reshape(shape) for n, shape in (
+        (span, (1, -1, 1, 1)), (n_chunks, (1, 1, -1, 1)),
+        (n_keys, (1, 1, 1, -1))))
+    qi, kj = qi.reshape(-1, 1, 1, 1), kj.reshape(-1, 1, 1, 1)
+    if by_key:      # a k tile and a span of q sub-tiles
+        rows, cols = (qi * span + j) * n_chunks + c, kj * n_keys + h
+    else:           # a q tile and a span of k sub-tiles
+        rows, cols = qi * n_chunks + c, (kj * span + j) * n_keys + h
+    masked = (_word_classes(words, span) == _MASKED)[:, :, None, None]
+    live = (masked & (classes[rows, cols] != _DEAD)).astype(onp.int64)
+    shift = (j * n_chunks + c) * n_keys + h
+    return (live << shift).sum((1, 2, 3)).astype(onp.uint32).view(onp.int32)
+
+
 def _head_div(bh, group):
     """Row of the key-value head that query-head row ``bh`` reads."""
     return bh if group == 1 else jax.lax.div(bh, jnp.int32(group))
@@ -279,36 +313,76 @@ def _tiles_of(e):
     return e >> 20, (e >> 10) & 0x3FF
 
 
-def _walk(word, span, rows, chunk, classes, body):
-    """``body(j, r0, masked)`` on every chunk of ``chunk`` query rows
-    (the first is row ``r0`` of ``rows``) of every live sub-tile ``j`` of
-    a grid step's span, in order.  Rolled loops:
-    an iteration of the outer one reads its sub-tile's class off the
-    schedule word and takes the mask-free or the masked body, whose own
-    loop walks the chunks; so a kernel traces each body once whatever
-    the tile and the span, only the bodies its schedule uses
-    (``classes``), and the device code of a body is a chunk's."""
+def _first(i, unit):
+    """The first row of unit ``i`` of ``unit`` rows: aligned, as the
+    compiler is told where ``i`` is a loop's."""
     import jax.experimental.pallas as pl
 
-    def chunks(j, masked):
-        if chunk == rows:
-            body(j, 0, masked)
+    return i * unit if isinstance(i, int) else pl.multiple_of(i * unit, unit)
+
+
+def _walk(word, quarters, side, body):
+    """``body(j, r0, k0, keys, masked)`` on what a grid step's span keeps
+    of the mask, in order: ``side.chunk`` query rows from row ``r0`` of
+    sub-tile ``j``'s rows against ``keys`` of its keys from key ``k0``.
+    Rolled loops: an iteration of the outer one reads its sub-tile's class
+    off the schedule word and takes the mask-free or the masked body on
+    all the sub-tile's keys, chunk of rows by chunk.  Where the plan cuts
+    masked sub-tiles in quarters (``side.keys`` under a sub-tile's keys),
+    a chunk of rows of a masked sub-tile reads off ``quarters``
+    (`_quarter_words`) which of its quarters are live: all of them, and it
+    takes the masked body on all the keys as before; else the masked body
+    on each live quarter alone — a dead quarter is never computed.  (A
+    body costs the kernels about a microsecond beside its products, so a
+    row of live quarters is one body, not two.)  A kernel traces a body
+    once for each size and class its plan uses, whatever the tile and the
+    span, and the device code of a body is a chunk's."""
+    import jax.experimental.pallas as pl
+
+    span, chunk, keys = side.span, side.chunk, side.keys
+    rows, cols = _extent(side)
+    n_chunks, n_keys = rows // chunk, cols // keys
+
+    def each(n, fn):
+        if n == 1:
+            fn(0)
         else:
-            jax.lax.fori_loop(
-                0, rows // chunk, lambda c, _: (body(
-                    j, pl.multiple_of(c * chunk, chunk), masked), _)[1], 0)
+            jax.lax.fori_loop(0, n, lambda i, c: (fn(i), c)[1], 0)
+
+    def whole(j, masked):
+        each(n_chunks, lambda c: body(j, _first(c, chunk), 0, cols, masked))
+
+    def quartered(j):
+        full = (1 << n_keys) - 1
+
+        def row(c):
+            r0 = _first(c, chunk)
+            live = (quarters >> ((j * n_chunks + c) * n_keys)) & full
+            if side.whole_rows:
+                pl.when(live == full)(
+                    functools.partial(body, j, r0, 0, cols, True))
+            each(n_keys, lambda h: pl.when(
+                (live != full) & (((live >> h) & 1) != 0))(
+                    functools.partial(body, j, r0, _first(h, keys), keys,
+                                      True)))
+
+        each(n_chunks, row)
+
+    def run(c, j):
+        if c == _MASKED and n_keys > 1:
+            quartered(j)
+        else:
+            whole(j, c == _MASKED)
 
     def visit(j):
         cls = (word >> (2 + 2 * j)) & 3
-        for c in classes:
-            pl.when(cls == c)(functools.partial(chunks, j, c == _MASKED))
+        for c in side.classes:
+            pl.when(cls == c)(functools.partial(run, c, j))
 
-    if span > 1:
-        jax.lax.fori_loop(0, span, lambda j, c: (visit(j), c)[1], 0)
-    elif len(classes) > 1:
-        visit(0)
+    if span > 1 or len(side.classes) > 1:
+        each(span, visit)
     else:       # one sub-tile a step, one class: every listed step is live
-        chunks(0, classes[0] == _MASKED)
+        run(side.classes[0], 0)
 
 
 def attention_reference(q, k, v, causal=False, scale=None,
@@ -362,15 +436,16 @@ def _pair_keep(seed_ref, bh, q_start, k_start, shape, dropout_p):
     return _dropout_keep(seed_ref[0], bh, q_pos, k_pos, dropout_p)
 
 
-def _fwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref, kc_ref,
-                o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                scale, classes, use_eq, tile, sub, span, chunk,
-                dropout_p=0.0):
+def _fwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref,
+                kc_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                scale, side, use_eq, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
+    tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
     # hoisted: program_id inside pl.when bodies breaks interpret mode
     bh_idx = pl.program_id(0)
     word = sched_ref[pl.program_id(1)]
+    quarters = quart_ref[pl.program_id(1)]
     q_idx, k_blk = _tiles_of(word)
 
     @pl.when((word & 2) != 0)
@@ -379,11 +454,11 @@ def _fwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref, kc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def sub_tile(j, r0, masked):
-        rows = pl.ds(r0, chunk)
-        v = v_ref[0, j]
-        s = _scores(q_ref[0, 0, rows], k_ref[0, j], qc_ref[0, rows],
-                    kc_ref[j], scale, masked, use_eq)  # (chunk, sub)
+    def sub_tile(j, r0, k0, keys, masked):
+        rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
+        v = v_ref[0, j, cols]
+        s = _scores(q_ref[0, 0, rows], k_ref[0, j, cols], qc_ref[0, rows],
+                    kc_ref[j, :, cols], scale, masked, use_eq)  # (chunk, keys)
         m_prev = m_ref[rows]                           # (chunk, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a row with nothing live so far (a sub-tile the mask half
@@ -399,14 +474,15 @@ def _fwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref, kc_ref,
         # stays un-dropped): out = sum M.p.v / (l.(1-p)) as FlashAttention
         if dropout_p > 0.0:
             keep = _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
-                              (k_blk * span + j) * sub, p.shape, dropout_p)
+                              (k_blk * span + j) * sub + k0, p.shape,
+                              dropout_p)
             p = jnp.where(keep, p, 0.0)
         acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[rows] = m_new
 
-    _walk(word, span, tile, chunk, classes, sub_tile)
+    _walk(word, quarters, side, sub_tile)
 
     @pl.when((word & 1) != 0)
     def _finish():
@@ -453,7 +529,9 @@ def _chunk_of(rows):
 def _working_set(tile, sub, span, dk, dv, itemsize, resident=0):
     """Bytes of fast memory a grid step plans for: three float32
     temporaries of a chunk of rows by a tile of columns (scores,
-    probabilities, their gradient), the resident rows and the streamed
+    probabilities, their gradient: the mask-free sub-tile's body, the
+    largest — a quarter of a masked sub-tile takes half the columns),
+    the resident rows and the streamed
     span of both widths, double-buffered, and the float32 accumulators.
     With ``resident`` key positions held for a whole head (the fused
     backward, which streams no span): K, V, dK and dV of all of them,
@@ -516,8 +594,31 @@ def _choose_tile(s_pad, dk, dv, itemsize):
 
 # one kernel's half of a plan: the resident tile's rows, a streamed
 # sub-tile's, the sub-tiles a grid step streams, the schedule, the classes
-# its steps use and the query rows of one pass of a body
-_Side = collections.namedtuple("_Side", "tile sub span words classes chunk")
+# its steps use, the query rows of one pass of a body, whether the resident
+# tile is the keys'; then the keys of a quarter of a masked sub-tile (all
+# the sub-tile's where the walk does not cut it), which quarters are live,
+# one word a step (`_quarter_words`), and whether some chunk of rows of a
+# masked sub-tile has every quarter live
+_Side = collections.namedtuple(
+    "_Side",
+    "tile sub span words classes chunk by_key keys quarters whole_rows")
+
+
+def _extent(side):
+    """(query rows, keys) of one sub-tile of a side's schedule."""
+    return (side.sub, side.tile) if side.by_key else (side.tile, side.sub)
+
+
+def _quarters_of(side):
+    """(steps, span, chunks, key pieces) booleans: a side's live quarters,
+    from `_quarter_words`' bits."""
+    import numpy as onp
+
+    rows, cols = _extent(side)
+    shape = side.span, rows // side.chunk, cols // side.keys
+    bits = side.quarters.view(onp.uint32)[:, None] >> onp.arange(
+        math.prod(shape)) & 1
+    return bits.reshape((-1,) + shape).astype(bool)
 
 
 def _maskfree_share(side):
@@ -528,10 +629,14 @@ def _maskfree_share(side):
 
 
 def _pairs_visited(side):
-    """Query-key pairs of one head in the sub-tiles a side's schedule
-    visits (every class but dead)."""
-    live = int((_word_classes(side.words, side.span) != _DEAD).sum())
-    return live * side.tile * side.sub
+    """Query-key pairs of one head that a side's kernels compute: the
+    sub-tiles its schedule visits (every class but dead), less the dead
+    quarters of the masked ones where the walk cuts them."""
+    classes = _word_classes(side.words, side.span)
+    if side.keys == _extent(side)[1]:
+        return int((classes != _DEAD).sum()) * side.tile * side.sub
+    return (int((classes == _FREE).sum()) * side.tile * side.sub
+            + int(_quarters_of(side).sum()) * side.chunk * side.keys)
 
 
 def _pairs_kept(codes):
@@ -560,6 +665,12 @@ class _Plan:
     and the backward's memory plan.  The forward, dQ and the fused
     backward hold a q tile and walk spans of k sub-tiles (``rows``);
     dK/dV holds a k tile and streams spans of q sub-tiles (``cols``).
+    A side whose masked sub-tiles hold whole quarters of `_ROWS` query
+    rows by `_ROWS` keys (`_side`: a sub-tile of a multiple of 2 x
+    `_ROWS` keys, rows in chunks of `_ROWS`), one of them dead, also
+    carries the mask classified at that size, one word a grid step
+    (``quarters``: a bit a live quarter): its kernels never compute a
+    masked sub-tile's dead quarters (`_walk`; ``keys`` is a quarter's).
     ``block_q`` is the q tile and the q sub-tile, ``block_k`` the k tile
     and the k sub-tile.  ``vmem_limit`` is the fast memory the fused
     backward is given, or None where a head's keys, values and their
@@ -606,15 +717,30 @@ class _Plan:
     def _side(self, codes, tile, sub, itemsize, by_key=False):
         import numpy as onp
 
+        def used(classes):
+            return tuple(int(c) for c in onp.unique(classes) if c != _DEAD)
+
         span = _span_for(self.s_len, tile, sub, self.dk, self.dv, itemsize)
-        classes = _classes(codes, self.s_len, *(
-            (sub, tile) if by_key else (tile, sub)))
+        rows, cols = (sub, tile) if by_key else (tile, sub)
+        classes = _classes(codes, self.s_len, rows, cols)
         words = _span_schedule(classes.T if by_key else classes, span,
                                by_key)
-        used = onp.unique(_word_classes(words, span))
-        return _Side(tile, sub, span, words,
-                     tuple(int(c) for c in used if c != _DEAD),
-                     _chunk_of(sub if by_key else tile))
+        chunk = _chunk_of(rows)
+        side = _Side(tile, sub, span, words, used(_word_classes(words, span)),
+                     chunk, by_key, cols, onp.zeros_like(words), False)
+        # a masked sub-tile in quarters of a chunk by `_ROWS` keys, where
+        # it holds whole ones, a step's fit a word and one of them is dead
+        n_chunks, n_keys = rows // chunk, cols // _ROWS
+        if (_MASKED in side.classes and chunk == _ROWS
+                and cols % (2 * _ROWS) == 0
+                and span * n_chunks * n_keys <= 32):
+            cut = side._replace(keys=_ROWS, quarters=_quarter_words(
+                words, span, _classes(codes, self.s_len, _ROWS, _ROWS),
+                n_chunks, n_keys, by_key))
+            live = _quarters_of(cut)[_word_classes(words, span) == _MASKED]
+            if not live.all():
+                side = cut._replace(whole_rows=bool(live.all(-1).any()))
+        return side
 
     def units(self, x, unit, kv=False):
         """(heads, units, rows of a unit, width): a tensor over the
@@ -634,16 +760,16 @@ class _Plan:
         width, q codes, k codes, q-indexed column).  The resident side's
         block is one tile, the streamed side's a span of sub-tiles;
         ``head_of(*grid indices)`` gives (query head row, key-value head
-        row).  An index map gets the grid indices, then the two
-        prefetched scalar operands (dropout seed, schedule).  A column
+        row).  An index map gets the grid indices, then the three
+        prefetched scalar operands (dropout seed, the quarters' words,
+        the schedule).  A column
         (lse, delta) stays (heads, S, 1), its block the step's rows: it
         is padded to a lane width in memory, and cutting it like the
         others is a relayout XLA spends megabytes of code on."""
         import jax.experimental.pallas as pl
 
         tile, sub, span = side[:3]
-        by_key = side is self.cols
-        q_rows, k_rows = ((span, sub), (1, tile)) if by_key else (
+        q_rows, k_rows = ((span, sub), (1, tile)) if side.by_key else (
             (1, tile), (span, sub))
 
         def q_of(a):
@@ -655,12 +781,12 @@ class _Plan:
         def q_block(width):
             return pl.BlockSpec(
                 (1,) + q_rows + (width,),
-                lambda *a: (head_of(*a[:-2])[0], q_of(a), 0, 0))
+                lambda *a: (head_of(*a[:-3])[0], q_of(a), 0, 0))
 
         def k_block(width):
             return pl.BlockSpec(
                 (1,) + k_rows + (width,),
-                lambda *a: (head_of(*a[:-2])[1], k_of(a), 0, 0))
+                lambda *a: (head_of(*a[:-3])[1], k_of(a), 0, 0))
 
         return (q_block, k_block,
                 pl.BlockSpec(q_rows + (self.codes[0].shape[-1],),
@@ -669,7 +795,7 @@ class _Plan:
                              lambda *a: (k_of(a), 0, 0)),
                 pl.BlockSpec(
                     (1, q_rows[0] * q_rows[1], 1),
-                    lambda *a: (head_of(*a[:-2])[0], q_of(a), 0)))
+                    lambda *a: (head_of(*a[:-3])[0], q_of(a), 0)))
 
     def head_block(self, sub, width):
         """BlockSpec of a key-value head's WHOLE sequence in sub-tiles of
@@ -685,13 +811,11 @@ class _Plan:
         from jax.experimental.pallas import tpu as pltpu
 
         return _pallas_call(
-            functools.partial(
-                kernel, classes=side.classes, use_eq=self.use_eq,
-                tile=side.tile, sub=side.sub, span=side.span,
-                chunk=side.chunk, **static),
+            functools.partial(kernel, side=side, use_eq=self.use_eq,
+                              **static),
             name=name, vmem_limit=vmem_limit,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+                num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=scratch),
             out_shape=out_shape, interpret=interpret)
 
@@ -762,8 +886,8 @@ def _flash_fwd_call(plan, scale, dropout_p, interpret, seed, q, k, v):
             _scratch((tile, dv)),  # output accumulator
         ],
         interpret=interpret, scale=scale, dropout_p=dropout_p,
-    )(seed, jnp.asarray(words), plan.units(q, tile),
-      plan.units(k, sub, True), plan.units(v, sub, True),
+    )(seed, jnp.asarray(plan.rows.quarters), jnp.asarray(words),
+      plan.units(q, tile), plan.units(k, sub, True), plan.units(v, sub, True),
       *plan.code_units(tile, sub))
     return out, lse
 
@@ -820,46 +944,49 @@ def _t_dot(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _bwd_dq_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, qc_ref, kc_ref, dq_ref, dq_acc, *,
-                   scale, classes, use_eq, tile, sub, span, chunk,
-                   dropout_p=0.0):
+def _bwd_dq_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
+                   do_ref, lse_ref, delta_ref, qc_ref, kc_ref, dq_ref,
+                   dq_acc, *, scale, side, use_eq, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
+    tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
     bh_idx = pl.program_id(0)
     word = sched_ref[pl.program_id(1)]
+    quarters = quart_ref[pl.program_id(1)]
     q_idx, k_blk = _tiles_of(word)
 
     @pl.when((word & 2) != 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def sub_tile(j, r0, masked):
-        rows = pl.ds(r0, chunk)
-        k = k_ref[0, j]
+    def sub_tile(j, r0, k0, keys, masked):
+        rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
+        k = k_ref[0, j, cols]
         _, _, ds = _p_ds(
-            q_ref[0, 0, rows], k, v_ref[0, j], do_ref[0, 0, rows],
+            q_ref[0, 0, rows], k, v_ref[0, j, cols], do_ref[0, 0, rows],
             lse_ref[0, rows], delta_ref[0, rows], qc_ref[0, rows],
-            kc_ref[j], scale, masked, use_eq, dropout_p,
+            kc_ref[j, :, cols], scale, masked, use_eq, dropout_p,
             lambda shape: _pair_keep(
                 seed_ref, bh_idx, q_idx * tile + r0,
-                (k_blk * span + j) * sub, shape, dropout_p))
+                (k_blk * span + j) * sub + k0, shape, dropout_p))
         dq_acc[rows] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _walk(word, span, tile, chunk, classes, sub_tile)
+    _walk(word, quarters, side, sub_tile)
 
     @pl.when((word & 1) != 0)
     def _finish():
         dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, qc_ref, kc_ref, dk_ref, dv_ref,
-                    dk_acc, dv_acc, *, scale, classes, use_eq, tile, sub,
-                    span, chunk, group, dropout_p=0.0):
+def _bwd_dkv_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
+                    do_ref, lse_ref, delta_ref, qc_ref, kc_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, scale, side, use_eq, group,
+                    dropout_p=0.0):
     import jax.experimental.pallas as pl
+
+    tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
 
     # grid: (key-value head, step of the k-major schedule, query head of
     # the group): the spans of q sub-tiles of one k tile and, inside
@@ -868,6 +995,7 @@ def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
     g_idx = pl.program_id(2)
     bh_idx = pl.program_id(0) * group + g_idx
     word = sched_ref[pl.program_id(1)]
+    quarters = quart_ref[pl.program_id(1)]
     q_blk, k_idx = _tiles_of(word)
 
     @pl.when(((word & 2) != 0) & (g_idx == 0))
@@ -875,22 +1003,22 @@ def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def sub_tile(j, r0, masked):
-        rows = pl.ds(r0, chunk)
+    def sub_tile(j, r0, k0, keys, masked):
+        rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
         # lse and delta: one column over the span, not cut into sub-tiles
         span_rows = pl.ds(pl.multiple_of(j * sub + r0, chunk), chunk)
         q, do = q_ref[0, j, rows], do_ref[0, j, rows]
         p, drop, ds = _p_ds(
-            q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, span_rows],
-            delta_ref[0, span_rows], qc_ref[j, rows], kc_ref[0], scale,
-            masked, use_eq, dropout_p,
+            q, k_ref[0, 0, cols], v_ref[0, 0, cols], do,
+            lse_ref[0, span_rows], delta_ref[0, span_rows], qc_ref[j, rows],
+            kc_ref[0, :, cols], scale, masked, use_eq, dropout_p,
             lambda shape: _pair_keep(
                 seed_ref, bh_idx, (q_blk * span + j) * sub + r0,
-                k_idx * tile, shape, dropout_p))         # (chunk, tile)
-        dv_acc[:] += _t_dot(drop(p).astype(do.dtype), do)
-        dk_acc[:] += _t_dot(ds.astype(q.dtype), q)
+                k_idx * tile + k0, shape, dropout_p))    # (chunk, keys)
+        dv_acc[cols] += _t_dot(drop(p).astype(do.dtype), do)
+        dk_acc[cols] += _t_dot(ds.astype(q.dtype), q)
 
-    _walk(word, span, sub, chunk, classes, sub_tile)
+    _walk(word, quarters, side, sub_tile)
 
     @pl.when(((word & 1) != 0) & (g_idx == group - 1))
     def _finish():
@@ -898,10 +1026,10 @@ def _bwd_dkv_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, qc_ref, kc_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-                dk_acc, dv_acc, *, scale, classes, use_eq, tile, sub, span,
-                chunk, group, dropout_p=0.0):
+def _bwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
+                lse_ref, delta_ref, qc_ref, kc_ref, dq_ref, dk_ref, dv_ref,
+                dq_acc, dk_acc, dv_acc, *, scale, side, use_eq, group,
+                dropout_p=0.0):
     """dQ, dK and dV from one computation of P, dP and dS a sub-tile.
 
     Grid: (key-value head, query head of its group, step of the q-major
@@ -913,9 +1041,10 @@ def _bwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     zeroed at the head's first step and stored at its last."""
     import jax.experimental.pallas as pl
 
+    tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
     g_idx, step = pl.program_id(1), pl.program_id(2)
     bh_idx = pl.program_id(0) * group + g_idx
-    word = sched_ref[step]
+    word, quarters = sched_ref[step], quart_ref[step]
     q_idx, k_blk = _tiles_of(word)
     n_sub = dk_acc.shape[0]
 
@@ -933,23 +1062,24 @@ def _bwd_kernel(seed_ref, sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def sub_tile(j, r0, masked):
-        rows = pl.ds(r0, chunk)
+    def sub_tile(j, r0, k0, keys, masked):
+        rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
         kj = k_blk * span + j                    # the sub-tile's own index
-        q, do, k = q_ref[0, 0, rows], do_ref[0, 0, rows], k_ref[0, kj]
+        q, do, k = q_ref[0, 0, rows], do_ref[0, 0, rows], k_ref[0, kj, cols]
         p, drop, ds = _p_ds(
-            q, k, v_ref[0, kj], do, lse_ref[0, rows], delta_ref[0, rows],
-            qc_ref[0, rows], kc_ref[j], scale, masked, use_eq, dropout_p,
+            q, k, v_ref[0, kj, cols], do, lse_ref[0, rows],
+            delta_ref[0, rows], qc_ref[0, rows], kc_ref[j, :, cols], scale,
+            masked, use_eq, dropout_p,
             lambda shape: _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
-                                     kj * sub, shape, dropout_p))
-        ds = ds.astype(k.dtype)                  # (chunk, sub), once
+                                     kj * sub + k0, shape, dropout_p))
+        ds = ds.astype(k.dtype)                  # (chunk, keys), once
         dq_acc[rows] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[kj] += _t_dot(ds, q)
-        dv_acc[kj] += _t_dot(drop(p).astype(do.dtype), do)
+        dk_acc[kj, cols] += _t_dot(ds, q)
+        dv_acc[kj, cols] += _t_dot(drop(p).astype(do.dtype), do)
 
-    _walk(word, span, tile, chunk, classes, sub_tile)
+    _walk(word, quarters, side, sub_tile)
 
     @pl.when((word & 1) != 0)
     def _finish():
@@ -978,9 +1108,9 @@ def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
     dq_shape = jax.ShapeDtypeStruct(
         (plan.bh, plan.s_len // tile, tile, dk_), q.dtype)
     k_units, v_units = plan.units(k, sub, True), plan.units(v, sub, True)
-    operands = (seed, jnp.asarray(words), plan.units(q, tile), k_units,
-                v_units, plan.units(g, tile), lse, delta,
-                *plan.code_units(tile, sub))
+    operands = (seed, jnp.asarray(plan.rows.quarters), jnp.asarray(words),
+                plan.units(q, tile), k_units, v_units, plan.units(g, tile),
+                lse, delta, *plan.code_units(tile, sub))
     if plan.fused:
         q_block, _, q_code, k_code, column = plan.specs(
             plan.rows, lambda b, j, t: (b * group + j, b), step_axis=2)
@@ -1028,7 +1158,8 @@ def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
         ],
         scratch=[_scratch((tile, dk_)), _scratch((tile, dv_))],
         interpret=interpret, scale=scale, dropout_p=dropout_p, group=group,
-    )(seed, jnp.asarray(words), plan.units(q, sub), plan.units(k, tile, True),
+    )(seed, jnp.asarray(plan.cols.quarters), jnp.asarray(words),
+      plan.units(q, sub), plan.units(k, tile, True),
       plan.units(v, tile, True), plan.units(g, sub), lse, delta,
       *plan.code_units(sub, tile))
     return dq, dk, dv
@@ -1140,14 +1271,24 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     mask-free (every pair kept: no codes read, no compare, no select) or
     masked.  The kernel walks the span with one rolled loop that takes
     the mask-free or the masked body by the class, so each body is traced
-    once a kernel whatever the span.  An unmasked call is the case where
-    every sub-tile is mask-free, padding one more masked class at the
-    edge.  The gauge ``attention_maskfree_share{kernel}`` is the share of
-    visited sub-tiles that take the mask-free body; the pair
+    once a kernel whatever the span.  A masked sub-tile of 1024 keys is
+    half dead or worse under every mask here, so it is walked in quarters
+    of 512 query rows by 512 keys, classified the same way a second time
+    (one more int32 a step, a bit a live quarter): a chunk of 512 rows
+    whose quarters are all live takes the masked body on all the keys, as
+    a sub-tile that is not cut does; a chunk with a dead quarter takes the
+    masked body on its live quarter alone — the diagonal's upper right
+    quarter, a band's lower left, all but the diagonal quarters of block
+    diffusion's noisy blocks are never computed.  Sub-tiles under 1024
+    keys (one-tile sequences, small named blocks) are computed whole.  An
+    unmasked call is the case where every sub-tile is mask-free, padding
+    one more masked class at the edge.  The gauge
+    ``attention_maskfree_share{kernel}`` is the share of the schedule's
+    visited sub-tiles that are whole; the pair
     ``attention_pairs_visited{mask}`` / ``attention_pairs_kept{mask}``
     gives, for the latest plan of each kind of mask, the pairs of one head
-    in the sub-tiles the forward's schedule visits and the pairs the mask
-    keeps.
+    that the forward computes (whole sub-tiles, and masked ones less their
+    dead quarters) and the pairs the mask keeps.
 
     **The backward's memory plan.**  One fused kernel,
     ``flash_attention_bwd``, where the working set of a key-value head

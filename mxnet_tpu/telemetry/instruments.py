@@ -187,12 +187,13 @@ attention_fused_backward_share = gauge(
     "when the plan of a signature is built (ops.pallas_attention._plan)")
 attention_pairs_visited = gauge(
     "attention_pairs_visited",
-    "Query-key pairs of one head in the sub-tiles the flash forward's span "
-    "schedule visits (every class but dead), for the latest plan of each "
-    "kind of mask: window, causal, block_diffusion, padding, none. Over "
-    "attention_pairs_kept it is the work the schedule spends per pair the "
-    "mathematics needs; set on the host when the plan of a signature is "
-    "built (ops.pallas_attention._plan)", ["mask"])
+    "Query-key pairs of one head that the flash forward computes: the "
+    "sub-tiles its span schedule visits (every class but dead), less the "
+    "dead quarters of the masked ones where the walk cuts them; for the "
+    "latest plan of each kind of mask: window, causal, block_diffusion, "
+    "padding, none. Over attention_pairs_kept it is the work the kernels "
+    "spend per pair the mathematics needs; set on the host when the plan "
+    "of a signature is built (ops.pallas_attention._plan)", ["mask"])
 attention_pairs_kept = gauge(
     "attention_pairs_kept",
     "Query-key pairs of one head that the static mask keeps, counted from "

@@ -883,7 +883,8 @@ def test_the_window_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
         ("window", "dense"): 1, ("global", "moe"): 1}
     visited, kept = ({k[0]: c.value for k, c in g.series()} for g in (
         ti.attention_pairs_visited, ti.attention_pairs_kept))
-    assert visited == {"window": 45 * 1024 ** 2, "causal": 136 * 1024 ** 2}
+    # computed: the masked sub-tiles less their dead quarters (ISSUE 52)
+    assert visited == {"window": 37.5 * 1024 ** 2, "causal": 132 * 1024 ** 2}
     assert kept == {"window": 31_458_304, "causal": 134_225_920}
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10 * 2 ** 30

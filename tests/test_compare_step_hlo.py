@@ -46,3 +46,23 @@ def test_another_instruction_is_a_difference(tool):
     a = TEXT.format(root="/root/repo", line=12, body="QUJD", op="add")
     b = TEXT.format(root="/root/repo", line=12, body="QUJD", op="ut_step/add")
     assert tool.outside_kernels(a) != tool.outside_kernels(b)
+
+
+def test_a_renumbering_alone_leaves_no_line_once_numbers_are_stripped(tool):
+    """One more operand of a kernel renumbers what follows it: line for
+    line most lines differ, as a multiset without instruction numbers
+    only the lines the change touched are left."""
+    a = ["  %constant.7 = s32[] constant(0)",
+         "  %add.12 = f32[8]{0} add(%p.1, %constant.7)",
+         "  %k = f32[8]{0} custom-call(%constant.7, %add.12)"]
+    b = ["  %constant.9 = s32[] constant(0)",
+         "  %constant.10 = s32[16]{0} constant({...})",
+         "  %add.15 = f32[8]{0} add(%p.1, %constant.9)",
+         "  %k = f32[8]{0} custom-call(%constant.9, %constant.10, %add.15)"]
+    assert sum(x != y for x, y in zip(a, b)) == 3
+    ua, ub = tool.unnumbered(a), tool.unnumbered(b)
+    assert sorted(ua - ub) == [
+        "  %k = f32[8]{0} custom-call(%constant, %add)"]
+    assert sorted(ub - ua) == [
+        "  %constant = s32[16]{0} constant({...})",
+        "  %k = f32[8]{0} custom-call(%constant, %constant, %add)"]

@@ -764,6 +764,7 @@ def test_classes_are_exact_on_a_band_counted_by_hand():
     pa._plan(shape, shape, shape, "float32", True, 64, 64, None, None, 128)
     pa._plan(shape, shape, shape, "float32", True, 64, 64, None, None)
     read = lambda g: {k[0]: c.value for k, c in g.series()}  # noqa: E731
+    # sub-tiles of 64 keys are not cut in quarters: computed whole
     assert read(ti.attention_pairs_visited) == {"window": 9 * 64 * 64,
                                                 "causal": 10 * 64 * 64}
     assert read(ti.attention_pairs_kept) == {
@@ -776,8 +777,10 @@ def test_classes_are_exact_on_a_band_counted_by_hand():
 def test_the_cells_band_visits_three_sub_tiles_for_the_two_it_needs():
     """16,384 positions, a window of 2,048, the chosen tile of 1,024 (the
     Trinity-Mini cell's sliding layers): 45 of 256 sub-tiles visited, 15
-    of them mask-free, 1.5 pairs visited a pair kept; the fused backward
-    holds a head's keys and values at 128 / 128 bf16."""
+    of them mask-free; the 30 masked ones are walked in quarters, of which
+    one is dead (ISSUE 52), so 37.5 sub-tiles' pairs are computed: 1.25
+    pairs a pair kept, where 1.5 are visited; the fused backward holds a
+    head's keys and values at 128 / 128 bf16."""
     shape, kv = (1, 32, 16384, 128), (1, 4, 16384, 128)
     _forget_plans()
     plan = pa._plan(shape, kv, kv, "bfloat16", True, 1024, 1024, None, None,
@@ -788,11 +791,227 @@ def test_the_cells_band_visits_three_sub_tiles_for_the_two_it_needs():
     assert (classes == pa._FREE).sum() == 15
     kept = pa._pairs_kept(plan.codes)
     assert kept == 2048 * 2049 // 2 + (16384 - 2048) * 2048
-    assert pa._pairs_visited(plan.rows) / kept == pytest.approx(1.5, abs=1e-3)
+    assert 45 * 1024 ** 2 / kept == pytest.approx(1.5, abs=1e-3)
+    assert pa._pairs_visited(plan.rows) == (15 + 30 * 3 / 4) * 1024 ** 2
+    assert pa._pairs_visited(plan.rows) / kept == pytest.approx(1.25,
+                                                                abs=1e-3)
     assert plan.fused and plan.cols is None
     full = pa._plan(shape, kv, kv, "bfloat16", True, 1024, 1024, None, None)
-    assert full.fused and pa._pairs_visited(full.rows) == 136 * 1024 ** 2
+    assert (pa._word_classes(full.rows.words, 2) != pa._DEAD).sum() == 136
+    assert full.fused and pa._pairs_visited(full.rows) == 132 * 1024 ** 2
     _forget_plans()
+
+
+# ---- a masked sub-tile walked in quarters (ISSUE 52) ---------------------
+
+@pytest.fixture
+def small_rows(monkeypatch):
+    """A body of 16 query rows by 16 keys, so that sub-tiles of 32 x 32 are
+    walked as the cells' 1024 x 1024 are at `_ROWS` = 512: two chunks by
+    two key halves.  Yields the plans built meanwhile."""
+    built, init = [], pa._Plan.__init__
+    monkeypatch.setattr(
+        pa._Plan, "__init__",
+        lambda self, *a: (init(self, *a), built.append(self))[0])
+    monkeypatch.setattr(pa, "_ROWS", 16)
+    _forget_plans()
+    yield built
+    _forget_plans()
+
+
+# (causal, block_diffusion, valid_len, window) over 128 positions
+_QUARTERED = {
+    "causal": (True, None, None, None),
+    "band_edge_and_diagonal": (False, None, None, 64),
+    "band_inside_a_quarter": (True, None, None, 40),
+    "block_diffusion": (False, (4, 64), None, None),
+    "padding": (False, None, 100, None),
+    "causal_padding": (True, None, 100, None),
+}
+
+
+@pytest.mark.parametrize("by_key", [False, True], ids=["q_major", "k_major"])
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32), (32, 64)],
+                         ids=lambda b: "%dx%d" % b)
+@pytest.mark.parametrize("mask", sorted(_QUARTERED))
+def test_quarter_bits_say_what_the_dense_mask_says(mask, blocks, by_key,
+                                                   small_rows, monkeypatch):
+    """Every quarter of every masked sub-tile of a plan's schedule against
+    the dense mask: its bit is set <=> a pair of it is kept, and the class
+    `_classes` gives it at the body's size says dead <=> none kept, whole
+    <=> all kept; sub-tiles of another class leave their bits 0; the pairs
+    the side counts as computed are those of its whole sub-tiles and live
+    quarters; and the walk is cut only where that skips something."""
+    causal, blockdiff, valid, window = _QUARTERED[mask]
+    monkeypatch.setattr(pa, "_vmem_capacity", lambda: 0)  # the by-key side
+    shape = (1, 2, 128, 16)
+    plan = pa._plan(shape, shape, shape, "float32", causal, *blocks, valid,
+                    blockdiff, window)
+    side = plan.cols if by_key else plan.rows
+    rows, cols = pa._extent(side)
+    dense = onp.asarray(pa._keep(*plan.codes, use_eq=True))
+    fine = pa._classes(plan.codes, 128, 16, 16)
+    cells = dense.reshape(8, 16, 8, 16)
+    assert onp.array_equal(fine == pa._DEAD, ~cells.any(axis=(1, 3)))
+    assert onp.array_equal(fine == pa._FREE, cells.all(axis=(1, 3)))
+    classes = pa._word_classes(side.words, side.span)
+    qi, kj = pa._tiles_of(side.words)
+
+    def cell(step, j):
+        r0, k0 = ((qi[step] * side.span + j) * rows, kj[step] * cols) \
+            if by_key else (qi[step] * rows,
+                            (kj[step] * side.span + j) * cols)
+        return dense[r0:r0 + rows, k0:k0 + cols]
+
+    masked = [cell(step, j).reshape(rows // 16, 16, -1, 16).any(axis=(1, 3))
+              for step, j in zip(*onp.nonzero(classes == pa._MASKED))]
+    if cols % 32 or all(live.all() for live in masked):
+        # a sub-tile's keys are one body's, or no quarter is dead: not cut
+        assert side.keys == cols and not side.whole_rows
+        assert not side.quarters.any()
+        assert pa._pairs_visited(side) == (classes != pa._DEAD).sum() \
+            * rows * cols
+        return
+    assert (side.chunk, side.keys) == (16, 16) and side.by_key == by_key
+    quarters = pa._quarters_of(side)
+    assert quarters.shape[1:] == (side.span, rows // 16, cols // 16)
+    assert not quarters[classes != pa._MASKED].any()
+    assert onp.array_equal(quarters[classes == pa._MASKED],
+                           onp.stack(masked))
+    assert side.whole_rows == any(live.all(-1).any() for live in masked)
+    computed = (classes == pa._FREE).sum() * rows * cols \
+        + 256 * sum(int(live.sum()) for live in masked)
+    assert pa._pairs_visited(side) == computed >= dense.sum()
+    assert computed < (classes != pa._DEAD).sum() * rows * cols
+
+
+_M, _F, _D = pa._MASKED, pa._FREE, pa._DEAD
+_DIAGONAL = ((_M, _D), (_F, _M))
+_BAND_EDGE = ((_M, _F), (_D, _M))
+_NOISY_DIAGONAL = ((_M, _D), (_D, _M))
+
+
+@pytest.mark.parametrize("case,s_len,mask,visited,computed,kinds", [
+    ("causal_8k", 8192, {"causal": True}, 36, 34, {_DIAGONAL: 8}),
+    ("causal_16k", 16384, {"causal": True}, 136, 132, {_DIAGONAL: 16}),
+    ("band_2k_over_16k", 16384, {"window": 2048}, 45, 37.5,
+     {_DIAGONAL: 16, _BAND_EDGE: 14}),
+    ("block_diffusion_8k", 8192, {"block_diffusion": (4, 4096)}, 24, 20,
+     {_NOISY_DIAGONAL: 4, _DIAGONAL: 8}),
+], ids=lambda c: c if isinstance(c, str) else None)
+def test_the_cells_masked_sub_tiles_hold_a_dead_quarter(case, s_len, mask,
+                                                        visited, computed,
+                                                        kinds):
+    """ISSUE 52's four tables at the cells' shapes (the chosen tile of
+    1024, a span of 2, `_ROWS` = 512): the sub-tiles a head's schedule
+    visits, the quarters [rows 0-511 / 512-1023] x [keys 0-511 / 512-1023]
+    of every masked one, and the sub-tiles' worth of pairs computed."""
+    import collections
+
+    _forget_plans()
+    shape, kv = (1, 32, s_len, 128), (1, 4, s_len, 128)
+    plan = pa._plan(shape, kv, kv, "bfloat16", mask.get("causal", False),
+                    1024, 1024, None, mask.get("block_diffusion"),
+                    mask.get("window"))
+    side = plan.rows
+    assert side[:3] == (1024, 1024, 2) and (side.chunk, side.keys) == (
+        512, 512)
+    classes = pa._word_classes(side.words, 2)
+    assert (classes != pa._DEAD).sum() == visited
+    # the second classification: `_classes` at the body's own size
+    fine = pa._classes(plan.codes, s_len, 512, 512)
+    qi, kj = pa._tiles_of(side.words)
+    found = collections.Counter()
+    for step, j in zip(*onp.nonzero(classes == pa._MASKED)):
+        r, c = 2 * qi[step], 2 * (2 * kj[step] + j)
+        quarters = fine[r:r + 2, c:c + 2]
+        found[tuple(map(tuple, quarters.tolist()))] += 1
+        # the kernels' word: a bit a live quarter
+        assert onp.array_equal(pa._quarters_of(side)[step, j],
+                               quarters != pa._DEAD)
+    assert found == kinds
+    # a row of live quarters takes one body on all the keys: every mask
+    # here has such rows beside the cut ones
+    assert side.whole_rows
+    assert pa._pairs_visited(side) == computed * 1024 ** 2
+    # the span schedule is the parent's: its share of whole sub-tiles too
+    assert pa._maskfree_share(side) == pytest.approx(
+        1 - sum(kinds.values()) / visited)
+    _forget_plans()
+
+
+@pytest.mark.parametrize("case,s_len,heads,mask,dropout", [
+    ("causal", 128, (4, 2), {"causal": True}, 0.0),
+    ("band_edge_and_diagonal", 128, (4, 2), {"window": 64}, 0.0),
+    ("band_inside_a_quarter", 128, (4, 2), {"window": 40}, 0.0),
+    ("block_diffusion", 128, (4, 2), {"block_diffusion": (4, 64)}, 0.0),
+    ("padding", 104, (4, 2), {}, 0.0),
+    ("causal_padding", 104, (4, 2), {"causal": True}, 0.0),
+    ("causal_dropout", 128, (4, 2), {"causal": True}, 0.3),
+    ("block_diffusion_dropout", 128, (2, 2), {"block_diffusion": (4, 64)},
+     0.2),
+    ("band_grouped_8_to_1", 128, (8, 1), {"window": 64}, 0.0),
+], ids=lambda c: c if isinstance(c, str) else None)
+def test_quartered_kernels_match_the_reference(case, s_len, heads, mask,
+                                               dropout, small_rows,
+                                               backward_path):
+    """Forward and all three gradients through the quartered walk (tiles
+    and sub-tiles of 32, a span of 2, quarters of 16 x 16), interpreted,
+    against the plain reference: every mask, padded keys, dropout (the
+    quarter's key offset enters the keep mask), grouped heads; through the
+    fused backward and through the two kernels."""
+    h, kv = heads
+    rs = onp.random.RandomState(11)
+    q, k, v, w = (jnp.asarray(rs.randn(1, n, s_len, 16).astype("f")) * 0.5
+                  for n in (h, kv, kv, h))
+    extra = dict(mask, dropout_p=dropout, dropout_seed=jnp.int32(
+        77)) if dropout else mask
+
+    def kernel(q, k, v):
+        return pa.flash_attention(q, k, v, interpret=True, block_q=32,
+                                  block_k=32, **extra)
+
+    def plain(q, k, v):
+        return pa.attention_reference(q, k, v, **extra)
+
+    onp.testing.assert_allclose(kernel(q, k, v), plain(q, k, v),
+                                rtol=1e-5, atol=2e-6)
+    got = jax.grad(lambda *a: (kernel(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, "qkv"):
+        onp.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5,
+                                    err_msg="d" + name)
+    assert small_rows
+    for plan in small_rows:
+        sides = [plan.rows] + ([] if plan.fused else [plan.cols])
+        for side in sides:
+            assert side[:3] == (32, 32, 2) and side.keys == 16
+            live = pa._quarters_of(side)[
+                pa._word_classes(side.words, 2) == pa._MASKED]
+            assert not live.all() and live.any()
+            # both walks of a chunk of rows: all its keys, a quarter alone
+            assert side.whole_rows == bool(live.all(-1).any())
+
+
+def test_an_unmasked_call_and_one_tile_keep_the_whole_walk(small_rows):
+    """Nothing masked: no quarters, whatever the sizes.  A masked sub-tile
+    whose keys are one body's (a sequence of one tile of 16) is computed
+    whole, as before."""
+    shape = (1, 2, 128, 16)
+    free = pa._plan(shape, shape, shape, "float32", False, 32, 32, None,
+                    None)
+    assert free.rows.keys == 32 and not free.rows.whole_rows
+    assert not free.rows.quarters.any()
+    assert pa._pairs_visited(free.rows) == 128 * 128
+    one = pa._plan((1, 2, 16, 16), (1, 2, 16, 16), (1, 2, 16, 16), "float32",
+                   True, 16, 16, None, None)
+    assert one.rows.keys == 16 and not one.rows.whole_rows
+    assert pa._pairs_visited(one.rows) == 16 * 16
+    q, k, v = _qkv(1, 2, 16, 16, seed=3)
+    onp.testing.assert_allclose(
+        flash_attention(q, k, v, causal=True, interpret=True, block_q=16,
+                        block_k=16),
+        attention_reference(q, k, v, causal=True), rtol=1e-5, atol=2e-6)
 
 
 @pytest.mark.parametrize("case,s_len,window,blocks,heads", [

@@ -9,6 +9,12 @@ the metadata's files and lines, and the debug locations inside each Mosaic
 kernel's serialised body.  So the text is compared line for line with the
 metadata's locations and the kernels' bodies cut out, and the kernels are
 compared as MLIR printed without debug information.  Exit code 0: equal.
+
+A change that gives a kernel one more operand renumbers every instruction
+after it, and then most lines differ by a number alone.  So the lines are
+also compared as a multiset with the instructions' numbers stripped
+(`%fusion.123` -> `%fusion`): what is left is what the change touched,
+printed with ``--show``.
 """
 import base64
 import collections
@@ -21,6 +27,7 @@ _LOCATION = re.compile(
 _TABLE = re.compile(
     r"(?ms)^(?:FileNames|FunctionNames|FileLocations|StackFrames).*?^\n")
 _BODY = re.compile(r'"body":"([^"]+)"')
+_NUMBER = re.compile(r"(%[A-Za-z_\-]+)(?:\.\d+)+")
 
 
 def outside_kernels(text):
@@ -46,7 +53,12 @@ def kernels(text):
     return out
 
 
-def main(a, b):
+def unnumbered(lines):
+    """The lines as a multiset, instruction numbers stripped."""
+    return collections.Counter(_NUMBER.sub(r"\1", line) for line in lines)
+
+
+def main(a, b, show=False):
     with open(a) as f, open(b) as g:
         ta, tb = f.read(), g.read()
     la, lb = outside_kernels(ta), outside_kernels(tb)
@@ -56,8 +68,17 @@ def main(a, b):
           f"{differing}; Mosaic calls {sum(ka.values())} / "
           f"{sum(kb.values())}, distinct kernels {len(ka)} / {len(kb)}, "
           f"{'equal' if ka == kb else 'DIFFERENT'}")
+    if differing:
+        ua, ub = unnumbered(la), unnumbered(lb)
+        only_a, only_b = ua - ub, ub - ua
+        print(f"numbers stripped: {sum(only_a.values())} lines only in the "
+              f"first, {sum(only_b.values())} only in the second")
+        for mark, lines in (("<", only_a), (">", only_b)) if show else ():
+            for line, n in sorted(lines.items()):
+                print(f"{mark} x{n} {line.strip()[:240]}")
     return int(bool(differing) or ka != kb)
 
 
 if __name__ == "__main__":
-    sys.exit(main(*sys.argv[1:3]))
+    files = [x for x in sys.argv[1:] if x != "--show"]
+    sys.exit(main(*files[:2], show="--show" in sys.argv))
