@@ -17,7 +17,15 @@ shapes (`_choose_tile`) and builds the plan of a signature once (`_plan`).
 Pallas backward (FlashAttention-2): the forward saves only (out, lse);
 backward recomputes P tiles per block from (q, k, lse), so training is
 O(S) memory end to end, and delta = rowsum(dO*O) supplies the softmax
-correction.  The backward is ONE kernel (`_bwd_kernel`) wherever a head's
+correction.  No per-row statistic is a (..., S, 1) column in HBM, where
+the tiling of the last two dimensions pads it 128-fold: the forward turns
+a q tile's finished lse into rows of a lane width once (`_store_lse`) and
+stores those (`_Plan.stat_shape`: dense at a tile of 1024), the backward
+turns them back into the column its bodies broadcast once a q tile
+(`_load_column`, into scratch), and delta is made inside the backward from
+the dO and out blocks of the rows it holds, so it never exists in HBM at
+all.  The
+backward is ONE kernel (`_bwd_kernel`) wherever a head's
 keys, values and their float32 gradients fit the core's fast memory: it
 walks the forward's schedule, computes P, dP and dS once a sub-tile and
 feeds dQ (summed over the spans of a resident q tile), dK and dV (summed
@@ -44,10 +52,12 @@ import jax.numpy as jnp
 
 from .registry import register_op
 
-__all__ = ["flash_attention", "attention_reference", "SAVED_BY_NAME"]
+__all__ = ["flash_attention", "attention_reference", "SAVED_BY_NAME",
+           "saved_lse", "stored_lse"]
 
 # names (jax.ad_checkpoint.checkpoint_name) of the forward kernel's output
-# and logsumexp among the residuals of `flash_attention`'s backward
+# and logsumexp (in its stored form: `saved_lse` undoes it) among the
+# residuals of `flash_attention`'s backward
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
@@ -313,12 +323,25 @@ def _tiles_of(e):
     return e >> 20, (e >> 10) & 0x3FF
 
 
-def _first(i, unit):
-    """The first row of unit ``i`` of ``unit`` rows: aligned, as the
-    compiler is told where ``i`` is a loop's."""
+def _aligned(row, unit):
+    """``row``, a multiple of ``unit``: the compiler is told so where it
+    is a loop's and not a constant."""
     import jax.experimental.pallas as pl
 
-    return i * unit if isinstance(i, int) else pl.multiple_of(i * unit, unit)
+    return row if isinstance(row, int) else pl.multiple_of(row, unit)
+
+
+def _first(i, unit):
+    """The first row of unit ``i`` of ``unit`` rows, aligned."""
+    return _aligned(i * unit, unit)
+
+
+def _each(n, fn):
+    """``fn(i)`` for i under ``n``: a rolled loop, or the one call."""
+    if n == 1:
+        fn(0)
+    else:
+        jax.lax.fori_loop(0, n, lambda i, c: (fn(i), c)[1], 0)
 
 
 def _walk(word, quarters, side, body):
@@ -343,14 +366,8 @@ def _walk(word, quarters, side, body):
     rows, cols = _extent(side)
     n_chunks, n_keys = rows // chunk, cols // keys
 
-    def each(n, fn):
-        if n == 1:
-            fn(0)
-        else:
-            jax.lax.fori_loop(0, n, lambda i, c: (fn(i), c)[1], 0)
-
     def whole(j, masked):
-        each(n_chunks, lambda c: body(j, _first(c, chunk), 0, cols, masked))
+        _each(n_chunks, lambda c: body(j, _first(c, chunk), 0, cols, masked))
 
     def quartered(j):
         full = (1 << n_keys) - 1
@@ -361,12 +378,12 @@ def _walk(word, quarters, side, body):
             if side.whole_rows:
                 pl.when(live == full)(
                     functools.partial(body, j, r0, 0, cols, True))
-            each(n_keys, lambda h: pl.when(
+            _each(n_keys, lambda h: pl.when(
                 (live != full) & (((live >> h) & 1) != 0))(
                     functools.partial(body, j, r0, _first(h, keys), keys,
                                       True)))
 
-        each(n_chunks, row)
+        _each(n_chunks, row)
 
     def run(c, j):
         if c == _MASKED and n_keys > 1:
@@ -380,7 +397,7 @@ def _walk(word, quarters, side, body):
             pl.when(cls == c)(functools.partial(run, c, j))
 
     if span > 1 or len(side.classes) > 1:
-        each(span, visit)
+        _each(span, visit)
     else:       # one sub-tile a step, one class: every listed step is live
         run(side.classes[0], 0)
 
@@ -434,6 +451,60 @@ def _pair_keep(seed_ref, bh, q_start, k_start, shape, dropout_p):
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return _dropout_keep(seed_ref[0], bh, q_pos, k_pos, dropout_p)
+
+
+def _store_lse(lse_ref, m_ref, l_ref):
+    """logsumexp per row of a finished q tile, m + log l (-inf for a row
+    the mask empties), from the running max and sum.  They are columns
+    while the tile runs — a (tile, 1) block is 128-fold padding in HBM and
+    as much in vector registers — so they are turned into rows of a lane
+    width first and the arithmetic runs on the dense form."""
+    shape = lse_ref.shape[-2:]
+    m, l = m_ref[:].reshape(shape), l_ref[:].reshape(shape)
+    lse_ref[0, 0] = jnp.where(jnp.isfinite(m), m + jnp.log(
+        jnp.maximum(l, 1e-30)), -jnp.inf)
+
+
+def _load_column(stat_ref, j, col_ref, r0=0, unroll=False):
+    """`_store_lse`'s turn undone: the stored rows of q unit ``j`` of a
+    block into rows ``r0``.. of a (rows, 1) column scratch, which is what
+    a body broadcasts along the keys.  A transpose a row of a lane width.
+    ``unroll``: the rows as static slices (a tile of 1024 has eight), as
+    the fused backward asks — a rolled pass with dynamic one-row loads and
+    stores costs it four times as much a q tile (PERF.md section 6,
+    PR 53).  The dQ and dK/dV kernels, which no cell runs and whose
+    bodies are held to a size (ISSUE 40), roll the loop."""
+    import jax.experimental.pallas as pl
+
+    n_rows, lanes = stat_ref.shape[-2:]
+
+    def row(r):
+        col_ref[pl.ds(_aligned(r0 + r * lanes, lanes), lanes)] = stat_ref[
+            0, j, pl.ds(r, 1), :].T
+
+    if unroll:
+        for r in range(n_rows):
+            row(r)
+    else:
+        _each(n_rows, row)
+
+
+def _delta(do_ref, out_ref, j):
+    """delta = rowsum(dO . O) in float32, the softmax-grad correction
+    (with dropout it still equals rowsum(P-hat . dP-hat) since O = P_d V),
+    of the rows of q unit ``j`` of a dO and an ``out`` block: a lane
+    reduction, so it is born the column a body broadcasts."""
+    return jnp.sum(do_ref[0, j].astype(jnp.float32)
+                   * out_ref[0, j].astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
+def _tile_columns(lse_ref, do_ref, out_ref, lse_col, delta_col, unroll=False):
+    """The two columns a backward's bodies broadcast, for the resident q
+    tile: lse from its stored rows, delta from the tile's dO and ``out``
+    blocks."""
+    _load_column(lse_ref, 0, lse_col, unroll=unroll)
+    delta_col[:] = _delta(do_ref, out_ref, 0)
 
 
 def _fwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref,
@@ -492,12 +563,7 @@ def _fwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, qc_ref,
         # dropout scale
         o_denom = denom * (1.0 - dropout_p) if dropout_p > 0.0 else denom
         o_ref[0, 0] = (acc_ref[:] / o_denom).astype(o_ref.dtype)
-        # logsumexp per row: m + log l (-inf for fully-masked rows).
-        # Stored as a (tile, 1) column: the trailing singleton keeps the
-        # block's last two dims legal for Mosaic tiling (tile % 8 == 0;
-        # 1 == array dim), where a (1, tile) block is not.
-        lse_ref[0] = jnp.where(jnp.isfinite(m_ref[:]),
-                               m_ref[:] + jnp.log(denom), -jnp.inf)
+        _store_lse(lse_ref, m_ref, l_ref)
 
 
 def _seed_arr(dropout_seed):
@@ -536,9 +602,11 @@ def _working_set(tile, sub, span, dk, dv, itemsize, resident=0):
     With ``resident`` key positions held for a whole head (the fused
     backward, which streams no span): K, V, dK and dV of all of them,
     double-buffered, and the float32 accumulators of both gradients;
-    the dQ block beside q and dO; and the lane-padded columns of the
-    resident rows (lse, delta, the query codes), which at a tile of 1024
-    rows are 3 MiB that the 12 MiB plans leave to the compiler's slack.
+    the dQ block beside q and the ``out`` block beside dO; and the
+    lane-padded columns of the resident rows — the query codes'
+    double-buffered block and the two scratch columns lse and delta are
+    turned into (their stored rows are a few KB) — which at a tile of 1024
+    rows are 2 MiB that the 12 MiB plans leave to the compiler's slack.
     A width under a lane (64-wide heads) fills the lane in fast memory
     and is planned as one."""
     dk, dv = max(dk, _LANES), max(dv, _LANES)
@@ -547,7 +615,8 @@ def _working_set(tile, sub, span, dk, dv, itemsize, resident=0):
              + 4 * tile * (dk + dv))
     if resident:
         total += (resident * (dk + dv) * (4 * itemsize + 4)
-                  + 2 * itemsize * tile * dk + 3 * 2 * 4 * tile * _LANES)
+                  + 2 * itemsize * tile * (dk + dv)
+                  + (2 + 2) * 4 * tile * _LANES)
     return total
 
 
@@ -659,6 +728,15 @@ def _pairs_kept(codes):
     return total
 
 
+def _stat_shape(heads, s_len, block_q):
+    """The form a per-row statistic of ``heads`` heads has in HBM: (heads,
+    q tiles, rows, lanes), a q tile's rows as rows of a lane width, or as
+    one row where the tile is no multiple of one (toy tiles)."""
+    block_q = min(block_q, s_len)
+    lanes = _LANES if block_q % _LANES == 0 else block_q
+    return heads, s_len // block_q, block_q // lanes, lanes
+
+
 class _Plan:
     """What the kernels of one signature share, built once (`_plan`):
     tile sizes, the mask's codes, the span schedules with their classes
@@ -672,10 +750,17 @@ class _Plan:
     (``quarters``: a bit a live quarter): its kernels never compute a
     masked sub-tile's dead quarters (`_walk`; ``keys`` is a quarter's).
     ``block_q`` is the q tile and the q sub-tile, ``block_k`` the k tile
-    and the k sub-tile.  ``vmem_limit`` is the fast memory the fused
-    backward is given, or None where a head's keys, values and their
-    gradients do not fit it (``fused`` says which): the backward is then
-    the dQ and the dK/dV kernel, and only then is ``cols`` built."""
+    and the k sub-tile.  ``stat_shape`` is the form the per-row statistic
+    lse has in HBM (`_stat_shape`): (heads,
+    q tiles, rows, lanes) with a tile's rows as rows of a lane width, or
+    as one row where the tile is no multiple of one (toy tiles) — a q
+    tile's block is its last two dimensions whole, so every tile size
+    takes it, and at a tile of 1024 it is one dense (8, 128) float32
+    tile where a (tile, 1) column is padded 128-fold.  ``vmem_limit`` is
+    the fast memory the fused backward is given, or None where a head's
+    keys, values and their gradients do not fit it (``fused`` says which):
+    the backward is then the dQ and the dK/dV kernel, and only then is
+    ``cols`` built."""
 
     def __init__(self, q_shape, k_shape, v_shape, itemsize, causal,
                  block_q, block_k, valid_len, block_diffusion, window=None):
@@ -702,6 +787,7 @@ class _Plan:
         self.use_eq = block_diffusion is not None
         self.codes = codes if codes is not None else _mask_codes(
             False, None, s_len, s_len)     # nothing reads them: all kept
+        self.stat_shape = _stat_shape(self.bh, s_len, block_q)
         self.rows = self._side(codes, block_q, block_k, itemsize)
         need = _working_set(block_q, block_k, 0, self.dk, self.dv, itemsize,
                             resident=s_len)
@@ -713,6 +799,15 @@ class _Plan:
     @property
     def fused(self):
         return self.vmem_limit is not None
+
+    @property
+    def stat_bytes_per_row(self):
+        """HBM bytes a query row's statistics occupy between the kernels:
+        lse's stored form under the (8, 128) tiling of its last two
+        dimensions (delta is made in the kernels and is never there)."""
+        rows, lanes = self.stat_shape[2:]
+        padded = 4 * (-(-rows // 8) * 8) * (-(-lanes // _LANES) * _LANES)
+        return padded / (rows * lanes)
 
     def _side(self, codes, tile, sub, itemsize, by_key=False):
         import numpy as onp
@@ -757,15 +852,16 @@ class _Plan:
     def specs(self, side, head_of, step_axis=1):
         """BlockSpecs for a grid whose axis ``step_axis`` walks ``side``'s
         schedule: (q-indexed tensor of a width, k-indexed tensor of a
-        width, q codes, k codes, q-indexed column).  The resident side's
-        block is one tile, the streamed side's a span of sub-tiles;
+        width, q codes, k codes, q-indexed statistic).  The resident
+        side's block is one tile, the streamed side's a span of sub-tiles;
         ``head_of(*grid indices)`` gives (query head row, key-value head
         row).  An index map gets the grid indices, then the three
         prefetched scalar operands (dropout seed, the quarters' words,
-        the schedule).  A column
-        (lse, delta) stays (heads, S, 1), its block the step's rows: it
-        is padded to a lane width in memory, and cutting it like the
-        others is a relayout XLA spends megabytes of code on."""
+        the schedule).  The statistic (lse) has ``stat_shape``, its
+        block the step's q units with their rows whole; the kernels turn
+        a column into rows and back (`_store_lse`, `_load_column`),
+        because doing it in XLA is a relayout of a lane-padded array that
+        costs megabytes of code a copy."""
         import jax.experimental.pallas as pl
 
         tile, sub, span = side[:3]
@@ -794,8 +890,8 @@ class _Plan:
                 pl.BlockSpec((k_rows[0], 2, k_rows[1]),
                              lambda *a: (k_of(a), 0, 0)),
                 pl.BlockSpec(
-                    (1, q_rows[0] * q_rows[1], 1),
-                    lambda *a: (head_of(*a[:-3])[0], q_of(a), 0)))
+                    (1, q_rows[0]) + self.stat_shape[2:],
+                    lambda *a: (head_of(*a[:-3])[0], q_of(a), 0, 0)))
 
     def head_block(self, sub, width):
         """BlockSpec of a key-value head's WHOLE sequence in sub-tiles of
@@ -826,9 +922,11 @@ def _plan(q_shape, k_shape, v_shape, dtype, causal, block_q, block_k,
     """The plan of one signature, built once: N layers, the kernels of a
     layer and remat's replays share one host computation.  Sets the gauges
     ``attention_maskfree_share{kernel}`` from the schedules' class bits,
-    ``attention_fused_backward_share`` from the memory plan and the pair
-    ``attention_pairs_visited{mask}`` / ``attention_pairs_kept{mask}``
-    from the forward's schedule and the codes."""
+    ``attention_fused_backward_share`` from the memory plan,
+    ``attention_stat_bytes_per_row`` from the statistics' stored form and
+    the pair ``attention_pairs_visited{mask}`` /
+    ``attention_pairs_kept{mask}`` from the forward's schedule and the
+    codes."""
     from ..telemetry import instruments as _telemetry
 
     plan = _Plan(q_shape, k_shape, v_shape, jnp.dtype(dtype).itemsize,
@@ -841,6 +939,7 @@ def _plan(q_shape, k_shape, v_shape, dtype, causal, block_q, block_k,
         {"flash_attention_fwd": rows, "flash_attention_bwd_dq": rows,
          "flash_attention_bwd_dkv": _maskfree_share(plan.cols)})
     _telemetry.record_attention_backward_plan(plan.fused)
+    _telemetry.attention_stat_bytes_per_row.set(plan.stat_bytes_per_row)
     _telemetry.set_attention_pairs(plan.mask, _pairs_visited(plan.rows),
                                    _pairs_kept(plan.codes))
     return plan
@@ -868,17 +967,17 @@ def _shared(fn, plan, *static):
 def _flash_fwd_call(plan, scale, dropout_p, interpret, seed, q, k, v):
     tile, sub, words = plan.rows.tile, plan.rows.sub, plan.rows.words
     group, dk, dv = plan.group, plan.dk, plan.dv
-    q_block, k_block, q_code, k_code, column = plan.specs(
+    q_block, k_block, q_code, k_code, stat = plan.specs(
         plan.rows, lambda b, t: (b, _head_div(b, group)))
     out, lse = plan.call(
         _fwd_kernel, "flash_attention_fwd", plan.rows,
         grid=(plan.bh, len(words)),
         in_specs=[q_block(dk), k_block(dk), k_block(dv), q_code, k_code],
-        out_specs=[q_block(dv), column],
+        out_specs=[q_block(dv), stat],
         out_shape=[
             jax.ShapeDtypeStruct((plan.bh, plan.s_len // tile, tile, dv),
                                  q.dtype),
-            jax.ShapeDtypeStruct((plan.bh, plan.s_len, 1), jnp.float32),
+            jax.ShapeDtypeStruct(plan.stat_shape, jnp.float32),
         ],
         scratch=[
             _scratch((tile, 1)),   # running max m
@@ -895,11 +994,29 @@ def _flash_fwd_call(plan, scale, dropout_p, interpret, seed, q, k, v):
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                valid_len=None, dropout_p=0.0, dropout_seed=None,
                block_diffusion=None, window=None):
+    """(out, lse): lse as the kernel stores it (`_Plan.stat_shape`; the
+    backward reads that form, `saved_lse` gives it by position)."""
     plan = _plan_of(q, k, v, causal, block_q, block_k, valid_len,
                     block_diffusion, window)
     out, lse = _shared(_flash_fwd_call, plan, float(scale), dropout_p,
                        interpret)(_seed_arr(dropout_seed), q, k, v)
-    return out.reshape(q.shape[:-1] + (plan.dv,)), lse[..., 0]
+    return out.reshape(q.shape[:-1] + (plan.dv,)), lse
+
+
+def saved_lse(lse, q_shape):
+    """The logsumexp the forward saves for the backward, by position:
+    (B, H, S) float32 for queries of ``q_shape`` (B, H, S, D).  The stored
+    form holds a head's rows in order, so this is its elements regrouped
+    (tests and the ring's merge read it; the backward does not)."""
+    return lse.reshape(q_shape[:3])
+
+
+def stored_lse(lse, block_q):
+    """`saved_lse` undone: a (B, H, S) logsumexp by position in the form
+    `_flash_bwd` takes, for q tiles of ``block_q`` rows (the ring, whose
+    lse is the hops' merged one)."""
+    b, h, s_len = lse.shape
+    return lse.reshape(_stat_shape(b * h, s_len, block_q))
 
 
 def _recompute_p(q, k, lse_col, qc, kc, scale, masked, use_eq):
@@ -945,8 +1062,11 @@ def _t_dot(a, b):
 
 
 def _bwd_dq_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
-                   do_ref, lse_ref, delta_ref, qc_ref, kc_ref, dq_ref,
-                   dq_acc, *, scale, side, use_eq, dropout_p=0.0):
+                   do_ref, out_ref, lse_ref, qc_ref, kc_ref, dq_ref, dq_acc,
+                   lse_col, delta_col, *, scale, side, use_eq,
+                   dropout_p=0.0):
+    """dQ over streaming K/V spans; the q tile's two columns as in the
+    fused kernel."""
     import jax.experimental.pallas as pl
 
     tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
@@ -958,13 +1078,14 @@ def _bwd_dq_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
     @pl.when((word & 2) != 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        _tile_columns(lse_ref, do_ref, out_ref, lse_col, delta_col)
 
     def sub_tile(j, r0, k0, keys, masked):
         rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
         k = k_ref[0, j, cols]
         _, _, ds = _p_ds(
             q_ref[0, 0, rows], k, v_ref[0, j, cols], do_ref[0, 0, rows],
-            lse_ref[0, rows], delta_ref[0, rows], qc_ref[0, rows],
+            lse_col[rows], delta_col[rows], qc_ref[0, rows],
             kc_ref[j, :, cols], scale, masked, use_eq, dropout_p,
             lambda shape: _pair_keep(
                 seed_ref, bh_idx, q_idx * tile + r0,
@@ -981,9 +1102,9 @@ def _bwd_dq_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
 
 
 def _bwd_dkv_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
-                    do_ref, lse_ref, delta_ref, qc_ref, kc_ref, dk_ref,
-                    dv_ref, dk_acc, dv_acc, *, scale, side, use_eq, group,
-                    dropout_p=0.0):
+                    do_ref, out_ref, lse_ref, qc_ref, kc_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, lse_col, delta_col, *, scale,
+                    side, use_eq, group, dropout_p=0.0):
     import jax.experimental.pallas as pl
 
     tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
@@ -1003,14 +1124,22 @@ def _bwd_dkv_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    # every step streams another span of q sub-tiles (or another query
+    # head's): their lse from its stored rows and their delta from the dO
+    # and ``out`` it streams beside q, as one column each over the span
+    def columns(j):
+        _load_column(lse_ref, j, lse_col, j * sub)
+        delta_col[pl.ds(_first(j, sub), sub)] = _delta(do_ref, out_ref, j)
+
+    _each(span, columns)
+
     def sub_tile(j, r0, k0, keys, masked):
         rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
-        # lse and delta: one column over the span, not cut into sub-tiles
         span_rows = pl.ds(pl.multiple_of(j * sub + r0, chunk), chunk)
         q, do = q_ref[0, j, rows], do_ref[0, j, rows]
         p, drop, ds = _p_ds(
             q, k_ref[0, 0, cols], v_ref[0, 0, cols], do,
-            lse_ref[0, span_rows], delta_ref[0, span_rows], qc_ref[j, rows],
+            lse_col[span_rows], delta_col[span_rows], qc_ref[j, rows],
             kc_ref[0, :, cols], scale, masked, use_eq, dropout_p,
             lambda shape: _pair_keep(
                 seed_ref, bh_idx, (q_blk * span + j) * sub + r0,
@@ -1027,9 +1156,9 @@ def _bwd_dkv_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref,
 
 
 def _bwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, qc_ref, kc_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, dk_acc, dv_acc, *, scale, side, use_eq, group,
-                dropout_p=0.0):
+                out_ref, lse_ref, qc_ref, kc_ref, dq_ref, dk_ref, dv_ref,
+                dq_acc, dk_acc, dv_acc, lse_col, delta_col, *, scale, side,
+                use_eq, group, dropout_p=0.0):
     """dQ, dK and dV from one computation of P, dP and dS a sub-tile.
 
     Grid: (key-value head, query head of its group, step of the q-major
@@ -1038,7 +1167,10 @@ def _bwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
     key-value head (fetched once a head, cut into sub-tiles on their
     leading axis), and dK and dV sum over every query tile and every
     query head of the group in float32 scratch of the whole sequence,
-    zeroed at the head's first step and stored at its last."""
+    zeroed at the head's first step and stored at its last.  The two
+    columns the bodies broadcast are made once a q tile, at its first
+    step, in scratch: lse from its stored rows, delta from the tile's dO
+    and ``out`` blocks."""
     import jax.experimental.pallas as pl
 
     tile, sub, span, chunk = side.tile, side.sub, side.span, side.chunk
@@ -1061,14 +1193,16 @@ def _bwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
     @pl.when((word & 2) != 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        _tile_columns(lse_ref, do_ref, out_ref, lse_col, delta_col,
+                      unroll=True)
 
     def sub_tile(j, r0, k0, keys, masked):
         rows, cols = pl.ds(r0, chunk), pl.ds(k0, keys)
         kj = k_blk * span + j                    # the sub-tile's own index
         q, do, k = q_ref[0, 0, rows], do_ref[0, 0, rows], k_ref[0, kj, cols]
         p, drop, ds = _p_ds(
-            q, k, v_ref[0, kj, cols], do, lse_ref[0, rows],
-            delta_ref[0, rows], qc_ref[0, rows], kc_ref[j, :, cols], scale,
+            q, k, v_ref[0, kj, cols], do, lse_col[rows],
+            delta_col[rows], qc_ref[0, rows], kc_ref[j, :, cols], scale,
             masked, use_eq, dropout_p,
             lambda shape: _pair_keep(seed_ref, bh_idx, q_idx * tile + r0,
                                      kj * sub + k0, shape, dropout_p))
@@ -1095,60 +1229,57 @@ def _bwd_kernel(seed_ref, quart_ref, sched_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
                     lse, g):
+    """The backward's kernels on the forward's residuals; ``lse`` in its
+    stored form.  Nothing but the kernels: delta = rowsum(dO . O) is made
+    inside them, from the ``out`` block a q tile holds beside its dO."""
     group, dk_, dv_ = plan.group, plan.dk, plan.dv
-    # delta = rowsum(dO * O): the softmax-grad correction term (with
-    # dropout it still equals rowsum(P-hat . dP-hat) since O = P_d V).
-    # lse/delta ride as (rows, 1) columns so their blocks satisfy
-    # Mosaic's last-two-dims tiling rule.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(plan.bh, plan.s_len, 1)
-    lse = lse.reshape(delta.shape)
-
     tile, sub, words = plan.rows.tile, plan.rows.sub, plan.rows.words
     dq_shape = jax.ShapeDtypeStruct(
         (plan.bh, plan.s_len // tile, tile, dk_), q.dtype)
     k_units, v_units = plan.units(k, sub, True), plan.units(v, sub, True)
     operands = (seed, jnp.asarray(plan.rows.quarters), jnp.asarray(words),
                 plan.units(q, tile), k_units, v_units, plan.units(g, tile),
-                lse, delta, *plan.code_units(tile, sub))
+                plan.units(out, tile), lse, *plan.code_units(tile, sub))
+    columns = [_scratch((tile, 1)), _scratch((tile, 1))]    # lse, delta
     if plan.fused:
-        q_block, _, q_code, k_code, column = plan.specs(
+        q_block, _, q_code, k_code, stat = plan.specs(
             plan.rows, lambda b, j, t: (b * group + j, b), step_axis=2)
         k_head, v_head = plan.head_block(sub, dk_), plan.head_block(sub, dv_)
         return plan.call(
             _bwd_kernel, "flash_attention_bwd", plan.rows,
             grid=(plan.bkv, group, len(words)),
-            in_specs=[q_block(dk_), k_head, v_head, q_block(dv_), column,
-                      column, q_code, k_code],
+            in_specs=[q_block(dk_), k_head, v_head, q_block(dv_),
+                      q_block(dv_), stat, q_code, k_code],
             out_specs=[q_block(dk_), k_head, v_head],
             out_shape=[dq_shape,
                        jax.ShapeDtypeStruct(k_units.shape, k.dtype),
                        jax.ShapeDtypeStruct(v_units.shape, v.dtype)],
             scratch=[_scratch((tile, dk_)), _scratch(k_units.shape[1:]),
-                     _scratch(v_units.shape[1:])],
+                     _scratch(v_units.shape[1:])] + columns,
             interpret=interpret, vmem_limit=plan.vmem_limit, scale=scale,
             dropout_p=dropout_p, group=group)(*operands)
 
-    q_block, k_block, q_code, k_code, column = plan.specs(
+    q_block, k_block, q_code, k_code, stat = plan.specs(
         plan.rows, lambda b, t: (b, _head_div(b, group)))
     dq = plan.call(
         _bwd_dq_kernel, "flash_attention_bwd_dq", plan.rows,
         grid=(plan.bh, len(words)),
         in_specs=[q_block(dk_), k_block(dk_), k_block(dv_), q_block(dv_),
-                  column, column, q_code, k_code],
+                  q_block(dv_), stat, q_code, k_code],
         out_specs=q_block(dk_), out_shape=dq_shape,
-        scratch=[_scratch((tile, dk_))],
+        scratch=[_scratch((tile, dk_))] + columns,
         interpret=interpret, scale=scale, dropout_p=dropout_p,
     )(*operands)
 
     tile, sub, words = plan.cols.tile, plan.cols.sub, plan.cols.words
-    q_block, k_block, q_code, k_code, column = plan.specs(
+    span = plan.cols.span
+    q_block, k_block, q_code, k_code, stat = plan.specs(
         plan.cols, lambda b, t, j: (b * group + j, b))
     dk, dv = plan.call(
         _bwd_dkv_kernel, "flash_attention_bwd_dkv", plan.cols,
         grid=(plan.bkv, len(words), group),
         in_specs=[q_block(dk_), k_block(dk_), k_block(dv_), q_block(dv_),
-                  column, column, q_code, k_code],
+                  q_block(dv_), stat, q_code, k_code],
         out_specs=[k_block(dk_), k_block(dv_)],
         out_shape=[
             jax.ShapeDtypeStruct((plan.bkv, plan.s_len // tile, tile, dk_),
@@ -1156,12 +1287,13 @@ def _flash_bwd_call(plan, scale, dropout_p, interpret, seed, q, k, v, out,
             jax.ShapeDtypeStruct((plan.bkv, plan.s_len // tile, tile, dv_),
                                  v.dtype),
         ],
-        scratch=[_scratch((tile, dk_)), _scratch((tile, dv_))],
+        scratch=[_scratch((tile, dk_)), _scratch((tile, dv_)),
+                 _scratch((span * sub, 1)), _scratch((span * sub, 1))],
         interpret=interpret, scale=scale, dropout_p=dropout_p, group=group,
     )(seed, jnp.asarray(plan.cols.quarters), jnp.asarray(words),
       plan.units(q, sub), plan.units(k, tile, True),
-      plan.units(v, tile, True), plan.units(g, sub), lse, delta,
-      *plan.code_units(sub, tile))
+      plan.units(v, tile, True), plan.units(g, sub), plan.units(out, sub),
+      lse, *plan.code_units(sub, tile))
     return dq, dk, dv
 
 
@@ -1170,7 +1302,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                dropout_seed=None, block_diffusion=None, window=None):
     """Block-streamed FlashAttention-2 backward: O(S) memory, no (S, S)
     residual: P sub-tiles are recomputed from (q, k, lse) (and the
-    dropout keep mask from its counter hash)."""
+    dropout keep mask from its counter hash).  ``lse`` is as `_flash_fwd`
+    stores it (`stored_lse` gives one by position that form)."""
     plan = _plan_of(q, k, v, causal, block_q, block_k, valid_len,
                     block_diffusion, window)
     dq, dk, dv = _shared(_flash_bwd_call, plan, float(scale), dropout_p,
@@ -1294,7 +1427,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     ``flash_attention_bwd``, where the working set of a key-value head
     held whole — K, V, dK and dV blocks of the entire sequence,
     double-buffered, their float32 accumulators, the resident q tile's
-    side and the temporaries of a chunk (`_working_set(resident=S)`: 44 MB
+    side (q, dO, ``out``, dQ, the two statistics' columns) and the
+    temporaries of a chunk (`_working_set(resident=S)`: 44 MB
     at 8192 positions of 192 / 128 bf16) — is at most 0.6 of the core's
     fast memory (`pltpu.get_tpu_info()` on a TPU, the v5e's 128 MiB where
     the kernel is lowered for a described chip or interpreted); the kernel
@@ -1308,6 +1442,18 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     give the same float32 terms; dK and dV sum them in another order.
     The gauge ``attention_fused_backward_share`` is the share of planned
     signatures that fused.
+
+    **The row statistics.**  The forward saves lse for the backward as
+    rows of a lane width (`_Plan.stat_shape`: at a tile of 1024 one dense
+    (8, 128) float32 tile a q tile, where a (heads, S, 1) column is
+    128-fold padding in HBM), turned from the running column once a q
+    tile; the backward turns them back into a column in scratch once a q
+    tile and makes delta = rowsum(dO . O) there too, from the tile's dO
+    and ``out`` blocks, so no XLA pass runs between the kernels.  (The
+    two-kernel plan: dQ does the same, and dK/dV makes delta for the span
+    of q sub-tiles it streams, from their dO and ``out``.)  The gauge
+    ``attention_stat_bytes_per_row`` is what a row's statistics occupy in
+    HBM: 4 at a tile of 1024.
 
     **Before the first step** the plan of a signature (codes, schedules,
     classes, the memory plan, the gauges) is built once on the host in

@@ -131,7 +131,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, interpret,
     """
     import jax as _jax
 
-    from ..ops.pallas_attention import _flash_fwd
+    from ..ops.pallas_attention import _flash_fwd, saved_lse
 
     n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
@@ -154,27 +154,27 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, interpret,
 
     def hop(i, out, lse, k_blk, v_blk):
         src = (my - i) % n
+
+        def block(is_causal):
+            # the kernel on the resident block; its lse by position
+            blk_out, blk_lse = _flash_fwd(q, k_blk, v_blk, is_causal, scale,
+                                          bq, bk, interpret, valid_len)
+            return blk_out, saved_lse(blk_lse, q.shape)
+
         if causal:
             def _skip():
                 # future keys: no kernel launch, zero contribution
                 return (jnp.zeros(q.shape, q.dtype),
-                        jnp.full((q.shape[0] * q.shape[1], q.shape[2]),
-                                 -jnp.inf, jnp.float32))
+                        jnp.full(q.shape[:3], -jnp.inf, jnp.float32))
 
             blk_out, blk_lse = _jax.lax.cond(
                 src > my,
                 _skip,
                 lambda: _jax.lax.cond(
-                    src == my,
-                    lambda: _flash_fwd(q, k_blk, v_blk, True, scale,
-                                       bq, bk, interpret, valid_len),
-                    lambda: _flash_fwd(q, k_blk, v_blk, False, scale,
-                                       bq, bk, interpret, valid_len)),
+                    src == my, lambda: block(True), lambda: block(False)),
             )
         else:
-            blk_out, blk_lse = _flash_fwd(q, k_blk, v_blk, False, scale,
-                                          bq, bk, interpret, valid_len)
-        blk_lse = blk_lse.reshape(q.shape[:3])
+            blk_out, blk_lse = block(False)
         return merge(out, lse, blk_out.astype(jnp.float32), blk_lse)
 
     def body(i, carry):
@@ -215,7 +215,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, interpret, valid_len,
     locally. Cross-hop causal structure maps onto the kernel's flag:
     past hops run it un-causal, the diagonal hop causal, future hops are
     skipped entirely."""
-    from ..ops.pallas_attention import _flash_bwd
+    from ..ops.pallas_attention import _flash_bwd, stored_lse
 
     q, k, v, out, lse = res
     n = jax.lax.axis_size(axis_name)
@@ -224,12 +224,10 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, interpret, valid_len,
     bq = min(128, s_local)
     bk = min(128, s_local)
     perm = [(i, (i + 1) % n) for i in range(n)]
-
-    b, h = q.shape[0], q.shape[1]
-    lse_flat = lse.reshape(b * h, s_local)  # _flash_bwd's (bh, S) layout
+    lse = stored_lse(lse, bq)      # the merged one, as the kernels read it
 
     def grads_for(k_blk, v_blk, is_causal):
-        return _flash_bwd(q, k_blk, v_blk, out, lse_flat, g, is_causal,
+        return _flash_bwd(q, k_blk, v_blk, out, lse, g, is_causal,
                           scale, bq, bk, interpret, valid_len)
 
     def body(i, carry):

@@ -185,6 +185,17 @@ attention_fused_backward_share = gauge(
     "the stated share of the core's fast memory); a signature whose "
     "backward is the dQ and the dK/dV kernel counts as 0. Set on the host "
     "when the plan of a signature is built (ops.pallas_attention._plan)")
+attention_stat_bytes_per_row = gauge(
+    "attention_stat_bytes_per_row",
+    "HBM bytes a query row's per-row statistics of the flash kernels (the "
+    "logsumexp the forward saves; delta = rowsum(dO . O) is made inside "
+    "the backward's kernels and is never there) occupy between the "
+    "kernels, under the (8, 128) tiling of the stored form's last two "
+    "dimensions: 4 where a q tile of 1024 rows stores its lse as one dense "
+    "(8, 128) tile, 32 at a tile of 128 (1024 where lse and delta were "
+    "(heads, S, 1) columns). The latest "
+    "signature's; set on the host when its plan is built "
+    "(ops.pallas_attention._plan)")
 attention_pairs_visited = gauge(
     "attention_pairs_visited",
     "Query-key pairs of one head that the flash forward computes: the "

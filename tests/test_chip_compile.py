@@ -245,7 +245,9 @@ def test_layers_of_one_signature_share_the_plan_and_the_kernels(
     under twice the earlier tree's bytes and the fused body under the sum
     of the two it replaces — the same tree's dQ and dK/dV bodies, lowered
     for a core whose fast memory holds no head, which stay under twice the
-    earlier tree's."""
+    earlier tree's (with the statistics' turns of PR 53: dQ turns lse,
+    makes delta and stores it; dK/dV turns lse and delta a q unit of the
+    span it streams in one rolled loop over the span)."""
     from mxnet_tpu.ops import pallas_attention as pa
 
     earlier = _CELL_KERNELS[cell][4]
@@ -331,6 +333,96 @@ def test_the_plan_for_8192_positions_costs_a_millisecond(mask):
     assert plan.rows[:3] == (1024, 1024, 2) and plan.fused
     assert pa._plan(*args) is plan
     pa._plan.cache_clear()
+
+
+# -- the kernels' row statistics (ISSUE 53): lse lane-dense, delta made in
+#    the backward kernel — no (heads, S, 1) float32 column, 128-fold padding
+#    in HBM, is an operand or a result of a flash call or lives beside one ---
+
+def _shapes(text):
+    """Every array type printed in a piece of HLO text, as (type, dims)."""
+    import re
+
+    return [(t, tuple(int(d) for d in dims.split(",") if d))
+            for t, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]*)\]", text)]
+
+
+def _call_shapes(text, line):
+    """(type, dims) of every result and every operand of the instruction
+    on ``line`` of a compiled module's ``text`` (which prints an operand
+    by name: its type is where the name is defined)."""
+    import re
+
+    types = {}
+    for l in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) [\w\-]+\(", l)
+        if m:
+            types[m.group(1)] = _shapes(m.group(2))
+    m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\((.*?)\), ", line)
+    operands = re.findall(r"%[\w.\-]+", m.group(2))
+    return _shapes(m.group(1)) + [t for o in operands for t in types[o]]
+
+
+def _columns_under_attention(text, s_len):
+    """Lines of a compiled step under the ``attention`` scope that hold a
+    float32 (heads, S, 1) array."""
+    return [l for l in text.splitlines() if "/attention/" in l and any(
+        t == "f32" and len(d) == 3 and d[1:] == (s_len, 1)
+        for t, d in _shapes(l.split("metadata=")[0]))]
+
+
+# (q, k, v shapes, mask) of the five decoder cells' flash calls
+_CELL_SIGNATURES = {
+    "sdar": ((2, 32, 8192, 128), (2, 4, 8192, 128), (2, 4, 8192, 128),
+             {"block_diffusion": (4, 4096)}),
+    "kanana2": ((2, 32, 8192, 192), (2, 32, 8192, 192), (2, 32, 8192, 128),
+                {"causal": True}),
+    "ouro": ((1, 16, 8192, 128), (1, 16, 8192, 128), (1, 16, 8192, 128),
+             {"causal": True}),
+    "lfm2": ((2, 32, 8192, 64), (2, 8, 8192, 64), (2, 8, 8192, 64),
+             {"causal": True}),
+    "trinity_window": ((1, 32, 16384, 128), (1, 4, 16384, 128),
+                       (1, 4, 16384, 128), {"window": 2048}),
+    "trinity_global": ((1, 32, 16384, 128), (1, 4, 16384, 128),
+                       (1, 4, 16384, 128), {"causal": True}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_SIGNATURES))
+def test_no_flash_call_takes_or_gives_a_padded_column(spec, cell):
+    """A decoder cell's flash signature, forward and backward under the
+    ``attention`` scope, compiled for the described v5e: two Mosaic calls,
+    none with an operand or a result whose trailing dimension is 1 (the
+    seed, one int32, apart), lse as one dense (8, 128) float32 tile a q
+    tile of 1024, and no float32 (heads, S, 1) array anywhere in the
+    program — the four XLA passes a layer that moved such columns are
+    gone with them."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    qs, ks, vs, mask = _CELL_SIGNATURES[cell]
+
+    def loss(q, k, v):
+        with jax.named_scope("attention"):
+            out = pa.flash_attention(q, k, v, interpret=False, **mask)
+        return out.astype(jnp.float32).sum()
+
+    pa._plan.cache_clear()
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        spec(qs, jnp.bfloat16), spec(ks, jnp.bfloat16),
+        spec(vs, jnp.bfloat16)).compile().as_text()
+    pa._plan.cache_clear()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and " custom-call(" in l and "flash_attention" in l]
+    assert len(calls) == 2
+    heads, s_len = qs[0] * qs[1], qs[2]
+    for line in calls:
+        shapes = _call_shapes(text, line)
+        assert len(shapes) >= 10            # results and operands, resolved
+        assert [d for t, d in shapes if d[-1:] == (1,) and len(d) > 1] == []
+        assert shapes.count(("f32", (heads, s_len // 1024, 8, 128))) == 1
+    assert not _columns_under_attention(text, s_len)
+    assert not [d for t, d in _shapes(text)
+                if t == "f32" and len(d) == 3 and d[1:] == (s_len, 1)]
 
 
 # -- query / key preparation (ISSUE 42): per-head norm, rotary positions and
@@ -473,6 +565,8 @@ def test_the_looped_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     # no (.., 8192, 49152) float32 logits anywhere: blocks of 256 positions
     assert "f32[4,8192,49152]" not in text and "f32[1,8192,49152]" not in text
     assert "f32[4,256,49152]" in text
+    # the kernels' row statistics: no (heads, S, 1) column (ISSUE 53)
+    assert not _columns_under_attention(text, 8192)
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12 * 2 ** 30
 
@@ -756,6 +850,7 @@ def test_the_hybrid_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
     flash = [l for l in text.splitlines() if "tpu_custom_call" in l
              and "flash_attention" in l and " custom-call(" in l]
     assert len(flash) == 2
+    assert not _columns_under_attention(text, 8192)
     prep = [l for l in text.splitlines() if "tpu_custom_call" in l
             and "rms_norm_rotary" in l and " custom-call(" in l]
     assert len(prep) == 6
@@ -873,6 +968,7 @@ def test_the_window_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
         flash = [l for l in calls if "flash_attention" in l and scope in l]
         assert len(flash) == 2, scope
         assert len([l for l in flash if "flash_attention_bwd" in l]) == 1
+    assert not _columns_under_attention(text, 16384)
     prep = [l for l in calls if "rms_norm_rotary" in l]
     assert len(prep) == 12
     assert len([l for l in prep if "rms_norm_rotary_bwd" in l]) == 4

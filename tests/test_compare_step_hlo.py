@@ -66,3 +66,41 @@ def test_a_renumbering_alone_leaves_no_line_once_numbers_are_stripped(tool):
     assert sorted(ub - ua) == [
         "  %constant = s32[16]{0} constant({...})",
         "  %k = f32[8]{0} custom-call(%constant, %constant, %add)"]
+
+
+def test_what_is_computed_ignores_names_placement_and_plumbing(tool):
+    """A fusion that went away moves numbers, memory spaces and prefetches
+    of lines it never touched; as (opcode, result type, scope) only the
+    work that came or went is left."""
+    a = ['  %param_2.7814 = f32[64,8192,1]{2,1,0:T(8,128)} parameter(2)',
+         '  %copy.4 = f32[64,8192,1]{2,1,0:T(8,128)} copy(%bitcast.9)',
+         '  %fusion.7 = f32[2,32,8192]{2,1,0:T(8,128)} fusion(%p.1, %p.2), '
+         'kind=kInput, calls=%fused_computation.7, '
+         'metadata={op_name="jit(step)/attention/reduce_sum"}',
+         '  %fusion.9 = bf16[2,8192,2048]{2,1,0:T(8,128)(2,1)} fusion(%x.3), '
+         'kind=kLoop, metadata={op_name="jit(step)/mlp/mul"}',
+         '  %k.1 = (bf16[64,8,1024,128]{3,2,1,0}, f32[64,8192,1]{2,1,0}) '
+         'custom-call(%q.1), custom_call_target="tpu_custom_call", '
+         'metadata={op_name="jit(step)/attention/pallas_call"}']
+    b = ['  %copy-start.2 = (f32[8]{0:S(1)}, f32[8]{0}) copy-start(%w.1)',
+         '  %fusion.5 = bf16[2,8192,2048]{2,1,0:T(8,128)(2,1)S(1)} '
+         'fusion(%x.2), kind=kLoop, metadata={op_name="jit(step)/mlp/mul"}',
+         '  %k.1 = (bf16[64,8,1024,128]{3,2,1,0}, f32[64,8,8,128]{3,2,1,0}) '
+         'custom-call(%q.1), custom_call_target="tpu_custom_call", '
+         'metadata={op_name="jit(step)/attention/pallas_call"}']
+    ca, cb = tool.computed(a), tool.computed(b)
+    assert sorted(ca - cb) == [
+        ("custom-call", "(bf16[64,8,1024,128], f32[64,8192,1])",
+         "jit(step)/attention/pallas_call"),
+        ("fusion", "f32[2,32,8192]", "jit(step)/attention/reduce_sum")]
+    assert sorted(cb - ca) == [
+        ("custom-call", "(bf16[64,8,1024,128], f32[64,8,8,128])",
+         "jit(step)/attention/pallas_call")]
+
+
+def test_a_fused_computations_parameter_is_renumbered_too(tool):
+    a = ["  %add.3 = f32[8]{0} add(%param_2.7814, %param_0.12)"]
+    b = ["  %add.9 = f32[8]{0} add(%param_2.7501, %param_0.33)"]
+    assert tool.unnumbered(a) == tool.unnumbered(b)
+    assert tool.unnumbered(["  %x = f32[8]{0} add(%param_1.5, %p)"]) != \
+        tool.unnumbered(["  %x = f32[8]{0} add(%param_2.5, %p)"])

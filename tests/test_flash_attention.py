@@ -202,7 +202,7 @@ def test_flash_forward_emits_lse():
     backward residual contract)."""
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.pallas_attention import _flash_fwd
+    from mxnet_tpu.ops.pallas_attention import _flash_fwd, saved_lse
 
     rs = onp.random.RandomState(1)
     B, H, S, D = 1, 1, 32, 8
@@ -210,10 +210,11 @@ def test_flash_forward_emits_lse():
                for _ in range(3))
     scale = D ** -0.5
     out, lse = _flash_fwd(q, k, v, False, scale, 16, 16, True)
+    assert lse.shape == (1, 2, 1, 16)       # a row a q tile, no column
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    ref_lse = jax.scipy.special.logsumexp(scores, axis=-1).reshape(-1, S)
-    onp.testing.assert_allclose(onp.asarray(lse), onp.asarray(ref_lse),
-                                rtol=1e-5, atol=1e-5)
+    ref_lse = jax.scipy.special.logsumexp(scores, axis=-1)
+    onp.testing.assert_allclose(onp.asarray(saved_lse(lse, q.shape)),
+                                onp.asarray(ref_lse), rtol=1e-5, atol=1e-5)
 
 
 class TestFlashDropout:
@@ -1071,3 +1072,162 @@ def test_a_band_over_the_sequence_is_the_causal_kernel(backward_path):
     onp.testing.assert_array_equal(a, b)
     codes = [pa._mask_codes(True, None, 128, window=w) for w in (128, None)]
     assert onp.array_equal(*(pa._classes(c, 128, 32, 32) for c in codes))
+
+
+# ---- row statistics off HBM's padded columns (ISSUE 53) -------------------
+
+def _reference_lse(q, k, scale, **mask):
+    """logsumexp of the masked score rows, (B, H, S), by the plain rule."""
+    s_len, group = q.shape[2], q.shape[1] // k.shape[1]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q,
+                        jnp.repeat(k, group, axis=1)) * scale
+    codes = pa._mask_codes(mask.get("causal", False),
+                           mask.get("block_diffusion"), s_len,
+                           window=mask.get("window"))
+    if codes is not None:
+        scores = jnp.where(pa._keep(*codes), scores, -jnp.inf)
+    return jax.scipy.special.logsumexp(scores, axis=-1)
+
+
+@pytest.mark.parametrize("heads,dk,dv", [
+    ((2, 2), 128, 128), ((4, 1), 192, 128), ((8, 1), 64, 64)],
+    ids=["group1_128_128", "group4_192_128", "group8_64_64"])
+@pytest.mark.parametrize("mask", [
+    {"causal": True}, {"window": 192}, {"block_diffusion": (4, 256)}],
+    ids=["causal", "window", "block_diffusion"])
+def test_lane_dense_statistics_match_the_reference(mask, heads, dk, dv,
+                                                   backward_path):
+    """512 positions in tiles of 256 (two rows of a lane width a tile, two
+    tiles a head: the stored form's every index moves), the cells' widths
+    and head groups: the output and the three gradients against the plain
+    reference, the saved lse against the reference's logsumexp by position,
+    and no statistic wider than 32 bytes a row between the kernels — lse
+    alone under either plan: delta is made inside the kernels."""
+    from mxnet_tpu.telemetry import instruments as ti
+
+    h, kv = heads
+    rs = onp.random.RandomState(13)
+    q, k, v, w = (jnp.asarray(rs.randn(1, n, 512, d).astype("f")) * 0.5
+                  for n, d in ((h, dk), (kv, dk), (kv, dv), (h, dv)))
+    scale = dk ** -0.5
+
+    def kernel(q, k, v):
+        return pa.flash_attention(q, k, v, interpret=True, block_q=256,
+                                  block_k=256, **mask)
+
+    def plain(q, k, v):
+        return pa.attention_reference(q, k, v, **mask)
+
+    onp.testing.assert_allclose(kernel(q, k, v), plain(q, k, v),
+                                rtol=1e-5, atol=2e-6)
+    got = jax.grad(lambda *a: (kernel(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, "qkv"):
+        onp.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5,
+                                    err_msg="d" + name)
+    _, lse = pa._flash_fwd(q, k, v, mask.get("causal", False), scale, 256,
+                           256, True, block_diffusion=mask.get(
+                               "block_diffusion"), window=mask.get("window"))
+    assert lse.shape == (h, 2, 2, 128) and lse.dtype == jnp.float32
+    onp.testing.assert_allclose(pa.saved_lse(lse, q.shape),
+                                _reference_lse(q, k, scale, **mask),
+                                rtol=1e-5, atol=1e-5)
+    assert ti.attention_stat_bytes_per_row.value == 16 <= 32
+
+
+@pytest.mark.parametrize("block_q,tail,want", [
+    (1024, (8, 128), 4),           # the cells: one dense (8, 128) tile
+    (512, (4, 128), 8),
+    (128, (1, 128), 32),
+    (32, (1, 32), 128),            # a toy tile: one row, lane-padded
+    (104, (1, 104), 4096 / 104),
+])
+def test_the_stored_form_is_read_off_the_tile(block_q, tail, want,
+                                              monkeypatch):
+    """(heads, q tiles, rows, lanes): rows of a lane width where the tile
+    is a multiple of one, else the tile as one row; never a trailing 1.
+    The gauge is the form's bytes a row under the (8, 128) tiling, under
+    either plan of the backward: 1024 where lse and delta were columns."""
+    from mxnet_tpu.telemetry import instruments as ti
+
+    s_len = 4 * block_q
+    shape = (2, 3, s_len, 64)
+    for capacity in (None, 0):
+        with pytest.MonkeyPatch.context() as patch:
+            if capacity is not None:
+                patch.setattr(pa, "_vmem_capacity", lambda: capacity)
+            _forget_plans()
+            plan = pa._plan(shape, shape, shape, "bfloat16", True, block_q,
+                            block_q, None, None)
+            assert plan.stat_shape == (6, 4) + tail
+            assert plan.fused == (capacity is None)
+            assert plan.stat_bytes_per_row == pytest.approx(want)
+            assert ti.attention_stat_bytes_per_row.value == pytest.approx(
+                want)
+            _forget_plans()
+
+
+@pytest.mark.parametrize("unroll", [True, False],
+                         ids=["unrolled", "rolled"])
+@pytest.mark.parametrize("tile,lanes", [(256, 128), (32, 32)])
+def test_rows_and_columns_turn_into_each_other_exactly(tile, lanes, unroll):
+    """`_store_lse` and `_load_column`, the two turns of the kernels, in a
+    kernel of their own (a running sum of 1, so lse is the running max):
+    a relayout each way, so every float32 comes back bit for bit, -inf (a
+    row the mask empties) included, and the stored form holds the
+    column's values in order — the fused backward's unrolled turn and the
+    dQ and dK/dV kernels' rolled one alike."""
+    import jax.experimental.pallas as pl
+
+    rs = onp.random.RandomState(2)
+    col = jnp.asarray(rs.randn(tile, 1).astype("f") * 1e3)
+    col = col.at[5, 0].set(-jnp.inf).at[tile - 3, 0].set(0.0)
+
+    def kernel(m_ref, l_ref, stat_ref, back_ref):
+        pa._store_lse(stat_ref, m_ref, l_ref)
+        pa._load_column(stat_ref, 0, back_ref, unroll=unroll)
+
+    stat, back = pl.pallas_call(
+        kernel, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct((1, 1, tile // lanes, lanes),
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct((tile, 1), jnp.float32)])(
+        col, jnp.ones_like(col))
+    onp.testing.assert_array_equal(stat.reshape(-1), col[:, 0])
+    onp.testing.assert_array_equal(back, col)
+
+
+def test_the_backward_takes_no_delta_and_the_forward_gives_no_column():
+    """The jaxpr of a gradient: the forward kernel's second result is the
+    stored form, the fused backward takes ``out`` where it took two
+    columns, and nothing between them touches the statistics — no
+    reduction over dO . O, no reshape of lse."""
+    _forget_plans()
+    q = jnp.ones((1, 2, 256, 64), jnp.float32)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, interpret=True, causal=True,
+                               block_q=128, block_k=128).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(q, q, q)
+    _forget_plans()
+    calls = {}
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = (eqn.params.get("name")
+                        or eqn.params["name_and_src_info"].name)
+                calls[name] = eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    fwd, bwd = calls["flash_attention_fwd"], calls["flash_attention_bwd"]
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]  # noqa: E731
+    assert shapes(fwd.outvars) == [(2, 2, 128, 64), (2, 2, 1, 128)]
+    assert all(s[-1] != 1 for s in shapes(bwd.invars) if len(s) > 1)
+    # q, k, v, dO and out (as many key-value heads as query heads: one
+    # shape), and one statistic, the stored lse
+    assert shapes(bwd.invars).count((2, 2, 128, 64)) == 5
+    assert shapes(bwd.invars).count((2, 2, 1, 128)) == 1
