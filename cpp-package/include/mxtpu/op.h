@@ -8274,6 +8274,27 @@ inline std::vector<PackedTensor> instance_norm(
   return rt.invoke("instance_norm", ins_, a_.str());
 }
 
+inline std::vector<PackedTensor> kda_scan(
+    PyRuntime& rt,
+    const PackedTensor& q,
+    const PackedTensor& k,
+    const PackedTensor& v,
+    const PackedTensor& g,
+    const PackedTensor& beta,
+    const char* scale_json = nullptr,
+    long long chunk = 64) {
+  std::vector<PackedTensor> ins_;
+  ins_.push_back(q);
+  ins_.push_back(k);
+  ins_.push_back(v);
+  ins_.push_back(g);
+  ins_.push_back(beta);
+  detail::JsonBuilder a_;
+  if (scale_json) a_.raw("scale", scale_json);
+  a_.put_int("chunk", chunk);
+  return rt.invoke("kda_scan", ins_, a_.str());
+}
+
 inline std::vector<PackedTensor> khatri_rao(
     PyRuntime& rt,
     const std::vector<PackedTensor>& inputs) {
@@ -9869,6 +9890,19 @@ inline std::vector<PackedTensor> shape_array(
   ins_.push_back(data);
   detail::JsonBuilder a_;
   return rt.invoke("shape_array", ins_, a_.str());
+}
+
+inline std::vector<PackedTensor> short_conv(
+    PyRuntime& rt,
+    const PackedTensor& x,
+    const PackedTensor& w,
+    const std::string& activation = "silu") {
+  std::vector<PackedTensor> ins_;
+  ins_.push_back(x);
+  ins_.push_back(w);
+  detail::JsonBuilder a_;
+  a_.put_str("activation", activation);
+  return rt.invoke("short_conv", ins_, a_.str());
 }
 
 inline std::vector<PackedTensor> sigmoid(

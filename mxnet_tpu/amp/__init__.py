@@ -171,10 +171,13 @@ def _keeps_fp32(p):
     # and so does an expert layer's router: its top-k is decided on
     # float32 probabilities; and a looped model's exit gate, whose
     # sigmoid weighs every exit's loss; and a short convolution's taps,
-    # a few numbers a channel that multiply in float32 inside the mix
+    # a few numbers a channel that multiply in float32 inside the mix;
+    # and a delta-rule layer's decay parameters (A_log, dt_bias): its
+    # log-decays are float32 sums through the sequence
     name = p.name.lower()
     return any(k in name for k in ("gamma", "beta", "running", "moving",
-                                   "router", "exit_gate", "conv_taps"))
+                                   "router", "exit_gate", "conv_taps",
+                                   "a_log", "dt_bias"))
 
 
 def convert_hybrid_block(net, target_dtype="bfloat16", target_dtype_ops=None,

@@ -44,7 +44,8 @@ from ...telemetry import instruments as _telemetry
 from ..block import HybridBlock
 from ..contrib.nn import DroplessMoE, GatedMLP
 from ..nn import Dense, Embedding, HybridSequential
-from .decoder import GroupedQueryAttention, RMSNorm, head_loss, run_layers
+from .decoder import (GroupedQueryAttention, RMSNorm, next_token_loss,
+                      run_layers)
 
 __all__ = ["AfmoeDecoderLayer", "AfmoeModel", "AfmoeForCausalLM", "afmoe"]
 
@@ -150,15 +151,8 @@ class AfmoeForCausalLM(HybridBlock):
         seq = tokens.shape[1]
         positions = jnp.arange(seq, dtype=jnp.int32)
         hidden = self.model(tokens, NDArray(positions))
-        # every position is scored, so that the shapes stay whole tiles;
-        # the last one, which has no next token, with weight 0
-        target = apply_op(lambda t: jnp.roll(t, -1, axis=1), tokens,
-                          name="next_token")
-        weight = NDArray(jnp.broadcast_to(
-            (positions < seq - 1).astype(jnp.float32) / (seq - 1),
-            tokens.shape))
-        return head_loss(hidden, self.lm_head.weight.data_for(tokens),
-                         target, weight, "causal_lm_loss")
+        return next_token_loss(hidden, self.lm_head.weight.data_for(tokens),
+                               tokens, positions)
 
 
 def afmoe(vocab_size, hidden_size, layer_types, num_attention_heads,

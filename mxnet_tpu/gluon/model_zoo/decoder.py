@@ -22,7 +22,7 @@ from ..nn import Dense
 from ..parameter import Parameter
 
 __all__ = ["RMSNorm", "attend", "GroupedQueryAttention", "run_layers",
-           "run_looped", "token_loss", "head_loss"]
+           "run_looped", "token_loss", "head_loss", "next_token_loss"]
 
 # float32 logits a head makes whole; a call that would make more makes them
 # by blocks of positions of at most _BLOCK_LOGITS_BYTES each
@@ -137,18 +137,23 @@ class GroupedQueryAttention(HybridBlock):
         return self.o_proj(out)
 
 
-def run_layers(layers, remat, x, *args):
+def run_layers(layers, remat, x, *args, parts=None):
     """x through ``layers``, each called with ``args``; with ``remat``
     each layer is one checkpoint segment of a training program
-    (`gluon.block.checkpoint_block`)."""
+    (`gluon.block.checkpoint_block`).  ``parts``: names a layer's
+    ``forward`` takes as its last argument, one call a name in order (a
+    layer's mixer half and its feed-forward half), each call its own
+    segment: the backward then holds one HALF's activations at a time."""
     for layer in layers:
-        if remat:
-            # recompute the layer on the way back, all but the flash
-            # kernel: its output and logsumexp are 1/16 of what the
-            # layer computes and the most expensive part to redo
-            x = checkpoint_block(layer, x, *args, save=SAVED_BY_NAME)
-        else:
-            x = layer(x, *args)
+        for part in parts or [None]:
+            call = args if part is None else args + (part,)
+            if remat:
+                # recompute the layer on the way back, all but the flash
+                # kernel: its output and logsumexp are 1/16 of what the
+                # layer computes and the most expensive part to redo
+                x = checkpoint_block(layer, x, *call, save=SAVED_BY_NAME)
+            else:
+                x = layer(x, *call)
     return x
 
 
@@ -272,3 +277,21 @@ def head_loss(hidden, head_weight, target, weight, name, positions=None):
                            axis=1)
 
     return apply_op(pure, hidden, head_weight, target, weight, name=name)
+
+
+def next_token_loss(hidden, head_weight, tokens, positions=None):
+    """Each sequence's mean next-token cross-entropy, float32: position
+    i < S - 1 of ``hidden`` (B, S, units) is scored on ``tokens``[i + 1]
+    over the rows of ``head_weight`` (`head_loss`).  Every position is
+    scored, so that the shapes stay whole tiles; the last one, which has no
+    next token, with weight 0.  ``positions``: 0 .. S - 1 as int32, from a
+    caller that made them for its layers already."""
+    seq = tokens.shape[1]
+    if positions is None:
+        positions = jnp.arange(seq, dtype=jnp.int32)
+    target = apply_op(lambda t: jnp.roll(t, -1, axis=1), tokens,
+                      name="next_token")
+    weight = NDArray(jnp.broadcast_to(
+        (positions < seq - 1).astype(jnp.float32) / (seq - 1),
+        tokens.shape))
+    return head_loss(hidden, head_weight, target, weight, "causal_lm_loss")

@@ -37,7 +37,7 @@ from ...ndarray.ndarray import NDArray, apply_op
 from ..block import HybridBlock
 from ..contrib.nn import DroplessMoE, GatedMLP
 from ..nn import Dense, Embedding, HybridSequential
-from .decoder import RMSNorm, attend, head_loss, run_layers
+from .decoder import RMSNorm, attend, next_token_loss, run_layers
 
 __all__ = ["MultiHeadLatentAttention", "DeepseekV3DecoderLayer",
            "DeepseekV3Model", "DeepseekV3ForCausalLM", "deepseek_v3"]
@@ -48,20 +48,27 @@ class MultiHeadLatentAttention(HybridBlock):
     latent a token (``kv_lora_rank`` wide, normed) and whose rotary part
     (``qk_rope_head_dim``) is decoupled: every head's query carries its
     own, the key's is one for all heads.  ``forward(x, positions)``: x
-    (B, S, units), ``positions`` the S position ids.
+    (B, S, units), ``positions`` the S position ids.  With
+    ``rotary=False`` the layer carries no positions (``mla_use_nope`` of
+    model type ``kimi_linear``): the decoupled parts stay unrotated, the
+    shared key part goes to every head as it is, and ``positions`` is not
+    read.
 
     Scopes, all under ``mla``: ``mla.q``, ``mla.kv_latent`` (down
     projection, latent norm, up projection), ``mla.rope`` (rotation and
-    the assembly of the heads: `npx.mla_heads`, one pass on a TPU),
+    the assembly of the heads: `npx.mla_heads`, one pass on a TPU; where
+    nothing rotates the assembly alone, under ``mla.heads``),
     ``attention`` (the flash kernels) and ``mla.out``."""
 
     def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
                  qk_rope_head_dim, v_head_dim, rope_theta=10000.0,
-                 rope_interleave=True, epsilon=1e-6, dtype="float32"):
+                 rope_interleave=True, epsilon=1e-6, dtype="float32",
+                 rotary=True):
         super().__init__()
         self._heads, self._rank, self._v = num_heads, kv_lora_rank, v_head_dim
         self._theta, self._interleave = float(rope_theta), \
             bool(rope_interleave)
+        self._rotary = bool(rotary)
 
         def proj(out_units, in_units):
             return Dense(out_units, use_bias=False, flatten=False,
@@ -87,12 +94,14 @@ class MultiHeadLatentAttention(HybridBlock):
                     lambda t: (t[..., :rank], t[..., rank:]), latent,
                     name="split_latent")
                 kv = self.kv_b_proj(self.kv_a_norm(c))
-            with jax.named_scope("mla.rope"):
+            with jax.named_scope("mla.rope" if self._rotary
+                                 else "mla.heads"):
                 # the rotation, the one key part beside every head's own
                 # and the move to (B, H, S, ..) are row-wise: one op,
                 # straight from the projections' layout
-                q, k, v = npx.mla_heads(q, kv, k_rope, positions,
-                                        self._theta, h, self._interleave)
+                q, k, v = npx.mla_heads(
+                    q, kv, k_rope, positions if self._rotary else None,
+                    self._theta, h, self._interleave)
             out = attend(q, k, v, causal=True)
             with jax.named_scope("mla.out"):
                 return self.o_proj(
@@ -170,15 +179,8 @@ class DeepseekV3ForCausalLM(HybridBlock):
         seq = tokens.shape[1]
         positions = jnp.arange(seq, dtype=jnp.int32)
         hidden = self.model(tokens, NDArray(positions))
-        # every position is scored, so that the shapes stay whole tiles;
-        # the last one, which has no next token, with weight 0
-        target = apply_op(lambda t: jnp.roll(t, -1, axis=1), tokens,
-                          name="next_token")
-        weight = NDArray(jnp.broadcast_to(
-            (positions < seq - 1).astype(jnp.float32) / (seq - 1),
-            tokens.shape))
-        return head_loss(hidden, self.lm_head.weight.data_for(tokens),
-                         target, weight, "causal_lm_loss")
+        return next_token_loss(hidden, self.lm_head.weight.data_for(tokens),
+                               tokens, positions)
 
 
 def deepseek_v3(vocab_size, hidden_size, num_hidden_layers,

@@ -34,12 +34,13 @@ import collections
 
 import jax.numpy as jnp
 
-from ...ndarray.ndarray import NDArray, apply_op
+from ...ndarray.ndarray import NDArray
 from ...telemetry import instruments as _telemetry
 from ..block import HybridBlock
 from ..contrib.nn import DroplessMoE, GatedMLP, GatedShortConv
 from ..nn import Dense, Embedding, HybridSequential
-from .decoder import GroupedQueryAttention, RMSNorm, head_loss, run_layers
+from .decoder import (GroupedQueryAttention, RMSNorm, next_token_loss,
+                      run_layers)
 
 __all__ = ["Lfm2MoeDecoderLayer", "Lfm2MoeModel", "Lfm2MoeForCausalLM",
            "lfm2_moe"]
@@ -142,17 +143,10 @@ class Lfm2MoeForCausalLM(HybridBlock):
         seq = tokens.shape[1]
         positions = jnp.arange(seq, dtype=jnp.int32)
         hidden = self.model(tokens, NDArray(positions))
-        # every position is scored, so that the shapes stay whole tiles;
-        # the last one, which has no next token, with weight 0
-        target = apply_op(lambda t: jnp.roll(t, -1, axis=1), tokens,
-                          name="next_token")
-        weight = NDArray(jnp.broadcast_to(
-            (positions < seq - 1).astype(jnp.float32) / (seq - 1),
-            tokens.shape))
         head = (self.model.embed_tokens if self.lm_head is None
                 else self.lm_head).weight
-        return head_loss(hidden, head.data_for(tokens), target, weight,
-                         "causal_lm_loss")
+        return next_token_loss(hidden, head.data_for(tokens), tokens,
+                               positions)
 
 
 def lfm2_moe(vocab_size, hidden_size, layer_types, num_attention_heads,

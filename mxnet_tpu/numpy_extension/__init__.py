@@ -13,6 +13,7 @@ from .. import _random
 from ..autograd import is_training
 from ..ndarray.ndarray import NDArray, apply_op
 from ..ops import nn as _nn
+from ..ops import pallas_kda as _kda
 from ..ops import pallas_mla_heads as _mla_heads
 from ..ops import pallas_qk_prep as _qk_prep
 from ..ops import short_conv as _short_conv
@@ -27,6 +28,7 @@ __all__ = [
     "softmin", "fully_connected", "convolution", "deconvolution", "pooling",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "rms_norm",
     "rotary_embedding", "rms_norm_rotary", "mla_heads", "gated_short_conv",
+    "short_conv", "kda_scan",
     "lrn", "dropout", "embedding", "one_hot", "pick", "topk", "sequence_mask",
     "sequence_last", "sequence_reverse", "l2_normalization", "upsampling",
     "moments", "gamma", "erf", "erfinv", "set_np", "reset_np", "is_np_array",
@@ -69,6 +71,8 @@ rotary_embedding = _op(_nn.rotary_embedding, 2)
 rms_norm_rotary = _op(_qk_prep.rms_norm_rotary, 3)
 mla_heads = _op(_mla_heads.mla_heads, 4)
 gated_short_conv = _op(_short_conv.gated_short_conv, 2)
+short_conv = _op(_short_conv.short_conv, 2)
+kda_scan = _op(_kda.kda_scan, 5)
 lrn = _op(_nn.lrn, 1)
 embedding = _op(_nn.embedding, 2)
 one_hot = _op(_nn.one_hot, 1)

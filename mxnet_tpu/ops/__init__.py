@@ -18,6 +18,7 @@ from . import legacy  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import pallas_qk_prep  # noqa: F401
+from . import pallas_kda  # noqa: F401
 from . import pallas_mla_heads  # noqa: F401
 from . import short_conv  # noqa: F401
 from .registry import list_ops, register_op, get_op  # noqa: F401

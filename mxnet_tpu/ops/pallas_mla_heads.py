@@ -74,8 +74,8 @@ _CHUNK = 64
 # heads, the widths of a head's nope and v lanes, rows of a block, the
 # pairing of the rope lanes, and whether Pallas interprets the kernels
 # (tests, off a TPU)
-_Sig = collections.namedtuple("_Sig",
-                              "heads nope v rows interleaved interpret")
+_Sig = collections.namedtuple(
+    "_Sig", "heads nope v rows interleaved interpret rotate")
 
 
 def _row_tile(s_len, nope, v, itemsize):
@@ -168,14 +168,28 @@ def _by_chunks(rows, body):
     lax.fori_loop(0, rows // _CHUNK, step, None)
 
 
-def _q_fwd_kernel(x_ref, cos_ref, sin_ref, o_ref, *, nope, interleaved):
+def _turn(tables, r, interleaved, back=False):
+    """Rows r of the tables read, and with them ``turn(t)``: a register
+    turned by their angles (``back``: the rotation's transpose).  Without
+    tables — a layer that carries no positions — nothing is read and a
+    register stays as it is."""
+    if not tables:
+        return lambda t: t
+    cos, sin = tables[0][r], tables[1][r]
+    how = _turned_back if back else _turned
+    return lambda t: how(t, cos, sin, interleaved)
+
+
+def _q_fwd_kernel(x_ref, *refs, nope, interleaved):
+    *tables, o_ref = refs       # cos and sin, or neither
+
     def body(r):
-        cos, sin = cos_ref[r], sin_ref[r]
+        turn = _turn(tables, r, interleaved)
         o_ref[0, 0, r, :nope] = x_ref[0, r, :nope]
         # [rope of the first head ; the second head's first 64 lanes]
         t = x_ref[0, r, nope:nope + _LANES].astype(jnp.float32)
         low = _low(t.shape)
-        t = jnp.where(low, _turned(t, cos, sin, interleaved), t)
+        t = jnp.where(low, turn(t), t)
         o_ref[0, 0, r, nope:] = t[:, :_ROPE].astype(o_ref.dtype)
         # the second head: each lane block is the upper half of one
         # register beside the lower half of the next; its rope lanes are
@@ -185,7 +199,7 @@ def _q_fwd_kernel(x_ref, cos_ref, sin_ref, o_ref, *, nope, interleaved):
             t = x_ref[0, r, nope + _LANES + at:nope + 2 * _LANES + at
                       ].astype(jnp.float32)
             if at + _LANES == nope:
-                t = jnp.where(low, t, _turned(t, cos, sin, interleaved))
+                t = jnp.where(low, t, turn(t))
             t = _swapped(t)
             o_ref[0, 1, r, at:at + _LANES] = jnp.where(low, last, t).astype(
                 o_ref.dtype)
@@ -195,14 +209,15 @@ def _q_fwd_kernel(x_ref, cos_ref, sin_ref, o_ref, *, nope, interleaved):
     _by_chunks(x_ref.shape[1], body)
 
 
-def _q_bwd_kernel(dy_ref, cos_ref, sin_ref, dx_ref, *, nope, interleaved):
+def _q_bwd_kernel(dy_ref, *refs, nope, interleaved):
+    *tables, dx_ref = refs
+
     def body(r):
-        cos, sin = cos_ref[r], sin_ref[r]
+        turn_back = _turn(tables, r, interleaved, back=True)
 
         def rope_back(head):
-            return _turned_back(
-                _twice(dy_ref[0, head, r, nope:].astype(jnp.float32)),
-                cos, sin, interleaved)
+            return turn_back(
+                _twice(dy_ref[0, head, r, nope:].astype(jnp.float32)))
 
         dx_ref[0, r, :nope] = dy_ref[0, 0, r, :nope]
         last = rope_back(0)
@@ -218,13 +233,16 @@ def _q_bwd_kernel(dy_ref, cos_ref, sin_ref, dx_ref, *, nope, interleaved):
     _by_chunks(dx_ref.shape[1], body)
 
 
-def _kv_fwd_kernel(kv_ref, kr_ref, cos_ref, sin_ref, k_ref, v_ref, *, nope,
-                   interleaved):
+def _kv_fwd_kernel(kv_ref, kr_ref, *refs, nope, interleaved):
+    *tables, k_ref, v_ref = refs
     width = nope + v_ref.shape[-1]
 
     def body(r):
-        rope = _turned(_twice(kr_ref[0, r].astype(jnp.float32)), cos_ref[r],
-                       sin_ref[r], interleaved)[:, :_ROPE].astype(k_ref.dtype)
+        rope = kr_ref[0, r]
+        if tables:
+            rope = _twice(rope.astype(jnp.float32))
+            rope = _turn(tables, r, interleaved)(rope)[:, :_ROPE].astype(
+                k_ref.dtype)
         for head in range(2):
             k_ref[0, head, r, :nope] = kv_ref[0, r, head * width:
                                               head * width + nope]
@@ -235,9 +253,10 @@ def _kv_fwd_kernel(kv_ref, kr_ref, cos_ref, sin_ref, k_ref, v_ref, *, nope,
     _by_chunks(kv_ref.shape[1], body)
 
 
-def _kv_bwd_kernel(dk_ref, dv_ref, cos_ref, sin_ref, dkv_ref, dkr_ref,
-                   sum_ref, *, nope, interleaved):
+def _kv_bwd_kernel(dk_ref, dv_ref, *refs, nope, interleaved):
     import jax.experimental.pallas as pl
+
+    *tables, dkv_ref, dkr_ref, sum_ref = refs
 
     width, rows = nope + dv_ref.shape[-1], dkv_ref.shape[1]
     pair = pl.program_id(2)
@@ -259,12 +278,15 @@ def _kv_bwd_kernel(dk_ref, dv_ref, cos_ref, sin_ref, dkv_ref, dkr_ref,
 
     @pl.when(pair == pl.num_programs(2) - 1)
     def _():
-        def turn_back(r):
-            dkr_ref[0, r] = _turned_back(
-                _twice(sum_ref[r]), cos_ref[r], sin_ref[r], interleaved
-            )[:, :_ROPE].astype(dkr_ref.dtype)
+        def store(r):
+            total = sum_ref[r]
+            if tables:
+                total = _twice(total)
+                total = _turn(tables, r, interleaved, back=True)(
+                    total)[:, :_ROPE]
+            dkr_ref[0, r] = total.astype(dkr_ref.dtype)
 
-        _by_chunks(rows, turn_back)
+        _by_chunks(rows, store)
 
 
 def _specs(sig):
@@ -302,19 +324,22 @@ def _heads_fwd_call(sig, q, kv, k_rope, cos, sin):
         return jax.ShapeDtypeStruct((b, heads, s_len, width), q.dtype)
 
     kernel = dict(nope=nope, interleaved=sig.interleaved)
+    # a layer without positions: no table among the operands
+    tables = (cos, sin) if sig.rotate else ()
     q_out = _pallas_call(
         functools.partial(_q_fwd_kernel, **kernel), name="mla_heads_q_fwd",
-        grid=grid, in_specs=[flat(nope + _ROPE), table, table],
+        grid=grid, in_specs=[flat(nope + _ROPE)] + [table] * len(tables),
         out_specs=major(nope + _ROPE), out_shape=head_major(nope + _ROPE),
         interpret=sig.interpret,
-    )(q, cos, sin)
+    )(q, *tables)
     k_out, v_out = _pallas_call(
         functools.partial(_kv_fwd_kernel, **kernel), name="mla_heads_kv_fwd",
-        grid=grid, in_specs=[flat(nope + v), shared, table, table],
+        grid=grid,
+        in_specs=[flat(nope + v), shared] + [table] * len(tables),
         out_specs=[major(nope + _ROPE), major(v)],
         out_shape=[head_major(nope + _ROPE), head_major(v)],
         interpret=sig.interpret,
-    )(kv, k_rope, cos, sin)
+    )(kv, k_rope, *tables)
     return q_out, k_out, v_out
 
 
@@ -330,20 +355,22 @@ def _heads_bwd_call(sig, dq, dk, dv, cos, sin):
         return jax.ShapeDtypeStruct((b, s_len, width), dq.dtype)
 
     kernel = dict(nope=nope, interleaved=sig.interleaved)
+    tables = (cos, sin) if sig.rotate else ()
     dx = _pallas_call(
         functools.partial(_q_bwd_kernel, **kernel), name="mla_heads_q_bwd",
-        grid=grid, in_specs=[major(nope + _ROPE), table, table],
+        grid=grid, in_specs=[major(nope + _ROPE)] + [table] * len(tables),
         out_specs=flat(nope + _ROPE),
         out_shape=projected(heads * (nope + _ROPE)), interpret=sig.interpret,
-    )(dq, cos, sin)
+    )(dq, *tables)
     dkv, dk_rope = _pallas_call(
         functools.partial(_kv_bwd_kernel, **kernel), name="mla_heads_kv_bwd",
-        grid=grid, in_specs=[major(nope + _ROPE), major(v), table, table],
+        grid=grid,
+        in_specs=[major(nope + _ROPE), major(v)] + [table] * len(tables),
         out_specs=[flat(nope + v), shared],
         out_shape=[projected(heads * (nope + v)), projected(_ROPE)],
         scratch_shapes=[pltpu.VMEM((sig.rows, _ROPE), jnp.float32)],
         interpret=sig.interpret,
-    )(dk, dv, cos, sin)
+    )(dk, dv, *tables)
     return dx, dkv, dk_rope
 
 
@@ -364,7 +391,8 @@ def _assembled_bwd(sig, res, cotangents):
     cos, sin = res
     dx, dkv, dk_rope = _shared(_heads_bwd_call, sig)(*cotangents, cos, sin)
     # the tables come from integer positions: nothing flows back to them
-    return dx, dkv, dk_rope, jnp.zeros_like(cos), jnp.zeros_like(sin)
+    return (dx, dkv, dk_rope, None if cos is None else jnp.zeros_like(cos),
+            None if sin is None else jnp.zeros_like(sin))
 
 
 _assembled.defvjp(_assembled_fwd, _assembled_bwd)
@@ -381,6 +409,8 @@ def _composition(q, kv, k_rope, positions, theta, num_heads, interleaved):
     kv = kv.reshape((b, s_len, num_heads, -1))
 
     def turned(t):
+        if positions is None:
+            return t
         return _nn.rotary_embedding(t, positions.reshape((s_len, 1)), theta,
                                     interleaved=interleaved)
 
@@ -401,7 +431,10 @@ def mla_heads(q, kv, k_rope, positions, theta=10000.0, num_heads=1,
     q: (B, S, num_heads * (nope + rope)), every head's [q_nope ; q_rope];
     kv: (B, S, num_heads * (nope + v)), every head's [k_nope ; v];
     k_rope: (B, S, rope), one for all heads; ``positions``: the S position
-    ids, shared by the batch.  Returns q and k (B, num_heads, S, nope +
+    ids, shared by the batch, or None for a layer that carries no positions
+    (``turn`` below is then the identity: the rotation is compiled out of
+    the same kernels and of the composition, no table is among their
+    operands, and ``theta`` and ``interleaved`` say nothing).  Returns q and k (B, num_heads, S, nope +
     rope) and v (B, num_heads, S, v) in q's type:
 
         q_h = [q_nope_h ; turn(q_rope_h)]
@@ -434,10 +467,12 @@ def mla_heads(q, kv, k_rope, positions, theta=10000.0, num_heads=1,
     if (nope <= 0 or v <= 0 or width != num_heads * (nope + rope)
             or kv.shape != (b, s_len, num_heads * (nope + v))
             or k_rope.shape != (b, s_len, rope) or rope % 2
-            or positions.size != s_len):
+            or (positions is not None and positions.size != s_len)):
         raise ValueError(
             f"q {q.shape}, kv {kv.shape}, k_rope {k_rope.shape} as "
-            f"{num_heads} heads, {positions.size} positions: q's last "
+            f"{num_heads} heads, "
+            f"{None if positions is None else positions.size} positions: "
+            "q's last "
             "dimension is num_heads heads of nope + rope, kv's num_heads "
             "heads of nope + v, k_rope's an even rope, and there is a "
             "position a row")
@@ -449,9 +484,11 @@ def mla_heads(q, kv, k_rope, positions, theta=10000.0, num_heads=1,
     if not kernels:
         return _composition(q, kv, k_rope, positions, theta, num_heads,
                             interleaved)
-    interleaved = bool(interleaved)
-    cos, sin = _tables(positions, theta, interleaved)
+    rotate = positions is not None
+    interleaved = bool(interleaved) and rotate
+    cos, sin = _tables(positions, theta, interleaved) if rotate \
+        else (None, None)
     sig = _Sig(int(num_heads), nope, v,
                _row_tile(s_len, nope, v, jnp.dtype(q.dtype).itemsize),
-               interleaved, interpret)
+               interleaved, interpret, rotate)
     return _assembled(q, kv, k_rope, cos, sin, sig)

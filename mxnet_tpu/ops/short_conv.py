@@ -20,15 +20,22 @@ computes u and c again, turns the cotangent back through the same L
 shifts the other way (anti-causal: a position's gradient comes from the
 L - 1 positions after it) and hands dB, dC and dx~ back as one (B, S,
 3D) cotangent; dw is summed over batch and positions in float32.
+
+`short_conv` is the same taps without the two gates, followed by an
+activation: what a linear-attention layer puts on its queries, keys and
+values (``kimi_linear``: 4 taps, SiLU).  The same shifts, the same rule:
+the backward keeps x and w and computes the taps' sum again.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from .registry import register_op
 
-__all__ = ["gated_short_conv"]
+__all__ = ["gated_short_conv", "short_conv"]
 
 
 def _shift(t, k):
@@ -115,3 +122,68 @@ def gated_short_conv(bcx, w):
     _telemetry.record_short_conv_site()
     with jax.named_scope("short_conv.mix"):
         return _mix(bcx, w)
+
+
+def _taps(x, w):
+    """c_t = sum over j of w[:, j] * x_{t - (L - 1) + j}, float32."""
+    taps, out = w.shape[1], 0.0
+    for j in range(taps):
+        out = out + w[:, j] * _shift(x, taps - 1 - j).astype(jnp.float32)
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv_act(x, w, silu):
+    c = _taps(x, w.astype(jnp.float32))
+    return (jax.nn.silu(c) if silu else c).astype(x.dtype)
+
+
+def _conv_act_fwd(x, w, silu):
+    return _conv_act(x, w, silu), (x, w)
+
+
+def _conv_act_bwd(silu, res, dy):
+    x, w = res
+    w32, taps = w.astype(jnp.float32), w.shape[1]
+    dc = dy.astype(jnp.float32)
+    if silu:
+        c = _taps(x, w32)
+        sig = jax.nn.sigmoid(c)
+        dc = dc * sig * (1.0 + c * (1.0 - sig))
+    dx, dw = 0.0, []
+    for j in range(taps):
+        k = taps - 1 - j
+        # the transpose of tap j: position s hears from position s + k
+        dx = dx + w32[:, j] * _shift(dc, -k)
+        dw.append(jnp.sum(dc * _shift(x, k).astype(jnp.float32),
+                          axis=(0, 1)))
+    return dx.astype(x.dtype), jnp.stack(dw, axis=-1).astype(w.dtype)
+
+
+_conv_act.defvjp(_conv_act_fwd, _conv_act_bwd)
+
+
+@register_op("short_conv")
+def short_conv(x, w, activation="silu"):
+    """A depthwise causal convolution over the last few positions, then an
+    activation, as one op.
+
+    x: (B, S, D); w: (D, L), the depthwise taps, tap L - 1 on the position
+    itself; ``activation``: ``"silu"`` or None.  Returns (B, S, D) in x's
+    type:
+
+        y_t = act(sum over j < L of w[:, j] * x_{t - (L - 1) + j})
+
+    with zeros before the sequence (PyTorch's ``Conv1d(D, D, L, groups=D,
+    padding=L - 1)`` cut to its first S outputs).  L shifted multiply-adds
+    in float32, rounded once; the backward (a hand-written VJP) keeps x
+    and w alone, computes the taps' sum again for the activation's slope
+    and sums dw over batch and positions in float32.  Forward and backward
+    run under the scope ``short_conv.taps``."""
+    if x.ndim != 3 or w.ndim != 2 or w.shape[0] != x.shape[-1]:
+        raise ValueError(f"x {x.shape}, taps {w.shape}: (B, S, D) and "
+                         "(D, L) taps")
+    if activation not in ("silu", None):
+        raise ValueError(f"activation {activation!r}: 'silu' or None")
+    with jax.named_scope("short_conv.taps"):
+        return _conv_act(x, w, activation == "silu")

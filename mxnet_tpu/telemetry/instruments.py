@@ -32,6 +32,8 @@ __all__ = [
     "mla_heads_kernel_share", "record_mla_heads_site",
     "looped_stack_copies", "ut_steps", "set_looped_stack", "exit_mass",
     "stage_exit_mass", "flush_exit_mass",
+    "kda_scan_calls", "kda_scan_chunks", "kda_scan_kept_bytes",
+    "record_kda_scan",
     "short_conv_sites", "decoder_layers", "record_short_conv_site",
     "short_conv_sites_traced", "set_decoder_stack",
     "xla_compile_seconds_total", "xla_programs_total",
@@ -227,6 +229,23 @@ mla_heads_kernel_share = gauge(
     "length that is a multiple of 8); a site on the composition of XLA ops "
     "(rotary_embedding, broadcast, concatenate, transpose) counts as 0. "
     "Set on the host each time the op is traced")
+kda_scan_calls = gauge(
+    "kda_scan_calls",
+    "Call sites of ops.pallas_kda.kda_scan (Kimi Delta Attention's chunked "
+    "scan) traced so far, by the path they took: kernel (the two Pallas "
+    "kernels: a TPU, key and value widths that are multiples of 128, a "
+    "chunk that is a multiple of 8) or composition (the same chunk "
+    "arithmetic as a lax.scan of XLA ops). Set on the host each time the "
+    "op is traced", ["path"])
+kda_scan_chunks = gauge(
+    "kda_scan_chunks",
+    "Chunks (batch x heads x ceil(S / chunk)) of the kda_scan call traced "
+    "last: the steps of its sequential walk times the states it carries")
+kda_scan_kept_bytes = gauge(
+    "kda_scan_kept_bytes",
+    "Bytes the backward of the kda_scan call traced last keeps beside the "
+    "op's five operands: the float32 state that entered each chunk "
+    "(chunks x d_k x d_v x 4)")
 looped_stack_copies = gauge(
     "looped_stack_copies",
     "Copies of the layer stack that the traced program of a model which "
@@ -933,6 +952,21 @@ _mla_heads_sites = [0, 0]    # traced call sites: on the kernels, in all
 
 def record_mla_heads_site(kernels):
     _record_kernel_site(_mla_heads_sites, mla_heads_kernel_share, kernels)
+
+
+_kda_scan_calls = {"kernel": 0, "composition": 0}
+
+
+def record_kda_scan(kernels, chunks, kept_bytes):
+    """One more traced call site of `kda_scan`: the path it took, its
+    chunks and what its backward keeps."""
+    if not REGISTRY.enabled:
+        return
+    path = "kernel" if kernels else "composition"
+    _kda_scan_calls[path] += 1
+    kda_scan_calls.labels(path).set(_kda_scan_calls[path])
+    kda_scan_chunks.set(chunks)
+    kda_scan_kept_bytes.set(kept_bytes)
 
 
 def set_looped_stack(steps):

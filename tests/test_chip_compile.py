@@ -990,6 +990,181 @@ def test_the_window_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
         g.clear()
 
 
+# -- the delta / latent hybrid (ISSUE 54): the chunked scan's two kernels,
+#    latent attention's assembly without positions, and the cell's step ------
+
+def _kda_specs(spec, b, seq, heads, d, dtype):
+    return (spec((b, seq, heads, d), dtype), spec((b, seq, heads, d), dtype),
+            spec((b, seq, heads, d), dtype),
+            spec((b, seq, heads, d), jnp.float32),
+            spec((b, seq, heads), jnp.float32))
+
+
+@pytest.mark.parametrize("b,seq,heads,dtype", [
+    (2, 8192, 32, jnp.bfloat16),        # the cell's shape
+    (1, 1000, 2, jnp.bfloat16),         # padded to 16 chunks of 64
+    (1, 256, 2, jnp.float32),           # one grid step of four chunks
+], ids=["cell", "ragged", "float32"])
+def test_the_delta_rules_scan_compiles_for_v5e(spec, monkeypatch, b, seq,
+                                               heads, dtype):
+    """`kda_scan` on its kernels: one Mosaic call forward, one back (the
+    backward's own forward is the first again), no 64-bit value in the
+    program, and what the backward holds beside the operands is the
+    states that entered the chunks."""
+    from mxnet_tpu.ops import pallas_kda as pk
+    from mxnet_tpu.telemetry import instruments as ti
+
+    monkeypatch.setattr(pk, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(ti, "_kda_scan_calls",
+                        {"kernel": 0, "composition": 0})
+    pk._shared.cache_clear()
+    operands = _kda_specs(spec, b, seq, heads, 128, dtype)
+
+    def loss(*a):
+        return pk.kda_scan(*a).astype(jnp.float32).sum()
+
+    assert _kernel_calls(loss, *operands) == 1
+    compiled = jax.jit(jax.value_and_grad(loss, range(5))).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'(kda_scan_\w+?)/', text)
+    assert sorted(names) == ["kda_scan_bwd", "kda_scan_fwd"]
+    assert "f64[" not in text and "s64[" not in text
+    assert ti._kda_scan_calls == {"kernel": 2, "composition": 0}
+    chunks = b * heads * (-(-seq // 64) if seq <= 512
+                          else -(-seq // 512) * 8)
+    assert ti.kda_scan_chunks.value == chunks
+    assert ti.kda_scan_kept_bytes.value == chunks * 128 * 128 * 4
+    pk._shared.cache_clear()
+
+
+def test_layers_share_one_copy_of_each_scan_kernel(spec, monkeypatch):
+    """Four delta layers at the cell's shape, forward and backward,
+    lowered for the described v5e: two Mosaic calls in the module, not two
+    a layer."""
+    from mxnet_tpu.ops import pallas_kda as pk
+
+    monkeypatch.setattr(pk, "_kernel_mode", lambda: False)
+    pk._shared.cache_clear()
+
+    def loss(q, k, v, g, beta):
+        total = 0.0
+        for i in range(4):
+            with jax.named_scope(f"layer{i}"):
+                out = pk.kda_scan(q, k, v, g, beta)
+            total = total + out.astype(jnp.float32).sum()
+            q = q + out.mean().astype(q.dtype)
+        return total
+
+    text = jax.jit(jax.value_and_grad(loss, range(5))).lower(
+        *_kda_specs(spec, 2, 8192, 32, 128, jnp.bfloat16)).as_text()
+    names = re.findall(r'stablehlo.custom_call @tpu_custom_call.*'
+                       r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == ["kda_scan_bwd", "kda_scan_fwd"]
+    assert text.count("call @kernel_fwd") == 4
+    assert text.count("call @kernel_bwd") == 4
+    pk._shared.cache_clear()
+
+
+@pytest.mark.parametrize("heads,seq,dtype", [
+    (32, 8192, jnp.bfloat16),           # the cell's shape
+    (2, 24, jnp.float32),
+], ids=["cell", "short-float32"])
+def test_position_free_latent_heads_assembly_compiles_for_v5e(
+        spec, monkeypatch, heads, seq, dtype):
+    """`mla_heads(positions=None)` on its kernels: the same four Mosaic
+    calls, and no table among the operands of any."""
+    from mxnet_tpu.ops import pallas_mla_heads as mh
+
+    monkeypatch.setattr(mh, "_kernel_mode", lambda: False)
+    q, kv, k_rope, _ = _mla_heads_specs(spec, heads, seq, dtype)
+
+    def loss(q, kv, k_rope):
+        outs = mh.mla_heads(q, kv, k_rope, None, 1e4, heads, True)
+        return sum(o.astype(jnp.float32).sum() for o in outs)
+
+    assert _kernel_calls(loss, q, kv, k_rope) == 2
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        q, kv, k_rope).compile().as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and " custom-call(" in l]
+    assert len(calls) == 4
+    assert "cosine" not in text and "f64[" not in text
+    for l in calls:
+        # q alone; kv and k_rope; dq alone; dk and dv
+        assert l.count("%") - 1 <= 2, l
+
+
+def test_the_delta_cells_whole_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The Kimi-Linear cell's step as gluon.TrainStep builds it — every
+    published width, 2 x 8,192 positions, the 20,480 rows held of
+    embedding and head, bf16 under Adam with masters, remat; TWO of its
+    five layers, one of each mixer (published layer 1: delta + dense;
+    layer 8: latent attention without positions + experts beside the
+    shared one) — compiled for the described v5e.  The delta layer is
+    three scan calls (forward, replayed, backward), the latent layer two
+    flash calls and six assembly calls with no table; arguments and
+    temporaries fit the chip.  (`chipbench/compile_check_large.py`
+    compiles all five.)"""
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, gluon
+    from mxnet_tpu.gluon.model_zoo.kimi_linear import kimi_linear
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import pallas_kda as pk
+    from mxnet_tpu.ops import pallas_mla_heads as mh
+    from mxnet_tpu.telemetry import instruments as ti
+
+    kernel = pa.flash_attention
+    monkeypatch.setattr(pa, "flash_attention", lambda *a, **kw: kernel(
+        *a, **{**kw, "interpret": False}))
+    monkeypatch.setattr(mh, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(pk, "_kernel_mode", lambda: False)
+    monkeypatch.setattr(ti, "_mla_heads_sites", [0, 0])
+    monkeypatch.setattr(ti, "_kda_scan_calls",
+                        {"kernel": 0, "composition": 0})
+    pa._plan.cache_clear()
+    pk._shared.cache_clear()
+    fallbacks = {k: c.value for k, c in
+                 ti.attention_kernel_fallback_total.series()}
+    lin = {"kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
+           "num_heads": 32, "head_dim": 128, "short_conv_kernel_size": 4}
+    net = kimi_linear(20480, 2304, lin, 32, 512, 128, 64, 128, 9216, 1024,
+                      256, 8, routed_scaling_factor=2.446, layers=[1, 8],
+                      ep_size=32, remat=True)
+    net.initialize(init=mx.initializer.Zero())
+    amp.convert_hybrid_block(net, target_dtype="bfloat16")
+    net.hybridize()
+    trainer = gluon.Trainer(
+        net.collect_params(), "adam",
+        {"learning_rate": 1e-5, "multi_precision": True}, kvstore="tpu_dist")
+    step = gluon.TrainStep(net, None, trainer, n_data=1)
+    compiled = _compiled_step(step, one_chip,
+                              mx.np.zeros((2, 8192), dtype="int32"))
+    text = compiled.as_text()
+    assert "f64[" not in text
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and " custom-call(" in l]
+    scans = [l for l in calls if "kda_scan" in l]
+    assert len(scans) == 3 and all("kda.scan" in l for l in scans)
+    assert len([l for l in scans if "kda_scan_bwd" in l]) == 1
+    flash = [l for l in calls if "flash_attention" in l]
+    assert len(flash) == 2 and all("/mla/" in l for l in flash)
+    assert len([l for l in calls if "mla_heads_" in l]) == 6
+    assert "mla.rope" not in text and "mla.heads" in text
+    assert fallbacks == {k: c.value for k, c in
+                         ti.attention_kernel_fallback_total.series()}
+    assert ti._mla_heads_sites == [1, 1]
+    assert ti._kda_scan_calls == {"kernel": 1, "composition": 0}
+    assert {k: g.value for k, g in ti.decoder_layers.series()} == {
+        ("kda", "dense"): 1, ("mla", "moe"): 1}
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10 * 2 ** 30
+    pa._plan.cache_clear()
+    pk._shared.cache_clear()
+    ti.decoder_layers.clear()
+
+
 # -- the whole step of a conv + BatchNorm net: XLA alone, and no f64 --------
 
 

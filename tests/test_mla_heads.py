@@ -257,3 +257,88 @@ def test_the_frontend_op_is_taped(mode):
     want = _value_and_grads(mh.mla_heads, operands, pos, 2, True, weights)
     for a, w in zip(arrays, want[3:]):
         onp.testing.assert_allclose(a.grad.asnumpy(), w, atol=1e-5)
+
+
+# -- a layer that carries no positions (``positions=None``) -------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads,s_len,nope,v,tile", [
+    (32, 16, 128, 128, None),     # the delta / latent hybrid's heads
+    (2, 40, 128, 128, 16),        # the last tile hangs over
+    (4, 16, 128, 256, None),      # values wider than the keys'
+], ids=["h32", "edge", "v256"])
+def test_without_positions_the_kernels_only_assemble(mode, monkeypatch, dtype,
+                                                     heads, s_len, nope, v,
+                                                     tile):
+    """q, k, v and the three gradients against the composition without
+    rotation, lane for lane (nothing turns, so no lane moves): the shared
+    key part reaches every head as it is, and its gradient is the sum over
+    the heads."""
+    if tile:
+        monkeypatch.setattr(mh, "_MAX_ROWS", tile)
+    operands = _operands(2, s_len, heads, nope, v, dtype)
+    weights = _weights(2, s_len, heads, nope, v)
+    mode(True)
+    got = _value_and_grads(mh.mla_heads, operands, None, heads, True,
+                           weights)
+    assert ti.mla_heads_kernel_share.value == 1.0
+    want = _value_and_grads(mh._composition, operands, None, heads, True,
+                            weights)
+    names = ["q", "k", "v", "dq", "dkv", "dk_rope"]
+    for name, g, w in zip(names, got, want):
+        assert g.shape == w.shape and g.dtype == operands[0].dtype, name
+        tol = 1e-6 if dtype == "float32" \
+            else 2.0 ** (-6 if name == "dk_rope" else -9)
+        g, w = onp.asarray(g, "f"), onp.asarray(w, "f")
+        onp.testing.assert_allclose(g / onp.abs(w).max(),
+                                    w / onp.abs(w).max(), atol=tol,
+                                    err_msg=name)
+    # what the composition without positions is: the projections' own
+    # numbers, the shared part under every head
+    q, kv, k_rope = operands
+    b = q.shape[0]
+    onp.testing.assert_array_equal(
+        onp.asarray(want[0], "f"),
+        onp.asarray(q.reshape(b, s_len, heads, -1).transpose(0, 2, 1, 3),
+                    "f"))
+    for h in (0, heads - 1):
+        onp.testing.assert_array_equal(onp.asarray(want[1][:, h, :, nope:],
+                                                   "f"),
+                                       onp.asarray(k_rope, "f"))
+
+
+def _pallas_operands(jaxpr):
+    """Operand counts of every pallas_call under ``jaxpr``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(len(eqn.invars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_operands(sub)
+    return found
+
+
+def test_without_positions_no_table_is_among_the_kernels_operands(mode):
+    """The rotation is compiled out: the kernels take q alone and kv,
+    k_rope alone, where a rotating layer's take two tables more."""
+    mode(True)
+    operands = _operands(1, 16, 2, 128, 128, "float32")
+
+    def kernels(positions):
+        return sorted(_pallas_operands(jax.make_jaxpr(
+            lambda *o: mh.mla_heads(*o, positions, THETA, 2, True))(
+                *operands).jaxpr))
+
+    assert kernels(None) == [1, 2]
+    assert kernels(jnp.arange(16)) == [3, 4]
+
+
+def test_the_frontend_op_takes_none_for_the_positions(mode):
+    mode(None)
+    q, kv, k_rope = (NDArray(o) for o in _operands(1, 8, 2, 16, 16,
+                                                   "float32", rope=8))
+    out = npx.mla_heads(q, kv, k_rope, None, THETA, 2, True)
+    want = mh._composition(q._data, kv._data, k_rope._data, None, THETA, 2,
+                           True)
+    for o, w in zip(out, want):
+        onp.testing.assert_array_equal(o.asnumpy(), onp.asarray(w))
